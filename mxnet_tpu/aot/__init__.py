@@ -7,10 +7,10 @@ serving its first token — even though sharded checkpointing already
 makes the *state* side of recovery fast.  This package is the compile
 side of that story, in three layers that compose but work alone:
 
-- :mod:`cache` — jax's persistent compilation cache wired behind
-  ``MXTPU_COMPILE_CACHE=<dir>`` (auto-enabled at import): XLA compiles
-  become disk reads across processes.  Eviction policy, version
-  namespacing, ``mxtpu_compile_cache_{hits,misses,puts}`` counters.
+- :mod:`cache` — jax's persistent compilation cache, enabled at import
+  in ``JAX_COMPILATION_CACHE_DIR`` (else ``<checkout>/.jax_cache``; off
+  for a CPU-pinned process): XLA compiles become disk reads across
+  processes.  ``mxtpu_compile_cache_{hits,misses,puts}`` counters.
 - :mod:`export_store` — serialized ``jax.export`` executables behind
   ``MXTPU_AOT_DIR=<dir>``: Python trace+lower of the serve engine's
   bucketed programs and the fused train step becomes a file
@@ -40,6 +40,6 @@ __all__ = ["cache", "export_store", "warmup", "CompileCacheManager",
 
 def enable_from_env():
     """Apply the env-var wiring (called from ``mxnet_tpu/__init__``):
-    ``MXTPU_COMPILE_CACHE`` enables the persistent compile cache.  The
+    the persistent compile cache (:func:`cache.enable_from_env`).  The
     export store and manifests resolve their env vars lazily at use."""
     return cache.enable_from_env()

@@ -1,34 +1,32 @@
-"""Persistent XLA compile-cache manager.
+"""Persistent XLA compile-cache wiring.
 
-JAX already ships a persistent compilation cache (compiled executables
-keyed by HLO module + compile options + jax version + backend
-fingerprint, written as ``<name>-<key>-cache`` files with ``-atime``
-companions for LRU accounting).  What it does NOT ship is an opinionated
-wiring for a serving framework: it is off by default, its 1-second
-minimum-compile-time threshold skips exactly the many-small-programs
-workload a bucketed serve engine produces, and nothing manages the
-directory's growth across deploys.
+JAX ships a persistent compilation cache (compiled executables keyed by
+HLO module + compile options + jax version + backend fingerprint,
+written as ``<name>-<key>-cache`` files).  It is off by default and its
+1-second minimum-compile-time threshold skips exactly the
+many-small-programs workload a bucketed serve engine produces.
 
-:class:`CompileCacheManager` owns that policy behind one env var::
+Where the cache lives is decided OUTSIDE the program:
 
-  MXTPU_COMPILE_CACHE=/var/cache/mxtpu   # auto-enabled at import
+- ``JAX_COMPILATION_CACHE_DIR=<dir>`` set: jax itself applies it and no
+  code here touches ``jax_compilation_cache_dir`` — the cache is exactly
+  ``<dir>``, no sub-directory appended.  The cache key covers the jax
+  version and the backend, so one directory serves any mix of both;
+  size limits are jax's own (``JAX_COMPILATION_CACHE_MAX_SIZE``).
+- unset: ``<checkout>/.jax_cache`` — a fixed path next to the package
+  (the path is part of what makes a later process hit), never a temp
+  dir, a uid, a pid or a time.
+- unset and the process is pinned to the CPU (``JAX_PLATFORMS=cpu``, the
+  test suite): no persistent cache.  Cached XLA:CPU executables are
+  tied to the host's CPU features and buy a test run nothing.
 
-- every program is cached (min-compile-time 0 by default — bucket
-  programs are individually small but collectively the whole cold
-  start);
-- entries land under a ``jax-<version>/`` subdirectory, so a jax
-  upgrade starts a fresh namespace and :meth:`prune` can drop the stale
-  one wholesale (the backend fingerprint is already inside jax's own
-  cache key — two backends share a subdirectory without collisions);
-- byte-size eviction is delegated to jax's own LRU file cache
-  (``MXTPU_COMPILE_CACHE_MAX_BYTES``); entry-count eviction
-  (``MXTPU_COMPILE_CACHE_MAX_ENTRIES``) is enforced here by pruning
-  oldest-access-first, covering jax builds without size limits;
-- cache traffic is visible as ``mxtpu_compile_cache_{hits,misses,puts}``
-  counters (fed by the ``jax.monitoring`` bridge in
-  ``telemetry/jaxmon.py``) and :meth:`snapshot_to` writes a
-  ``metrics.jsonl``-shaped line that ``tools/metrics_report.py``
-  renders directly.
+:class:`CompileCacheManager` adds what jax does not: every program is
+cached (min-compile-time 0 — bucket programs are individually small but
+collectively the whole cold start), cache traffic is visible as
+``mxtpu_compile_cache_{hits,misses,puts}`` counters (fed by the
+``jax.monitoring`` bridge in ``telemetry/jaxmon.py``) and on /statusz,
+and :meth:`snapshot_to` writes a ``metrics.jsonl``-shaped line that
+``tools/metrics_report.py`` renders directly.
 """
 
 from __future__ import annotations
@@ -37,124 +35,78 @@ import json
 import os
 import time
 
-from ..base import env_int
+__all__ = ["CompileCacheManager", "enable_from_env", "active", "ENV_DIR",
+           "DEFAULT_DIR"]
 
-__all__ = ["CompileCacheManager", "enable", "enable_from_env", "active",
-           "ENV_DIR", "ENV_MAX_BYTES", "ENV_MAX_ENTRIES", "ENV_MIN_SECS"]
-
-ENV_DIR = "MXTPU_COMPILE_CACHE"
-ENV_MAX_BYTES = "MXTPU_COMPILE_CACHE_MAX_BYTES"
-ENV_MAX_ENTRIES = "MXTPU_COMPILE_CACHE_MAX_ENTRIES"
-ENV_MIN_SECS = "MXTPU_COMPILE_CACHE_MIN_COMPILE_SECS"
+ENV_DIR = "JAX_COMPILATION_CACHE_DIR"
+DEFAULT_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
 _active = None
 
 
 def active():
-    """The process-wide manager installed by :func:`enable`, or None."""
+    """The process-wide manager installed by :func:`enable_from_env`,
+    or None."""
     return _active
 
 
 class CompileCacheManager:
-    """Wires and polices jax's persistent compilation cache.
+    """Reports on, and tunes, the persistent cache jax resolved.
 
-    Construction only records the policy; :meth:`enable` applies it to
-    the jax config (idempotent, safe before or after backend init).
+    :meth:`enable` adopts ``JAX_COMPILATION_CACHE_DIR`` when the
+    environment sets it and points jax at :data:`DEFAULT_DIR` otherwise
+    (idempotent, safe before or after backend init).
     """
 
-    def __init__(self, dir, max_bytes=-1, max_entries=0,
-                 min_compile_secs=0.0):
-        import jax
-
-        self.base_dir = str(dir)
-        # jax's own key covers backend + compile options; the version
-        # subdir exists so prune() can retire a whole stale namespace
-        self.dir = os.path.join(self.base_dir, f"jax-{jax.__version__}")
-        self.max_bytes = int(max_bytes)      # -1 = unlimited
-        self.max_entries = int(max_entries)  # 0  = unlimited
-        self.min_compile_secs = float(min_compile_secs)
+    def __init__(self):
+        self.dir = None
         self.enabled = False
 
     # -- wiring ------------------------------------------------------------
     def enable(self):
-        """Point jax's persistent cache at the managed directory."""
         import jax
+        from jax.experimental.compilation_cache import compilation_cache
 
+        if not os.environ.get(ENV_DIR):
+            jax.config.update("jax_compilation_cache_dir", DEFAULT_DIR)
+        self.dir = jax.config.jax_compilation_cache_dir
         os.makedirs(self.dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", self.dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          self.min_compile_secs)
-        try:
-            jax.config.update("jax_persistent_cache_min_entry_size_bytes",
-                              -1)
-        except AttributeError:
-            pass                       # knob absent on this jax: fine
-        if self.max_bytes:
-            try:
-                jax.config.update("jax_compilation_cache_max_size",
-                                  self.max_bytes)
-            except AttributeError:
-                pass                   # byte eviction then rides prune()
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
         # jax memoizes its cache-enabled decision at the FIRST compile
         # of the task; enabling after any jit has run (an embedding
         # process, a test suite) would silently never cache without
         # this reset
-        try:
-            from jax.experimental.compilation_cache import \
-                compilation_cache as _jax_cc
-
-            _jax_cc.reset_cache()
-        except (ImportError, AttributeError):
-            pass                       # nothing compiled yet: no memo
+        compilation_cache.reset_cache()
         self.enabled = True
-        self.prune()
-        # live introspection: /statusz shows cache geometry, on-disk
-        # occupancy and the hit/miss/put traffic counters.  A strong
-        # ref is deliberate: the active manager is a process singleton
-        # (enable() replaces _active AND, via the fixed name here, the
-        # provider) — there is no retire-without-replacement path
+        # live introspection: /statusz shows the cache directory,
+        # on-disk occupancy and the hit/miss/put traffic counters.  A
+        # strong ref is deliberate: the active manager is a process
+        # singleton — there is no retire-without-replacement path
         from ..telemetry import statusz
 
         statusz.register("aot.compile_cache", self.statusz)
         return self
 
     # -- inspection --------------------------------------------------------
-    def _entries(self):
-        """[(cache_path, atime, bytes)] oldest-access first.  jax writes
-        ``-atime`` companion files; fall back to the filesystem mtime
-        when one is missing."""
+    def stats(self):
+        """On-disk occupancy of the cache directory."""
+        entries = size = 0
         try:
             names = os.listdir(self.dir)
         except OSError:
-            return []
-        out = []
+            names = []
         for n in names:
             if not n.endswith("-cache"):
                 continue
-            path = os.path.join(self.dir, n)
             try:
-                size = os.path.getsize(path)
-                stamp = os.path.getmtime(path)
+                size += os.path.getsize(os.path.join(self.dir, n))
+                entries += 1
             except OSError:
                 continue               # raced with jax's own eviction
-            atime_file = os.path.join(self.dir, n[:-len("-cache")]
-                                      + "-atime")
-            try:
-                raw = open(atime_file, "rb").read(8)
-                if len(raw) == 8:      # u64 nanoseconds since epoch
-                    stamp = int.from_bytes(raw, "little") / 1e9
-            except OSError:
-                pass
-            out.append((path, stamp, size))
-        out.sort(key=lambda t: t[1])
-        return out
-
-    def stats(self):
-        entries = self._entries()
-        return {"dir": self.dir, "entries": len(entries),
-                "bytes": sum(s for _, _, s in entries),
-                "max_bytes": self.max_bytes,
-                "max_entries": self.max_entries}
+        return {"dir": self.dir, "entries": entries, "bytes": size}
 
     def statusz(self):
         """/statusz provider: on-disk stats plus the
@@ -173,88 +125,6 @@ class CompileCacheManager:
             out[short] = reg.counter(f"mxtpu_compile_cache_{short}",
                                      help).labels().value
         return out
-
-    # how long an unused sibling jax-version namespace survives: a
-    # rolling deploy / rollback window keeps BOTH versions' caches warm
-    # (their files keep getting touched); only a namespace nothing has
-    # written or read for this long is truly retired
-    STALE_NAMESPACE_DAYS = 14
-
-    # -- eviction ----------------------------------------------------------
-    def prune(self):
-        """Evict oldest-access-first down to the entry/byte budgets and
-        drop ``jax-*`` version namespaces idle for
-        :data:`STALE_NAMESPACE_DAYS`.  Returns the number of entries
-        removed."""
-        removed = 0
-        # mxtpu-lint: disable=wall-clock (compared against filesystem
-        # atimes, which are wall-clock by definition)
-        cutoff = time.time() - self.STALE_NAMESPACE_DAYS * 86400
-        try:
-            for n in os.listdir(self.base_dir):
-                p = os.path.join(self.base_dir, n)
-                if (n.startswith("jax-") and os.path.isdir(p)
-                        and p != self.dir
-                        and self._newest_mtime(p) < cutoff):
-                    removed += self._drop_tree(p)
-        except OSError:
-            pass
-        entries = self._entries()
-        total = sum(s for _, _, s in entries)
-        over_count = (len(entries) - self.max_entries
-                      if self.max_entries else 0)
-        for path, _, size in entries:
-            over_bytes = self.max_bytes > 0 and total > self.max_bytes
-            if over_count <= 0 and not over_bytes:
-                break
-            for victim in (path, path[:-len("-cache")] + "-atime"):
-                try:
-                    os.remove(victim)
-                except OSError:
-                    pass
-            total -= size
-            over_count -= 1
-            removed += 1
-        return removed
-
-    @staticmethod
-    def _newest_mtime(path):
-        """Most recent mtime under ``path`` (the dir itself counts —
-        an empty namespace still ages out)."""
-        newest = 0.0
-        try:
-            newest = os.path.getmtime(path)
-            for root, _, files in os.walk(path):
-                for f in files:
-                    try:
-                        newest = max(newest, os.path.getmtime(
-                            os.path.join(root, f)))
-                    except OSError:
-                        pass
-        except OSError:
-            pass
-        return newest
-
-    @staticmethod
-    def _drop_tree(path):
-        removed = 0
-        for root, dirs, files in os.walk(path, topdown=False):
-            for f in files:
-                try:
-                    os.remove(os.path.join(root, f))
-                    removed += 1
-                except OSError:
-                    pass
-            for d in dirs:
-                try:
-                    os.rmdir(os.path.join(root, d))
-                except OSError:
-                    pass
-        try:
-            os.rmdir(path)
-        except OSError:
-            pass
-        return removed
 
     # -- telemetry snapshot ------------------------------------------------
     def snapshot_to(self, path=None):
@@ -289,25 +159,16 @@ class CompileCacheManager:
         return path
 
 
-def enable(dir, **kw):
-    """Install and enable a process-wide manager (idempotent per dir)."""
-    global _active
-    if _active is not None and _active.base_dir == str(dir):
-        return _active
-    _active = CompileCacheManager(dir, **kw).enable()
-    return _active
-
-
 def enable_from_env():
-    """``MXTPU_COMPILE_CACHE=<dir>`` auto-enable hook (package import).
-    Returns the manager, or None when the env var is unset."""
-    d = os.environ.get(ENV_DIR)
-    if not d:
+    """The import-time hook (``mxnet_tpu/__init__``): install the
+    process-wide manager unless the process is pinned to the CPU with
+    no cache directory given.  Reads configuration only — initialises
+    no backend.  Returns the manager, or None when the cache stays off."""
+    global _active
+    import jax
+
+    if not os.environ.get(ENV_DIR) and jax.config.jax_platforms == "cpu":
         return None
-    min_secs = os.environ.get(ENV_MIN_SECS)
-    return enable(
-        d,
-        max_bytes=env_int(ENV_MAX_BYTES, -1),
-        max_entries=env_int(ENV_MAX_ENTRIES, 0),
-        min_compile_secs=float(min_secs) if min_secs else 0.0,
-    )
+    if _active is None:
+        _active = CompileCacheManager().enable()
+    return _active

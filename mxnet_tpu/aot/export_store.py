@@ -29,7 +29,8 @@ import hashlib
 import json
 import os
 
-from .. import jax_compat
+import jax
+
 from .. import telemetry
 
 __all__ = ["ExportStore", "fingerprint", "digest", "default_store",
@@ -46,8 +47,6 @@ def fingerprint(**fields):
     plus format/jax-version/backend.  Everything must be JSON-stable —
     tuples arrive as lists, which is fine as long as producers and
     consumers build the dict the same way (they share this helper)."""
-    import jax
-
     fp = {"format": FORMAT, "jax_version": jax.__version__,
           "backend": jax.default_backend()}
     fp.update(fields)
@@ -87,7 +86,7 @@ class ExportStore:
         Returns the path, or None when serialization is unavailable
         (saving is an optimization — never a hard failure)."""
         try:
-            blob = jax_compat.serialize_exported(exported)
+            blob = exported.serialize()
         except Exception:
             _counter("mxtpu_aot_errors_total",
                      "AOT artifact failures").labels(kind="serialize").inc()
@@ -140,7 +139,7 @@ class ExportStore:
                 _counter("mxtpu_aot_errors_total",
                          "AOT artifact failures").labels(kind="stale").inc()
                 return None
-            exported = jax_compat.deserialize_exported(raw[header_end:])
+            exported = jax.export.deserialize(raw[header_end:])
         except Exception:
             _counter("mxtpu_aot_errors_total",
                      "AOT artifact failures").labels(kind="corrupt").inc()
@@ -172,6 +171,4 @@ def default_store():
     d = os.environ.get(ENV_DIR)
     if not d:
         return None
-    if jax_compat.jax_export() is None:
-        return None                    # this jax cannot round-trip
     return ExportStore(d)

@@ -139,52 +139,51 @@ def gpt_prefill_flops(n_layers, d_model, num_heads, head_dim, kv_heads,
     return total + n_logits * head
 
 
-# bf16 peak FLOP/s per chip by device_kind substring (public figures)
-_PEAKS = [
-    ("v6e", 918e12), ("v6", 918e12),
-    ("v5p", 459e12), ("v5 lite", 197e12), ("v5e", 197e12), ("v5", 459e12),
-    ("v4", 275e12),
-    ("v3", 123e12),
-    ("v2", 45e12),
-]
+# Published per-chip peaks keyed by the exact ``device_kind`` jax reports:
+# (bf16 FLOP/s, HBM bytes/s).  Source: Google Cloud TPU documentation,
+# the "System architecture" page of each generation (v5e: 197 TFLOP/s
+# bf16, 819 GB/s).  One table, exact keys: a TPU that is not listed is
+# an error, never a neighbouring generation's peak.
+_TPU_PEAKS = {
+    "TPU v2": (45e12, 700e9),
+    "TPU v3": (123e12, 900e9),
+    "TPU v4": (275e12, 1228e9),
+    "TPU v5 lite": (197e12, 819e9),
+    "TPU v5e": (197e12, 819e9),
+    "TPU v5": (459e12, 2765e9),
+    "TPU v5p": (459e12, 2765e9),
+    "TPU v6 lite": (918e12, 1640e9),
+    "TPU v6e": (918e12, 1640e9),
+}
+
+
+def _tpu_peaks(device):
+    """The ``_TPU_PEAKS`` row for ``device`` (default: the first local
+    device): None off-TPU, ValueError for a TPU the table does not list."""
+    import jax
+
+    d = device or jax.devices()[0]
+    if d.platform != "tpu":
+        return None
+    try:
+        return _TPU_PEAKS[d.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks recorded for TPU device_kind "
+            f"{d.device_kind!r}; add its row to flops._TPU_PEAKS with "
+            "its source") from None
 
 
 def peak_flops_per_chip(device=None):
-    """Peak bf16 FLOP/s for the local accelerator, or None if unknown."""
-    import jax
-
-    d = device or jax.devices()[0]
-    kind = getattr(d, "device_kind", "").lower()
-    if d.platform != "tpu":
-        return None
-    for tag, peak in _PEAKS:
-        if tag in kind:
-            return peak
-    return None
-
-
-# peak HBM bandwidth (bytes/s) per chip by device_kind substring
-# (public figures) — the MBU denominator
-_HBM_PEAKS = [
-    ("v6e", 1640e9), ("v6", 1640e9),
-    ("v5p", 2765e9), ("v5 lite", 819e9), ("v5e", 819e9), ("v5", 2765e9),
-    ("v4", 1228e9),
-    ("v3", 900e9),
-    ("v2", 700e9),
-]
+    """Peak bf16 FLOP/s of the local accelerator: the MFU denominator.
+    None off-TPU; raises for a TPU kind the table does not list."""
+    row = _tpu_peaks(device)
+    return None if row is None else row[0]
 
 
 def peak_hbm_bytes_per_chip(device=None):
-    """Peak HBM bandwidth (bytes/s) for the local accelerator, or None
-    if unknown — memory-bandwidth-utilization's denominator, the
-    figure decode (bandwidth-bound) is judged against."""
-    import jax
-
-    d = device or jax.devices()[0]
-    kind = getattr(d, "device_kind", "").lower()
-    if d.platform != "tpu":
-        return None
-    for tag, peak in _HBM_PEAKS:
-        if tag in kind:
-            return peak
-    return None
+    """Peak HBM bandwidth (bytes/s) of the local accelerator: the MBU
+    denominator, the figure bandwidth-bound decode is judged against.
+    None off-TPU; raises for a TPU kind the table does not list."""
+    row = _tpu_peaks(device)
+    return None if row is None else row[1]

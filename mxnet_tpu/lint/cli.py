@@ -2,8 +2,7 @@
 
 Exit codes: 0 clean (all findings baselined or none), 1 new findings
 or parse errors, 2 usage errors.  ``--json`` emits one machine-readable
-document (the bench_watch ``lint`` stage consumes it to trend finding
-counts per checker).
+document (finding counts per checker).
 """
 
 from __future__ import annotations

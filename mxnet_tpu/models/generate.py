@@ -24,6 +24,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ..ops.attention import score_scale
+
 __all__ = ["gpt_generate", "gpt_decode_config", "normalize_gpt_params",
            "detect_gpt_variant", "reconcile_decode_config"]
 
@@ -49,7 +51,9 @@ def _fc(x, w, b):
 
 def _gelu(x):
     xf = x.astype(jnp.float32)
-    return (0.5 * xf * (1.0 + jax.lax.erf(xf / np.sqrt(2.0)))).astype(x.dtype)
+    # np.float32: a NumPy float64 scalar would promote the erf to f64
+    return (0.5 * xf * (1.0 + jax.lax.erf(xf / np.float32(np.sqrt(2.0))))
+            ).astype(x.dtype)
 
 
 def gpt_decode_config(symbol):
@@ -319,7 +323,7 @@ def _build_decoder(name, n_layers, num_heads, head_dim, B, P,
             # grouped-query: kv head g serves q heads [g*group, ...)
             qg = qh.reshape(B, kv_heads, group, head_dim)
             scores = jnp.einsum("bkgd,bksd->bkgs", qg, cache_k[i])
-            scores = scores / np.sqrt(head_dim)
+            scores = scores * score_scale(head_dim)
             scores = jnp.where(pos_mask[None, None, None, :], scores,
                                -jnp.inf)
             probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)

@@ -37,7 +37,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .. import jax_compat
 from ..aot import export_store as aot_store
 from ..base import MXNetError, env_flag
 from ..lint.annotations import hot_path
@@ -199,7 +198,7 @@ class FusedTrainStep:
         exported = store.load(fp, label="fused-step")
         if exported is None:
             try:
-                exported = jax_compat.export_fn(self._program, *specs)
+                exported = jax.export.export(self._program)(*specs)
             except Exception:
                 return                 # unexportable: keep the plain jit
             store.save(fp, exported, label="fused-step")

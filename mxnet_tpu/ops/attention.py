@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import contextlib
 import contextvars
+import logging
 import os
 
 import jax
@@ -18,7 +19,16 @@ import numpy as np
 
 from ..lint.annotations import hot_path
 from ..param import Params, field
+from . import pallas_util
 from .op import OpDef, register_op, register_simple_op
+
+
+def score_scale(head_dim):
+    """1/sqrt(head_dim) as an f32 scalar.  ``np.sqrt`` returns a NumPy
+    float64, which is not weak-typed: under ``jax_enable_x64`` it
+    promotes the whole score tensor to f64, which a TPU emulates."""
+    return np.float32(1.0 / np.sqrt(head_dim))
+
 
 # Ambient SPMD context for the fused-attention op: Mosaic kernels cannot
 # be auto-partitioned by GSPMD, so when a FlashAttention op runs inside
@@ -128,7 +138,8 @@ register_simple_op(
     "gelu",
     lambda x: (0.5 * x.astype(jnp.float32)
                * (1.0 + jax.lax.erf(x.astype(jnp.float32)
-                                    / np.sqrt(2.0)))).astype(x.dtype),
+                                    / np.float32(np.sqrt(2.0))))
+               ).astype(x.dtype),
     nin=1)
 
 # f32-activation convention like gelu: bf16 models must compute the
@@ -194,7 +205,7 @@ class FlashAttentionOp(OpDef):
 
     def forward(self, params, inputs, aux, train, key):
         q, k, v = inputs
-        from .flash_attention import _on_tpu, flash_attention
+        from .flash_attention import flash_attention
 
         spmd = _SPMD_ATTN.get()
         mesh = batch_ax = None
@@ -235,9 +246,16 @@ class FlashAttentionOp(OpDef):
         seq_axis = 1 if params.layout == "bshd" else 2
         S = q.shape[seq_axis]
         from .flash_attention import flash_eligible
-        use_flash = params.impl == "flash" or (
-            params.impl == "auto" and _on_tpu()
-            and flash_eligible(S, S, params.block_q, params.block_k))
+        use_flash = params.impl == "flash"
+        if params.impl == "auto" and pallas_util.on_tpu():
+            use_flash = flash_eligible(S, S, params.block_q, params.block_k)
+            if not use_flash:
+                # trace-time only: say which implementation a TPU program
+                # got when the geometry declines the kernel
+                logging.getLogger(__name__).warning(
+                    "FlashAttention impl=auto resolved to dense XLA on a "
+                    "TPU: seq_len %d admits no Mosaic-scale block <= "
+                    "(%d, %d)", S, params.block_q, params.block_k)
         if use_flash:
             # wrap only when the BATCH axis is actually sharded: a
             # dp=1 x tp=N mesh must not funnel tp-sharded activations
@@ -252,8 +270,6 @@ class FlashAttentionOp(OpDef):
                 # Mosaic custom call on its own)
                 from jax.sharding import PartitionSpec
 
-                from ..jax_compat import shard_map
-
                 spec = PartitionSpec(batch_ax, *([None] * (q.ndim - 1)))
 
                 def _local(q_s, k_s, v_s):
@@ -264,9 +280,10 @@ class FlashAttentionOp(OpDef):
                                            layout=params.layout,
                                            window=params.window)
 
-                out = shard_map(_local, mesh=mesh,
-                                in_specs=(spec, spec, spec),
-                                out_specs=spec, check_vma=False)(q, k, v)
+                out = jax.shard_map(_local, mesh=mesh,
+                                    in_specs=(spec, spec, spec),
+                                    out_specs=spec,
+                                    check_vma=False)(q, k, v)
                 return [out], []
             out = flash_attention(q, k, v, causal=params.causal,
                                   block_q=params.block_q,
@@ -274,7 +291,7 @@ class FlashAttentionOp(OpDef):
                                   layout=params.layout,
                                   window=params.window)
             return [out], []
-        scale = 1.0 / np.sqrt(q.shape[-1])
+        scale = score_scale(q.shape[-1])
         h_ax = 2 if params.layout == "bshd" else 1
         if k.shape[h_ax] != q.shape[h_ax]:
             # grouped-query attention through the dense path: expand K/V
@@ -333,8 +350,7 @@ def resolve_paged_impl(block_size, head_dim, impl=None):
                          f"(got {impl!r})")
     if impl == "jnp":
         return "jnp"
-    from .flash_attention import _on_tpu
-    if impl == "pallas" or (_on_tpu()
+    if impl == "pallas" or (pallas_util.on_tpu()
                             and paged_eligible(block_size, head_dim)):
         return "pallas"
     return "jnp"
@@ -343,7 +359,7 @@ def resolve_paged_impl(block_size, head_dim, impl=None):
 @hot_path
 def paged_attention(q, k_cache, v_cache, block_tables, context_lens,
                     window=0, scale=None, k_scale=None, v_scale=None,
-                    impl=None):
+                    impl=None, mesh=None, head_axis=None):
     """Single-token decode attention over a paged KV-cache.
 
     The serving engine (``mxnet_tpu/serve``) keeps one fixed
@@ -382,6 +398,13 @@ def paged_attention(q, k_cache, v_cache, block_tables, context_lens,
         ``int8 * scale``.  Pass both or neither.
       impl: "auto" (kernel on TPU), "pallas", or "jnp"; default the
         ``MXTPU_PAGED_ATTENTION`` env var, else "auto".
+      mesh/head_axis: the device mesh of the enclosing sharded jit and
+        the mesh axis the q/kv HEAD dimension is split over (None =
+        replicated).  GSPMD cannot partition a Mosaic custom call, so
+        under a mesh the kernel runs per head shard inside a
+        ``shard_map`` (heads are independent; a contiguous split keeps
+        every q-head group with its kv head).  The jnp formulation is
+        plain XLA and partitions on its own.
 
     Returns (B, Hq, Dh) attention output in q's dtype.
     """
@@ -400,11 +423,26 @@ def paged_attention(q, k_cache, v_cache, block_tables, context_lens,
         # kernel (or jax.experimental.pallas itself) misbehaves, so it
         # must not require the Pallas modules to import
         from .pallas_paged_attention import paged_attention_kernel
-        return paged_attention_kernel(
-            q, k_cache, v_cache, block_tables, context_lens,
-            window=window, scale=scale, k_scale=k_scale,
-            v_scale=v_scale)
-    scale = scale if scale is not None else 1.0 / np.sqrt(Dh)
+
+        def kernel(q, k_cache, v_cache, block_tables, context_lens,
+                   *scales):
+            ks, vs = scales or (None, None)
+            return paged_attention_kernel(
+                q, k_cache, v_cache, block_tables, context_lens,
+                window=window, scale=scale, k_scale=ks, v_scale=vs)
+
+        scales = () if k_scale is None else (k_scale, v_scale)
+        args = (q, k_cache, v_cache, block_tables, context_lens) + scales
+        if mesh is None:
+            return kernel(*args)
+        from jax.sharding import PartitionSpec as P
+
+        q_spec = P(None, head_axis, None)
+        specs = ((q_spec,) + (P(None, None, head_axis, None),) * 2
+                 + (P(), P()) + (P(None, None, head_axis),) * len(scales))
+        return jax.shard_map(kernel, mesh=mesh, in_specs=specs,
+                             out_specs=q_spec, check_vma=False)(*args)
+    scale = score_scale(Dh) if scale is None else np.float32(scale)
     S = block_tables.shape[1] * bs
     # (B, W, bs, Hkv, Dh) -> (B, S, Hkv, Dh): each row's logical view
     k = k_cache[block_tables].reshape(B, S, Hkv, Dh)

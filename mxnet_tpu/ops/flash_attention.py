@@ -35,6 +35,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import pallas_util
 from .pallas_util import idx32
 
 __all__ = ["flash_attention", "flash_eligible", "gqa_group"]
@@ -46,13 +47,6 @@ __all__ = ["flash_attention", "flash_eligible", "gqa_group"]
 _NEG_INF = np.float32(-1e30)
 _ZERO = np.float32(0.0)
 _TINY = np.float32(1e-30)
-
-
-def _on_tpu():
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
 
 
 def _fit_block(S, target):
@@ -394,12 +388,7 @@ def _params(interpret):
     (ARBITRARY).  Unsupported by the interpreter backend."""
     if interpret:
         return {}
-    # renamed upstream: TPUCompilerParams (older jax) -> CompilerParams;
-    # the string spellings parse in both generations, where the
-    # pltpu.PARALLEL/ARBITRARY constants only exist in the newer one
-    cp = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams")
-    return {"compiler_params": cp(
+    return {"compiler_params": pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"))}
 
 
@@ -694,7 +683,7 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=512,
     if scale is None:
         scale = float(1.0 / np.sqrt(D))
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = not pallas_util.on_tpu()
     bq, bk = _block_sizes(Sq, Sk, block_q, block_k)
     bq, bk = _fit_vmem(bq, bk, Sq, Sk, D,
                        H if layout == "bshd" else None,

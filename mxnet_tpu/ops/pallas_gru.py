@@ -27,9 +27,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import pallas_util
+from .pallas_lstm import fused_lstm_eligible
 from .pallas_util import idx32
-
-from .pallas_lstm import _on_tpu, fused_lstm_eligible
 
 __all__ = ["fused_gru", "fused_gru_eligible"]
 
@@ -244,7 +244,7 @@ def fused_gru(gx, h0, wh, bh, interpret=None):
     Returns ``(ys, hT)``; differentiable w.r.t. all four arrays.
     """
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = not pallas_util.on_tpu()
     T, N, G = gx.shape
     H = G // 3
     if wh.shape != (G, H):

@@ -36,16 +36,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import pallas_util
 from .pallas_util import idx32
 
 __all__ = ["fused_lstm", "fused_lstm_eligible"]
-
-
-def _on_tpu():
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
 
 
 def _sigmoid(x):
@@ -299,7 +293,7 @@ def fused_lstm_eligible(T, N, H, force=None):
     if env == "0":
         return False
     forced = bool(force) or env == "1"
-    on_tpu = _on_tpu()
+    on_tpu = pallas_util.on_tpu()
     if on_tpu:
         if H % 128 or N % 8:
             return False
@@ -333,7 +327,7 @@ def fused_lstm(gx, h0, c0, wh, bh, interpret=None):
     Gate order i, f, g, o matches ops/rnn.py's scan cell.
     """
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = not pallas_util.on_tpu()
     T, N, G = gx.shape
     H = G // 4
     if wh.shape != (G, H):

@@ -42,8 +42,9 @@ from jax.experimental.pallas import tpu as pltpu
 from ..lint.annotations import hot_path
 # the single eligibility definition lives with the dispatcher (which
 # must be importable without Pallas); re-exported here for the tests
-from .attention import paged_eligible  # noqa: F401
-from .flash_attention import _on_tpu, gqa_group
+from . import pallas_util
+from .attention import paged_eligible, score_scale  # noqa: F401
+from .flash_attention import gqa_group
 from .pallas_util import idx32
 
 __all__ = ["paged_attention_kernel", "paged_eligible"]
@@ -133,9 +134,7 @@ def _params(interpret):
     carries the running-softmax scratch and must stay sequential."""
     if interpret:
         return {}
-    cp = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams")
-    return {"compiler_params": cp(
+    return {"compiler_params": pltpu.CompilerParams(
         dimension_semantics=("parallel", "arbitrary"))}
 
 
@@ -163,9 +162,9 @@ def paged_attention_kernel(q, k_cache, v_cache, block_tables,
                          "given together (quantized K/V blocks carry "
                          "both)")
     quant = k_scale is not None
-    scale = np.float32(scale if scale is not None else 1.0 / np.sqrt(Dh))
+    scale = score_scale(Dh) if scale is None else np.float32(scale)
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = not pallas_util.on_tpu()
     W = block_tables.shape[1]
     q4 = q.reshape(B, Hkv, group, Dh)
 

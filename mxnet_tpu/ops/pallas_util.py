@@ -4,9 +4,19 @@ from __future__ import annotations
 
 import functools
 
+import jax
 import jax.numpy as jnp
 
-__all__ = ["idx32"]
+__all__ = ["idx32", "on_tpu"]
+
+
+def on_tpu():
+    """Whether the default backend is a TPU: what every kernel's
+    ``interpret=None`` default and every ``impl="auto"`` dispatcher
+    consults.  Call sites look it up through the module
+    (``pallas_util.on_tpu()``), so the TPU-lowering gates that run on
+    CPU patch this one name and reach every kernel."""
+    return jax.default_backend() == "tpu"
 
 
 def idx32(fn):

@@ -30,7 +30,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from ..jax_compat import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 __all__ = ["moe_apply", "moe_reference", "MoELayer", "init_moe_params"]
@@ -138,7 +137,7 @@ def _build_moe_run(mesh: Mesh, axis: str, k: int, E: int, C: int, expert_fn,
     def run(gate_w, experts, x):
         exp_spec = jax.tree_util.tree_map(lambda _: PartitionSpec(axis),
                                           experts)
-        return shard_map(
+        return jax.shard_map(
             shard_fn, mesh=mesh,
             in_specs=(gate_spec, exp_spec, tok_spec),
             out_specs=(tok_spec, PartitionSpec()),

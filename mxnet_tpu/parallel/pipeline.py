@@ -20,7 +20,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from ..jax_compat import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 __all__ = ["pipeline_apply", "PipelineModule"]
@@ -96,9 +95,10 @@ def _build_pipeline_run(stage_fn, mesh: Mesh, axis: str, param_specs=None,
                                           stacked_params)
         else:
             spec = p_spec
-        return shard_map(shard_fn, mesh=mesh, in_specs=(spec, feed_spec),
-                         out_specs=out_spec, check_vma=False)(stacked_params,
-                                                              feed)
+        return jax.shard_map(shard_fn, mesh=mesh,
+                             in_specs=(spec, feed_spec),
+                             out_specs=out_spec,
+                             check_vma=False)(stacked_params, feed)
 
     _RUN_CACHE[key] = run
     return run

@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from ..jax_compat import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 __all__ = ["ring_attention", "attention_reference"]
@@ -185,7 +184,7 @@ def _build_ring_run(mesh: Mesh, axis: str, scale: float, causal: bool,
             return _ring_body(q_s, k_s, v_s, axis, n_shards, scale, causal,
                               idx, window=window, n_steps=n_steps)
 
-        return shard_map(
+        return jax.shard_map(
             shard_fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
             check_vma=False)(q, k, v)
 
@@ -259,7 +258,7 @@ def ring_attention(q, k, v, mesh: Mesh, axis: str = "sp", causal=False,
     over (combined dp x sp data+sequence parallelism); each dp
     replica's sp group runs an independent ring.
     """
-    from ..ops.flash_attention import _on_tpu
+    from ..ops import pallas_util
 
     if layout not in ("bhsd", "bshd"):
         raise ValueError(f"layout must be 'bhsd' or 'bshd', got {layout!r}")
@@ -267,7 +266,7 @@ def ring_attention(q, k, v, mesh: Mesh, axis: str = "sp", causal=False,
     scale = float(1.0 / np.sqrt(q.shape[-1]))
     n_shards = mesh.shape[axis]
     S_blk = q.shape[seq_axis] // n_shards
-    interpret = not _on_tpu()
+    interpret = not pallas_util.on_tpu()
     if impl == "auto":
         from ..ops.flash_attention import flash_eligible
         fits = flash_eligible(S_blk, S_blk, block_q, block_k)

@@ -541,7 +541,10 @@ class ShardedTrainer:
                     f"{sorted(seq_axes)}; pass seq_axis= to name the "
                     "sequence-parallel axis explicitly")
             self._attn_seq_axis = seq_axes.pop() if seq_axes else None
-        self._key = _random.next_key()
+        # placed like every later key (the step returns it replicated): an
+        # uncommitted first key gives step 2 a different input sharding
+        # than step 1, and jit compiles the whole train step a second time
+        self._key = jax.device_put(_random.next_key(), self._replicated)
         # telemetry handles (no-op objects when disabled).  step time is
         # HOST time around the jitted call — dispatch cost when XLA runs
         # async, the full device step when the result is consumed

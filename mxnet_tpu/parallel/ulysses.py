@@ -31,7 +31,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from ..jax_compat import shard_map
 from jax.sharding import Mesh, NamedSharding
 
 __all__ = ["ulysses_attention"]
@@ -104,7 +103,7 @@ def _build_ulysses_run(mesh: Mesh, axis: str, scale: float, causal: bool,
             return lax.all_to_all(oh, axis, split_axis=seq_ax,
                                   concat_axis=head_ax, tiled=True)
 
-        return shard_map(
+        return jax.shard_map(
             shard_fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
             check_vma=False)(q, k, v)
 
@@ -127,7 +126,7 @@ def ulysses_attention(q, k, v, mesh: Mesh, axis: str = "sp", causal=False,
     impl: "flash" = fused Pallas kernel per head group; "xla" = dense
     softmax attention; "auto" picks flash on TPU when shapes fit.
     """
-    from ..ops.flash_attention import _on_tpu
+    from ..ops import pallas_util
     from .ring_attention import _flash_available, _ring_spec
 
     if layout not in ("bhsd", "bshd"):
@@ -156,7 +155,7 @@ def ulysses_attention(q, k, v, mesh: Mesh, axis: str = "sp", causal=False,
                          f"(got {window})")
     scale = float(1.0 / np.sqrt(q.shape[-1]))
     S = q.shape[seq_axis]
-    interpret = not _on_tpu()
+    interpret = not pallas_util.on_tpu()
     if impl == "auto":
         from ..ops.flash_attention import flash_eligible
         fits = flash_eligible(S, S, block_q, block_k)

@@ -262,7 +262,6 @@ def export_model(path, symbol, arg_params, aux_params, input_shapes,
     import jax
 
     from .executor import _CompiledGraph
-    from .jax_compat import export_fn
 
     graph = _CompiledGraph(symbol)
     arg_names = symbol.list_arguments()
@@ -300,7 +299,8 @@ def export_model(path, symbol, arg_params, aux_params, input_shapes,
     param_spec = {k: jax.ShapeDtypeStruct(v.shape, v.dtype)
                   for k, v in params_np.items()}
     kw = {"platforms": list(platforms)} if platforms else {}
-    exported = export_fn(jax.jit(infer_fn), data_spec, param_spec, **kw)
+    exported = jax.export.export(jax.jit(infer_fn), **kw)(
+        data_spec, param_spec)
     manifest = {
         "format": "mxnet_tpu.exported_model.v1",
         "data_names": data_names,
@@ -328,11 +328,12 @@ class ExportedPredictor:
     time — the graph is already compiled to StableHLO."""
 
     def __init__(self, path):
-        from .jax_compat import deserialize_exported
+        import jax
 
         with zipfile.ZipFile(path) as zf:
             self.manifest = json.loads(zf.read(_MANIFEST))
-            self._exported = deserialize_exported(zf.read(_STABLEHLO))
+            self._exported = jax.export.deserialize(
+                zf.read(_STABLEHLO))
             from .ndarray import _decode_bf16
 
             with np.load(io.BytesIO(zf.read(_PARAMS))) as pz:

@@ -55,7 +55,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from .. import jax_compat, telemetry
+from .. import telemetry
 from ..aot import export_store as aot_store
 from ..aot import warmup as aot_warmup
 from ..base import env_flag, env_int
@@ -65,7 +65,7 @@ from ..models.generate import (_fc, _gelu, _ln, detect_gpt_variant,
                                reconcile_decode_config)
 from ..parallel import partition as partition_mod
 from ..parallel.mesh import NamedSharding, PartitionSpec, make_mesh
-from ..ops.attention import paged_attention
+from ..ops.attention import paged_attention, score_scale
 from ..telemetry import flight as flight_mod
 from ..telemetry import profiling
 from ..telemetry import statusz as statusz_mod
@@ -1258,6 +1258,10 @@ class Engine:
                               "written": self._rtrace.written,
                               "path": self._rtrace.path},
             "numeric_watch": self._numeric_watch,
+            # which paged-attention implementation the decode programs
+            # trace ("pallas" | "jnp"): impl="auto" declining the kernel
+            # for this cache geometry must be visible, not silent
+            "paged_attention": self._paged_impl(),
             "aot": aot,
         }
 
@@ -2408,7 +2412,9 @@ class Engine:
         Every path eagerly compiles (``.lower(specs).compile()``): on
         the hot path the compile was due this very step anyway, and
         eagerness is what makes ``warmup()`` mean "ready" rather than
-        "will compile at the first unlucky request"."""
+        "will compile at the first unlucky request".  A compile failure
+        (a kernel Mosaic refuses, a compile-time OOM) raises here — a
+        program that cannot compile is not ready."""
         specs = self._program_specs(kind, bucket)
 
         def build():
@@ -2418,10 +2424,7 @@ class Engine:
             return self._program_builder(kind, bucket)
 
         def compiled(jitted):
-            try:
-                return jitted.lower(*specs).compile()
-            except Exception:
-                return jitted          # lazy compile on first call
+            return jitted.lower(*specs).compile()
 
         if self._aot is None:
             return compiled(build())
@@ -2429,17 +2432,7 @@ class Engine:
         label = f"serve-{kind}{bucket}"
         exported = self._aot.load(fp, label=label)
         if exported is None:
-            jitted = build()
-            try:
-                exported = jax_compat.export_fn(jitted, *specs)
-            except Exception:
-                # this jax cannot export: fall back to the plain jit,
-                # but count it — a fleet silently serving unexportable
-                # programs loses its warm-restart story
-                telemetry.counter(
-                    "mxtpu_aot_errors_total", "AOT artifact failures",
-                    ("kind",)).labels(kind="export").inc()
-                return compiled(jitted)
+            exported = jax.export.export(build())(*specs)
             self._aot.save(fp, exported, label=label)
         else:
             telemetry.counter(
@@ -2714,12 +2707,15 @@ def _logits(cfg, params, x):
 
 
 def _forward_token_batch(cfg, params, ck, cv, ksc, vsc, toks, pos, tables,
-                         adp=None, slots=None):
+                         adp=None, slots=None, shardings=None):
     """Shared decode math: write each row's K/V at its position,
     attend through the block tables, return logits (B, V).  With
     ``cfg.kv_quant`` the caches are int8 and ``ksc``/``vsc`` carry the
     per-slot-per-head f32 scales (None otherwise): writes quantize,
-    attention dequantizes through the same tables."""
+    attention dequantizes through the same tables.  ``shardings`` (the
+    program's tp placement bundle) tells ``paged_attention`` the mesh
+    and the axis the cache's head dimension is split over, so the
+    Mosaic kernel runs per head shard."""
     name = cfg.name
     Hq, Hkv, Dh = cfg.num_heads, cfg.kv_heads, cfg.head_dim
     d_model = Hq * Dh
@@ -2731,6 +2727,12 @@ def _forward_token_batch(cfg, params, ck, cv, ksc, vsc, toks, pos, tables,
                               axis=1)[:, 0]
     off = pos % cfg.block_size
     ctx = pos + 1
+    paged_kw = {}
+    if shardings is not None:
+        cache_spec = shardings.cache.spec       # (L, nb, bs, Hkv, Dh)
+        paged_kw = {"mesh": shardings.mesh,
+                    "head_axis": (cache_spec[3] if len(cache_spec) > 3
+                                  else None)}
     for i in range(cfg.n_layers):
         p = f"{name}_l{i}"
         h = _ln(x, params[f"{p}_ln1_gamma"],
@@ -2752,12 +2754,13 @@ def _forward_token_batch(cfg, params, ck, cv, ksc, vsc, toks, pos, tables,
             vsc = vsc.at[i, blk, off].set(vs)
             attn = paged_attention(qh, ck[i], cv[i], tables, ctx,
                                    window=cfg.window,
-                                   k_scale=ksc[i], v_scale=vsc[i])
+                                   k_scale=ksc[i], v_scale=vsc[i],
+                                   **paged_kw)
         else:
             ck = ck.at[i, blk, off].set(kh)
             cv = cv.at[i, blk, off].set(vh)
             attn = paged_attention(qh, ck[i], cv[i], tables, ctx,
-                                   window=cfg.window)
+                                   window=cfg.window, **paged_kw)
         x = x + _awfc(cfg, params, adp, f"{p}_proj",
                       attn.reshape(B, d_model), slots)
         x = x + _mlp(cfg, params, p, x, adp=adp, slots=slots)
@@ -2840,7 +2843,7 @@ def _build_decode(cfg, donate, shardings=None):
             rng, = tail
         logits, ck, cv, ksc, vsc = _forward_token_batch(
             cfg, params, ck, cv, ksc, vsc, toks, pos, tables,
-            adp=adp, slots=slots)
+            adp=adp, slots=slots, shardings=shardings)
         if cfg.sampling:
             tok = _sample_ops(cfg, logits, rng, temp, topp, topk)
             lead = (tok,) + _logprob_outs(logits, tok)
@@ -2923,7 +2926,7 @@ def _build_prefill(cfg, P, donate, shardings=None):
             # prompt (same head grouping as paged_attention)
             qg = qh.reshape(P, Hkv, group, Dh)
             sc = jnp.einsum("qkgd,skd->kgqs", qg, kh)
-            sc = sc / np.sqrt(Dh)
+            sc = sc * score_scale(Dh)
             sc = jnp.where(keep[None, None], sc,
                            jnp.asarray(-jnp.inf, sc.dtype))
             pr = jax.nn.softmax(sc.astype(jnp.float32),
@@ -3059,7 +3062,7 @@ def _build_chunk(cfg, C, donate, shardings=None):
                                  x.dtype)
             qg = qh.reshape(C, Hkv, group, Dh)
             sc = jnp.einsum("ckgd,skd->kgcs", qg, kb)
-            sc = sc / np.sqrt(Dh)
+            sc = sc * score_scale(Dh)
             sc = jnp.where(keep[None, None], sc,
                            jnp.asarray(-jnp.inf, sc.dtype))
             pr = jax.nn.softmax(sc.astype(jnp.float32),

@@ -61,14 +61,13 @@ from __future__ import annotations
 import collections
 import threading
 
-import numpy as np
-
 import jax
 import jax.numpy as jnp
 
 from ..base import env_float
 from ..models.generate import (detect_gpt_variant, normalize_gpt_params,
                                reconcile_decode_config)
+from ..ops.attention import score_scale
 from ..telemetry import flight as flight_mod
 
 __all__ = ["DraftWorker", "ENV_SPEC", "ENV_MIN_ACCEPT"]
@@ -359,7 +358,8 @@ def _build_draft(cfg, k, donate, shardings=None, sample_cfg=None):
             # the verify-side emit cap drops
             tbl = jnp.where((pos + j < S)[:, None], tables, 0)
             logits, ck, cv, _, _ = _forward_token_batch(
-                cfg, params, ck, cv, None, None, cur, pos + j, tbl)
+                cfg, params, ck, cv, None, None, cur, pos + j, tbl,
+                shardings=shardings)
             if j < k:
                 cur = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                 outs.append(cur)
@@ -374,7 +374,8 @@ def _build_draft(cfg, k, donate, shardings=None, sample_cfg=None):
         for j in range(k + 1):
             tbl = jnp.where((pos + j < S)[:, None], tables, 0)
             logits, ck, cv, _, _ = _forward_token_batch(
-                cfg, params, ck, cv, None, None, cur, pos + j, tbl)
+                cfg, params, ck, cv, None, None, cur, pos + j, tbl,
+                shardings=shardings)
             if j < k:
                 # sample the proposal from the warped draft
                 # distribution and keep that EXACT distribution —
@@ -445,7 +446,7 @@ def _build_verify(cfg, k, donate, shardings=None):
     d_model = Hq * Dh
     window = cfg.window
     K1 = k + 1
-    scale = 1.0 / np.sqrt(Dh)
+    scale = score_scale(Dh)
 
     def verify(params, *rest):
         """``rows`` (B, K1) int32 token ids; ``pos0`` (B,) the cache

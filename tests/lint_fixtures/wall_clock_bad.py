@@ -11,6 +11,6 @@ def check_speed(run, N):
 
 
 def watch_deadline(hours):
-    # pre-fix tools/bench_watch.py: a deadline on the wall clock moves
+    # a deadline on the wall clock moves
     # when NTP does
     return time.time() + 3600 * hours

@@ -125,7 +125,7 @@ def test_compress_end_to_end_and_cli(tmp_path):
     prefix = str(tmp_path / "m")
     model.save(prefix, 1)
     out_prefix = str(tmp_path / "m-acc")
-    env = dict(os.environ, MXTPU_PLATFORMS="cpu")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     r = subprocess.run(
         [sys.executable, os.path.join(os.path.dirname(accnn.__file__),
                                       "accnn.py"),

@@ -49,19 +49,29 @@ def tel():
 
 
 @pytest.fixture
-def compile_cache(tmp_path):
-    """Persistent compile cache in a per-test dir; jax config restored
-    afterwards so later tests never write into a deleted tmp dir."""
+def compile_cache(tmp_path, monkeypatch):
+    """Persistent compile cache in a per-test dir, placed the way a
+    deployment places it — ``JAX_COMPILATION_CACHE_DIR`` (jax reads the
+    variable at import, so the fixture applies it to the live config
+    too); jax config restored afterwards so later tests never write
+    into a deleted tmp dir."""
     import jax
     from jax.experimental.compilation_cache import compilation_cache
 
     prev = jax.config.jax_compilation_cache_dir
     prev_min = jax.config.jax_persistent_cache_min_compile_time_secs
-    mgr = aot.cache.CompileCacheManager(str(tmp_path / "cc")).enable()
+    prev_size = jax.config.jax_persistent_cache_min_entry_size_bytes
+    cache_dir = str(tmp_path / "cc")
+    monkeypatch.setenv(aot.cache.ENV_DIR, cache_dir)
+    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    mgr = aot.cache.CompileCacheManager().enable()
+    assert mgr.dir == cache_dir        # adopted verbatim, no sub-directory
     yield mgr
     jax.config.update("jax_compilation_cache_dir", prev)
     jax.config.update("jax_persistent_cache_min_compile_time_secs",
                       prev_min)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes",
+                      prev_size)
     # drop the memoized cache object: it still points at this test's
     # (deleted) tmp dir and jax would otherwise keep using it
     compilation_cache.reset_cache()
@@ -121,7 +131,7 @@ def _prompts(rng=None):
 
 # -- compile-cache manager ---------------------------------------------------
 def test_cache_manager_wires_jax_and_counts(tel, compile_cache):
-    """MXTPU_COMPILE_CACHE wiring: a fresh jit of an already-compiled
+    """Compile-cache wiring: a fresh jit of an already-compiled
     module is served from disk, visible as hit/miss/put counters and
     on-disk entries; the snapshot line is metrics_report-loadable."""
     import jax
@@ -157,39 +167,19 @@ def test_cache_manager_wires_jax_and_counts(tel, compile_cache):
     assert "mxtpu_compile_cache_hits" in metrics
 
 
-def test_cache_manager_eviction_policy(tmp_path):
-    """Entry-count eviction drops oldest-access first; a stale jax
-    version namespace is pruned wholesale."""
-    mgr = aot.cache.CompileCacheManager(str(tmp_path), max_entries=2)
-    os.makedirs(mgr.dir, exist_ok=True)
-    for i in range(4):
-        with open(os.path.join(mgr.dir, f"jit_f{i}-k{i}-cache"), "wb") as f:
-            f.write(b"x" * 10)
-        with open(os.path.join(mgr.dir, f"jit_f{i}-k{i}-atime"), "wb") as f:
-            f.write(int((1000 + i) * 1e9).to_bytes(8, "little"))
-    # a sibling version namespace is dropped only once IDLE long enough
-    # (a mixed-version fleet mid-rollout keeps both caches warm)
-    fresh = os.path.join(str(tmp_path), "jax-9.9.9")
-    os.makedirs(fresh)
-    with open(os.path.join(fresh, "jit_live-k-cache"), "wb") as f:
-        f.write(b"y")
-    stale = os.path.join(str(tmp_path), "jax-0.0.1")
-    os.makedirs(stale)
-    with open(os.path.join(stale, "jit_old-k-cache"), "wb") as f:
-        f.write(b"y")
-    old = 100.0   # epoch 1970: long past any staleness threshold
-    os.utime(os.path.join(stale, "jit_old-k-cache"), (old, old))
-    os.utime(stale, (old, old))
-    removed = mgr.prune()
-    assert removed >= 3              # 2 evictions + the stale namespace
-    left = sorted(n for n in os.listdir(mgr.dir) if n.endswith("-cache"))
-    assert left == ["jit_f2-k2-cache", "jit_f3-k3-cache"]  # newest kept
-    assert not os.path.exists(stale)
-    assert os.path.exists(fresh)      # recently-touched namespace kept
-    # byte budget: everything over 10 bytes goes, oldest first
-    mgr2 = aot.cache.CompileCacheManager(str(tmp_path), max_bytes=10)
-    assert mgr2.prune() >= 1
-    assert len(mgr2._entries()) == 1
+def test_cache_placed_from_outside(monkeypatch):
+    """Where the cache lives is decided outside the program: with
+    ``JAX_COMPILATION_CACHE_DIR`` set nothing here touches jax's
+    directory; unset, the cache is ``<checkout>/.jax_cache`` — and a
+    CPU-pinned process (this suite) gets no persistent cache at all."""
+    import jax
+
+    assert aot.cache.DEFAULT_DIR == os.path.join(REPO, ".jax_cache")
+    monkeypatch.delenv(aot.cache.ENV_DIR, raising=False)
+    monkeypatch.setattr(aot.cache, "_active", None)
+    assert jax.config.jax_platforms == "cpu"
+    assert aot.cache.enable_from_env() is None
+    assert aot.cache.active() is None
 
 
 # -- export store ------------------------------------------------------------
@@ -204,10 +194,8 @@ def test_export_store_roundtrip_stale_and_corrupt(tel, tmp_path):
     def g(x):
         return jnp.tanh(x @ x)
 
-    from mxnet_tpu import jax_compat
-
     spec = jax.ShapeDtypeStruct((8, 8), jnp.float32)
-    exported = jax_compat.export_fn(jax.jit(g), spec)
+    exported = jax.export.export(jax.jit(g))(spec)
     path = store.save(fp, exported)
     assert path and os.path.exists(path)
     loaded = store.load(fp)
@@ -378,6 +366,34 @@ def test_engine_warmup_grid_and_range_checks(tel, model):
     eng2 = _engine(model, max_batch=3, max_model_len=24)
     assert eng2.warmup() == 15
     eng2.shutdown()
+
+
+def test_engine_warmup_raises_on_compile_failure(tel, model, monkeypatch):
+    """A program that cannot be built or compiled is not "ready":
+    warmup() raises instead of deferring the failure to the first
+    request that needs the program."""
+    eng = _engine(model)
+
+    def broken(kind, bucket):
+        raise RuntimeError("Mosaic refused the kernel")
+
+    monkeypatch.setattr(eng, "_program_builder", broken)
+    with pytest.raises(RuntimeError, match="Mosaic refused"):
+        eng.warmup([{"kind": "decode", "bucket": 2}])
+    monkeypatch.undo()
+
+    def uncompilable(kind, bucket):
+        import jax
+
+        def f(*args):
+            raise ValueError("compile-time failure")
+
+        return jax.jit(f)
+
+    monkeypatch.setattr(eng, "_program_builder", uncompilable)
+    with pytest.raises(ValueError, match="compile-time failure"):
+        eng.warmup([{"kind": "decode", "bucket": 2}])
+    eng.shutdown()
 
 
 def test_engine_warmup_precompiles_without_aot_store(tel, model):

@@ -167,7 +167,7 @@ def test_cli_roundtrip(tmp_path):
     pt.write_text(PROTOTXT)
     cm.write_bytes(net)
     prefix = str(tmp_path / "imported")
-    env = dict(os.environ, MXTPU_PLATFORMS="cpu")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     tool = os.path.join(os.path.dirname(__file__), "..", "tools",
                         "caffe_converter.py")
     r = subprocess.run([sys.executable, tool, str(pt), str(cm), prefix],

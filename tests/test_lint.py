@@ -82,7 +82,7 @@ def test_repo_is_lint_clean():
 
 def test_cli_acceptance_invocation():
     """The acceptance-criteria command exits 0 and the JSON report is
-    machine-readable (bench_watch's lint stage consumes it)."""
+    machine-readable."""
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "tools", "mxtpu_lint.py"),
          "mxnet_tpu", "tools", "--json"],

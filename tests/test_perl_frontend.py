@@ -50,7 +50,7 @@ def perl_pkg(tmp_path_factory):
     env = dict(os.environ)
     env["MXTPU_HOME"] = REPO
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-    env.setdefault("MXTPU_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"
     for cmd in (["perl", "Makefile.PL"], ["make"]):
         r = subprocess.run(cmd, cwd=pkg, env=env, capture_output=True,
                            text=True)

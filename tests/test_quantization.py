@@ -255,7 +255,7 @@ def test_quantize_cli_tool(tmp_path):
         [_sys.executable, os.path.join(repo, "tools", "quantize.py"),
          "--prefix", prefix, "--epoch", "3", "--out", out],
         capture_output=True, text=True, timeout=300,
-        env={**os.environ, "MXTPU_PLATFORMS": "cpu"})
+        env={**os.environ, "JAX_PLATFORMS": "cpu"})
     assert r.returncode == 0, (r.stdout + "\n" + r.stderr)[-2000:]
     assert "quantized 2 layers" in r.stdout
     sym2, args2, aux2 = mx.model.load_checkpoint(out, 0)
@@ -305,7 +305,7 @@ def test_quantize_cli_calibrated_rec(tmp_path):
          "--calib-rec", rec_path, "--batch-size", "4",
          "--data-shape", "3,12,12", "--scale", str(1.0 / 255)],
         capture_output=True, text=True, timeout=300,
-        env={**os.environ, "MXTPU_PLATFORMS": "cpu"})
+        env={**os.environ, "JAX_PLATFORMS": "cpu"})
     assert r.returncode == 0, (r.stdout + "\n" + r.stderr)[-2000:]
     conf = json.loads(open(out + "-symbol.json").read())
     scales = [float(n["param"]["act_scale"]) for n in conf["nodes"]

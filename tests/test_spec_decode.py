@@ -627,8 +627,7 @@ def test_spec_fingerprint_keys_k_and_draft(model):
 # -- bench contract (slow) ---------------------------------------------------
 @pytest.mark.slow
 def test_spec_bench_contract(tmp_path):
-    """tools/serve_bench.py --workload spec (the SPEC_BENCH.json
-    bench_watch stage) emits the speculative A/B record on CPU smoke
+    """tools/serve_bench.py --workload spec (SPEC_BENCH.json) emits the speculative A/B record on CPU smoke
     shapes: byte-identical tokens, a measured (non-vacuous) acceptance
     rate, and the complete:true contract the serve_spec stage gates."""
     import subprocess

@@ -187,8 +187,8 @@ def test_compare_baseline_table(tmp_path):
     (tmp_path / "IO_BENCH.json").write_text(json.dumps({
         "metric": "image_pipeline_throughput", "value": 539.5,
         "vs_baseline_per_core": 2.158, "host_cores": 1}))
-    # bench_watch writes these artifacts as INDENTED multi-line JSON —
-    # the loader must accept that format, not just one-liners
+    # artifacts may be INDENTED multi-line JSON — the loader must
+    # accept that format, not just one-liners
     (tmp_path / "QUANT_BENCH.json").write_text(json.dumps({
         "metric": "resnet50_int8_inference", "platform": "tpu",
         "int8_img_per_sec": 5200.0, "int8_speedup": 1.9}, indent=1))

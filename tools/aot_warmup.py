@@ -4,13 +4,14 @@
 A deploy can pay the trace+compile bill on a build machine instead of
 in the serving fleet's critical restart path: point this tool at the
 checkpoint and the warmup manifest your production traffic recorded
-(``MXTPU_WARMUP_MANIFEST``), ship the resulting ``--aot-dir`` (and
-``--compile-cache`` dir) with the release, and every engine that boots
-against them loads executables instead of tracing.
+(``MXTPU_WARMUP_MANIFEST``), ship the resulting ``--aot-dir`` (and the
+``JAX_COMPILATION_CACHE_DIR`` the bake ran under) with the release, and
+every engine that boots against them loads executables instead of
+tracing.
 
   # bake everything a traffic manifest lists (plus the compile cache)
+  JAX_COMPILATION_CACHE_DIR=/release/xla_cache \\
   python tools/aot_warmup.py --aot-dir /release/aot \\
-      --compile-cache /release/xla_cache \\
       --checkpoint ckpt/gpt 12 --num-heads 16 \\
       --manifest /var/log/mxtpu_manifest.jsonl
 
@@ -40,8 +41,6 @@ def main():
     p = argparse.ArgumentParser()
     p.add_argument("--aot-dir", required=True,
                    help="export-store directory to populate")
-    p.add_argument("--compile-cache", default=None,
-                   help="also populate this persistent XLA compile cache")
     p.add_argument("--manifest", default=None,
                    help="warmup manifest JSONL (default: full bucket grid)")
     p.add_argument("--checkpoint", nargs=2, metavar=("PREFIX", "EPOCH"),
@@ -62,9 +61,7 @@ def main():
     args = p.parse_args()
 
     if args.platform:
-        os.environ["MXTPU_PLATFORMS"] = args.platform
-    if args.compile_cache:
-        os.environ["MXTPU_COMPILE_CACHE"] = args.compile_cache
+        os.environ["JAX_PLATFORMS"] = args.platform
 
     import numpy as np
 

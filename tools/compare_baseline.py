@@ -22,9 +22,9 @@ import os
 
 
 def _load(path):
-    """Read one artifact: whole-file JSON (bench_watch writes indented
-    multi-line payloads) or, failing that, the last line of an
-    append-style .jsonl log."""
+    """Read one artifact: whole-file JSON (possibly indented over many
+    lines) or, failing that, the last line of an append-style .jsonl
+    log."""
     try:
         with open(path) as f:
             text = f.read()
@@ -101,27 +101,17 @@ def rows_from(repo):
     return rows
 
 
-def _latest_map():
-    """metric -> LATEST artifact filename, imported from bench.py (the
-    single source of truth) with a frozen fallback for standalone use."""
-    import sys
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    if repo not in sys.path:
-        sys.path.insert(0, repo)
-    try:
-        from bench import LATEST_ARTIFACTS
-        return LATEST_ARTIFACTS
-    except Exception:
-        return {"resnet50_train_throughput": "BENCH_TPU_LATEST.json",
-                "gpt_train_throughput": "BENCH_GPT_LATEST.json",
-                "cifar_inception_bn_small_train_throughput":
-                    "BENCH_CIFAR_LATEST.json"}
+# metric -> the artifact holding its CURRENT measurement
+LATEST_ARTIFACTS = {
+    "resnet50_train_throughput": "BENCH_TPU_LATEST.json",
+    "gpt_train_throughput": "BENCH_GPT_LATEST.json",
+    "cifar_inception_bn_small_train_throughput": "BENCH_CIFAR_LATEST.json",
+}
 
 
 def _tpu_records(rec, metric):
     """Every TPU measurement of ``metric`` reachable from one artifact
-    payload: the record itself, its embedded best_tpu_record (CPU
-    fallback lines carry the best prior hardware number), and sweep
+    payload: the record itself, an embedded best_tpu_record, and sweep
     result lists."""
     if not isinstance(rec, dict):
         return
@@ -147,7 +137,7 @@ def check(repo, threshold):
     failures = []
     history = sorted(glob.glob(os.path.join(repo, "BENCH_r*.json"))) + [
         os.path.join(repo, "BENCH_SWEEP.json")]
-    for metric, latest_name in _latest_map().items():
+    for metric, latest_name in LATEST_ARTIFACTS.items():
         cur_rec = _load(os.path.join(repo, latest_name))
         if not cur_rec or cur_rec.get("platform") != "tpu":
             continue                    # nothing current to gate

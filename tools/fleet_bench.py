@@ -12,7 +12,7 @@ seed), one ``fleet.Supervisor`` (crash restarts), one ``fleet.Router``
   phase 2  drain-based rolling restart of ALL replicas under light
            load.
 
-Recorded (FLEET_BENCH.json, the bench_watch ``fleet`` stage):
+Recorded (FLEET_BENCH.json):
 
   availability            completed / submitted over phase 1 (the
                           headline: 1.0 means the kill was invisible)
@@ -32,7 +32,7 @@ N single-host processes cannot share one TPU client, and the
 property under test (fault-transparent routing) is backend-agnostic.
 
 ``--disagg`` runs the disaggregated prefill/decode A/B instead
-(DISAGG_BENCH.json, the bench_watch ``fleet_disagg`` stage): a
+(DISAGG_BENCH.json): a
 1-prefill + N-decode role-split fleet vs an equal-size role="both"
 fleet, same seeded workload — steady decode streams with long prompts
 injected mid-run.  Per-replica request traces yield the headline:
@@ -46,7 +46,7 @@ handoff wire bytes, dedup hits (content keys the receivers already
 cached), availability, and token identity between the two arms.
 
 ``--obs`` runs the fleet-observability A/B instead
-(FLEET_OBS_BENCH.json, the bench_watch ``fleet_obs`` stage): the same
+(FLEET_OBS_BENCH.json): the same
 seeded workload through (arm A) a plain fleet and (arm B) a fleet with
 the full observability plane live — FleetCollector scraping every
 replica, terminal trace lines pushed to its ``/trace``, a lenient
@@ -59,7 +59,7 @@ pins that the burn-rate alert demonstrably FIRES and the flight dump
 lands on the offending replica.
 
 ``--workload autoscale`` runs the fleet control-plane smoke instead
-(AUTOSCALE_BENCH.json, the bench_watch ``fleet_autoscale`` stage): a
+(AUTOSCALE_BENCH.json): a
 role="both" process pool under a live ``fleet.Autoscaler``
 (``MXTPU_AUTOSCALE_SPEC`` grammar via ``--autoscale-spec``) and
 ``fleet.FleetCollector``.  Phase A steps the load up (open-loop burst
@@ -73,7 +73,7 @@ byte-identical on the canary set, while light load keeps flowing
 the kill and the drains).
 
 ``--workload cache-route`` runs the cache-aware-routing A/B
-(CACHE_ROUTE_BENCH.json, the bench_watch ``fleet_cache_route`` stage):
+(CACHE_ROUTE_BENCH.json):
 the same returning-users order (distinct multi-block prefix per user,
 shuffled arrivals) through (arm A) a least-loaded fleet with
 ``MXTPU_ROUTE_AFFINITY=0`` — the byte-inert baseline — and (arm B) the
@@ -106,10 +106,10 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
 # The orchestrating parent pins ITSELF to the cpu backend before the
-# package import: it must never claim the (single-client) TPU the
-# round driver owns just to spawn subprocesses — and the replica
-# children pin cpu explicitly anyway (N processes cannot share a chip).
-os.environ.setdefault("MXTPU_PLATFORMS", "cpu")
+# package import: a parent that holds the (single-client) chip starves
+# every child that needs it — and the replica children pin cpu
+# explicitly anyway (N processes cannot share a chip).
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 from mxnet_tpu.fleet import ProcessReplica, Router, Supervisor, \
     probe_health  # noqa: E402
@@ -1260,7 +1260,7 @@ def main():
     # startup INSIDE the try: a slot that fails wait_ready mid-start
     # must still tear down the replicas already spawned (sup.stop()
     # terminates every handle in the slots list) instead of orphaning
-    # them for the rest of the bench_watch window
+    # them
     try:
         sup.start()
         out["fleet_ready_s"] = round(time.perf_counter() - t_start, 3)

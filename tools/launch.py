@@ -11,7 +11,9 @@ program everywhere with those env vars set (`MXTPU_COORDINATOR`,
 mxnet_tpu.kvstore.create("dist_sync")).
 
 Modes:
-  local: spawn -n processes on this machine (CPU mesh testing)
+  local: spawn -n processes on this machine, each pinned to the CPU
+         backend (CPU mesh testing: N processes cannot share one
+         machine's chip, which belongs to a single process)
   ssh:   spawn one process per host in -H hostfile via ssh, rsyncing
          the working dir first (reference ssh tracker behavior)
 """
@@ -46,11 +48,15 @@ def _with_repo_path(env):
 
 
 def _child_env(coordinator, n, rank, extra=None):
+    """Environment of one LOCAL rank: the rendezvous variables, and the
+    CPU pin — a chip belongs to one process, so n local ranks that all
+    opened it would fail or hang."""
     env = dict(os.environ)
     env.update({
         "MXTPU_COORDINATOR": coordinator,
         "MXTPU_NUM_PROCS": str(n),
         "MXTPU_PROC_ID": str(rank),
+        "JAX_PLATFORMS": "cpu",
     })
     if extra:
         env.update(extra)

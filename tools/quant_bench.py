@@ -127,10 +127,9 @@ def main():
     result["weight_only_speedup"] = round(
         result["weight_only_img_per_sec"] / f, 3)
     result["int8_speedup"] = round(result["int8_img_per_sec"] / f, 3)
-    # explicit completeness contract: bench_watch's run_json_artifact
-    # trends the --json line, and a stamped complete=true marks this
-    # single-shot payload as a full capture (all three modes measured)
-    # rather than relying on the single-shot default
+    # explicit completeness contract: a stamped complete=true marks
+    # this single-shot payload as a full capture (all three modes
+    # measured)
     result["complete"] = True
     print(json.dumps(result))
     if args.json:

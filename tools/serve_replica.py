@@ -133,7 +133,7 @@ def main():
     args = p.parse_args()
 
     if args.platform:
-        os.environ["MXTPU_PLATFORMS"] = args.platform
+        os.environ["JAX_PLATFORMS"] = args.platform
 
     t0 = time.perf_counter()
     import mxnet_tpu as mx

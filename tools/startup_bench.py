@@ -6,7 +6,7 @@ traffic, restarting with and without persisted compile artifacts.
 Two child processes measure the same engine config against the same
 artifact directories:
 
-  cold   empty MXTPU_COMPILE_CACHE + MXTPU_AOT_DIR: every bucket
+  cold   empty JAX_COMPILATION_CACHE_DIR + MXTPU_AOT_DIR: every bucket
          program is traced, lowered, XLA-compiled — and written through
          to both stores on the way.
   warm   the directories the cold child just populated: programs
@@ -21,8 +21,7 @@ telemetry: ``mxtpu_aot_programs_total{source=trace}`` (fresh traces —
 0 on a healthy warm start) and the ``mxtpu_compile_cache_*`` counters.
 
 Emits the shared last-line-JSON + ``--json`` artifact contract
-(complete:true stamped before the final record); tools/bench_watch.py
-captures it as the STARTUP_BENCH.json stage.
+(complete:true stamped before the final record): STARTUP_BENCH.json.
 
 Usage: python tools/startup_bench.py [--backend cpu] [--json OUT]
        [--keep-dirs DIR]
@@ -113,10 +112,10 @@ def child(args):
 def run_child(mode, args, aot_dir, cache_dir):
     env = dict(os.environ)
     env.update({"MXTPU_AOT_DIR": aot_dir,
-                "MXTPU_COMPILE_CACHE": cache_dir})
+                "JAX_COMPILATION_CACHE_DIR": cache_dir})
     env.pop("MXTPU_WARMUP_MANIFEST", None)  # both modes warm the grid
     if args.platform:
-        env["MXTPU_PLATFORMS"] = args.platform
+        env["JAX_PLATFORMS"] = args.platform
     cmd = [sys.executable, os.path.abspath(__file__), "--child",
            "--layers", str(args.layers), "--d-model", str(args.d_model),
            "--heads", str(args.heads), "--vocab", str(args.vocab),
@@ -155,7 +154,7 @@ def main():
 
     if args.child:
         if args.platform:
-            os.environ["MXTPU_PLATFORMS"] = args.platform
+            os.environ["JAX_PLATFORMS"] = args.platform
         child(args)
         return
 

@@ -11,8 +11,7 @@ bound; on TPU the win comes additionally from donation (in-place param
 buffers) and uninterrupted device occupancy.
 
 Emits the shared last-line-JSON + ``--json`` artifact contract
-(complete:true stamped before the final record); tools/bench_watch.py
-captures it as the TRAIN_BENCH.json stage.
+(complete:true stamped before the final record): TRAIN_BENCH.json.
 
 Usage: python tools/train_bench.py [--backend cpu] [--json OUT]
 """
@@ -108,7 +107,7 @@ def main():
     args = p.parse_args()
 
     if args.platform:
-        os.environ["MXTPU_PLATFORMS"] = args.platform
+        os.environ["JAX_PLATFORMS"] = args.platform
 
     import numpy as np
 
