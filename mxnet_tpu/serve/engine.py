@@ -247,8 +247,8 @@ class Engine:
         within them — ``top_k`` values past the cap behave as the
         cap, and a nucleus needing more than ``cap`` candidates is
         truncated there (exact whenever cap >= vocab).
-      clock: injectable monotonic clock (tests drive deadlines with a
-        fake clock).
+      clock: the one clock of every request stamp, ``time.perf_counter``
+        like the spans and the step profiler (tests inject a fake one).
       aot_dir: exported-executable store for AOT restart
         (env ``MXTPU_AOT_DIR``; see mxnet_tpu/aot/).  When set, bucket
         programs are serialized on first build and restarted engines
@@ -338,7 +338,7 @@ class Engine:
                  max_batch=None, max_queue=None, max_model_len=None,
                  max_prefills_per_step=1, temperature=0.0, top_k=None,
                  top_p=None, sampling=None, sample_cap=None,
-                 seed=0, clock=time.monotonic, aot_dir=None, tp=None,
+                 seed=0, clock=time.perf_counter, aot_dir=None, tp=None,
                  partition_rules=None, tenant_share=None,
                  prefix_cache=None, prefill_chunk=None, spec_k=None,
                  draft_params=None, draft_num_heads=None,
@@ -987,6 +987,7 @@ class Engine:
 
     def _has_pending_fanout(self):
         with self._fanout_lock:
+            # mxtpu-lint: disable=host-sync (a host list, no device value)
             return bool(self._pending_fanout)
 
     def _release_fanout(self):
@@ -1034,80 +1035,85 @@ class Engine:
         # (the default) every t0() below returns None and no dispatch
         # gains a sync
         self._perf.arm(self._step_id)
-        # step decomposition: begin/lap/commit bracket the whole
-        # iteration; laps inside _run_prefill/_run_decode/_run_spec_
-        # decode split dispatch / device-wait / host bookkeeping (see
-        # telemetry/profiling.py for the phase map)
+        # the one step instrument (telemetry/profiling.py): begin/enter/
+        # commit tile the iteration into phases; with telemetry on the
+        # same intervals are the serve.step > serve.prefill | serve.decode
+        # > serve.<phase> spans, and note() hangs the counts on them
         sprof = self._sprof
         sprof.begin(self._step_id)
-        with telemetry.span("serve.step"):
-            self._release_fanout()
-            prefills, decodes = self.scheduler.schedule()
-            if self._host_pool is not None:
-                # host-tier hits allocated by this schedule() queue
-                # their restores; dispatch them NOW, before the first
-                # prefill/decode program that reads the blocks
-                self._restore_pending()
-            # blocks for this iteration are all held right now — the
-            # honest high-water sample (post-drain reads would be ~0)
-            self._stats.on_utilization(self.blocks.utilization())
-            sprof.lap("schedule")
-            emitted = 0
-            for req in prefills:
-                with telemetry.span("serve.prefill", rid=req.rid):
-                    # the per-iteration prefill token budget is shared
-                    # with the decode slots: each decode slot emits up
-                    # to 1 + spec_k tokens this step (one, without
-                    # speculative decoding), so a chunk shrinks by the
-                    # batch's worst-case token count
-                    emitted += self._run_prefill(
-                        req,
-                        decode_slots=len(decodes) * (1 + self.spec_k))
-            if decodes:
-                with telemetry.span("serve.decode", batch=len(decodes)):
-                    if self._spec is not None:
-                        emitted += self._run_spec_decode(decodes)
-                    else:
-                        emitted += self._run_decode(decodes)
-            if prefills or decodes:
-                # scheduler decisions ride the flight ring (bounded,
-                # always on) so post-mortems see the recent schedule;
-                # with the host tier live its occupancy rides along
-                # (off-path records stay byte-identical)
-                step_fields = dict(
-                    id=self._step_id, prefills=len(prefills),
-                    decodes=len(decodes),
-                    queue=self.scheduler.queue_depth,
-                    blocks_in_use=self.blocks.blocks_in_use)
-                if self._host_pool is not None:
-                    step_fields["host_kv_entries"] = len(self._host_pool)
-                    step_fields["host_kv_bytes"] = \
-                        self._host_pool.bytes_used
-                flight_mod.recorder().record("step", **step_fields)
-            if emitted == 0 and not prefills and not decodes:
-                self._noop_steps += 1
-                if self._noop_steps > 1000 and self.scheduler.has_work():
-                    raise RuntimeError(
-                        "scheduler stalled: work queued but 1000 consecutive "
-                        "steps scheduled nothing (cache/queue misconfigured?)")
-            else:
-                self._noop_steps = 0
-            self._stats.on_step(emitted, decode_batch=len(decodes))
-            self._perf.on_step(emitted)
+        self._release_fanout()
+        prefills, decodes = self.scheduler.schedule()
+        if self._host_pool is not None:
+            # host-tier hits allocated by this schedule() queue their
+            # restores; dispatch them NOW, before the first prefill/
+            # decode program that reads the blocks
+            self._restore_pending()
+        # blocks for this iteration are all held right now — the honest
+        # high-water sample (post-drain reads would be ~0)
+        self._stats.on_utilization(self.blocks.utilization())
+        if sprof.tracing:
+            sprof.note(queue=self.scheduler.queue_depth,
+                       running=len(self.scheduler.running))
+        emitted = 0
+        for req in prefills:
+            sprof.enter("prefill_dispatch", rid=req.rid)
+            # the per-iteration prefill token budget is shared with the
+            # decode slots: each decode slot emits up to 1 + spec_k tokens
+            # this step (one, without speculative decoding), so a chunk
+            # shrinks by the batch's worst-case token count
+            emitted += self._run_prefill(
+                req, decode_slots=len(decodes) * (1 + self.spec_k))
+        if decodes:
+            sprof.enter("decode_dispatch", batch=len(decodes),
+                        bucket=_next_bucket(len(decodes), self.max_batch))
             if self._spec is not None:
-                # bound the draft ingest ledger by the LIVE running
-                # set: a request that leaves the engine between decodes
-                # (preempted, then deadline-rejected or cancelled)
-                # never reaches the forget() in _run_spec_decode.  A
-                # pruned-then-resumed request simply re-ingests.
-                self._spec.prune({r.rid for r in self.scheduler.running})
-            self._tel_queue.set(self.scheduler.queue_depth)
-            self._tel_running.set(len(self.scheduler.running))
-            self._tel_blocks.set(self.blocks.blocks_in_use)
-            self._tel_block_util.set(self.blocks.utilization())
-            self._tel_preempt.set(self.scheduler.preemptions)
-            self._tel_evict.set(self.blocks.evictions)
-            self._tel_rejected.set(self.scheduler.rejections)
+                emitted += self._run_spec_decode(decodes)
+            else:
+                emitted += self._run_decode(decodes)
+        sprof.enter("callbacks")
+        if prefills or decodes:
+            # scheduler decisions ride the flight ring (bounded, always
+            # on) so post-mortems see the recent schedule; with the host
+            # tier live its occupancy rides along (off-path records stay
+            # byte-identical)
+            step_fields = dict(
+                id=self._step_id, prefills=len(prefills),
+                decodes=len(decodes), queue=self.scheduler.queue_depth,
+                blocks_in_use=self.blocks.blocks_in_use)
+            if self._host_pool is not None:
+                step_fields["host_kv_entries"] = len(self._host_pool)
+                step_fields["host_kv_bytes"] = self._host_pool.bytes_used
+            flight_mod.recorder().record("step", **step_fields)
+        if emitted == 0 and not prefills and not decodes:
+            self._noop_steps += 1
+            if self._noop_steps > 1000 and self.scheduler.has_work():
+                raise RuntimeError(
+                    "scheduler stalled: work queued but 1000 consecutive "
+                    "steps scheduled nothing (cache/queue misconfigured?)")
+        else:
+            self._noop_steps = 0
+        self._stats.on_step(emitted, decode_batch=len(decodes))
+        self._perf.on_step(emitted)
+        if self._spec is not None:
+            # bound the draft ingest ledger by the LIVE running set: a
+            # request that leaves the engine between decodes (preempted,
+            # then deadline-rejected or cancelled) never reaches the
+            # forget() in _run_spec_decode.  A pruned-then-resumed
+            # request simply re-ingests.
+            self._spec.prune({r.rid for r in self.scheduler.running})
+        self._tel_queue.set(self.scheduler.queue_depth)
+        self._tel_running.set(len(self.scheduler.running))
+        self._tel_blocks.set(self.blocks.blocks_in_use)
+        self._tel_block_util.set(self.blocks.utilization())
+        self._tel_preempt.set(self.scheduler.preemptions)
+        self._tel_evict.set(self.blocks.evictions)
+        self._tel_rejected.set(self.scheduler.rejections)
+        if sprof.tracing:
+            # preemptions is the lifetime count: a reader takes differences
+            sprof.note(blocks_in_use=self.blocks.blocks_in_use,
+                       emitted=emitted,
+                       preemptions=self.scheduler.preemptions,
+                       work_left=int(self.has_work()))
         sprof.commit(emitted, prefills=len(prefills), decodes=len(decodes))
         return emitted
 
@@ -1715,14 +1721,19 @@ class Engine:
                     jnp.asarray(blk), jnp.asarray(off)) \
                 + self._req_adapter_operand(req) \
                 + self._req_sampling_operands(req) + (sub,)
+        sprof = self._sprof
+        if sprof.tracing:
+            sprof.note(kind=pkind, tokens=span, bucket=bucket,
+                       cached=req.cached_prefix_len)
         t0 = self._perf.t0()
         outs = fn(*args)
         self._perf.done(t0, pkind, bucket, outs)
-        self._sprof.lap("prefill_dispatch")
+        sprof.enter("device_wait")
         lead = self._unpack_outs(outs, 4 if self._sampling else 1,
                                  "prefill_logits", rid=req.rid)
-        self._sprof.lap("device_wait")
+        sprof.enter("host_sync")
         tok = lead[0]
+        req.prefill_passes += 1
         req.cache_len = end
         self._stats.on_prefill(span)
         # publish the newly-FULL blocks under their chain keys so later
@@ -1737,7 +1748,6 @@ class Engine:
             # lane and owns the next iteration's prefill budget
             self._rtrace.event(req, "prefill_chunk", done=int(end),
                                target=int(n), tokens=int(span))
-            self._sprof.lap("host_sync")
             return 0
         self._rtrace.event(req, "prefill_end", tokens=int(n - start),
                            resume=resume)
@@ -1752,11 +1762,18 @@ class Engine:
             # gap (spanning the preempted wait) IS the client-visible
             # inter-token latency — it belongs in the TPOT tail
             self._stats.on_tokens(req, 1, now=now)
+        if sprof.tracing:
+            # admission to first token: with serve.request.queued (the
+            # scheduler's) it splits a request's TTFT, joined by rid
+            telemetry.tracer().add_complete(
+                "serve.request.prefill", req.admit_t, now,
+                {"rid": req.rid, "resume": int(resume),
+                 "passes": req.prefill_passes,
+                 "tokens": n - req.cached_prefix_len})
         req.tokens.append(int(tok))
         if self._sampling:
             self._note_logprobs(req, [lead[1]], [lead[2]], [lead[3]])
         self._maybe_finish(req)
-        self._sprof.lap("host_sync")
         return 1
 
     @hot_path
@@ -1781,11 +1798,11 @@ class Engine:
                   *self._batch_adapter_operands(reqs, bucket),
                   *self._batch_sampling_operands(reqs, bucket), sub)
         self._perf.done(t0, "decode", bucket, outs)
-        self._sprof.lap("decode_dispatch")
+        self._sprof.enter("device_wait")
         lead = self._unpack_outs(outs, 4 if self._sampling else 1,
                                  "decode_logits", batch_size=B,
                                  rids=[r.rid for r in reqs])
-        self._sprof.lap("device_wait")
+        self._sprof.enter("host_sync")
         out = lead[0]
         now = self.clock()
         for i, req in enumerate(reqs):
@@ -1799,7 +1816,6 @@ class Engine:
                                batch_size=B, tokens=len(req.tokens),
                                emitted=1)
             self._maybe_finish(req)
-        self._sprof.lap("host_sync")
         return B
 
     def _spec_ingest(self, req):
@@ -1876,34 +1892,30 @@ class Engine:
         self._key, sub = jax.random.split(self._key)
         if self._sampling:
             samp = self._batch_sampling_operands(reqs, bucket)
-            with telemetry.span("serve.draft", batch=B, k=k):
-                t0 = self._perf.t0()
-                douts = self._draft_fn(bucket)(
-                    sw.params, sw.cache_k, sw.cache_v,
-                    jnp.asarray(toks), jp, jtab, *samp, sub)
-                self._perf.done(t0, "draft", bucket, douts)
-                drafted, q_at, q_vals, q_idx, sw.cache_k, sw.cache_v = \
-                    douts
-            self._sprof.lap("decode_dispatch")
+            t0 = self._perf.t0()
+            douts = self._draft_fn(bucket)(
+                sw.params, sw.cache_k, sw.cache_v,
+                jnp.asarray(toks), jp, jtab, *samp, sub)
+            self._perf.done(t0, "draft", bucket, douts)
+            drafted, q_at, q_vals, q_idx, sw.cache_k, sw.cache_v = douts
             # drafted ids and their candidate-space q views stay ON
             # DEVICE: acceptance runs inside the verify program, so
             # the only host sync this iteration is the emitted rows
             fn = self._verify_fn(bucket)
             self._key, sub = jax.random.split(self._key)
-            with telemetry.span("serve.verify", batch=B, k=k):
-                t0 = self._perf.t0()
-                outs = fn(self.params, *self._adapter_args(),
-                          *self._cache_args(),
-                          jnp.asarray(toks), drafted, q_at, q_vals,
-                          q_idx, jp, jtab,
-                          *self._batch_adapter_operands(reqs, bucket),
-                          *samp, sub)
-                self._perf.done(t0, "verify", bucket, outs)
-                self._sprof.lap("decode_dispatch")
-                emit_rows, acc, lp, tv, ti = self._unpack_outs(
-                    outs, 5, "verify_logits", batch_size=B,
-                    rids=[r.rid for r in reqs])
-                self._sprof.lap("device_wait")
+            t0 = self._perf.t0()
+            outs = fn(self.params, *self._adapter_args(),
+                      *self._cache_args(),
+                      jnp.asarray(toks), drafted, q_at, q_vals,
+                      q_idx, jp, jtab,
+                      *self._batch_adapter_operands(reqs, bucket),
+                      *samp, sub)
+            self._perf.done(t0, "verify", bucket, outs)
+            self._sprof.enter("device_wait")
+            emit_rows, acc, lp, tv, ti = self._unpack_outs(
+                outs, 5, "verify_logits", batch_size=B,
+                rids=[r.rid for r in reqs])
+            self._sprof.enter("host_sync")
             emitted = 0
             now = self.clock()
             for i, req in enumerate(reqs):
@@ -1934,50 +1946,48 @@ class Engine:
                     sw.forget(req.rid)
                 else:
                     self.blocks.truncate(req.rid, req.cache_len)
-            self._sprof.lap("host_sync")
             return emitted
-        with telemetry.span("serve.draft", batch=B, k=k):
-            t0 = self._perf.t0()
-            douts = self._draft_fn(bucket)(
-                sw.params, sw.cache_k, sw.cache_v, jnp.asarray(toks),
-                jp, jtab, sub)
-            self._perf.done(t0, "draft", bucket, douts)
-            drafted, sw.cache_k, sw.cache_v = douts
-            self._sprof.lap("decode_dispatch")
-            # mxtpu-lint: disable=host-sync (designed sync point: the
-            # drafted ids feed the verify dispatch's host-built rows)
-            drafted = np.asarray(drafted)
-            self._sprof.lap("device_wait")
+        t0 = self._perf.t0()
+        douts = self._draft_fn(bucket)(
+            sw.params, sw.cache_k, sw.cache_v, jnp.asarray(toks),
+            jp, jtab, sub)
+        self._perf.done(t0, "draft", bucket, douts)
+        drafted, sw.cache_k, sw.cache_v = douts
+        self._sprof.enter("device_wait")
+        # mxtpu-lint: disable=host-sync (designed sync point: the
+        # drafted ids feed the verify dispatch's host-built rows)
+        drafted = np.asarray(drafted)
+        self._sprof.enter("decode_dispatch")
         rows = np.zeros((bucket, k + 1), np.int32)
         rows[:, 0] = toks
         rows[:, 1:] = drafted
         fn = self._verify_fn(bucket)
         self._key, sub = jax.random.split(self._key)
-        with telemetry.span("serve.verify", batch=B, k=k):
-            t0 = self._perf.t0()
-            outs = fn(self.params, *self._adapter_args(),
-                      *self._cache_args(),
-                      jnp.asarray(rows), jp, jtab,
-                      *self._batch_adapter_operands(reqs, bucket), sub)
-            self._perf.done(t0, "verify", bucket, outs)
-            if self._cfg.numeric_watch:
-                out, ok = outs[0], outs[1]
-                self._set_caches(outs[2:])
-                # one batched read for tokens + watchdog flag
-                # mxtpu-lint: disable=host-sync (designed sync point:
-                # acceptance needs the target tokens on the host)
-                out, ok = jax.device_get((out, ok))
-                if not ok:
-                    flight_mod.record_anomaly(
-                        "verify_logits", step=self._step_id,
-                        batch_size=B, rids=[r.rid for r in reqs])
-            else:
-                out = outs[0]
-                self._set_caches(outs[1:])
-                # mxtpu-lint: disable=host-sync (designed sync point:
-                # acceptance needs the target tokens on the host)
-                out = np.asarray(out)
-        self._sprof.lap("device_wait")
+        t0 = self._perf.t0()
+        outs = fn(self.params, *self._adapter_args(),
+                  *self._cache_args(),
+                  jnp.asarray(rows), jp, jtab,
+                  *self._batch_adapter_operands(reqs, bucket), sub)
+        self._perf.done(t0, "verify", bucket, outs)
+        self._sprof.enter("device_wait")
+        if self._cfg.numeric_watch:
+            out, ok = outs[0], outs[1]
+            self._set_caches(outs[2:])
+            # one batched read for tokens + watchdog flag
+            # mxtpu-lint: disable=host-sync (designed sync point:
+            # acceptance needs the target tokens on the host)
+            out, ok = jax.device_get((out, ok))
+            if not ok:
+                flight_mod.record_anomaly(
+                    "verify_logits", step=self._step_id,
+                    batch_size=B, rids=[r.rid for r in reqs])
+        else:
+            out = outs[0]
+            self._set_caches(outs[1:])
+            # mxtpu-lint: disable=host-sync (designed sync point:
+            # acceptance needs the target tokens on the host)
+            out = np.asarray(out)
+        self._sprof.enter("host_sync")
         emitted = 0
         for i, req in enumerate(reqs):
             accepted, emit = spec_mod.accept_greedy(drafted[i], out[i], k)
@@ -2008,7 +2018,6 @@ class Engine:
                 # the accepted sequence return to the free list (never
                 # a shared prefix block — truncate stops at refcount>1)
                 self.blocks.truncate(req.rid, req.cache_len)
-        self._sprof.lap("host_sync")
         return emitted
 
     def _maybe_finish(self, req):
@@ -2056,42 +2065,23 @@ class Engine:
             entries = list(entries) + [
                 {"kind": "restore", "bucket": b}
                 for b in self._bucket_ladder(self.table_width)]
+        # kind -> largest bucket this engine runs it at (absent: not its)
+        caps = {"decode": self.max_batch, "prefill": self.max_model_len,
+                "chunk": self._chunk_cap()}
+        if self._spec is not None:
+            caps.update(verify=self.max_batch, draft=self.max_batch,
+                        draft_chunk=self.max_model_len)
+        if self._host_pool is not None:
+            caps["restore"] = self.table_width
         ready = 0
         self._warming = True   # warmup must not re-record the manifest
         try:
             with telemetry.span("serve.warmup", programs=len(entries)):
                 for e in entries:
-                    kind, bucket = e["kind"], int(e["bucket"])
-                    if kind == "decode" and 1 <= bucket <= self.max_batch:
-                        self._decode_fn(_next_bucket(bucket, self.max_batch))
-                    elif (kind == "prefill"
-                          and 1 <= bucket <= self.max_model_len):
-                        self._prefill_fn(
-                            _next_bucket(bucket, self.max_model_len))
-                    elif (kind == "chunk"
-                          and 1 <= bucket <= self._chunk_cap()):
-                        self._chunk_fn(
-                            _next_bucket(bucket, self._chunk_cap()))
-                    elif (kind in ("verify", "draft")
-                          and self._spec is not None
-                          and 1 <= bucket <= self.max_batch):
-                        self._program(kind,
-                                      _next_bucket(bucket, self.max_batch))
-                    elif (kind == "draft_chunk"
-                          and self._spec is not None
-                          and 1 <= bucket <= self.max_model_len):
-                        self._program(
-                            "draft_chunk",
-                            _next_bucket(bucket, self.max_model_len))
-                    elif (kind == "restore"
-                          and self._host_pool is not None
-                          and 1 <= bucket <= self.table_width):
-                        self._program(
-                            "restore",
-                            _next_bucket(bucket, self.table_width))
-                    else:
-                        continue
-                    ready += 1
+                    cap, bucket = caps.get(e["kind"]), int(e["bucket"])
+                    if cap is not None and 1 <= bucket <= cap:
+                        self._program(e["kind"], _next_bucket(bucket, cap))
+                        ready += 1
         finally:
             self._warming = False
         return ready
@@ -2407,7 +2397,10 @@ class Engine:
         trace it fresh (and write it through for the next restart).
         ``mxtpu_aot_programs_total{kind,source}`` counts which happened
         — ``source="trace"`` is exactly a cold-start compile the warm
-        path is supposed to avoid.
+        path is supposed to avoid — and the ``serve.resolve`` span, with
+        its children ``.build`` (trace + export, or the artifact's load;
+        lowering either way) and ``.compile`` (XLA; a compile-cache read
+        on a warm start), says what each program cost at start-up.
 
         Every path eagerly compiles (``.lower(specs).compile()``): on
         the hot path the compile was due this very step anyway, and
@@ -2416,32 +2409,42 @@ class Engine:
         (a kernel Mosaic refuses, a compile-time OOM) raises here — a
         program that cannot compile is not ready."""
         specs = self._program_specs(kind, bucket)
+        with telemetry.span("serve.resolve", kind=kind,
+                            bucket=int(bucket)) as resolve:
+            with telemetry.span("serve.resolve.build") as build:
+                exported = None
+                if self._aot is not None:
+                    fp = dict(self._aot_base_fp(), kind=kind,
+                              bucket=int(bucket))
+                    label = f"serve-{kind}{bucket}"
+                    exported = self._aot.load(fp, label=label)
+                source = "trace" if exported is None else "artifact"
+                resolve.set(source=source)
+                build.set(source=source)
+                telemetry.counter(
+                    "mxtpu_aot_programs_total", "bucket-program resolutions",
+                    ("kind", "source")).labels(kind=kind,
+                                               source=source).inc()
+                if exported is None:
+                    jitted = self._program_builder(kind, bucket)
+                    if self._aot is not None:
+                        exported = jax.export.export(jitted)(*specs)
+                        self._aot.save(fp, exported, label=label)
+                if exported is not None:
+                    # both the cold and the warm process execute the
+                    # round-tripped module, so the XLA compile below has
+                    # the same persistent-cache key in both
+                    jitted = jax.jit(
+                        exported.call,
+                        donate_argnums=self._donated_argnums(kind))
+                lowered = jitted.lower(*specs)
+            with telemetry.span("serve.resolve.compile"):
+                return lowered.compile()
 
-        def build():
-            telemetry.counter(
-                "mxtpu_aot_programs_total", "bucket-program resolutions",
-                ("kind", "source")).labels(kind=kind, source="trace").inc()
-            return self._program_builder(kind, bucket)
-
-        def compiled(jitted):
-            return jitted.lower(*specs).compile()
-
-        if self._aot is None:
-            return compiled(build())
-        fp = dict(self._aot_base_fp(), kind=kind, bucket=int(bucket))
-        label = f"serve-{kind}{bucket}"
-        exported = self._aot.load(fp, label=label)
-        if exported is None:
-            exported = jax.export.export(build())(*specs)
-            self._aot.save(fp, exported, label=label)
-        else:
-            telemetry.counter(
-                "mxtpu_aot_programs_total", "bucket-program resolutions",
-                ("kind", "source")).labels(kind=kind,
-                                           source="artifact").inc()
-        # both the cold and the warm process execute the round-tripped
-        # module, so the XLA compile below has the same persistent-cache
-        # key in both — a warm start's compile is a disk read
+    def _donated_argnums(self, kind):
+        """Positions of the cache operands a program donates."""
+        if not self._donate:
+            return ()
         n_caches = (4 if self._cfg.kv_quant
                     and kind not in ("draft", "draft_chunk") else 2)
         # the restore program has no params operand: its donated cache
@@ -2455,10 +2458,7 @@ class Engine:
             first = 2
         else:
             first = 1
-        return compiled(jax.jit(
-            exported.call,
-            donate_argnums=(tuple(range(first, first + n_caches))
-                            if self._donate else ())))
+        return tuple(range(first, first + n_caches))
 
 
 # -- quantized serving helpers ------------------------------------------------

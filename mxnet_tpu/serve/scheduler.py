@@ -37,6 +37,7 @@ import time
 
 import numpy as np
 
+from .. import telemetry
 from ..telemetry.request_trace import NOOP_TRACER
 from .kv_block_manager import NoFreeBlocks, blocks_for
 
@@ -109,7 +110,14 @@ class Request:
         self.host_restored_len = 0
         self.prefill_target = None  # prefill length at admission
         self._prefill_started = False
-        self.submit_t = None       # stamped by the scheduler
+        # stamps, all on the scheduler's clock (perf_counter unless a
+        # test injects one): submit, the instant it last entered the
+        # waiting queue (submit, or a preemption), the instant it last
+        # left it (first admission and every resume), first token, end
+        self.submit_t = None
+        self.queued_t = None
+        self.admit_t = None
+        self.prefill_passes = 0    # prefill passes since admit_t
         self.first_token_t = None
         self.finish_t = None
         self.n_preemptions = 0
@@ -170,7 +178,7 @@ class Scheduler:
     unlocked-shared-state checker."""
 
     def __init__(self, block_mgr, max_batch, max_queue,
-                 max_prefills_per_step=1, clock=time.monotonic,
+                 max_prefills_per_step=1, clock=time.perf_counter,
                  trace=None, tenant_share=None, prefill_chunk=None,
                  spec_slots=0):
         self.blocks = block_mgr
@@ -261,7 +269,7 @@ class Scheduler:
                 # at submit, rather than deadlock in the waiting queue
                 outcome = "exceeds_cache"
             else:
-                req.submit_t = self.clock()
+                req.submit_t = req.queued_t = self.clock()
                 self.waiting.append(req)
                 outcome = None
         # trace/telemetry emission stays OUTSIDE the lock: the step
@@ -314,8 +322,6 @@ class Scheduler:
                 label = tenant
             else:
                 label = "other"    # registry children never evict
-        from .. import telemetry
-
         if outcome == "rejected":
             telemetry.counter(
                 "mxtpu_serve_tenant_rejections_total",
@@ -488,6 +494,17 @@ class Scheduler:
                 except NoFreeBlocks:
                     break
                 self.waiting.remove(req)
+                req.admit_t = self.clock()
+                req.prefill_passes = 0
+                if telemetry.enabled():
+                    # recorded AT admission, so a request unfinished
+                    # when a reader looks still has its wait; with the
+                    # engine's serve.request.prefill (same rid) it
+                    # splits the time to the first token
+                    telemetry.tracer().add_complete(
+                        "serve.request.queued", req.queued_t, req.admit_t,
+                        {"rid": req.rid,
+                         "resume": int(req.n_preemptions > 0)})
                 req.cache_len = cached
                 req.cached_prefix_len = cached
                 req.host_restored_len = self.blocks.host_tokens(req.rid)
@@ -575,6 +592,7 @@ class Scheduler:
             req.prefill_target = None
             req._prefill_started = False
             req.n_preemptions += 1
+            req.queued_t = self.clock()
             self.preemptions += 1
             self.trace.event(req, "preempted", reason="cache_pressure",
                              generated=len(req.tokens))

@@ -186,7 +186,7 @@ def _pct_ms(res, q):
 
 
 class StatsRecorder:
-    def __init__(self, clock=time.monotonic, window_steps=64):
+    def __init__(self, clock=time.perf_counter, window_steps=64):
         self.clock = clock
         self.steps = 0
         self.completed = 0

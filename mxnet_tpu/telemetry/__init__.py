@@ -23,6 +23,14 @@ opt-in; docs/how_to/observability.md):
   MXTPU_NUMERIC_WATCH=1        NaN/Inf watchdog on fused-train-step
                                loss/grad-norm and serve logits
 
+One clock, one step record: every span is ``(name, id, parent,
+start_s, end_s, args)`` on ``time.perf_counter`` — the clock of the
+serve engine's request stamps and of ``profiling.StepProfiler``, the ONE
+instrument of an ``Engine.step()``, whose phases are, with telemetry
+enabled, the ``serve.step`` > ``serve.prefill`` | ``serve.decode`` >
+``serve.<phase>`` spans.  ``tracer().spans(prefix, since, until)``
+reads them back (docs/how_to/observability.md lists names and args).
+
 Disabled, every accessor returns a shared no-op object — instrumented
 hot paths (Module.fit, io iterators, serve.Engine, ShardedTrainer) pay
 one attribute call per event and allocate nothing (pinned by

@@ -6,13 +6,15 @@ Answers "where did the *host* wall-clock go?" inside every
 ROADMAP 1(c)'s multi-step host loop (any future N-steps-per-turn
 dispatch has to beat these numbers, phase by phase).
 
-**Lap/cursor model.**  ``begin(step_id)`` stamps the step start and
-resets the cursor; every ``lap(phase)`` attributes the time elapsed
-since the cursor to ``phase`` and advances the cursor; ``commit(...)``
-sweeps whatever remains into ``callbacks`` and seals the entry.  Every
-nanosecond between begin and commit lands in exactly one phase, so the
-per-step phase seconds sum to the step wall time by construction
-(pinned in tests/test_profiling.py).  Phases:
+**One step instrument.**  ``begin(step_id)`` stamps the step start and
+opens the first phase (``schedule``); every ``enter(phase)`` reads the
+clock ONCE, closes the phase that was open at that instant and opens
+``phase`` there; ``commit(...)`` closes the last phase and seals the
+entry.  Every instant between begin and commit lies in exactly one
+phase, so the per-step phase seconds sum to the step wall time by
+construction (pinned in tests/test_profiling.py).  ``enter`` comes
+BEFORE the work it names — a trace annotation cannot be named after
+the fact.  Phases:
 
   schedule          admission fanout + scheduler.schedule() +
                     host-KV restore dispatch + utilization sampling
@@ -27,11 +29,25 @@ per-step phase seconds sum to the step wall time by construction
   callbacks         step tail: flight record, stats/perf callbacks,
                     spec-window prune, telemetry gauges
 
-**Cost.**  A lap is one ``perf_counter`` read and a dict add — the
-recorder is default ON (``MXTPU_STEP_PROFILE=0`` to disable) and gated
-≤1.02x tokens/s by the serve_bench ``step-profile`` A/B contract
-(PROFILE_BENCH.json).  Disabled, the engine holds the NOOP recorder
-whose methods are empty — zero clock reads on the hot path.
+**The same intervals as spans.**  With telemetry enabled every step is
+also a ``SpanTracer`` span ``serve.step`` (args ``step``, and through
+``note()`` the engine's counts), every phase interval a span
+``serve.<phase>``, and the passes they belong to the spans between:
+``prefill_dispatch`` opens a ``serve.prefill`` (one per prefill pass),
+``decode_dispatch`` a ``serve.decode`` (one per step), the phases that
+follow are their children, ``schedule`` and ``callbacks`` are children
+of ``serve.step``.  All are stamped from the SAME clock reads as the
+phase seconds (so the phase spans tile their step exactly) and enter a
+``jax.profiler.TraceAnnotation`` as they open, so a device trace names
+an idle gap by the phase the host was in.  There is no second
+instrument: the engine makes one call per interval.
+
+**Cost.**  An ``enter`` is one ``perf_counter`` read and a dict add —
+the recorder is default ON (``MXTPU_STEP_PROFILE=0`` to disable, spans
+included) and gated ≤1.02x tokens/s by the serve_bench ``step-profile``
+A/B contract (PROFILE_BENCH.json).  With telemetry off it allocates no
+span.  Disabled, the engine holds the NOOP recorder whose methods are
+empty — zero clock reads on the hot path.
 
 Surfaces: a bounded ring of per-step entries (``MXTPU_STEP_PROFILE_RING``,
 default 256), cumulative per-phase totals, the ``step_profile`` engine
@@ -50,6 +66,7 @@ from __future__ import annotations
 import collections
 import time
 
+from .. import telemetry as tel
 from ..base import env_flag, env_int
 
 __all__ = ["StepProfiler", "NOOP_STEP_PROFILER", "make_step_profiler",
@@ -66,6 +83,13 @@ PHASE_SECONDS_BUCKETS = (1e-6, 2.5e-6, 5e-6, 1e-5, 2.5e-5, 5e-5,
                          1e-4, 2.5e-4, 5e-4, 1e-3, 2.5e-3, 5e-3,
                          1e-2, 2.5e-2, 5e-2, 0.1, 0.25)
 
+# a dispatch phase opens the span of the pass it belongs to; schedule
+# and callbacks sit directly under serve.step; device_wait and host_sync
+# stay inside the pass that is open
+_OPENS_PASS = {"prefill_dispatch": "serve.prefill",
+               "decode_dispatch": "serve.decode"}
+_STEP_PHASES = ("schedule", "callbacks")
+
 _STATUSZ_RECENT = 50     # ring tail carried on statusz / flight dumps
 
 
@@ -73,15 +97,19 @@ class _NoopStepProfiler:
     """Shared disabled recorder: every hot-path call is a no-op pass.
 
     The engine holds this singleton when ``MXTPU_STEP_PROFILE=0`` so
-    the step loop pays one attribute load + empty call per lap and
+    the step loop pays one attribute load + empty call per phase and
     zero clock reads."""
 
     enabled = False
+    tracing = False
 
     def begin(self, step_id):
         pass
 
-    def lap(self, phase):
+    def enter(self, phase, **args):
+        pass
+
+    def note(self, **args):
         pass
 
     def commit(self, emitted=0, prefills=0, decodes=0):
@@ -103,11 +131,13 @@ NOOP_STEP_PROFILER = _NoopStepProfiler()
 class StepProfiler:
     """One per engine, constructed AFTER ``telemetry.enable()`` (the
     handle-caching asymmetry: the phase histogram handle is cached here
-    at construction).  Single-writer: only the engine step loop calls
-    begin/lap/commit; readers (statusz handlers on HTTP threads) see a
+    at construction; spans follow ``telemetry.enabled()`` as each step
+    begins).  Single-writer: only the engine step loop calls
+    begin/enter/note/commit; readers (statusz handlers on HTTP threads) see a
     consistent tail because entries are appended whole."""
 
     enabled = True
+    tracing = False               # this step is also recorded as spans
 
     def __init__(self, clock=time.perf_counter, ring=None):
         self._clock = clock
@@ -121,12 +151,12 @@ class StepProfiler:
         self._step_id = 0
         self._t_begin = 0.0
         self._t_cursor = 0.0
+        self._phase = "schedule"  # the phase open since the cursor
+        self._spans = []          # open spans: step[, pass], phase
         # perf_counter<->epoch anchor: lets timeline_report place ring
         # entries (perf-domain t0s) on the fleet's wall-clock axis.
         # mxtpu-lint: disable=wall-clock (one-shot epoch anchor for trace stitching)
         self._anchor = {"perf": clock(), "epoch": time.time()}
-        from .. import telemetry as tel
-
         self._hist = tel.histogram(
             "mxtpu_step_phase_seconds",
             "host wall-time per serve-step phase", ("phase",),
@@ -134,25 +164,67 @@ class StepProfiler:
 
     # -- hot path (engine step loop only) --------------------------------
     def begin(self, step_id):
-        """Stamp the step start; resets the lap cursor."""
-        self._step_id = step_id
-        self._t_begin = self._t_cursor = self._clock()
-        self._cur = {}
-
-    def lap(self, phase):
-        """Attribute elapsed-since-cursor to ``phase``; advance cursor."""
+        """Stamp the step start and open its first phase, ``schedule``."""
         now = self._clock()
-        self._cur[phase] = self._cur.get(phase, 0.0) + (now - self._t_cursor)
-        self._t_cursor = now
+        if self._spans:
+            self._close(0, now)   # the last step raised before commit
+        self._step_id = step_id
+        self._t_begin = self._t_cursor = now
+        self._cur = {}
+        self._phase = "schedule"
+        self.tracing = tel.enabled()
+        if self.tracing:
+            tr = tel.tracer()
+            self._spans = [tr.span("serve.step", step=step_id).start(now),
+                           tr.span("serve.schedule").start(now)]
 
-    def commit(self, emitted=0, prefills=0, decodes=0):
-        """Seal the in-flight step: the residual since the last lap goes
-        to ``callbacks``, the entry enters the ring, totals/histograms
-        update."""
+    def enter(self, phase, **args):
+        """Close the open phase and open ``phase`` at one clock read.
+        ``args`` go to the pass span when this phase opens one
+        (``serve.prefill`` / ``serve.decode``), else to the phase's."""
         now = self._clock()
         cur = self._cur
-        cur["callbacks"] = cur.get("callbacks", 0.0) + (now - self._t_cursor)
+        cur[self._phase] = cur.get(self._phase, 0.0) + (now - self._t_cursor)
         self._t_cursor = now
+        self._phase = phase
+        if not self.tracing:
+            return
+        spans, tr = self._spans, tel.tracer()
+        opens = _OPENS_PASS.get(phase)
+        if opens == "serve.decode" and len(spans) == 3 \
+                and spans[1].name == opens:
+            opens = None          # a later dispatch of the same decode
+        # a new pass and a step-level phase close the pass that is open
+        self._close(1 if opens or phase in _STEP_PHASES else len(spans) - 1,
+                    now)
+        if opens:
+            spans.append(tr.span(opens, **args).start(now))
+            args = {}
+        spans.append(tr.span("serve." + phase, **args).start(now))
+
+    def note(self, **args):
+        """Counts for the innermost pass span open (``serve.prefill`` /
+        ``serve.decode``), or for ``serve.step`` between passes.  Call
+        under ``if sprof.tracing`` where building the args costs."""
+        if self.tracing:
+            self._spans[-2].set(**args)
+
+    def _close(self, keep, now):
+        """Finish the open spans beyond the first ``keep``, innermost
+        first, all at ``now``."""
+        spans = self._spans
+        while len(spans) > keep:
+            spans.pop().finish(now)
+
+    def commit(self, emitted=0, prefills=0, decodes=0):
+        """Seal the in-flight step: the open phase ends here, the entry
+        enters the ring, totals/histograms update."""
+        now = self._clock()
+        cur = self._cur
+        cur[self._phase] = cur.get(self._phase, 0.0) + (now - self._t_cursor)
+        self._t_cursor = now
+        if self.tracing:
+            self._close(0, now)
         wall = now - self._t_begin
         entry = {
             "step": self._step_id,

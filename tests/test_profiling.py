@@ -142,15 +142,18 @@ def test_step_profiler_phases_sum_to_wall():
         return clock["now"]
 
     sp = profiling.StepProfiler(clock=tick, ring=4)
-    laps = [("schedule", 0.010), ("prefill_dispatch", 0.002),
-            ("device_wait", 0.050), ("host_sync", 0.001),
-            ("decode_dispatch", 0.004), ("device_wait", 0.030)]
+    # begin() opens "schedule"; each enter() closes the open phase at
+    # the instant it opens the next; commit() closes the last
+    phases = [("schedule", 0.010), ("prefill_dispatch", 0.002),
+              ("device_wait", 0.050), ("host_sync", 0.001),
+              ("decode_dispatch", 0.004), ("device_wait", 0.030),
+              ("callbacks", 0.003)]
     for step in range(6):
         sp.begin(step)
-        for phase, dt in laps:
+        for i, (phase, dt) in enumerate(phases):
+            if i:
+                sp.enter(phase)
             clock["now"] += dt
-            sp.lap(phase)
-        clock["now"] += 0.003           # residual -> callbacks
         sp.commit(emitted=2, prefills=1, decodes=1)
     # ring bounded at 4; totals keep counting all 6 steps
     entries = sp.recent()
@@ -162,7 +165,7 @@ def test_step_profiler_phases_sum_to_wall():
         # the accounting identity: phases sum EXACTLY to the wall
         assert sum(e["phases"].values()) == pytest.approx(
             e["wall_s"], rel=1e-12)
-        # repeated laps into one phase accumulate (two device waits)
+        # a phase entered twice accumulates (two device waits)
         assert e["phases"]["device_wait"] == pytest.approx(0.08)
         assert e["phases"]["callbacks"] == pytest.approx(0.003)
         assert e["emitted"] == 2
@@ -184,8 +187,10 @@ def test_step_profiler_env_knobs(monkeypatch):
     assert profiling.make_step_profiler() is profiling.NOOP_STEP_PROFILER
     noop = profiling.make_step_profiler()
     noop.begin(1)
-    noop.lap("schedule")
+    noop.enter("device_wait")
+    noop.note(tokens=3)
     noop.commit()
+    assert noop.tracing is False
     assert noop.recent() == [] and noop.summary() is None
     assert noop.statusz() == {"enabled": False}
     monkeypatch.setenv(profiling.ENV_ENABLE, "1")

@@ -99,6 +99,7 @@ def test_registry_consistency_enforced():
 # -- disabled path (the overhead-guard contract) -----------------------------
 def test_disabled_returns_noop_objects():
     assert not telemetry.enabled()
+    telemetry.reset()      # whatever an earlier file of this worker left
     assert telemetry.counter("anything_total") is telemetry.NOOP
     assert telemetry.gauge("anything") is telemetry.NOOP
     assert telemetry.histogram("anything_seconds") is telemetry.NOOP
@@ -116,6 +117,7 @@ def test_disabled_instrumented_sites_use_noop():
     fit-loop call sites must all hold the shared no-op objects and the
     registry must stay empty."""
     assert not telemetry.enabled()
+    telemetry.reset()      # whatever an earlier file of this worker left
     it = NDArrayIter(np.zeros((8, 3), np.float32),
                      np.zeros(8, np.float32), batch_size=4)
     for _ in it:
@@ -131,6 +133,65 @@ def test_disabled_instrumented_sites_use_noop():
     assert telemetry.tracer().trace_events() == [
         {"name": "process_name", "ph": "M",
          "pid": os.getpid(), "args": {"name": "mxtpu host"}}]
+
+
+def test_disabled_serve_step_allocates_no_span_and_bounds_clock_reads(
+        serve_model, monkeypatch):
+    """The untraced step's bound: no span object, no annotation, and the
+    step instrument reads its clock once per phase interval — begin,
+    callbacks and commit (3 a step) plus dispatch, wait and sync (3)
+    for each prefill pass and for the decode; the engine's own clock
+    (request stamps and ``StatsRecorder``) once per schedule(), per
+    ``on_step``, per admission, per first token, per decode and per
+    finish — the admission stamp is the one read this record added."""
+    from mxnet_tpu.telemetry import tracing
+
+    assert not telemetry.enabled()
+    net, params = serve_model
+
+    def no_span(*a, **kw):
+        raise AssertionError("a span was allocated with telemetry off")
+
+    monkeypatch.setattr(tracing._Span, "__init__", no_span)
+    reads = {"prof": 0, "req": 0}
+
+    def prof_clock():
+        reads["prof"] += 1
+        return float(reads["prof"])
+
+    def req_clock():
+        reads["req"] += 1
+        return float(reads["req"])
+
+    eng = mx.serve.Engine(params, symbol=net, block_size=4, num_blocks=64,
+                          max_batch=4, max_model_len=64, clock=req_clock,
+                          max_prefills_per_step=1)
+    eng._sprof._clock = prof_clock
+    assert eng._sprof.tracing is False
+    rng = np.random.RandomState(7)
+    for n in (8, 12):
+        eng.submit(rng.randint(0, VOCAB, (n,)).astype(np.int32),
+                   max_new_tokens=4)
+    submits = reads["req"]
+    assert submits == 2
+    steps = admissions = first_tokens = passes = decodes = 0
+    while eng.has_work():
+        before = dict(reads)
+        eng.step()
+        entry = eng._sprof.recent()[-1]
+        steps += 1
+        passes += entry["prefills"]
+        admissions += entry["prefills"]      # whole-prompt: one pass each
+        first_tokens += entry["prefills"]
+        decodes += bool(entry["decodes"])
+        busy = entry["prefills"] + bool(entry["decodes"])
+        assert reads["prof"] - before["prof"] == 3 + 3 * busy
+    assert reads["prof"] == 3 * steps + 3 * (passes + decodes)
+    finishes = 2
+    assert reads["req"] - submits \
+        == 2 * steps + admissions + first_tokens + decodes + finishes
+    assert telemetry.tracer().spans() == []
+    eng.shutdown()
 
 
 # -- tracer ------------------------------------------------------------------
