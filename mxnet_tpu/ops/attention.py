@@ -359,7 +359,7 @@ def resolve_paged_impl(block_size, head_dim, impl=None):
 @hot_path
 def paged_attention(q, k_cache, v_cache, block_tables, context_lens,
                     window=0, scale=None, k_scale=None, v_scale=None,
-                    impl=None, mesh=None, head_axis=None):
+                    impl=None, mesh=None, head_axis=None, layer=None):
     """Single-token decode attention over a paged KV-cache.
 
     The serving engine (``mxnet_tpu/serve``) keeps one fixed
@@ -378,8 +378,10 @@ def paged_attention(q, k_cache, v_cache, block_tables, context_lens,
     Args:
       q: (B, Hq, Dh) — one query token per sequence.
       k_cache/v_cache: (num_blocks, block_size, Hkv, Dh) physical
-        cache.  Hq must be a multiple of Hkv (grouped-query native:
-        kv head g serves q heads [g*group, (g+1)*group)).
+        cache, or with ``layer`` the stacked (L, num_blocks,
+        block_size, Hkv, Dh) pool of every layer.  Hq must be a
+        multiple of Hkv (grouped-query native: kv head g serves q
+        heads [g*group, (g+1)*group)).
       block_tables: (B, W) int32 physical block ids per sequence, in
         logical order; rows pad with the null block (id 0) past the
         sequence's last block.
@@ -395,7 +397,8 @@ def paged_attention(q, k_cache, v_cache, block_tables, context_lens,
       k_scale/v_scale: per-slot-per-head f32 dequantization scales
         (num_blocks, block_size, Hkv) for int8 K/V caches
         (``MXTPU_SERVE_KV_DTYPE=int8``): the cache entry is
-        ``int8 * scale``.  Pass both or neither.
+        ``int8 * scale``.  Pass both or neither; stacked over the
+        layers like the caches when ``layer`` is given.
       impl: "auto" (kernel on TPU), "pallas", or "jnp"; default the
         ``MXTPU_PAGED_ATTENTION`` env var, else "auto".
       mesh/head_axis: the device mesh of the enclosing sharded jit and
@@ -405,11 +408,21 @@ def paged_attention(q, k_cache, v_cache, block_tables, context_lens,
         ``shard_map`` (heads are independent; a contiguous split keeps
         every q-head group with its kv head).  The jnp formulation is
         plain XLA and partitions on its own.
+      layer: static index of the layer to read from stacked caches
+        (None = the caches are one layer's, 4-D).  The layer is
+        addressed in place — by the kernel's DMA index, or as one more
+        index of the jnp gather — and never sliced out first: a slice
+        of the stack that feeds a custom call is a copy of that
+        layer's whole pool (serve/engine.py passes its stack as is).
 
     Returns (B, Hq, Dh) attention output in q's dtype.
     """
     B, Hq, Dh = q.shape
-    nb, bs, Hkv, _ = k_cache.shape
+    if (layer is None) != (k_cache.ndim == 4):
+        raise ValueError("paged_attention: stacked (L, num_blocks, "
+                         "block_size, Hkv, Dh) caches take `layer`, "
+                         "a single layer's 4-D caches do not")
+    bs, Hkv = k_cache.shape[-3:-1]
     if window < 0:
         raise ValueError(f"paged_attention: window must be >= 0 "
                          f"(got {window})")
@@ -429,7 +442,8 @@ def paged_attention(q, k_cache, v_cache, block_tables, context_lens,
             ks, vs = scales or (None, None)
             return paged_attention_kernel(
                 q, k_cache, v_cache, block_tables, context_lens,
-                window=window, scale=scale, k_scale=ks, v_scale=vs)
+                window=window, scale=scale, k_scale=ks, v_scale=vs,
+                layer=layer)
 
         scales = () if k_scale is None else (k_scale, v_scale)
         args = (q, k_cache, v_cache, block_tables, context_lens) + scales
@@ -438,24 +452,29 @@ def paged_attention(q, k_cache, v_cache, block_tables, context_lens,
         from jax.sharding import PartitionSpec as P
 
         q_spec = P(None, head_axis, None)
-        specs = ((q_spec,) + (P(None, None, head_axis, None),) * 2
-                 + (P(), P()) + (P(None, None, head_axis),) * len(scales))
+        # the caches' (and scales') leading axes — blocks and slots,
+        # under a layer axis when stacked — stay whole on every shard
+        lead = (None,) * (k_cache.ndim - 2)
+        specs = ((q_spec,) + (P(*lead, head_axis, None),) * 2
+                 + (P(), P()) + (P(*lead, head_axis),) * len(scales))
         return jax.shard_map(kernel, mesh=mesh, in_specs=specs,
                              out_specs=q_spec, check_vma=False)(*args)
     scale = score_scale(Dh) if scale is None else np.float32(scale)
     S = block_tables.shape[1] * bs
-    # (B, W, bs, Hkv, Dh) -> (B, S, Hkv, Dh): each row's logical view
-    k = k_cache[block_tables].reshape(B, S, Hkv, Dh)
-    v = v_cache[block_tables].reshape(B, S, Hkv, Dh)
+    # (B, W, bs, Hkv, Dh) -> (B, S, Hkv, Dh): each row's logical view,
+    # ONE gather (the layer of a stacked cache is an index of it)
+    at = block_tables if layer is None else (layer, block_tables)
+    k = k_cache[at].reshape(B, S, Hkv, Dh)
+    v = v_cache[at].reshape(B, S, Hkv, Dh)
     if k_scale is not None:
         # int8 blocks dequantize through the same gathered view; the
         # scale arrays ride the same block tables (serve/engine.py owns
         # them alongside k_cache/v_cache)
         k = (k.astype(jnp.float32)
-             * k_scale[block_tables].reshape(B, S, Hkv)[..., None]
+             * k_scale[at].reshape(B, S, Hkv)[..., None]
              ).astype(q.dtype)
         v = (v.astype(jnp.float32)
-             * v_scale[block_tables].reshape(B, S, Hkv)[..., None]
+             * v_scale[at].reshape(B, S, Hkv)[..., None]
              ).astype(q.dtype)
     qg = q.reshape(B, Hkv, group, Dh)
     s = jnp.einsum("bkgd,bskd->bkgs", qg, k) * scale

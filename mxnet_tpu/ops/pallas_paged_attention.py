@@ -12,6 +12,15 @@ vLLM-PagedAttention formulation on TPU), updating flash-style running
 max / sum-exp / f32 accumulators per kv head.  No gathered copy of the
 cache ever exists; HBM traffic is exactly the live context bytes.
 
+The cache operand is the engine's WHOLE stacked pool ``(L, num_blocks,
+block_size, Hkv, Dh)`` and the layer to read is a static index in the
+DMA's source address, ``(layer, bt[b, w], 0, 0, 0)``.  A custom call
+needs each operand as a buffer of its own, so a caller that sliced one
+layer out of the stack first (``cache[i]``) made XLA copy that layer's
+whole pool, K and V, every layer of every step — half of a decode
+step's device time (PERF.md, PR 27).  Callers pass the stack and
+``layer=i``; a lone 4-D cache is the same kernel through ``cache[None]``.
+
 Grouped-query attention is native: the kernel loops the (static) kv
 heads and each grid step's block fetch serves every q head of the
 group — with int8 KV blocks (``k_scale``/``v_scale`` per-slot-per-head
@@ -92,12 +101,12 @@ def _kernel(bt_ref, ctx_ref, q_ref, k_ref, v_ref, *rest, scale, bs, nW,
         if window:
             keep = jnp.logical_and(keep, pos > ctx - 1 - window)
         for h in range(Hkv):
-            k = k_ref[0, :, h, :]
-            v = v_ref[0, :, h, :]
+            k = k_ref[0, 0, :, h, :]
+            v = v_ref[0, 0, :, h, :]
             if quant:
                 # fused dequant in VMEM: the HBM stream was int8
-                k = k.astype(jnp.float32) * ksc_ref[0, :, h][:, None]
-                v = v.astype(jnp.float32) * vsc_ref[0, :, h][:, None]
+                k = k.astype(jnp.float32) * ksc_ref[0, 0, :, h][:, None]
+                v = v.astype(jnp.float32) * vsc_ref[0, 0, :, h][:, None]
             q = q_ref[0, h]                              # (group, Dh)
             s = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
@@ -141,27 +150,46 @@ def _params(interpret):
 @hot_path
 def paged_attention_kernel(q, k_cache, v_cache, block_tables,
                            context_lens, window=0, scale=None,
-                           k_scale=None, v_scale=None, interpret=None):
+                           k_scale=None, v_scale=None, interpret=None,
+                           layer=None):
     """Single-token paged decode attention, block-streamed.
 
     Same contract as ``ops.attention.paged_attention``: q ``(B, Hq,
-    Dh)``, caches ``(num_blocks, block_size, Hkv, Dh)`` (int8 when
-    ``k_scale``/``v_scale`` — ``(num_blocks, block_size, Hkv)`` f32 —
-    are given), ``block_tables (B, W)`` int32 padded with the null
-    block, ``context_lens (B,)``.  Returns ``(B, Hq, Dh)`` in q's
-    dtype.  Empty rows (``context_lens == 0``) return zeros.
+    Dh)``, caches ``(L, num_blocks, block_size, Hkv, Dh)`` stacked over
+    the layers (int8 when ``k_scale``/``v_scale`` — ``(L, num_blocks,
+    block_size, Hkv)`` f32 — are given) with the static ``layer`` to
+    read, ``block_tables (B, W)`` int32 padded with the null block,
+    ``context_lens (B,)``.  A single layer's 4-D cache (3-D scales,
+    ``layer=None``) is the same kernel seen through ``cache[None]``.
+    Returns ``(B, Hq, Dh)`` in q's dtype.  Empty rows
+    (``context_lens == 0``) return zeros.
     """
     B, Hq, Dh = q.shape
-    nb, bs, Hkv, _ = k_cache.shape
-    if window < 0:
-        raise ValueError(f"paged_attention: window must be >= 0 "
-                         f"(got {window})")
-    group = gqa_group(Hq, Hkv)
+    if (layer is None) != (k_cache.ndim == 4):
+        raise ValueError("paged_attention: stacked (L, num_blocks, "
+                         "block_size, Hkv, Dh) caches take `layer`, "
+                         "a single layer's 4-D caches do not")
     if (k_scale is None) != (v_scale is None):
         raise ValueError("paged_attention: k_scale and v_scale must be "
                          "given together (quantized K/V blocks carry "
                          "both)")
     quant = k_scale is not None
+    if layer is None:
+        # a reshape, not a copy: the one kernel below addresses layer 0
+        # of a one-layer stack
+        layer = 0
+        k_cache, v_cache = k_cache[None], v_cache[None]
+        if quant:
+            k_scale, v_scale = k_scale[None], v_scale[None]
+    L, nb, bs, Hkv, _ = k_cache.shape
+    layer = int(layer)
+    if not 0 <= layer < L:
+        raise ValueError(f"paged_attention: layer {layer} outside the "
+                         f"cache's {L} layers")
+    if window < 0:
+        raise ValueError(f"paged_attention: window must be >= 0 "
+                         f"(got {window})")
+    group = gqa_group(Hq, Hkv)
     scale = score_scale(Dh) if scale is None else np.float32(scale)
     if interpret is None:
         interpret = not pallas_util.on_tpu()
@@ -174,19 +202,20 @@ def paged_attention_kernel(q, k_cache, v_cache, block_tables,
         full (Hkv, Dh) / (Hkv,) trailing axes always satisfies it)."""
         return shape
 
+    # the layer is one more index of the block's DMA source address
     per_req = idx32(lambda b, w, bt, ctx: (b, 0, 0, 0))
-    per_blk = idx32(lambda b, w, bt, ctx: (bt[b, w], 0, 0, 0))
-    per_blk_sc = idx32(lambda b, w, bt, ctx: (bt[b, w], 0, 0))
+    per_blk = idx32(lambda b, w, bt, ctx: (layer, bt[b, w], 0, 0, 0))
+    per_blk_sc = idx32(lambda b, w, bt, ctx: (layer, bt[b, w], 0, 0))
     in_specs = [
         pl.BlockSpec(blk(1, Hkv, group, Dh), per_req),
-        pl.BlockSpec(blk(1, bs, Hkv, Dh), per_blk),
-        pl.BlockSpec(blk(1, bs, Hkv, Dh), per_blk),
+        pl.BlockSpec(blk(1, 1, bs, Hkv, Dh), per_blk),
+        pl.BlockSpec(blk(1, 1, bs, Hkv, Dh), per_blk),
     ]
     args = [q4, k_cache, v_cache]
     if quant:
         in_specs += [
-            pl.BlockSpec(blk(1, bs, Hkv), per_blk_sc),
-            pl.BlockSpec(blk(1, bs, Hkv), per_blk_sc),
+            pl.BlockSpec(blk(1, 1, bs, Hkv), per_blk_sc),
+            pl.BlockSpec(blk(1, 1, bs, Hkv), per_blk_sc),
         ]
         args += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
 

@@ -27,7 +27,10 @@ recompiles, the serving analog of ``BucketingModule``'s bucket trick.
 The KV-cache is ONE device-resident array pair per engine,
 (layers, num_blocks, block_size, kv_heads, head_dim), carved into
 blocks by ``kv_block_manager.BlockManager``; decode attends through
-``ops.attention.paged_attention``.  Cache-pressure policy lives in
+``ops.attention.paged_attention``.  Every program passes the stacked
+array whole and names the layer by a static index (``layer=i``,
+``ck[i, table]``) — never ``ck[i]``, which on the chip is a copy of
+that layer's whole pool.  Cache-pressure policy lives in
 ``scheduler.Scheduler`` (preemption + back-pressure), never here —
 the engine only executes the schedule it is handed.
 
@@ -803,8 +806,13 @@ class Engine:
         # upgrades into the kernel (or escapes it via
         # MXTPU_PAGED_ATTENTION=jnp after a kernel bug) would silently
         # warm-load the other implementation's artifacts forever
+        # The value names the kernel's cache CONTRACT, not only the
+        # choice: since PR 27 the kernel reads the stacked cache in
+        # place, and an artifact exported before that still slices a
+        # layer out per call (right answers at half the speed) — the
+        # fingerprint is the store's only version, so it moves here
         paged = ({} if self._paged_impl() != "pallas"
-                 else dict(paged_attention="pallas"))
+                 else dict(paged_attention="pallas-stacked"))
         return aot_store.fingerprint(
             subsystem="serve", cfg=cfg_d,
             num_blocks=self.num_blocks, table_width=self.table_width,
@@ -2752,14 +2760,14 @@ def _forward_token_batch(cfg, params, ck, cv, ksc, vsc, toks, pos, tables,
             ksc = ksc.at[i, blk, off].set(ks)
             cv = cv.at[i, blk, off].set(vq)
             vsc = vsc.at[i, blk, off].set(vs)
-            attn = paged_attention(qh, ck[i], cv[i], tables, ctx,
+            attn = paged_attention(qh, ck, cv, tables, ctx, layer=i,
                                    window=cfg.window,
-                                   k_scale=ksc[i], v_scale=vsc[i],
+                                   k_scale=ksc, v_scale=vsc,
                                    **paged_kw)
         else:
             ck = ck.at[i, blk, off].set(kh)
             cv = cv.at[i, blk, off].set(vh)
-            attn = paged_attention(qh, ck[i], cv[i], tables, ctx,
+            attn = paged_attention(qh, ck, cv, tables, ctx, layer=i,
                                    window=cfg.window, **paged_kw)
         x = x + _awfc(cfg, params, adp, f"{p}_proj",
                       attn.reshape(B, d_model), slots)
@@ -3053,12 +3061,14 @@ def _build_chunk(cfg, C, donate, shardings=None):
                 cv = cv.at[i, blk, off].set(vh)
             # all rows share one table: gather the request's logical
             # cache view ONCE per layer, then mask per-row by position
-            kb = ck[i][table].reshape(S, Hkv, Dh)
-            vb = cv[i][table].reshape(S, Hkv, Dh)
+            # (ck[i, table] is one gather over the stack; ck[i][table]
+            # would first copy the layer's whole pool)
+            kb = ck[i, table].reshape(S, Hkv, Dh)
+            vb = cv[i, table].reshape(S, Hkv, Dh)
             if cfg.kv_quant:
-                kb = _kv_dequant(kb, ksc[i][table].reshape(S, Hkv),
+                kb = _kv_dequant(kb, ksc[i, table].reshape(S, Hkv),
                                  x.dtype)
-                vb = _kv_dequant(vb, vsc[i][table].reshape(S, Hkv),
+                vb = _kv_dequant(vb, vsc[i, table].reshape(S, Hkv),
                                  x.dtype)
             qg = qh.reshape(C, Hkv, group, Dh)
             sc = jnp.einsum("ckgd,skd->kgcs", qg, kb)

@@ -518,13 +518,14 @@ def _build_verify(cfg, k, donate, shardings=None):
             # every row of a request shares its table: gather the
             # request's logical cache view once per layer, mask per
             # row by position (paged_attention's formulation with a
-            # row axis added)
-            kb = ck[i][tables].reshape(B, S, Hkv, Dh)
-            vb = cv[i][tables].reshape(B, S, Hkv, Dh)
+            # row axis added); ck[i, tables] is one gather over the
+            # stack, ck[i][tables] would first copy the layer's pool
+            kb = ck[i, tables].reshape(B, S, Hkv, Dh)
+            vb = cv[i, tables].reshape(B, S, Hkv, Dh)
             if cfg.kv_quant:
-                kb = _kv_dequant(kb, ksc[i][tables].reshape(B, S, Hkv),
+                kb = _kv_dequant(kb, ksc[i, tables].reshape(B, S, Hkv),
                                  x.dtype)
-                vb = _kv_dequant(vb, vsc[i][tables].reshape(B, S, Hkv),
+                vb = _kv_dequant(vb, vsc[i, tables].reshape(B, S, Hkv),
                                  x.dtype)
             qg = qh.reshape(B, K1, Hkv, group, Dh)
             sc = jnp.einsum("bckgd,bskd->bkgcs", qg, kb) * scale

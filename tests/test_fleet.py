@@ -475,6 +475,16 @@ def test_router_timeout_retries_hung_replica(model, fleet_cleanup):
     router.scrape()
     prompts = _prompts(1, seed=51)
     [ref] = _reference_tokens(model, prompts, 6)
+    # the repeated prompt hits the live replica's prefix cache and runs
+    # the suffix (chunk) program: compile it here, into the process-wide
+    # program cache, not inside the router's 0.5 s timeout (a 0.3 s XLA
+    # compile on a CPU shared with five other test workers outlasts it,
+    # and then the LIVE replica is the one that times out)
+    warm = _engine(model)
+    for _ in range(2):
+        warm.submit(prompts[0], max_new_tokens=6)
+        warm.run()
+    warm.shutdown()
     # drive requests until one lands on the hung replica first (the
     # rr tiebreak guarantees it within two requests)
     saw_timeout = False

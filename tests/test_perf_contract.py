@@ -162,29 +162,41 @@ def _nonscalar_f64(text):
     return re.findall(r"tensor<[0-9x]+xf64>", text)
 
 
+@pytest.mark.parametrize("cache", ["stacked", "single"])
 @pytest.mark.parametrize("variant", ["bf16", "int8kv", "windowed"])
-def test_paged_kernel_lowers_for_tpu(variant):
+def test_paged_kernel_lowers_for_tpu(variant, cache):
     """The paged-decode kernel is the TPU default of the serve engine
     and must pass Mosaic lowering compiled (``interpret=False``) at a
-    served geometry: 8 rows, 32 q / 8 kv heads of 128, 16-token blocks."""
+    served geometry: 8 rows, 32 q / 8 kv heads of 128, 16-token blocks.
+    ``stacked`` is the engine's call: the whole ``(L, ...)`` cache and a
+    static layer, which must reach the custom call as the 5-D operand
+    with no slice in front of it.  ``single`` is a lone 4-D cache, the
+    same kernel through ``cache[None]``."""
     from mxnet_tpu.ops.pallas_paged_attention import paged_attention_kernel
 
-    B, Hq, Hkv, Dh, bs, W, nb = 8, 32, 8, 128, 16, 32, 65
+    B, Hq, Hkv, Dh, bs, W, nb, L = 8, 32, 8, 128, 16, 32, 65, 4
     quant = variant == "int8kv"
+    lead, layer = ((L,), 2) if cache == "stacked" else ((), None)
     q = jnp.zeros((B, Hq, Dh), jnp.bfloat16)
-    kc = jnp.zeros((nb, bs, Hkv, Dh), jnp.int8 if quant else jnp.bfloat16)
-    sc = jnp.ones((nb, bs, Hkv), jnp.float32) if quant else None
+    kc = jnp.zeros(lead + (nb, bs, Hkv, Dh),
+                   jnp.int8 if quant else jnp.bfloat16)
+    sc = jnp.ones(lead + (nb, bs, Hkv), jnp.float32) if quant else None
     bt = jnp.zeros((B, W), jnp.int32)
     ctx = jnp.ones((B,), jnp.int32)
 
-    def fwd(q, kc, bt, ctx):
+    def fwd(q, kc, sc, bt, ctx):
         return paged_attention_kernel(
             q, kc, kc, bt, ctx, window=64 if variant == "windowed" else 0,
-            k_scale=sc, v_scale=sc, interpret=False)
+            k_scale=sc, v_scale=sc, interpret=False, layer=layer)
 
-    t = _tpu_text(fwd, q, kc, bt, ctx)
+    t = _tpu_text(fwd, q, kc, sc, bt, ctx)
     assert len(re.findall(r"tpu_custom_call", t)) == 1
     assert not _nonscalar_f64(t)
+    assert not re.search(r"stablehlo\.(dynamic_)?slice\b", t)
+    (operands,) = hlo_audit.custom_call_operand_dims(t)
+    n_layers = L if cache == "stacked" else 1
+    assert operands.count((n_layers, nb, bs, Hkv, Dh)) == 2, operands
+    assert operands.count((n_layers, nb, bs, Hkv)) == (2 if quant else 0)
 
 
 @pytest.mark.parametrize("tp", [1, 2])
@@ -211,6 +223,60 @@ def test_serve_programs_lower_for_tpu(tp):
                     assert len(re.findall(r"tpu_custom_call", t)) == 2
         finally:
             eng.shutdown()
+
+
+@pytest.fixture(scope="module", params=[1, 2], ids=["tp1", "tp2"])
+def serve_tpu_texts(request):
+    """TPU-lowered StableHLO of the cache-READING serve programs at
+    ``tp`` 1 and 2 (bf16 parameters, the paged kernel on), and the
+    stacked cache's shape.  Lowered once per ``tp``; ``assume_tpu`` is
+    left again before any test runs."""
+    with hlo_audit.assume_tpu():
+        eng = hlo_audit.build_serve_engine(dtype="bfloat16",
+                                           tp=request.param, block_size=8)
+        try:
+            texts = {kind: hlo_audit.serve_lower_text(eng, kind, bucket,
+                                                      platform="tpu")
+                     for kind, bucket in (("decode", 4), ("chunk", 8),
+                                          ("verify", 4), ("draft", 4))}
+            return request.param, tuple(eng._cache_k.shape), texts
+        finally:
+            eng.shutdown()
+
+
+@pytest.mark.parametrize("kind", ["decode", "chunk", "verify", "draft"])
+def test_serve_programs_read_cache_in_place(serve_tpu_texts, kind):
+    """A layer of the stacked KV cache is addressed, never sliced: no
+    ``slice`` / ``dynamic_slice`` of a serve program yields a whole
+    layer of the cache (on the chip each was a copy of the layer's
+    pool, K and V, every layer of every pass), and every paged-kernel
+    custom call takes the 5-D stack itself, a head shard of it at
+    tp=2.  The draft program reads its own one-layer stack."""
+    tp, cache_shape, texts = serve_tpu_texts
+    text = texts[kind]
+    assert hlo_audit.cache_layer_slices(text, cache_shape) == []
+    calls = hlo_audit.custom_call_operand_dims(text)
+    L, nb, bs, Hkv, Dh = cache_shape
+    if kind == "decode":
+        assert len(calls) == L
+        for operands in calls:
+            assert operands.count((L, nb, bs, Hkv // tp, Dh)) == 2, operands
+    elif kind == "draft":
+        # k drafting steps of a one-layer draft model (the write-only
+        # last step's attention is dead code); the draft's cache is
+        # replicated under tp, so its kernel sees every head
+        assert len(calls) == 2
+        for operands in calls:
+            stacks = [d for d in operands if len(d) == 5]
+            assert len(stacks) == 2 and stacks[0] == stacks[1], operands
+            assert stacks[0][:3] == (1, nb, bs), operands
+    else:
+        # chunk and verify score through one gather over the stack
+        assert calls == []
+        gathers = [ln for ln in text.splitlines()
+                   if "stablehlo.gather" in ln
+                   and f"tensor<{L}x{nb}x{bs}x" in ln]
+        assert len(gathers) == 2 * L, (kind, len(gathers))
 
 
 # -- 2. HLO structural audits over the bench train steps --------------------
@@ -564,20 +630,33 @@ def test_dp_sp_flash_gpt_lowers_for_tpu():
 # engine._program_builder) and pin dot_general / transpose counts plus
 # cost_analysis() flops, so a lowering regression in the decode hot
 # path — an extra gather-induced transpose, a duplicated matmul, a
-# flops blow-up — fails CI on CPU alone.  Counts measured identical
+# flops blow-up — fails CI on CPU alone (flops as a band around the
+# analytic count: see SERVE_FLOPS_TOL).  Counts measured identical
 # under cpu and --tpu lowering at this config (no Pallas at these tiny
 # shapes), so the CPU pins audit the real TPU program structure too.
 
 SERVE_PINS = {
-    # (kind, bucket): transposes, act_transposes, dot_generals, flops
-    ("prefill", 8):     (17, 4, 17, 451136),
-    ("chunk", 8):       (17, 4, 17, 518645),
-    ("decode", 4):      (13, 0, 17, 275472),
-    ("draft", 4):       (16, 0, 20, 106390),
-    ("draft_chunk", 8): (9, 2, 9, 82665),
-    ("verify", 4):      (17, 4, 17, 824608),
-    ("restore", 4):     (0, 0, 0, 566),
+    # (kind, bucket): transposes, act_transposes, dot_generals, and
+    # cost_analysis() flops over the engine's analytic count
+    # (Engine._analytic_cost: flops.gpt_token_flops / gpt_prefill_flops
+    # over the padded shapes; None: a pure copy program, no matmul)
+    ("prefill", 8):     (17, 4, 17, 1.013),
+    ("chunk", 8):       (17, 4, 17, 1.037),
+    ("decode", 4):      (13, 0, 17, 1.100),
+    ("draft", 4):       (16, 0, 20, 1.278),
+    ("draft_chunk", 8): (9, 2, 9, 0.996),
+    ("verify", 4):      (17, 4, 17, 1.100),
+    ("restore", 4):     (0, 0, 0, None),
 }
+# How far a family's flops ratio may sit from its pin.  XLA's count is
+# the matmuls (the analytic count, which moves with the audit engine's
+# geometry and not with jax) plus whatever the installed jax bills for
+# softmax, layer norm and the other elementwise work, and that part has
+# moved by 2-5 % of the total between jax releases (verify-4 read 824608
+# under the jax these pins were first taken with and 801848 under 0.4.x
+# today, draft-4 106390 and 101064).  The smallest structural slip worth
+# catching, one more d_model x d_model projection in decode, is 6 %.
+SERVE_FLOPS_TOL = 0.055
 
 
 @pytest.fixture(scope="module")
@@ -602,10 +681,20 @@ def test_serve_program_op_counts(serve_audit_engine, kind, bucket):
 @pytest.mark.parametrize("kind,bucket", sorted(SERVE_PINS))
 def test_serve_program_cost_flops(serve_audit_engine, kind, bucket):
     """cost_analysis() flops — the numbers the engine's perf cost
-    table captures at resolve time — stay pinned per family."""
+    table captures at resolve time — stay in a band around the
+    engine's own analytic count for the family."""
     flops = hlo_audit.serve_cost_flops(serve_audit_engine, kind, bucket)
     assert flops is not None, (kind, bucket)
-    assert int(flops) == SERVE_PINS[(kind, bucket)][3], (kind, flops)
+    analytic, _ = serve_audit_engine._analytic_cost(kind, bucket)
+    pin = SERVE_PINS[(kind, bucket)][3]
+    if pin is None:
+        # restore scatters host blocks into the cache: index arithmetic
+        # only, and the analytic table has no flops for it either
+        assert analytic is None and flops < 1024, (kind, flops)
+        return
+    ratio = flops / analytic
+    assert abs(ratio / pin - 1.0) < SERVE_FLOPS_TOL, (kind, flops,
+                                                      analytic, ratio)
 
 
 def test_analytic_flops_cross_check(serve_audit_engine):

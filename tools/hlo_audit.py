@@ -128,6 +128,44 @@ def audit_counts(text):
     }
 
 
+def _dims(tensor_type):
+    """``tensor<2x64x8x4x8xbf16>`` -> ``(2, 64, 8, 4, 8)``."""
+    return tuple(int(d) for d in re.findall(r"(\d+)x", tensor_type))
+
+
+def cache_layer_slices(text, cache_shape):
+    """The ``stablehlo.slice`` / ``dynamic_slice`` ops of lowered text
+    whose RESULT is a whole layer of the stacked ``(L, num_blocks,
+    block_size, Hkv, Dh)`` KV cache (any head count: a tp shard holds
+    Hkv / tp).  Such a slice in front of a custom call or a gather is a
+    copy of that layer's whole pool on the chip (PERF.md, PR 27), so
+    the serve programs must lower with none."""
+    _, nb, bs, _, dh = cache_shape
+    found = []
+    for line in text.splitlines():
+        if not re.search(r"stablehlo\.(dynamic_)?slice\b", line):
+            continue
+        dims = _dims(line.rsplit("->", 1)[-1])
+        while dims[:1] == (1,):
+            dims = dims[1:]
+        if len(dims) == 4 and (dims[0], dims[1], dims[3]) == (nb, bs, dh):
+            found.append(line.strip()[:200])
+    return found
+
+
+def custom_call_operand_dims(text):
+    """Per ``tpu_custom_call`` of lowered text, the dims of each of its
+    operands (the type signature that ends the op's line)."""
+    calls = []
+    for line in text.splitlines():
+        if "@tpu_custom_call" not in line:
+            continue
+        operands = line.rsplit(" : (", 1)[-1].rsplit(") ->", 1)[0]
+        calls.append([_dims(t) for t in re.findall(r"tensor<[^>]*>",
+                                                   operands)])
+    return calls
+
+
 # -- serve program families ---------------------------------------------------
 # the serve-side analog of the train-step audit: lower the EXACT
 # bucketed programs serve.Engine dispatches (engine._program_builder —
