@@ -10,8 +10,9 @@ from .inception import inception_bn, inception_bn_small, googlenet
 from .vgg import vgg, alexnet
 from .transformer import gpt
 from .generate import gpt_decode_config, gpt_generate
+from .hybrid import hybrid_decoder
 
 __all__ = ["lenet", "mlp", "resnet", "lstm_unroll", "lstm_cell",
            "LSTMState", "LSTMParam", "ssd",
            "inception_bn", "inception_bn_small", "googlenet", "vgg", "alexnet",
-           "gpt", "gpt_generate", "gpt_decode_config"]
+           "gpt", "gpt_generate", "gpt_decode_config", "hybrid_decoder"]

@@ -359,7 +359,8 @@ def resolve_paged_impl(block_size, head_dim, impl=None):
 @hot_path
 def paged_attention(q, k_cache, v_cache, block_tables, context_lens,
                     window=0, scale=None, k_scale=None, v_scale=None,
-                    impl=None, mesh=None, head_axis=None, layer=None):
+                    impl=None, mesh=None, head_axis=None, layer=None,
+                    flat_heads=None):
     """Single-token decode attention over a paged KV-cache.
 
     The serving engine (``mxnet_tpu/serve``) keeps one fixed
@@ -414,10 +415,20 @@ def paged_attention(q, k_cache, v_cache, block_tables, context_lens,
         index of the jnp gather — and never sliced out first: a slice
         of the stack that feeds a custom call is a copy of that
         layer's whole pool (serve/engine.py passes its stack as is).
+      flat_heads: the caches are FLAT in their minor axis, ``(L,
+        num_blocks, block_size, flat_heads * Dh)``, every kv head of a
+        position side by side (serve/hybrid.py's layout for heads
+        smaller than the 128 lanes: a ``(8, 64)`` bfloat16 tile is
+        padded fourfold on the chip, side by side nothing is).  Takes
+        ``layer``; no window, int8 scales or mesh.
 
     Returns (B, Hq, Dh) attention output in q's dtype.
     """
     B, Hq, Dh = q.shape
+    if flat_heads is not None:
+        return _paged_attention_flat(q, k_cache, v_cache, block_tables,
+                                     context_lens, layer, int(flat_heads),
+                                     scale, impl, window, k_scale, mesh)
     if (layer is None) != (k_cache.ndim == 4):
         raise ValueError("paged_attention: stacked (L, num_blocks, "
                          "block_size, Hkv, Dh) caches take `layer`, "
@@ -493,6 +504,50 @@ def paged_attention(q, k_cache, v_cache, block_tables, context_lens,
     out = jnp.where((context_lens > 0)[:, None, None, None], out,
                     jnp.zeros((), out.dtype))
     return out.reshape(B, Hq, Dh)
+
+
+def packed_eligible(kv_heads, head_dim):
+    """Whether the packed kernel can serve a flat cache of this geometry:
+    whole kv heads fill the 128 lanes (head size 64: two, 32: four) and
+    the kv heads divide into such groups."""
+    return (head_dim < 128 and 128 % head_dim == 0
+            and kv_heads % (128 // head_dim) == 0)
+
+
+def flat_paged_impl(block_size, kv_heads, head_dim, impl=None):
+    """``resolve_paged_impl`` for a flat stacked cache: the packed kernel
+    serves it only where whole kv heads fill the lanes."""
+    if (resolve_paged_impl(block_size, head_dim, impl) == "pallas"
+            and packed_eligible(kv_heads, head_dim)):
+        return "pallas"
+    return "jnp"
+
+
+def _paged_attention_flat(q, k_cache, v_cache, block_tables, context_lens,
+                          layer, Hkv, scale, impl, window, k_scale, mesh):
+    B, Hq, Dh = q.shape
+    if (window or k_scale is not None or mesh is not None or layer is None
+            or k_cache.ndim != 4):
+        raise ValueError("paged_attention: a flat cache is stacked (L, "
+                         "num_blocks, block_size, Hkv * Dh) and takes "
+                         "`layer`, no window, no int8 scales and no mesh")
+    bs, flat = k_cache.shape[-2:]
+    if flat != Hkv * Dh or Hq % Hkv:
+        raise ValueError(f"paged_attention: a flat cache of width {flat} "
+                         f"does not hold kv heads of size {Dh} that "
+                         f"divide {Hq} query heads")
+    if flat_paged_impl(bs, Hkv, Dh, impl) == "pallas":
+        from .pallas_paged_attention import paged_attention_packed_kernel
+
+        return paged_attention_packed_kernel(
+            q, k_cache, v_cache, block_tables, context_lens, layer,
+            scale=scale)
+    # the XLA formulation is the stacked one's, over the same bytes seen
+    # with the heads apart (off the chip a reshape costs nothing)
+    split = k_cache.shape[:3] + (Hkv, Dh)
+    return paged_attention(q, k_cache.reshape(split), v_cache.reshape(split),
+                           block_tables, context_lens, scale=scale,
+                           impl="jnp", layer=layer)
 
 
 # -- rotary position embedding ------------------------------------------------

@@ -52,11 +52,13 @@ from ..lint.annotations import hot_path
 # the single eligibility definition lives with the dispatcher (which
 # must be importable without Pallas); re-exported here for the tests
 from . import pallas_util
-from .attention import paged_eligible, score_scale  # noqa: F401
+from .attention import (packed_eligible, paged_eligible,  # noqa: F401
+                        score_scale)
 from .flash_attention import gqa_group
 from .pallas_util import idx32
 
-__all__ = ["paged_attention_kernel", "paged_eligible"]
+__all__ = ["paged_attention_kernel", "paged_attention_packed_kernel",
+           "packed_eligible", "paged_eligible"]
 
 # np.float32, not Python floats: under jax_enable_x64 a bare literal in
 # a Mosaic kernel body is a weak f64 constant with no f64->f32 cast
@@ -242,3 +244,130 @@ def paged_attention_kernel(q, k_cache, v_cache, block_tables,
     )(jnp.asarray(block_tables, jnp.int32),
       jnp.asarray(context_lens, jnp.int32), *args)
     return out.reshape(B, Hq, Dh)
+
+
+# -- small heads: several kv heads side by side on the lanes ------------------
+
+def _packed_kernel(bt_ref, ctx_ref, q_ref, k_ref, v_ref, o_ref, acc, m_sc,
+                   l_sc, *, scale, bs, nW, Hp, rows):
+    """One grid step (b, w) over a cache whose minor axis holds every kv
+    head of a position side by side.  Lane group ``j`` (128 lanes) holds
+    ``pack`` kv heads; the query block is block-diagonal (the rows of
+    head ``p`` are zero outside its own lanes), so ONE lane-aligned
+    product gives every head's scores and one more its outputs.
+
+    Tried on the chip and taken out again (PERF.md, PR 29): streaming 8
+    table slots a step, and naming a dead slot's last live block so that
+    its DMA is skipped; neither shortened the kernel (10.2 -> 10.6 and
+    12.0 ms for 4 layers x 64 rows), whose time is the per-slot branch
+    and the (rows x 128) x (128 x 16) products, not the DMAs."""
+    b = pl.program_id(0)
+    w = pl.program_id(1)
+
+    @pl.when(w == 0)
+    def _():
+        acc[...] = jnp.zeros_like(acc)
+        m_sc[...] = jnp.full_like(m_sc, _NEG_INF)
+        l_sc[...] = jnp.zeros_like(l_sc)
+
+    ctx = ctx_ref[b]
+    base = w * bs
+
+    @pl.when(base < ctx)
+    def _():
+        pos = base + jax.lax.broadcasted_iota(jnp.int32, (rows, bs), 1)
+        keep = pos < ctx
+        for j in range(Hp):
+            k = k_ref[0, 0, :, j * 128:(j + 1) * 128]        # (bs, 128)
+            v = v_ref[0, 0, :, j * 128:(j + 1) * 128]
+            q = q_ref[0, j]                                  # (rows, 128)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            s = jnp.where(keep, s, _NEG_INF)
+            m_prev = m_sc[j, :, 0]
+            m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1))
+            p = jnp.where(keep, jnp.exp(s - m_cur[:, None]), _ZERO)
+            alpha = jnp.exp(m_prev - m_cur)
+            l_sc[j, :, 0] = l_sc[j, :, 0] * alpha + jnp.sum(p, axis=-1)
+            acc[j] = acc[j] * alpha[:, None] + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_sc[j, :, 0] = m_cur
+
+    @pl.when(w == nW - 1)
+    def _():
+        for j in range(Hp):
+            l_row = l_sc[j, :, 0]
+            valid = l_row > _ZERO
+            l_fin = jnp.maximum(l_row, _TINY)
+            o_ref[0, j] = jnp.where(valid[:, None], acc[j] / l_fin[:, None],
+                                    _ZERO).astype(o_ref.dtype)
+
+
+@hot_path
+def paged_attention_packed_kernel(q, k_cache, v_cache, block_tables,
+                                  context_lens, layer, scale=None,
+                                  interpret=None):
+    """Paged decode attention over a FLAT stacked cache ``(L, num_blocks,
+    block_size, Hkv * Dh)`` for heads smaller than the 128 lanes.
+
+    A cache whose two minor axes are ``(Hkv, Dh) = (8, 64)`` is tiled
+    (16, 128) in bfloat16 on the chip: padded fourfold, in HBM and in
+    every block the kernel streams.  Flat, a position's heads lie side by
+    side and nothing is padded.  Same contract otherwise as
+    :func:`paged_attention_kernel` (no window, no int8 scales): q ``(B,
+    Hq, Dh)``, returns ``(B, Hq, Dh)``; empty rows return zeros.
+    """
+    B, Hq, Dh = q.shape
+    L, nb, bs, flat = k_cache.shape
+    Hkv = flat // Dh
+    if flat != Hkv * Dh or not packed_eligible(Hkv, Dh):
+        raise ValueError(f"paged_attention: a flat cache of width {flat} "
+                         f"does not hold whole lane groups of heads of "
+                         f"size {Dh}")
+    layer = int(layer)
+    if not 0 <= layer < L:
+        raise ValueError(f"paged_attention: layer {layer} outside the "
+                         f"cache's {L} layers")
+    group = gqa_group(Hq, Hkv)
+    pack = 128 // Dh
+    Hp, rows = Hkv // pack, pack * group
+    scale = score_scale(Dh) if scale is None else np.float32(scale)
+    if interpret is None:
+        interpret = not pallas_util.on_tpu()
+    W = block_tables.shape[1]
+    # block-diagonal queries: head p's rows are zero outside its lanes
+    q6 = q.reshape(B, Hp, pack, group, 1, Dh)
+    eye = jnp.eye(pack, dtype=q.dtype)[None, None, :, None, :, None]
+    q2 = (q6 * eye).reshape(B, Hp, rows, 128)
+
+    per_req = idx32(lambda b, w, bt, ctx: (b, 0, 0, 0))
+
+    per_blk = idx32(lambda b, w, bt, ctx: (layer, bt[b, w], 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B, W),
+        in_specs=[pl.BlockSpec((1, Hp, rows, 128), per_req),
+                  pl.BlockSpec((1, 1, bs, flat), per_blk),
+                  pl.BlockSpec((1, 1, bs, flat), per_blk)],
+        out_specs=pl.BlockSpec((1, Hp, rows, 128), per_req),
+        scratch_shapes=[pltpu.VMEM((Hp, rows, 128), jnp.float32),
+                        pltpu.VMEM((Hp, rows, 1), jnp.float32),
+                        pltpu.VMEM((Hp, rows, 1), jnp.float32)],
+    )
+    out = pl.pallas_call(
+        functools.partial(_packed_kernel, scale=scale, bs=bs, nW=W, Hp=Hp,
+                          rows=rows),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, Hp, rows, 128), q.dtype),
+        name="paged_attention_packed",
+        # mxtpu-lint: disable=host-sync (static host flag chosen at
+        # trace time, never a device value)
+        interpret=bool(interpret),
+        **_params(interpret),
+    )(jnp.asarray(block_tables, jnp.int32),
+      jnp.asarray(context_lens, jnp.int32), q2, k_cache, v_cache)
+    o6 = out.reshape(B, Hp, pack, group, pack, Dh)
+    own = jnp.stack([o6[:, :, p, :, p, :] for p in range(pack)], axis=2)
+    return own.reshape(B, Hq, Dh)

@@ -1,0 +1,132 @@
+"""The single-token state-space update as a Pallas TPU kernel.
+
+A decode step of a state-space layer reads each live row's state
+(H x P x N float32, 2 MiB at 64 x 64 x 128) and writes it back:
+
+    S = exp(dA) * S + (dt * x) (x) B          y = S C
+
+The states of every layer and request live in ONE stacked pool
+``(layers, slots, H, P, N)``.  The grid walks ``(row, head group)``;
+the row's slot is scalar-prefetched, so the block's DMA address is
+``(layer, slots[b], g, 0, 0)`` and the pool is aliased to the output:
+each live state crosses HBM once in and once out, and nothing else of
+the pool moves.  A ``pool[layer]`` slice in front of a custom call, or
+a gather and a scatter around an XLA fusion, would copy the layer's
+pool or the live states two more times (PERF.md, PR 27's lesson).
+
+Layout inside the kernel: a head's state tile is (P, N) with N on the
+lanes.  ``dt * x`` arrives transposed, (P, heads), so that a head's
+column broadcasts along the lanes with no relayout, and ``y`` leaves
+the same way; the caller transposes both (a few KB).
+
+Padded rows of a bucket name the null slot 0, whose contents are
+garbage by design.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ..lint.annotations import hot_path
+from . import pallas_util
+from .pallas_util import idx32
+
+__all__ = ["ssm_update_kernel", "KERNEL_NAME"]
+
+# the kernel's name in a device trace (benchmark readers look for it)
+KERNEL_NAME = "ssm_state_update"
+
+# heads of one row a grid step moves: 64 x (64, 128) float32 = 2 MiB in
+# and 2 MiB out, double-buffered 8 MiB of VMEM.  On one v5e chip at 64
+# rows x 36 layers: 16 heads a step 64 % of the HBM peak, 32: 69 %,
+# 64: 71 % (my chip run, PR 29)
+HEADS_PER_STEP = 64
+
+
+def _kernel(slots_ref, pool_ref, xdt_ref, dec_ref, b_ref, c_ref,
+            y_ref, pool_out_ref, *, hb):
+    del slots_ref                       # consumed by the index maps
+    Bv = b_ref[0]                       # (1, N)
+    Cv = c_ref[0]
+    for h in range(hb):
+        S = pool_ref[0, 0, h]           # (P, N)
+        col = xdt_ref[0, 0, :, h:h + 1]         # (P, 1)
+        a = dec_ref[0, 0, :, h:h + 1]           # (1, 1)
+        S = a * S + col * Bv
+        pool_out_ref[0, 0, h] = S
+        y_ref[0, 0, :, h:h + 1] = jnp.sum(S * Cv, axis=-1, keepdims=True)
+
+
+@hot_path
+def ssm_update_kernel(pool, layer, slots, x, dt, dA, Bm, Cm, D,
+                      heads_per_step=None, interpret=None):
+    """Same contract as ``ops.ssm.ssm_state_update``: pool (L, S, H, P,
+    N) float32 with the static ``layer``; slots (B,) int32; x (B, H,
+    P); dt, dA (B, H); Bm, Cm (B, N); D (H,).  Returns ``(y (B, H, P)
+    in x's dtype, the pool)``."""
+    L, S, H, P, N = pool.shape
+    B = x.shape[0]
+    if not 0 <= layer < L:
+        raise ValueError(f"ssm_update: layer {layer} outside the pool's "
+                         f"{L} layers")
+    if heads_per_step is None:
+        heads_per_step = HEADS_PER_STEP
+    hb = min(int(heads_per_step), H)
+    if H % hb:
+        raise ValueError(f"ssm_update: {H} heads do not divide into "
+                         f"groups of {hb}")
+    G = H // hb
+    if interpret is None:
+        interpret = not pallas_util.on_tpu()
+    f32 = jnp.float32
+    xf = x.astype(f32)
+    # (B, H, P) -> (B, G, P, hb): a head's dt*x is one lane-column
+    xdt = (xf * dt.astype(f32)[..., None]).reshape(B, G, hb, P)
+    xdt = jnp.swapaxes(xdt, 2, 3)
+    dec = jnp.exp(dA.astype(f32)).reshape(B, G, 1, hb)
+    b3 = Bm.astype(f32).reshape(B, 1, N)
+    c3 = Cm.astype(f32).reshape(B, 1, N)
+
+    per_state = idx32(lambda b, g, sl: (layer, sl[b], g, 0, 0))
+    per_row = idx32(lambda b, g, sl: (b, g, 0, 0))
+    per_vec = idx32(lambda b, g, sl: (b, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(B, G),
+        in_specs=[
+            pl.BlockSpec((1, 1, hb, P, N), per_state),
+            pl.BlockSpec((1, 1, P, hb), per_row),
+            pl.BlockSpec((1, 1, 1, hb), per_row),
+            pl.BlockSpec((1, 1, N), per_vec),
+            pl.BlockSpec((1, 1, N), per_vec),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, 1, P, hb), per_row),
+            pl.BlockSpec((1, 1, hb, P, N), per_state),
+        ],
+    )
+    kw = {}
+    if not interpret:
+        kw["compiler_params"] = pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"))
+    yT, pool = pl.pallas_call(
+        functools.partial(_kernel, hb=hb),
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((B, G, P, hb), f32),
+                   jax.ShapeDtypeStruct(pool.shape, pool.dtype)],
+        # operand 0 is the scalar-prefetched slots; the pool is operand 1
+        input_output_aliases={1: 1},
+        name=KERNEL_NAME,
+        # mxtpu-lint: disable=host-sync (static host flag chosen at
+        # trace time, never a device value)
+        interpret=bool(interpret),
+        **kw,
+    )(jnp.asarray(slots, jnp.int32), pool, xdt, dec, b3, c3)
+    y = jnp.swapaxes(yT, 2, 3).reshape(B, H, P)
+    y = y + xf * D.astype(f32)[None, :, None]
+    return y.astype(x.dtype), pool

@@ -68,6 +68,7 @@ from ..models.generate import (_fc, _gelu, _ln, detect_gpt_variant,
                                reconcile_decode_config)
 from ..parallel import partition as partition_mod
 from ..parallel.mesh import NamedSharding, PartitionSpec, make_mesh
+from ..models.hybrid import HybridDecoder
 from ..ops.attention import paged_attention, score_scale
 from ..telemetry import flight as flight_mod
 from ..telemetry import profiling
@@ -75,6 +76,7 @@ from ..telemetry import statusz as statusz_mod
 from ..telemetry.perf_attrib import PerfAttrib
 from ..telemetry.request_trace import RequestTracer
 from . import adapters as adapters_mod
+from . import hybrid as hybrid_mod
 from .kv_block_manager import BlockManager, HostKVPool
 from .scheduler import (CANCELLED, FINISHED, REJECTED, WAITING, QueueFull,
                         Request, Scheduler)
@@ -113,8 +115,13 @@ _ModelCfg = collections.namedtuple("_ModelCfg", [
     # padded rank ceiling.  adapters=0 (off, the default) follows the
     # sampling precedent — both fields leave the AOT fingerprint so an
     # adapters-off engine keeps its historical digests
-    "adapters", "adapter_rank"],
-    defaults=(0, 0))
+    "adapters", "adapter_rank",
+    # hybrid decoders (serve/hybrid.py): the decoder's description and
+    # each layer's place in its kind's cache stack.  None (every gpt()
+    # engine) follows the same only-when-on rule and leaves the AOT
+    # fingerprint, so the gpt programs keep their digests
+    "hybrid"],
+    defaults=(0, 0, None))
 
 # top-logprob candidates every sampling-mode program returns per
 # sampled position (static — the per-request ``logprobs`` count only
@@ -190,6 +197,14 @@ def _cfg_fp_fields(cfg):
         # same only-when-on rule: adapters-off keeps pre-LoRA digests
         d.pop("adapters", None)
         d.pop("adapter_rank", None)
+    if d.get("hybrid") is None:
+        d.pop("hybrid", None)
+    else:
+        # JSON-stable: the description's fields (the stack indices are
+        # derived from layer_types)
+        dec = d["hybrid"].dec._asdict()
+        dec["layer_types"] = list(dec["layer_types"])
+        d["hybrid"] = dec
     return d
 
 
@@ -349,7 +364,18 @@ class Engine:
                  quantize=None, kv_dtype=None, host_kv_bytes=None,
                  adapters=None, adapter_rank=None,
                  adapter_host_bytes=None):
-        if symbol is not None:
+        # a hybrid decoder's description (models/hybrid.py) in place of a
+        # gpt() symbol: layers of two kinds, a state pool beside the K/V
+        self._hybrid = (symbol if isinstance(symbol, HybridDecoder)
+                        else None)
+        if self._hybrid is not None:
+            if num_heads not in (None, self._hybrid.num_heads) or window:
+                raise ValueError(
+                    "a hybrid decoder carries its own num_heads and has "
+                    "no attention window")
+            num_heads, window, name = (self._hybrid.num_heads, 0,
+                                       self._hybrid.name)
+        elif symbol is not None:
             num_heads, window = reconcile_decode_config(symbol, num_heads,
                                                         window)
         if num_heads is None:
@@ -368,8 +394,29 @@ class Engine:
         max_queue = (int(max_queue) if max_queue is not None
                      else env_int("MXTPU_SERVE_MAX_QUEUE", 64))
 
-        params = normalize_gpt_params(params, name)
-        self.spec = detect_gpt_variant(params, num_heads, name)
+        if self._hybrid is not None:
+            self.spec = hybrid_mod.check_params(self._hybrid, params)
+        else:
+            params = normalize_gpt_params(params, name)
+            self.spec = detect_gpt_variant(params, num_heads, name)
+        if self._hybrid is not None:
+            # what a hybrid engine refuses, by name (docs/how_to/serve.md
+            # "Hybrid decoders"): arguments and their env defaults alike
+            def _arg(v, env):
+                return int(v) if v is not None else env_int(env, 0)
+
+            hybrid_mod.refuse(
+                prefix_cache=bool(prefix_cache),
+                spec_k=_arg(spec_k, "MXTPU_SERVE_SPEC"),
+                adapters=_arg(adapters, "MXTPU_SERVE_ADAPTERS"),
+                kv_dtype=(kv_dtype
+                          or os.environ.get("MXTPU_SERVE_KV_DTYPE")),
+                quantize=quantize or os.environ.get("MXTPU_SERVE_QUANT"),
+                tp=(int(tp) if tp is not None
+                    else env_int("MXTPU_SERVE_TP", 1)),
+                host_kv_bytes=_arg(host_kv_bytes,
+                                   "MXTPU_SERVE_HOST_KV_BYTES"))
+            prefix_cache = False
         self.name = name
         self.num_heads = int(num_heads)
         self.window = window
@@ -563,9 +610,11 @@ class Engine:
         self._host_pool = (HostKVPool(self.host_kv_bytes,
                                       block_tokens=self.block_size)
                            if self.host_kv_bytes else None)
-        self.blocks = BlockManager(self.num_blocks, self.block_size,
-                                   prefix_cache=prefix_cache,
-                                   host_pool=self._host_pool)
+        self.blocks = BlockManager(
+            self.num_blocks, self.block_size, prefix_cache=prefix_cache,
+            host_pool=self._host_pool,
+            # a hybrid request owns blocks AND one state slot
+            state_slots=self.max_batch if self._hybrid is not None else 0)
         # always registered: the eviction path only offloads with a
         # pool attached, but export_blocks (the prefill→decode handoff
         # serializer) gathers device blocks D2H through the same fetch
@@ -634,7 +683,9 @@ class Engine:
                 host_bytes=self.adapter_host_bytes,
                 shardings=(None if self._shardings is None
                            else self._shardings.adapters))
-        L = self.spec["n_layers"]
+        # a hybrid decoder's K/V is stacked over its attention layers only
+        L = (self.spec["n_layers"] if self._hybrid is None
+             else len(self._hybrid.attention_layers))
         # int8 KV blocks store quantized slots plus per-slot-per-head
         # f32 scales in a small parallel array pair indexed by the SAME
         # block ids — BlockManager accounting, the radix prefix cache,
@@ -644,6 +695,10 @@ class Engine:
         shape = (L, self.num_blocks, self.block_size,
                  self.spec["kv_heads"], self.spec["head_dim"])
         sshape = shape[:-1]
+        if self._hybrid is not None:
+            # flat in the minor axis: a (kv_heads, head_dim) = (8, 64)
+            # bf16 tile is padded fourfold on the chip (serve/hybrid.py)
+            shape = shape[:3] + (shape[3] * shape[4],)
         self._scale_k = self._scale_v = None
         if self._shardings is not None:
             # allocate the cache BORN sharded: a jnp.zeros-then-reshard
@@ -664,12 +719,25 @@ class Engine:
             if self._kv_quant:
                 self._scale_k = jnp.zeros(sshape, jnp.float32)
                 self._scale_v = jnp.zeros(sshape, jnp.float32)
+        # the per-request state pool of a hybrid decoder, beside the K/V:
+        # recurrent states float32, convolution rows in the activation
+        # dtype, slot 0 the null slot that padded rows write to
+        self._state_ssm = self._state_conv = None
+        if self._hybrid is not None:
+            hd = self._hybrid
+            M, S = len(hd.mamba_layers), self.max_batch + 1
+            self._state_ssm = jnp.zeros(
+                (M, S, hd.mamba_heads, hd.mamba_head_dim, hd.mamba_state),
+                jnp.float32)
+            self._state_conv = jnp.zeros(
+                (M, S, (hd.mamba_conv - 1) * hd.conv_dim), dt)
         self._key = jax.random.PRNGKey(seed)
         # donating the cache through each step avoids a full cache copy
         # per token; CPU PJRT can't donate (it would warn every call)
         self._donate = (jax.default_backend() != "cpu")
         self._cfg = _ModelCfg(
-            name=name, n_layers=L, num_heads=self.num_heads,
+            name=name, n_layers=self.spec["n_layers"],
+            num_heads=self.num_heads,
             head_dim=self.spec["head_dim"], kv_heads=self.spec["kv_heads"],
             pos_table=self.spec["pos_table"], swiglu=self.spec["swiglu"],
             tied=self.spec["tied"], rmsnorm=self.spec["rmsnorm"],
@@ -679,7 +747,15 @@ class Engine:
             numeric_watch=self._numeric_watch,
             kv_quant=self._kv_quant,
             adapters=self._adapters,
-            adapter_rank=self.adapter_rank if self._adapters else 0)
+            adapter_rank=self.adapter_rank if self._adapters else 0,
+            hybrid=(None if self._hybrid is None
+                    else hybrid_mod.hybrid_cfg(self._hybrid)))
+        # every parameter's shape and dtype, once: what _spec_key() needs
+        # beyond _ModelCfg to tell two engines' compiled programs apart
+        # (the vocabulary, the MLP width: widths no cfg field carries)
+        self._params_sig = hash(tuple(sorted(
+            (k, tuple(v.shape), str(v.dtype))
+            for k, v in self.params.items())))
         # draft worker last among the device placements: params, then
         # the target cache, then the (much smaller) draft side — the
         # same one-model-at-a-time HBM discipline shutdown() preserves
@@ -740,6 +816,14 @@ class Engine:
             "mxtpu_serve_evictions", "retained-block evictions (lifetime)")
         self._tel_rejected = telemetry.gauge(
             "mxtpu_serve_rejected", "rejected requests (lifetime)")
+        if self._hybrid is not None:
+            self._tel_state_slots = telemetry.gauge(
+                "mxtpu_serve_state_slots_in_use",
+                "state-pool slots held by admitted requests")
+            self._tel_state_resets = telemetry.counter(
+                "mxtpu_serve_state_resets_total",
+                "prefill passes that started a slot's state from zero",
+                ("reason",))
         telemetry.gauge("mxtpu_serve_blocks_total",
                         "allocatable KV-cache blocks").set(
             self.blocks.total_blocks)
@@ -766,7 +850,16 @@ class Engine:
                 # (env + backend + geometry): a kernel-decode program
                 # must never be served to an engine whose env pinned
                 # the jnp formulation, and vice versa
-                self._paged_impl())
+                self._paged_impl(),
+                # the parameters' shapes (vocabulary, MLP width): two
+                # engines that differ only there must not share compiled
+                # programs.  Not in the AOT fingerprint, which keeps the
+                # gpt digests where they were
+                self._params_sig,
+                # a hybrid engine's state pool (slots + 1, dtypes)
+                None if self._state_ssm is None else
+                (self._state_ssm.shape[1], str(self._state_ssm.dtype),
+                 str(self._state_conv.dtype)))
 
     def _aot_base_fp(self):
         """The on-disk form of _spec_key(): same fields, JSON-stable,
@@ -813,17 +906,25 @@ class Engine:
         # fingerprint is the store's only version, so it moves here
         paged = ({} if self._paged_impl() != "pallas"
                  else dict(paged_attention="pallas-stacked"))
+        # a hybrid engine's state pool and vocabulary (only-when-on)
+        state = ({} if self._state_ssm is None else dict(
+            state_slots=int(self._state_ssm.shape[1]),
+            state_dtypes=[str(self._state_ssm.dtype),
+                          str(self._state_conv.dtype)]))
         return aot_store.fingerprint(
             subsystem="serve", cfg=cfg_d,
             num_blocks=self.num_blocks, table_width=self.table_width,
             cache_dtype=str(self._cache_k.dtype), donate=self._donate,
-            **sharded, **spec, **quant, **paged)
+            **sharded, **spec, **quant, **paged, **state)
 
     def _paged_impl(self):
         """The paged-attention implementation this engine's programs
         trace ("pallas" or "jnp") — resolved from the env/backend/cache
         geometry exactly as ``ops.attention.paged_attention`` will."""
-        from ..ops.attention import resolve_paged_impl
+        from ..ops.attention import flat_paged_impl, resolve_paged_impl
+        if self._hybrid is not None:
+            return flat_paged_impl(self.block_size, self.spec["kv_heads"],
+                                   self.spec["head_dim"])
         return resolve_paged_impl(self.block_size,
                                   self.spec["head_dim"])
 
@@ -1062,6 +1163,9 @@ class Engine:
         if sprof.tracing:
             sprof.note(queue=self.scheduler.queue_depth,
                        running=len(self.scheduler.running))
+            if self._hybrid is not None:
+                # slots held right now, like the blocks sampled above
+                sprof.note(state_slots=self.blocks.state_slots_in_use)
         emitted = 0
         for req in prefills:
             sprof.enter("prefill_dispatch", rid=req.rid)
@@ -1116,6 +1220,8 @@ class Engine:
         self._tel_preempt.set(self.scheduler.preemptions)
         self._tel_evict.set(self.blocks.evictions)
         self._tel_rejected.set(self.scheduler.rejections)
+        if self._hybrid is not None:
+            self._tel_state_slots.set(self.blocks.state_slots_in_use)
         if sprof.tracing:
             # preemptions is the lifetime count: a reader takes differences
             sprof.note(blocks_in_use=self.blocks.blocks_in_use,
@@ -1236,6 +1342,9 @@ class Engine:
             # cache-cold replica (also nested in kv_blocks.prefix_cache)
             "prefix_cache": self.blocks.prefix_stats(),
             "kv_cache": self.kv_cache_stats(),
+            # a hybrid decoder's state pool: slots, in use, bytes, dtypes
+            # (None for gpt engines)
+            "state_cache": self.state_cache_stats(),
             # host-DRAM offload tier occupancy and hit/restore counters
             # (None when the tier is off — the inert default)
             "host_kv": self.host_kv_stats(),
@@ -1424,6 +1533,22 @@ class Engine:
             out["scale_bytes_per_device"] = sb // self.tp
         return out
 
+    def state_cache_stats(self):
+        """The ``/statusz`` ``state_cache`` section of a hybrid engine:
+        the pool's slots (the null slot excluded), how many admitted
+        requests hold one, its bytes and dtypes (None for gpt engines)."""
+        if self._state_ssm is None:
+            return None
+        slots = int(self._state_ssm.shape[1]) - 1
+        total = int(self._state_ssm.nbytes) + int(self._state_conv.nbytes)
+        return {"slots": slots,
+                "in_use": self.blocks.state_slots_in_use,
+                "layers": int(self._state_ssm.shape[0]),
+                "bytes_total": total,
+                "bytes_per_slot": total // (slots + 1),
+                "ssm_dtype": str(self._state_ssm.dtype),
+                "conv_dtype": str(self._state_conv.dtype)}
+
     def shutdown(self):
         """Cancel in-flight work and release the device cache.
 
@@ -1458,7 +1583,9 @@ class Engine:
             self._spec = None
         for arr in (self._owned + [self._cache_k, self._cache_v]
                     + ([self._scale_k, self._scale_v]
-                       if self._scale_k is not None else [])):
+                       if self._scale_k is not None else [])
+                    + ([self._state_ssm, self._state_conv]
+                       if self._state_ssm is not None else [])):
             try:
                 arr.delete()
             except (RuntimeError, ValueError):
@@ -1466,6 +1593,7 @@ class Engine:
         self._owned = []
         self._cache_k = self._cache_v = None
         self._scale_k = self._scale_v = None
+        self._state_ssm = self._state_conv = None
         if self._host_pool is not None:
             # the DRAM tier releases WITH the device buffers: two
             # engines back-to-back must never transiently hold two
@@ -1532,6 +1660,23 @@ class Engine:
             slots[i] = req.adapter_slot
         return (jnp.asarray(slots),)
 
+    def _req_state_operand(self, req):
+        """Scalar state-slot operand of a hybrid engine's prefill and
+        chunk programs (empty for gpt engines)."""
+        if self._hybrid is None:
+            return ()
+        return (jnp.asarray(self.blocks.state_slot(req.rid), jnp.int32),)
+
+    def _batch_state_operands(self, reqs, bucket):
+        """(B,)-shaped state slots of a hybrid engine's decode program;
+        padded rows name the null slot 0."""
+        if self._hybrid is None:
+            return ()
+        slots = np.zeros(bucket, np.int32)
+        for i, req in enumerate(reqs):
+            slots[i] = self.blocks.state_slot(req.rid)
+        return (jnp.asarray(slots),)
+
     def _note_logprobs(self, req, chosen, tv, ti):
         """Record emitted tokens' logprob outputs on the request: the
         chosen-token logprob always (sampling mode), the top view
@@ -1579,6 +1724,9 @@ class Engine:
         if self._kv_quant:
             return (self._cache_k, self._cache_v,
                     self._scale_k, self._scale_v)
+        if self._hybrid is not None:
+            return (self._cache_k, self._cache_v,
+                    self._state_ssm, self._state_conv)
         return (self._cache_k, self._cache_v)
 
     def _set_caches(self, arrs):
@@ -1587,6 +1735,9 @@ class Engine:
         if self._kv_quant:
             (self._cache_k, self._cache_v,
              self._scale_k, self._scale_v) = arrs
+        elif self._hybrid is not None:
+            (self._cache_k, self._cache_v,
+             self._state_ssm, self._state_conv) = arrs
         else:
             self._cache_k, self._cache_v = arrs
 
@@ -1703,6 +1854,7 @@ class Engine:
                     jnp.asarray(toks), jnp.asarray(n, jnp.int32),
                     jnp.asarray(blk), jnp.asarray(off)) \
                 + self._req_adapter_operand(req) \
+                + self._req_state_operand(req) \
                 + self._req_sampling_operands(req) + (sub,)
         else:
             # suffix/chunk pass: positions [start, end) attend through
@@ -1728,11 +1880,23 @@ class Engine:
                     jnp.asarray(span, jnp.int32), jnp.asarray(tw),
                     jnp.asarray(blk), jnp.asarray(off)) \
                 + self._req_adapter_operand(req) \
+                + self._req_state_operand(req) \
                 + self._req_sampling_operands(req) + (sub,)
         sprof = self._sprof
+        state = None
+        if self._hybrid is not None:
+            # a pass from position 0 starts the slot's state from zero
+            # inside the program: at admission, and again when a
+            # preempted request is prefilled anew
+            state = ("carried" if start else
+                     "reset" if resume else "fresh")
+            if not start:
+                self._tel_state_resets.labels(
+                    reason="preempt" if resume else "admit").inc()
         if sprof.tracing:
             sprof.note(kind=pkind, tokens=span, bucket=bucket,
-                       cached=req.cached_prefix_len)
+                       cached=req.cached_prefix_len,
+                       **({} if state is None else {"state": state}))
         t0 = self._perf.t0()
         outs = fn(*args)
         self._perf.done(t0, pkind, bucket, outs)
@@ -1804,6 +1968,7 @@ class Engine:
                   jnp.asarray(toks), jnp.asarray(pos),
                   jnp.asarray(tables),
                   *self._batch_adapter_operands(reqs, bucket),
+                  *self._batch_state_operands(reqs, bucket),
                   *self._batch_sampling_operands(reqs, bucket), sub)
         self._perf.done(t0, "decode", bucket, outs)
         self._sprof.enter("device_wait")
@@ -2204,6 +2369,12 @@ class Engine:
         tests/test_perf_contract.py."""
         from .. import flops as flops_mod
 
+        if self._hybrid is not None:
+            # 2 operations per matrix parameter per row, the tied head
+            # once per sampled row; attention's score terms left out
+            rows = 1 if kind != "decode" else bucket
+            return (hybrid_mod.matmul_flops(self._hybrid, bucket, rows),
+                    None)
         if kind in ("draft", "draft_chunk") and self._spec is not None:
             cfg, params = self._spec.cfg, self._spec.params
         else:
@@ -2277,8 +2448,9 @@ class Engine:
 
         def aslot(shape):
             # the per-request adapter-slot index operand (scalar for
-            # prefill/chunk, (B,) for decode/verify)
-            if not self._cfg.adapters:
+            # prefill/chunk, (B,) for decode/verify); a hybrid engine's
+            # state-slot operand sits in the same place, the same shape
+            if not self._cfg.adapters and not self._cfg.hybrid:
                 return ()
             return (sds(shape, i32),)
 
@@ -2309,6 +2481,10 @@ class Engine:
         # caches in every target-model program (same order as
         # _cache_args)
         caches = (cspec, cspec)
+        if self._hybrid is not None:
+            # the state pool follows the K/V (same order as _cache_args)
+            caches += (sds(self._state_ssm.shape, self._state_ssm.dtype),
+                       sds(self._state_conv.shape, self._state_conv.dtype))
         if self._kv_quant:
             sspec = sds(self._scale_k.shape, self._scale_k.dtype,
                         sh.scale if sh is not None else None)
@@ -2371,6 +2547,15 @@ class Engine:
         exact builders traffic runs, not a reconstruction).  The
         builders close over immutable ``_ModelCfg``s only — never an
         Engine (the _STEP_CACHE retention rule)."""
+        if self._cfg.hybrid is not None:
+            # the hybrid family: one layer function under three builders
+            if kind == "decode":
+                return hybrid_mod.build_decode(self._cfg, self._donate)
+            if kind == "chunk":
+                return hybrid_mod.build_chunk(self._cfg, bucket,
+                                              self._donate)
+            return hybrid_mod.build_prefill(self._cfg, bucket,
+                                            self._donate)
         if kind == "decode":
             return _build_decode(self._cfg, self._donate,
                                  self._shardings)
@@ -2453,7 +2638,7 @@ class Engine:
         """Positions of the cache operands a program donates."""
         if not self._donate:
             return ()
-        n_caches = (4 if self._cfg.kv_quant
+        n_caches = (4 if (self._cfg.kv_quant or self._cfg.hybrid)
                     and kind not in ("draft", "draft_chunk") else 2)
         # the restore program has no params operand: its donated cache
         # arguments START the signature instead of following the pytree.

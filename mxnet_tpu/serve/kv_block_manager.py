@@ -117,6 +117,12 @@ ENV_HOST_RESTORE_BUDGET = "MXTPU_SERVE_HOST_KV_RESTORE_BUDGET"
 _ROOT = b"mxtpu-radix-root"
 
 
+# why a block manager with a state pool keeps the radix prefix cache off
+# (prefix_stats() and the engine's refusal both say it)
+STATE_POOL_NO_PREFIX = ("a cached block holds K/V only; the recurrent state "
+                        "at its edge was not kept")
+
+
 class NoFreeBlocks(Exception):
     """Raised when an allocation cannot be satisfied even after
     evicting every refcount-0 retained/cached block.  The scheduler
@@ -544,7 +550,7 @@ class BlockManager:
     ``allocate``/``ensure_capacity`` call ``_take`` under the lock."""
 
     def __init__(self, num_blocks, block_size, prefix_cache=None,
-                 host_pool=None):
+                 host_pool=None, state_slots=0):
         if num_blocks < 2:
             raise ValueError("need >= 2 blocks (block 0 is the null block)")
         if block_size < 1:
@@ -558,6 +564,21 @@ class BlockManager:
         # discards, exactly the pre-offload lifecycle)
         self.host = host_pool
         self._lock = threading.RLock()
+        # a hybrid decoder's per-request state pool (serve/hybrid.py):
+        # a request takes ONE slot with its first blocks and gives it
+        # back with them (finish, cancel, preempt), under the same lock.
+        # Slot 0 is the null slot padded rows write to; 0 slots = a
+        # gpt() engine, and every path below is the pre-state one
+        self.state_slots = int(state_slots)
+        self.prefix_off_reason = None
+        if self.state_slots:
+            if self.prefix_cache:
+                raise ValueError(
+                    "a state pool and the radix prefix cache cannot go "
+                    "together: nobody keeps the state at a block's edge")
+            self.prefix_off_reason = STATE_POOL_NO_PREFIX
+        self._slot_free = deque(range(1, self.state_slots + 1))  # guarded-by: _lock
+        self._slot_of = {}                        # guarded-by: _lock
         # block 0 reserved as the null/padding block
         self._free = deque(range(1, num_blocks))  # guarded-by: _lock
         self._tables = {}                         # guarded-by: _lock
@@ -709,6 +730,10 @@ class BlockManager:
                 # whose cached K/V is gone for good
                 discarded += self.host.discarded_tokens
             return {"enabled": self.prefix_cache,
+                    # why it is off when the model, not the operator,
+                    # turned it off (a hybrid decoder's state pool)
+                    **({"disabled_reason": self.prefix_off_reason}
+                       if self.prefix_off_reason else {}),
                     "cached_blocks": len(self._index),
                     "reusable_blocks": len(self._lru),
                     "shared_blocks": shared,
@@ -764,6 +789,9 @@ class BlockManager:
         if token_ids is not None:
             cached_blocks, _ = self.prefix_probe(token_ids)
             need -= cached_blocks
+        with self._lock:
+            if self.state_slots and not self._slot_free:
+                return False          # blocks AND a state slot, or neither
         return need <= self.free_blocks
 
     def fits_at_all(self, n_tokens):
@@ -842,6 +870,10 @@ class BlockManager:
         from the host pool without claiming.  A block missing from
         both tiers (evicted under pressure) ends the chain — the
         importer recomputes the rest, never a gap."""
+        if self.state_slots:
+            raise ValueError(
+                "block export: a hybrid decoder's request is K/V blocks "
+                "AND a recurrent state, and only the blocks would travel")
         with self._lock:
             if not self.prefix_cache or self._offload_fetch is None:
                 return []
@@ -883,6 +915,10 @@ class BlockManager:
         rejected)`` block counts; imported blocks are radix-walk hits
         from the very next ``allocate``, restored HBM-ward by the
         existing async restore path."""
+        if self.state_slots:
+            raise ValueError(
+                "block import: a hybrid decoder cannot resume from K/V "
+                "blocks alone (no recurrent state comes with them)")
         imported = deduped = 0
         with self._lock:
             expect_parent = None
@@ -1017,6 +1053,10 @@ class BlockManager:
                 # rid is freed again later (its published blocks live
                 # in the prefix index and may be hit again right here)
                 self._free.extend(self._retained.pop(rid))
+            if self.state_slots and not self._slot_free:
+                raise NoFreeBlocks(
+                    f"request {rid!r} needs a state slot, all "
+                    f"{self.state_slots} are held")
             hits, host_keys = [], []
             if self.prefix_cache and token_ids is not None:
                 hits, host_keys = self._walk(token_ids, salt=salt)
@@ -1097,6 +1137,8 @@ class BlockManager:
                 self._pending_restores.append((blk, arrays))
             self._tables[rid] = [blk for _, blk in hits] + fresh
             self._lens[rid] = n * self.block_size
+            if self.state_slots:
+                self._slot_of[rid] = self._slot_free.popleft()
             self._chain[rid] = ([key for key, _ in hits]
                                 + [key for key, _, _ in claimed])
             if token_ids is not None:
@@ -1120,6 +1162,16 @@ class BlockManager:
     def table(self, rid):
         with self._lock:
             return list(self._tables[rid])
+
+    def state_slot(self, rid):
+        """The state-pool slot ``rid`` holds (hybrid engines only)."""
+        with self._lock:
+            return self._slot_of[rid]
+
+    @property
+    def state_slots_in_use(self):
+        with self._lock:
+            return len(self._slot_of)
 
     def capacity(self, rid):
         """Token slots currently reserved for ``rid``."""
@@ -1266,6 +1318,9 @@ class BlockManager:
         with self._lock:
             blocks = self._tables.pop(rid)
             self._lens.pop(rid)
+            slot = self._slot_of.pop(rid, None)
+            if slot is not None:
+                self._slot_free.append(slot)
             self._chain.pop(rid, None)
             self._host_tokens.pop(rid, None)
             loose = []
@@ -1282,6 +1337,8 @@ class BlockManager:
     def reset(self):
         with self._lock:
             self._free = deque(range(1, self.num_blocks))
+            self._slot_free = deque(range(1, self.state_slots + 1))
+            self._slot_of.clear()
             self._tables.clear()
             self._lens.clear()
             self._retained.clear()
