@@ -326,12 +326,29 @@ class FlashAttentionOp(OpDef):
 
 
 # -- paged attention (serving) -----------------------------------------------
+# Positions of a row's context the Mosaic decode kernels fold in one
+# step of their walk: one score product, one softmax update and one
+# p x V product per tile.  A constant from the chip, not a knob: at 16
+# tokens a block 16 table slots make a tile.  On one v5e chip, 16 rows
+# at 1.5-3.9 k tokens of context, 8 kv heads x 128, a layer: 128
+# positions 309 us, 256: 271 us, 512: 276 us (the slot-a-step walk
+# before: 2,328 us); the packed kernel and rows of a few tokens lose 4-9 %
+# at 256 against 128, a tile's tail being read whole (PERF.md, PR 30).
+PAGED_TILE_TOKENS = 256
+
+
+def paged_tile_slots(block_size):
+    """Table slots in one tile of the paged decode kernels' walk."""
+    return max(1, PAGED_TILE_TOKENS // int(block_size))
+
+
 def paged_eligible(block_size, head_dim):
     """Whether the Mosaic kernel's tile shapes are worth lowering for
     this cache geometry: head_dim should fill MXU/VPU lanes (multiples
-    of 8 keep Mosaic's f32 tiling happy; 128 is the sweet spot) and the
-    per-step K/V tile is one block, so a 1-token block would crawl
-    through a 16x larger grid than the default geometry."""
+    of 8 keep Mosaic's f32 tiling happy; 128 is the sweet spot).  The
+    kernel folds ``PAGED_TILE_TOKENS`` positions a step whatever the
+    block holds, but every block of a tile is a DMA of its own, so a
+    1-token block would issue 256 copies a tile."""
     return head_dim % 8 == 0 and block_size >= 4
 
 
@@ -369,8 +386,10 @@ def paged_attention(q, k_cache, v_cache, block_tables, context_lens,
     positions onto physical blocks.  Each query attends against its
     own context through the tables — the vLLM-style paged-attention
     formulation.  On TPU this dispatches to the Mosaic kernel in
-    ``ops/pallas_paged_attention.py`` that streams K/V blocks from HBM
-    with f32 accumulation (``impl="auto"`` default, overridable per
+    ``ops/pallas_paged_attention.py`` that walks each row's live
+    context a tile of ``PAGED_TILE_TOKENS`` positions at a time, the
+    tile's blocks copied from HBM through the table, with f32
+    accumulation (``impl="auto"`` default, overridable per
     process via ``MXTPU_PAGED_ATTENTION=auto|pallas|jnp`` — the same
     selection shape as ``flash_attention``); everywhere else it runs
     the XLA gather + masked softmax below, which doubles as the

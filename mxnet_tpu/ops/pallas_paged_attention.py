@@ -4,34 +4,53 @@ The serving decode hot loop (``ops.attention.paged_attention``) is a
 jnp gather + masked softmax: XLA materializes each request's whole
 logical K/V view ``(B, S, Hkv, Dh)`` in HBM before attending, even
 though a decode step only *reads* ``context_lens`` tokens of it.  This
-kernel is the Mosaic follow-up the jnp docstring names: the grid walks
-``(batch, table_slot)`` and streams ONE physical K/V block per step
-from HBM into VMEM through the request's block table (scalar-prefetched
-so the DMA's source index is known before the body runs — the
-vLLM-PagedAttention formulation on TPU), updating flash-style running
-max / sum-exp / f32 accumulators per kv head.  No gathered copy of the
-cache ever exists; HBM traffic is exactly the live context bytes.
+kernel is the Mosaic follow-up the jnp docstring names, in the shape of
+jax's own TPU paged attention (``pages_per_compute_block``): the grid
+walks the batch rows, and INSIDE a row a loop walks the row's live
+context a TILE at a time.  A tile is ``T = PAGED_TILE_TOKENS /
+block_size`` table slots; the caches stay in HBM (``memory_space=ANY``)
+and each of the tile's blocks is one ``make_async_copy`` through the
+scalar-prefetched block table into a double-buffered VMEM scratch, the
+next tile's copies (at a row's end the next row's first tile's) in
+flight while this tile is folded into flash-style running max /
+sum-exp / f32 accumulators: ONE score product, one softmax update and
+one ``p x V`` product per tile.  Row ``b`` walks tiles ``[lo_b,
+cdiv(ctx_b, T * block_size))`` and nothing else (``lo_b`` is 0, or the
+first tile of the window band): a table slot past the context costs no
+DMA, no branch and no grid step.  No gathered copy of the cache ever
+exists; HBM traffic is the live context rounded up to a tile.
 
 The cache operand is the engine's WHOLE stacked pool ``(L, num_blocks,
-block_size, Hkv, Dh)`` and the layer to read is a static index in the
-DMA's source address, ``(layer, bt[b, w], 0, 0, 0)``.  A custom call
-needs each operand as a buffer of its own, so a caller that sliced one
-layer out of the stack first (``cache[i]``) made XLA copy that layer's
-whole pool, K and V, every layer of every step — half of a decode
-step's device time (PERF.md, PR 27).  Callers pass the stack and
-``layer=i``; a lone 4-D cache is the same kernel through ``cache[None]``.
+block_size, Hkv, Dh)`` and the layer to read is a static index in each
+copy's source address, ``(layer, bt[b, slot])``.  A custom call needs
+each operand as a buffer of its own, so a caller that sliced one layer
+out of the stack first (``cache[i]``) made XLA copy that layer's whole
+pool, K and V, every layer of every step (PERF.md, PR 27).  Callers
+pass the stack and ``layer=i``; a lone 4-D cache is the same kernel
+through ``cache[None]``.
 
-Grouped-query attention is native: the kernel loops the (static) kv
-heads and each grid step's block fetch serves every q head of the
-group — with int8 KV blocks (``k_scale``/``v_scale`` per-slot-per-head
-f32 scales) the dequantize happens in VMEM, fused into the same pass,
-so the HBM read is the int8 bytes.
+Two kernels share that walk (``_walk``) and differ in a tile's
+arithmetic.  Heads of 128 (``paged_attention_kernel``): a block is seen
+as the matrix ``(block_size * Hkv, Dh)`` it already is in memory, rows
+ordered (position, kv head), so a tile is ONE dense ``(T * block_size *
+Hkv, Dh)`` operand and all query heads score it at once, ``(Hq, Dh) x
+(Dh, T * block_size * Hkv)``; an additive mask keeps each query head's
+own kv head.  Reading one head out of the tile instead picks a sublane
+of every position's ``(Hkv, Dh)`` tile and was what the kernel's time
+went to (0.72 us a 16-token block; PERF.md, PR 30).  Heads under 128
+over a flat cache (``paged_attention_packed_kernel``): the lanes hold
+``128 / Dh`` kv heads side by side and the queries are block-diagonal,
+one ``(rows, 128) x (128, T * block_size)`` product per lane group.
+With int8 KV blocks (``k_scale``/``v_scale`` per-slot-per-head f32
+scales) the scales ride the same tile copies and the dequantize happens
+in VMEM, so the HBM read is the int8 bytes.
 
-Padded table rows point at the null block (id 0); their positions sit
-at or beyond ``context_lens`` so the mask (and the compute-skip guard)
-drops them, and a fully-empty row (``context_lens == 0``) never runs a
-tile — its accumulator stays zero and the output is zeros, matching
-the jnp path's empty-row guard.
+Padded table rows point at the null block (id 0); their slots sit at or
+beyond ``context_lens``, and a slot past the row's last live block is
+never addressed (a tile's tail re-reads the last live block and the
+position mask drops it).  A fully-empty row (``context_lens == 0``)
+walks no tile: its accumulator stays zero and the output is zeros,
+matching the jnp path's empty-row guard.
 
 ``interpret=True`` (automatic off-TPU) runs the kernel through the
 Pallas interpreter so the parity tests exercise the identical code
@@ -53,7 +72,7 @@ from ..lint.annotations import hot_path
 # must be importable without Pallas); re-exported here for the tests
 from . import pallas_util
 from .attention import (packed_eligible, paged_eligible,  # noqa: F401
-                        score_scale)
+                        paged_tile_slots, score_scale)
 from .flash_attention import gqa_group
 from .pallas_util import idx32
 
@@ -68,85 +87,220 @@ _ZERO = np.float32(0.0)
 _TINY = np.float32(1e-30)
 
 
-def _kernel(bt_ref, ctx_ref, q_ref, k_ref, v_ref, *rest, scale, bs, nW,
-            Hkv, group, window, quant):
-    """One grid step (b, w): stream physical block ``bt[b, w]`` and
-    fold its ``bs`` positions into the running softmax state of every
-    kv head.  With ``quant`` the K/V refs are int8 and two
-    per-slot-per-head scale refs follow them in the input list."""
-    if quant:
-        ksc_ref, vsc_ref, o_ref, acc, m_sc, l_sc = rest
-    else:
-        (o_ref, acc, m_sc, l_sc), ksc_ref, vsc_ref = rest, None, None
+# -- the walk both kernels share ----------------------------------------------
+
+def _walk(bt_ref, ctx_ref, streams, sems, slot_ref, fold, carry, *, layer,
+          T, bs, window):
+    """Fold the live tiles of row ``program_id(0)`` into ``carry``.
+
+    ``streams`` pairs each HBM operand ``(L, num_blocks, rows, ...)`` with
+    its VMEM scratch ``(2, T * rows, ...)``; ``sems`` is ``(len(streams),
+    2)`` DMA semaphores, one per stream and buffer.  ``fold(buf, base,
+    ctx, carry)`` folds the tile in buffer ``buf`` whose first position is
+    ``base``.  ``slot_ref`` (SMEM, one int32) carries the buffer of a
+    row's first tile from row to row: the grid is sequential and a row's
+    last step starts the next row's first tile."""
     b = pl.program_id(0)
-    w = pl.program_id(1)
+    last_row = pl.num_programs(0) - 1
+    span = T * bs
 
-    @pl.when(w == 0)
+    def cdiv(x, n):
+        # lax.div on int32 (nothing here is negative): jnp's floor
+        # division does not lower inside a Mosaic kernel under x64
+        return jax.lax.div(x + jnp.int32(n - 1), jnp.int32(n))
+
+    def tiles(r):
+        ctx = ctx_ref[r]
+        lo = (jax.lax.div(jnp.maximum(ctx - window, 0), jnp.int32(span))
+              if window else jnp.int32(0))
+        return lo, cdiv(ctx, span)
+
+    def copies(r, t, buf):
+        """Tile ``t`` of row ``r`` into buffer ``buf``: slot by slot,
+        never past the row's last live block (what is read twice the
+        position mask drops)."""
+        live = jnp.maximum(cdiv(ctx_ref[r], bs) - 1, 0)
+        out = []
+        for i in range(T):
+            blk = bt_ref[r, jnp.minimum(t * T + i, live)]
+            out += _slot_copies(streams, sems, layer, blk, buf, i, T)
+        return out
+
+    lo, hi = tiles(b)
+
+    @pl.when(b == 0)
     def _():
-        acc[...] = jnp.zeros_like(acc)
-        m_sc[...] = jnp.full_like(m_sc, _NEG_INF)
-        l_sc[...] = jnp.zeros_like(l_sc)
+        slot_ref[0] = jnp.int32(0)
 
-    ctx = ctx_ref[b]
-    base = w * bs
-    # compute-skip: blocks entirely beyond the context (padded table
-    # rows -> the null block) or entirely below the window band
-    # contribute nothing; the DMA still ran, the math doesn't
-    live = base < ctx
-    if window:
-        live = jnp.logical_and(live, base + bs > ctx - 1 - window)
+    first = slot_ref[0]
+    plo, phi = tiles(jnp.maximum(b - 1, 0))
+    nxt = jnp.minimum(b + 1, last_row)
+    nlo, nhi = tiles(nxt)
+    next_live = jnp.logical_and(b < last_row, nhi > nlo)
 
-    @pl.when(live)
+    # the row before this one started this row's first tile, unless it
+    # walked nothing (or there is none)
+    @pl.when(jnp.logical_and(hi > lo,
+                             jnp.logical_or(b == 0, phi <= plo)))
     def _():
-        pos = base + jax.lax.broadcasted_iota(jnp.int32, (group, bs), 1)
-        keep = pos < ctx
+        for c in copies(b, lo, first):
+            c.start()
+
+    def step(t, carry):
+        buf = jnp.bitwise_and(first + (t - lo), 1)
+        more = t + 1 < hi
+
+        @pl.when(jnp.logical_or(more, next_live))
+        def _():
+            for c in copies(jnp.where(more, b, nxt),
+                            jnp.where(more, t + 1, nlo), 1 - buf):
+                c.start()
+
+        for i in range(T):
+            for c in _slot_copies(streams, sems, layer, 0, buf, i, T):
+                c.wait()
+        return fold(buf, t * span, ctx_ref[b], carry)
+
+    carry = jax.lax.fori_loop(lo, hi, step, carry)
+    slot_ref[0] = jnp.bitwise_and(first + (hi - lo), 1)
+    return carry
+
+
+def _slot_copies(streams, sems, layer, blk, buf, i, T):
+    """One block of every stream into slot ``i`` of buffer ``buf``."""
+    out = []
+    for n, (hbm, vmem) in enumerate(streams):
+        rows = vmem.shape[1] // T
+        out.append(pltpu.make_async_copy(
+            # int32 indices: under x64 a Python int is an i64 Mosaic
+            # cannot slice a memref with
+            hbm.at[jnp.int32(layer), jnp.int32(blk)],
+            vmem.at[buf, pl.ds(i * rows, rows)],
+            sems.at[jnp.int32(n), buf]))
+    return out
+
+
+def _lanes(x):
+    """``(T, n)`` -> ``(1, T * n)``: the tile's slots side by side."""
+    return jnp.concatenate([x[i:i + 1] for i in range(x.shape[0])], axis=1)
+
+
+def _softmax_fold(s, v, m_prev, l_prev, acc, p_scale=None):
+    """One flash-style update: scores ``s`` (rows, columns) with
+    ``_NEG_INF`` in every masked column, values ``v`` (columns, width),
+    ``p_scale`` (1, columns) what a column's value is still to be
+    multiplied by (int8 V).
+    Every row of a walked tile keeps a column (the walk visits no tile
+    outside ``[lo, hi)``), so the running max is finite and a masked
+    column's ``exp`` is exactly 0."""
+    m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.exp(s - m_cur)
+    alpha = jnp.exp(m_prev - m_cur)
+    l_cur = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    if p_scale is not None:
+        p = p * p_scale
+    # p cast to v's dtype keeps a bf16 cache's PV matmul on the fast MXU
+    # pass (dequantized int8 is already f32)
+    acc = acc * alpha + jax.lax.dot_general(
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    return m_cur, l_cur, acc
+
+
+def _normalized(acc, l_row, dtype):
+    # a fully-masked row (context_lens == 0) accumulated nothing: emit
+    # zeros, never 0/0 NaN
+    return jnp.where(l_row > _ZERO, acc / jnp.maximum(l_row, _TINY),
+                     _ZERO).astype(dtype)
+
+
+def _call(kernel, block_tables, context_lens, q_like, whole, caches, T, *,
+          name, interpret):
+    """The ``pallas_call`` both kernels make: a grid over the rows, the
+    row's queries in and its outputs out as blocks, the ``whole``
+    operands resident in VMEM, the caches left in HBM for the walk's own
+    copies into a double buffer of one tile (``T`` slots) each."""
+    B = q_like.shape[0]
+    row = (1,) + q_like.shape[1:]
+    per_row = idx32(lambda b, bt, ctx: (b,) + (0,) * (len(row) - 1))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B,),
+        in_specs=[pl.BlockSpec(row, per_row)]
+        + [pl.BlockSpec(w.shape, idx32(
+            lambda b, bt, ctx, n=w.ndim: (0,) * n)) for w in whole]
+        + [pl.BlockSpec(memory_space=pl.ANY)] * len(caches),
+        out_specs=pl.BlockSpec(row, per_row),
+        scratch_shapes=[
+            pltpu.VMEM((2, T * c.shape[2]) + c.shape[3:], c.dtype)
+            for c in caches] + [
+            pltpu.SemaphoreType.DMA((len(caches), 2)),
+            pltpu.SMEM((1,), jnp.int32)],
+    )
+    kw = {}
+    if not interpret:
+        # sequential: a row starts the next row's first copies and hands
+        # it the buffer they land in
+        kw["compiler_params"] = pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",))
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(q_like.shape, q_like.dtype),
+        name=name,
+        # mxtpu-lint: disable=host-sync (static host flag chosen at
+        # trace time — never a device value, nothing to sync)
+        interpret=bool(interpret),
+        **kw,
+    )(jnp.asarray(block_tables, jnp.int32),
+      jnp.asarray(context_lens, jnp.int32), q_like, *whole, *caches)
+
+
+# -- heads of 128: every kv head of a position is a row of the tile -----------
+
+def _kernel(bt_ref, ctx_ref, q_ref, mask_ref, *rest, scale, layer, T, bs,
+            Hkv, window, quant):
+    """Row ``b``: all ``Hq`` query heads against tiles whose rows are
+    (position, kv head).  ``mask_ref`` (Hq, T * bs * Hkv) is 0 where the
+    column's kv head is the query head's own and ``_NEG_INF`` elsewhere.
+    With ``quant`` the K/V streams are int8 and two per-slot-per-head
+    scale streams follow them."""
+    n = 4 if quant else 2
+    hbm, (o_ref, *vmem, sems, slot_ref) = rest[:n], rest[n:]
+    q = q_ref[0]                                        # (Hq, Dh)
+    Hq, Dh = q.shape
+    cols = T * bs * Hkv
+    col = jax.lax.broadcasted_iota(jnp.int32, (Hq, cols), 1)
+
+    def fold(buf, base, ctx, carry):
+        k, v = vmem[0][buf], vmem[1][buf]               # (cols, Dh)
+        if quant:
+            # fused dequant in VMEM: the HBM stream was int8.  A slot's
+            # scales are a row of its columns' order, so they scale the
+            # slot's scores and probabilities, not its K and V rows; the
+            # integers themselves are exact in q's dtype
+            k, v = k.astype(q.dtype), v.astype(jnp.float32)
+            ks, vs = vmem[2][buf], vmem[3][buf]         # (T, cols / T)
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        if quant:
+            s = s * _lanes(ks)
+        s = s * scale + mask_ref[...]
+        # column c holds position base + c // Hkv: no division needed
+        keep = col < (ctx - base) * Hkv
         if window:
-            keep = jnp.logical_and(keep, pos > ctx - 1 - window)
-        for h in range(Hkv):
-            k = k_ref[0, 0, :, h, :]
-            v = v_ref[0, 0, :, h, :]
-            if quant:
-                # fused dequant in VMEM: the HBM stream was int8
-                k = k.astype(jnp.float32) * ksc_ref[0, 0, :, h][:, None]
-                v = v.astype(jnp.float32) * vsc_ref[0, 0, :, h][:, None]
-            q = q_ref[0, h]                              # (group, Dh)
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
-            s = jnp.where(keep, s, _NEG_INF)
-            m_prev = m_sc[h, :, 0]
-            m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-            p = jnp.where(keep, jnp.exp(s - m_cur[:, None]), _ZERO)
-            alpha = jnp.exp(m_prev - m_cur)
-            l_cur = l_sc[h, :, 0] * alpha + jnp.sum(p, axis=-1)
-            # p cast to v's dtype keeps a bf16 cache's PV matmul on the
-            # fast MXU pass (dequantized int8 is already f32)
-            acc[h] = acc[h] * alpha[:, None] + jax.lax.dot_general(
-                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            m_sc[h, :, 0] = m_cur
-            l_sc[h, :, 0] = l_cur
+            keep = jnp.logical_and(
+                keep, col >= (ctx - window - base) * Hkv)
+        return _softmax_fold(jnp.where(keep, s, _NEG_INF), v, *carry,
+                             p_scale=_lanes(vs) if quant else None)
 
-    @pl.when(w == nW - 1)
-    def _():
-        for h in range(Hkv):
-            l_row = l_sc[h, :, 0]
-            # a fully-masked row (context_lens == 0) accumulated
-            # nothing: emit zeros, never 0/0 NaN
-            valid = l_row > _ZERO
-            l_fin = jnp.maximum(l_row, _TINY)
-            o_ref[0, h] = jnp.where(valid[:, None],
-                                    acc[h] / l_fin[:, None],
-                                    _ZERO).astype(o_ref.dtype)
-
-
-def _params(interpret):
-    """Batch rows are independent (parallel); the table-slot axis
-    carries the running-softmax scratch and must stay sequential."""
-    if interpret:
-        return {}
-    return {"compiler_params": pltpu.CompilerParams(
-        dimension_semantics=("parallel", "arbitrary"))}
+    carry = (jnp.full((Hq, 1), _NEG_INF), jnp.zeros((Hq, 1), jnp.float32),
+             jnp.zeros((Hq, Dh), jnp.float32))
+    _, l_row, acc = _walk(bt_ref, ctx_ref, list(zip(hbm, vmem)), sems,
+                          slot_ref, fold, carry, layer=layer, T=T, bs=bs,
+                          window=window)
+    o_ref[0] = _normalized(acc, l_row, o_ref.dtype)
 
 
 @hot_path
@@ -154,7 +308,7 @@ def paged_attention_kernel(q, k_cache, v_cache, block_tables,
                            context_lens, window=0, scale=None,
                            k_scale=None, v_scale=None, interpret=None,
                            layer=None):
-    """Single-token paged decode attention, block-streamed.
+    """Single-token paged decode attention, a tile of the context a step.
 
     Same contract as ``ops.attention.paged_attention``: q ``(B, Hq,
     Dh)``, caches ``(L, num_blocks, block_size, Hkv, Dh)`` stacked over
@@ -195,114 +349,65 @@ def paged_attention_kernel(q, k_cache, v_cache, block_tables,
     scale = score_scale(Dh) if scale is None else np.float32(scale)
     if interpret is None:
         interpret = not pallas_util.on_tpu()
-    W = block_tables.shape[1]
-    q4 = q.reshape(B, Hkv, group, Dh)
-
-    def blk(*shape):
-        """Whole-trailing-dims block (Mosaic: the last two block dims
-        must divide the tile or equal the array dims — spanning the
-        full (Hkv, Dh) / (Hkv,) trailing axes always satisfies it)."""
-        return shape
-
-    # the layer is one more index of the block's DMA source address
-    per_req = idx32(lambda b, w, bt, ctx: (b, 0, 0, 0))
-    per_blk = idx32(lambda b, w, bt, ctx: (layer, bt[b, w], 0, 0, 0))
-    per_blk_sc = idx32(lambda b, w, bt, ctx: (layer, bt[b, w], 0, 0))
-    in_specs = [
-        pl.BlockSpec(blk(1, Hkv, group, Dh), per_req),
-        pl.BlockSpec(blk(1, 1, bs, Hkv, Dh), per_blk),
-        pl.BlockSpec(blk(1, 1, bs, Hkv, Dh), per_blk),
-    ]
-    args = [q4, k_cache, v_cache]
+    T = paged_tile_slots(bs)
+    # a block as the (positions x kv heads, Dh) matrix it is in memory:
+    # the same bytes, no copy (tests/test_perf_contract.py reads the
+    # compiled decode program for one)
+    rows = (L, nb, bs * Hkv)
+    caches = [k_cache.reshape(rows + (Dh,)), v_cache.reshape(rows + (Dh,))]
     if quant:
-        in_specs += [
-            pl.BlockSpec(blk(1, 1, bs, Hkv), per_blk_sc),
-            pl.BlockSpec(blk(1, 1, bs, Hkv), per_blk_sc),
-        ]
-        args += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
+        # a block's scales as one row in its columns' order
+        caches += [k_scale.astype(jnp.float32).reshape(L, nb, 1, bs * Hkv),
+                   v_scale.astype(jnp.float32).reshape(L, nb, 1, bs * Hkv)]
+    own = (np.arange(T * bs * Hkv)[None, :] % Hkv
+           == np.arange(Hq)[:, None] // group)
+    mask = jnp.asarray(np.where(own, _ZERO, _NEG_INF))
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, W),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec(blk(1, Hkv, group, Dh), per_req),
-        scratch_shapes=[
-            pltpu.VMEM((Hkv, group, Dh), jnp.float32),
-            pltpu.VMEM((Hkv, group, 1), jnp.float32),
-            pltpu.VMEM((Hkv, group, 1), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        functools.partial(_kernel, scale=scale, bs=bs, nW=W, Hkv=Hkv,
-                          group=group, window=int(window), quant=quant),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, group, Dh), q.dtype),
-        # mxtpu-lint: disable=host-sync (static host flag chosen at
-        # trace time — never a device value, nothing to sync)
-        interpret=bool(interpret),
-        **_params(interpret),
-    )(jnp.asarray(block_tables, jnp.int32),
-      jnp.asarray(context_lens, jnp.int32), *args)
-    return out.reshape(B, Hq, Dh)
+    kernel = functools.partial(_kernel, scale=scale, layer=layer, T=T,
+                               bs=bs, Hkv=Hkv, window=int(window),
+                               quant=quant)
+    return _call(kernel, block_tables, context_lens, q, [mask], caches, T,
+                 name="paged_attention", interpret=interpret)
 
 
 # -- small heads: several kv heads side by side on the lanes ------------------
 
-def _packed_kernel(bt_ref, ctx_ref, q_ref, k_ref, v_ref, o_ref, acc, m_sc,
-                   l_sc, *, scale, bs, nW, Hp, rows):
-    """One grid step (b, w) over a cache whose minor axis holds every kv
-    head of a position side by side.  Lane group ``j`` (128 lanes) holds
-    ``pack`` kv heads; the query block is block-diagonal (the rows of
-    head ``p`` are zero outside its own lanes), so ONE lane-aligned
-    product gives every head's scores and one more its outputs.
+def _packed_kernel(bt_ref, ctx_ref, q_ref, k_hbm, v_hbm, o_ref, k_vmem,
+                   v_vmem, sems, slot_ref, *, scale, layer, T, bs):
+    """Row ``b`` over a cache whose minor axis holds every kv head of a
+    position side by side.  Lane group ``j`` (128 lanes) holds ``pack``
+    kv heads; the query block is block-diagonal (the rows of head ``p``
+    are zero outside its own lanes), so ONE lane-aligned product per
+    lane group gives a tile's scores for its heads and one more their
+    outputs.
 
-    Tried on the chip and taken out again (PERF.md, PR 29): streaming 8
-    table slots a step, and naming a dead slot's last live block so that
-    its DMA is skipped; neither shortened the kernel (10.2 -> 10.6 and
-    12.0 ms for 4 layers x 64 rows), whose time is the per-slot branch
-    and the (rows x 128) x (128 x 16) products, not the DMAs."""
-    b = pl.program_id(0)
-    w = pl.program_id(1)
+    What PR 29 tried on the old slot-a-step walk and took out (8 slots'
+    DMAs a step, dead slots' DMAs spared: 10.2 -> 10.6 and 12.0 ms for 4
+    layers x 64 rows) left the per-slot branch and the 16-position
+    products in place; the time was those (PERF.md, PR 30)."""
+    _, Hp, rows, _ = q_ref.shape
+    pos = jax.lax.broadcasted_iota(jnp.int32, (rows, T * bs), 1)
 
-    @pl.when(w == 0)
-    def _():
-        acc[...] = jnp.zeros_like(acc)
-        m_sc[...] = jnp.full_like(m_sc, _NEG_INF)
-        l_sc[...] = jnp.zeros_like(l_sc)
-
-    ctx = ctx_ref[b]
-    base = w * bs
-
-    @pl.when(base < ctx)
-    def _():
-        pos = base + jax.lax.broadcasted_iota(jnp.int32, (rows, bs), 1)
-        keep = pos < ctx
+    def fold(buf, base, ctx, carry):
+        keep = pos < ctx - base
+        out = []
         for j in range(Hp):
-            k = k_ref[0, 0, :, j * 128:(j + 1) * 128]        # (bs, 128)
-            v = v_ref[0, 0, :, j * 128:(j + 1) * 128]
-            q = q_ref[0, j]                                  # (rows, 128)
+            lanes = slice(j * 128, (j + 1) * 128)
             s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
+                q_ref[0, j], k_vmem[buf, :, lanes],
+                (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale
-            s = jnp.where(keep, s, _NEG_INF)
-            m_prev = m_sc[j, :, 0]
-            m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-            p = jnp.where(keep, jnp.exp(s - m_cur[:, None]), _ZERO)
-            alpha = jnp.exp(m_prev - m_cur)
-            l_sc[j, :, 0] = l_sc[j, :, 0] * alpha + jnp.sum(p, axis=-1)
-            acc[j] = acc[j] * alpha[:, None] + jax.lax.dot_general(
-                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            m_sc[j, :, 0] = m_cur
+            out.append(_softmax_fold(jnp.where(keep, s, _NEG_INF),
+                                     v_vmem[buf, :, lanes], *carry[j]))
+        return tuple(out)
 
-    @pl.when(w == nW - 1)
-    def _():
-        for j in range(Hp):
-            l_row = l_sc[j, :, 0]
-            valid = l_row > _ZERO
-            l_fin = jnp.maximum(l_row, _TINY)
-            o_ref[0, j] = jnp.where(valid[:, None], acc[j] / l_fin[:, None],
-                                    _ZERO).astype(o_ref.dtype)
+    carry = ((jnp.full((rows, 1), _NEG_INF),
+              jnp.zeros((rows, 1), jnp.float32),
+              jnp.zeros((rows, 128), jnp.float32)),) * Hp
+    carry = _walk(bt_ref, ctx_ref, [(k_hbm, k_vmem), (v_hbm, v_vmem)], sems,
+                  slot_ref, fold, carry, layer=layer, T=T, bs=bs, window=0)
+    for j, (_, l_row, acc) in enumerate(carry):
+        o_ref[0, j] = _normalized(acc, l_row, o_ref.dtype)
 
 
 @hot_path
@@ -336,38 +441,17 @@ def paged_attention_packed_kernel(q, k_cache, v_cache, block_tables,
     scale = score_scale(Dh) if scale is None else np.float32(scale)
     if interpret is None:
         interpret = not pallas_util.on_tpu()
-    W = block_tables.shape[1]
+    T = paged_tile_slots(bs)
     # block-diagonal queries: head p's rows are zero outside its lanes
     q6 = q.reshape(B, Hp, pack, group, 1, Dh)
     eye = jnp.eye(pack, dtype=q.dtype)[None, None, :, None, :, None]
     q2 = (q6 * eye).reshape(B, Hp, rows, 128)
 
-    per_req = idx32(lambda b, w, bt, ctx: (b, 0, 0, 0))
-
-    per_blk = idx32(lambda b, w, bt, ctx: (layer, bt[b, w], 0, 0))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, W),
-        in_specs=[pl.BlockSpec((1, Hp, rows, 128), per_req),
-                  pl.BlockSpec((1, 1, bs, flat), per_blk),
-                  pl.BlockSpec((1, 1, bs, flat), per_blk)],
-        out_specs=pl.BlockSpec((1, Hp, rows, 128), per_req),
-        scratch_shapes=[pltpu.VMEM((Hp, rows, 128), jnp.float32),
-                        pltpu.VMEM((Hp, rows, 1), jnp.float32),
-                        pltpu.VMEM((Hp, rows, 1), jnp.float32)],
-    )
-    out = pl.pallas_call(
-        functools.partial(_packed_kernel, scale=scale, bs=bs, nW=W, Hp=Hp,
-                          rows=rows),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hp, rows, 128), q.dtype),
-        name="paged_attention_packed",
-        # mxtpu-lint: disable=host-sync (static host flag chosen at
-        # trace time, never a device value)
-        interpret=bool(interpret),
-        **_params(interpret),
-    )(jnp.asarray(block_tables, jnp.int32),
-      jnp.asarray(context_lens, jnp.int32), q2, k_cache, v_cache)
+    caches = [k_cache, v_cache]
+    kernel = functools.partial(_packed_kernel, scale=scale, layer=layer,
+                               T=T, bs=bs)
+    out = _call(kernel, block_tables, context_lens, q2, [], caches, T,
+                name="paged_attention_packed", interpret=interpret)
     o6 = out.reshape(B, Hp, pack, group, pack, Dh)
     own = jnp.stack([o6[:, :, p, :, p, :] for p in range(pack)], axis=2)
     return own.reshape(B, Hq, Dh)

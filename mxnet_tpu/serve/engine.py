@@ -69,7 +69,8 @@ from ..models.generate import (_fc, _gelu, _ln, detect_gpt_variant,
 from ..parallel import partition as partition_mod
 from ..parallel.mesh import NamedSharding, PartitionSpec, make_mesh
 from ..models.hybrid import HybridDecoder
-from ..ops.attention import paged_attention, score_scale
+from ..ops.attention import (PAGED_TILE_TOKENS, paged_attention,
+                             paged_tile_slots, score_scale)
 from ..telemetry import flight as flight_mod
 from ..telemetry import profiling
 from ..telemetry import statusz as statusz_mod
@@ -899,13 +900,15 @@ class Engine:
         # upgrades into the kernel (or escapes it via
         # MXTPU_PAGED_ATTENTION=jnp after a kernel bug) would silently
         # warm-load the other implementation's artifacts forever
-        # The value names the kernel's cache CONTRACT, not only the
-        # choice: since PR 27 the kernel reads the stacked cache in
-        # place, and an artifact exported before that still slices a
-        # layer out per call (right answers at half the speed) — the
-        # fingerprint is the store's only version, so it moves here
+        # The value names the kernel's VERSION, not only the choice: an
+        # exported artifact replays the kernel it was traced with (right
+        # answers at the old speed) and the fingerprint is the store's
+        # only version, so it moves with the kernel: "pallas-stacked"
+        # (PR 27) read the stacked cache in place one block a grid step;
+        # since PR 30 the walk folds a tile of PAGED_TILE_TOKENS
+        # positions a step, gpt and hybrid engines alike
         paged = ({} if self._paged_impl() != "pallas"
-                 else dict(paged_attention="pallas-stacked"))
+                 else dict(paged_attention=f"pallas-tile{PAGED_TILE_TOKENS}"))
         # a hybrid engine's state pool and vocabulary (only-when-on)
         state = ({} if self._state_ssm is None else dict(
             state_slots=int(self._state_ssm.shape[1]),
@@ -1176,8 +1179,10 @@ class Engine:
             emitted += self._run_prefill(
                 req, decode_slots=len(decodes) * (1 + self.spec_k))
         if decodes:
-            sprof.enter("decode_dispatch", batch=len(decodes),
-                        bucket=_next_bucket(len(decodes), self.max_batch))
+            bucket = _next_bucket(len(decodes), self.max_batch)
+            sprof.enter("decode_dispatch", batch=len(decodes), bucket=bucket,
+                        **(self._kv_tiles(decodes, bucket)
+                           if sprof.tracing else {}))
             if self._spec is not None:
                 emitted += self._run_spec_decode(decodes)
             else:
@@ -1230,6 +1235,22 @@ class Engine:
                        work_left=int(self.has_work()))
         sprof.commit(emitted, prefills=len(prefills), decodes=len(decodes))
         return emitted
+
+    def _kv_tiles(self, reqs, bucket):
+        """How much of the block table one layer's paged-attention call
+        of this decode pass walks, in tiles of ``PAGED_TILE_TOKENS``
+        positions: ``kv_tiles`` over the live rows' contexts (the band
+        only, under a sliding window), ``kv_tiles_table`` what a walk of
+        every row's whole table would visit."""
+        slots = paged_tile_slots(self.block_size)
+        span, window = slots * self.block_size, self.window
+        walked = 0
+        for req in reqs:
+            ctx = req.cache_len + 1
+            walked += -(-ctx // span) - (max(ctx - window, 0) // span
+                                         if window else 0)
+        return {"kv_tiles": walked,
+                "kv_tiles_table": bucket * -(-self.table_width // slots)}
 
     def has_work(self):
         """Whether ``step()`` still has anything to do: scheduler
@@ -1385,6 +1406,10 @@ class Engine:
             # trace ("pallas" | "jnp"): impl="auto" declining the kernel
             # for this cache geometry must be visible, not silent
             "paged_attention": self._paged_impl(),
+            # positions the kernel's walk folds a step (None under jnp)
+            "paged_tile_tokens": (PAGED_TILE_TOKENS
+                                  if self._paged_impl() == "pallas"
+                                  else None),
             "aot": aot,
         }
 

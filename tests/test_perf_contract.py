@@ -169,9 +169,11 @@ def test_paged_kernel_lowers_for_tpu(variant, cache):
     and must pass Mosaic lowering compiled (``interpret=False``) at a
     served geometry: 8 rows, 32 q / 8 kv heads of 128, 16-token blocks.
     ``stacked`` is the engine's call: the whole ``(L, ...)`` cache and a
-    static layer, which must reach the custom call as the 5-D operand
-    with no slice in front of it.  ``single`` is a lone 4-D cache, the
-    same kernel through ``cache[None]``."""
+    static layer, which must reach the custom call whole, with no slice
+    in front of it: seen as ``(L, num_blocks, block_size * Hkv, Dh)``,
+    each block the matrix it already is in memory (the scales a row of
+    ``block_size * Hkv``).  ``single`` is a lone 4-D cache, the same
+    kernel through ``cache[None]``."""
     from mxnet_tpu.ops.pallas_paged_attention import paged_attention_kernel
 
     B, Hq, Hkv, Dh, bs, W, nb, L = 8, 32, 8, 128, 16, 32, 65, 4
@@ -195,8 +197,8 @@ def test_paged_kernel_lowers_for_tpu(variant, cache):
     assert not re.search(r"stablehlo\.(dynamic_)?slice\b", t)
     (operands,) = hlo_audit.custom_call_operand_dims(t)
     n_layers = L if cache == "stacked" else 1
-    assert operands.count((n_layers, nb, bs, Hkv, Dh)) == 2, operands
-    assert operands.count((n_layers, nb, bs, Hkv)) == (2 if quant else 0)
+    assert operands.count((n_layers, nb, bs * Hkv, Dh)) == 2, operands
+    assert operands.count((n_layers, nb, 1, bs * Hkv)) == (2 if quant else 0)
 
 
 @pytest.mark.parametrize("tp", [1, 2])
@@ -250,8 +252,9 @@ def test_serve_programs_read_cache_in_place(serve_tpu_texts, kind):
     ``slice`` / ``dynamic_slice`` of a serve program yields a whole
     layer of the cache (on the chip each was a copy of the layer's
     pool, K and V, every layer of every pass), and every paged-kernel
-    custom call takes the 5-D stack itself, a head shard of it at
-    tp=2.  The draft program reads its own one-layer stack."""
+    custom call takes the whole stack, a head shard of it at tp=2, each
+    block seen as its ``(block_size * Hkv, Dh)`` matrix.  The draft
+    program reads its own one-layer stack."""
     tp, cache_shape, texts = serve_tpu_texts
     text = texts[kind]
     assert hlo_audit.cache_layer_slices(text, cache_shape) == []
@@ -260,16 +263,16 @@ def test_serve_programs_read_cache_in_place(serve_tpu_texts, kind):
     if kind == "decode":
         assert len(calls) == L
         for operands in calls:
-            assert operands.count((L, nb, bs, Hkv // tp, Dh)) == 2, operands
+            assert operands.count((L, nb, bs * Hkv // tp, Dh)) == 2, operands
     elif kind == "draft":
         # k drafting steps of a one-layer draft model (the write-only
         # last step's attention is dead code); the draft's cache is
         # replicated under tp, so its kernel sees every head
         assert len(calls) == 2
         for operands in calls:
-            stacks = [d for d in operands if len(d) == 5]
+            stacks = [d for d in operands if len(d) == 4]
             assert len(stacks) == 2 and stacks[0] == stacks[1], operands
-            assert stacks[0][:3] == (1, nb, bs), operands
+            assert stacks[0][:2] == (1, nb) and not stacks[0][2] % bs, operands
     else:
         # chunk and verify score through one gather over the stack
         assert calls == []
@@ -277,6 +280,180 @@ def test_serve_programs_read_cache_in_place(serve_tpu_texts, kind):
                    if "stablehlo.gather" in ln
                    and f"tensor<{L}x{nb}x{bs}x" in ln]
         assert len(gathers) == 2 * L, (kind, len(gathers))
+
+
+# -- 1b. The paged kernels and the decode programs, COMPILED for the chip -----
+# Lowering (above) ends where Mosaic's own compiler begins: a kernel that
+# lowers can still be refused for a tiling, and a program whose kernel
+# lowers can still copy its cache pool in front of it.  The chip's
+# compiler is installed here and compiles for a chip that is described,
+# not attached; these cases run it, at the benchmark cells' attention
+# geometry with the depth and the MLP cut (what they pin is per layer).
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _chip_compile(fn, specs, one_chip):
+    """``fn`` compiled for the described chip; the persistent cache is
+    off around it (an entry written for a described device cannot be
+    read back without one, and warns)."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    specs = jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+        s.shape, s.dtype, sharding=one_chip), specs)
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        return fn.lower(*specs).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
+PAGED_KERNEL_CASES = {
+    # name: (rows, Hq, Hkv, Dh, cache dtype, window, flat)
+    "doc-batch": (16, 32, 8, 128, jnp.bfloat16, 0, False),
+    "chat-concurrent": (64, 32, 8, 64, jnp.bfloat16, 0, True),
+    "int8kv": (16, 32, 8, 128, jnp.int8, 0, False),
+    "windowed": (16, 32, 8, 128, jnp.bfloat16, 1000, False),
+    "tp4-shard": (16, 8, 2, 128, jnp.bfloat16, 0, False),
+    "one-row": (1, 32, 8, 128, jnp.bfloat16, 0, False),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PAGED_KERNEL_CASES))
+def test_paged_kernels_compile_for_the_chip(case, one_chip):
+    """Both paged kernels through the chip's compiler at the cells' real
+    shapes (16 rows x 32 heads x 128 and 64 rows x 32 heads x 64 flat,
+    256 table slots of 16 tokens) and at what else the engine asks of
+    them: int8 K/V, a sliding window, a tensor-parallel shard's two kv
+    heads, a bucket of one row.  No temporary of a pool layer's size:
+    the views the kernels take of the cache are bitcasts."""
+    from mxnet_tpu.ops.attention import paged_attention
+
+    B, Hq, Hkv, Dh, dtype, window, flat = PAGED_KERNEL_CASES[case]
+    L, nb, bs, W = 4, 513, 16, 256
+    quant = dtype == jnp.int8
+    S = jax.ShapeDtypeStruct
+    cache = S((L, nb, bs) + ((Hkv * Dh,) if flat else (Hkv, Dh)), dtype)
+    scales = [S((L, nb, bs, Hkv), jnp.float32)] * 2 if quant else []
+    kw = {"flat_heads": Hkv} if flat else {"window": window}
+
+    def fwd(q, kc, vc, bt, ctx, *sc):
+        if sc:
+            kw.update(k_scale=sc[0], v_scale=sc[1])
+        return paged_attention(q, kc, vc, bt, ctx, layer=2, impl="pallas",
+                               **kw)
+
+    with hlo_audit.assume_tpu():
+        compiled = _chip_compile(
+            jax.jit(fwd), [S((B, Hq, Dh), jnp.bfloat16), cache, cache,
+                           S((B, W), jnp.int32), S((B,), jnp.int32)] + scales,
+            one_chip)
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    layer_bytes = nb * bs * Hkv * Dh * jnp.dtype(dtype).itemsize
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes
+
+
+def _cell_width_engine(kind):
+    """An engine with a benchmark cell's attention geometry, batch and
+    table (``mistral7b-l16``: 32 / 8 heads x 128, 16 rows; ``granite-
+    4.0-h-micro``: 32 / 8 heads x 64 flat, 64 rows; 256 slots x 16
+    tokens; the cells' pools, which no faster memory holds), two layers,
+    a small MLP and vocabulary."""
+    geo = dict(block_size=16, max_model_len=4096, prefill_chunk=512,
+               num_blocks=8193 if kind == "hybrid" else 5001)
+    if kind == "hybrid":
+        dec = mx.models.hybrid_decoder(
+            256, 2048, ["mamba", "attention"], num_heads=32, kv_heads=8,
+            d_ff=256, mamba_heads=64, mamba_head_dim=64, mamba_state=128)
+        return mx.serve.Engine(dec.init_params(0, dtype="bfloat16"),
+                               symbol=dec, max_batch=64, **geo), 64
+    net = mx.models.gpt(256, 4096, num_layers=2, d_model=4096, num_heads=32,
+                        d_ff=256, norm="rmsnorm", mlp="swiglu",
+                        pos_embed="rope", tie_embeddings=False, kv_heads=8)
+    shapes, _, _ = net.infer_shape(data=(1, 4096), softmax_label=(1, 4096))
+    params = {n: jnp.zeros(s, jnp.bfloat16)
+              for n, s in zip(net.list_arguments(), shapes)
+              if n not in ("data", "softmax_label")}
+    return mx.serve.Engine(params, symbol=net, max_batch=16, **geo), 16
+
+
+@pytest.mark.parametrize("kind", ["gpt", "hybrid"])
+def test_decode_program_holds_no_copy_of_a_cache_pool(kind, one_chip):
+    """The decode program as the chip's compiler leaves it: one Mosaic
+    call a layer (the paged kernel; in a hybrid the state update for a
+    state-space layer), and the only results of the K/V pool's size are
+    the in-place writes, two scatter fusions an attention layer.  A
+    ``copy`` of the pool, or a temporary of its size, is what PR 27
+    took out; the kernels' views of the stack must stay bitcasts."""
+    with hlo_audit.assume_tpu():
+        eng, bucket = _cell_width_engine(kind)
+        try:
+            assert eng.statusz()["paged_attention"] == "pallas"
+            # a CPU-built engine does not donate, and a program that
+            # does not donate must copy its pool to return it
+            eng._donate = True
+            compiled = _chip_compile(eng._program_builder("decode", bucket),
+                                     eng._program_specs("decode", bucket),
+                                     one_chip)
+            pool_shape, dtype = eng._cache_k.shape, eng._cache_k.dtype
+            n_layers = eng.spec["n_layers"]
+        finally:
+            eng.shutdown()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == n_layers
+    writes = hlo_audit.pool_sized_results(text, pool_shape)
+    assert len(writes) == 2 * pool_shape[0], writes
+    assert {op for _, op in writes} == {"fusion"}, writes
+    pool_bytes = int(np.prod(pool_shape)) * jnp.dtype(dtype).itemsize
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 2
+
+
+def test_aot_fingerprint_names_the_tiled_kernel(tmp_path):
+    """The AOT store has no version but the fingerprint: the paged
+    kernel's value moved with the walk, so an artifact exported under
+    ``pallas-stacked`` (the slot-a-step kernel) is not found."""
+    from mxnet_tpu import aot
+    from mxnet_tpu.ops.attention import PAGED_TILE_TOKENS
+
+    with hlo_audit.assume_tpu():
+        eng = hlo_audit.build_serve_engine(dtype="bfloat16", block_size=8)
+        try:
+            fp = dict(eng._aot_base_fp(), kind="decode", bucket=4)
+            status = eng.statusz()
+        finally:
+            eng.shutdown()
+    assert fp["paged_attention"] == f"pallas-tile{PAGED_TILE_TOKENS}"
+    assert status["paged_attention"] == "pallas"
+    assert status["paged_tile_tokens"] == PAGED_TILE_TOKENS
+    store = aot.ExportStore(str(tmp_path / "aot"))
+    exported = jax.export.export(jax.jit(jnp.tanh))(
+        jax.ShapeDtypeStruct((8,), jnp.float32))
+    old = dict(fp, paged_attention="pallas-stacked")
+    assert store.save(old, exported)
+    assert store.load(old) is not None
+    assert store.load(fp) is None
+    # the jnp formulation never carried the key, and still does not
+    eng = hlo_audit.build_serve_engine()
+    try:
+        assert "paged_attention" not in eng._aot_base_fp()
+        assert eng.statusz()["paged_tile_tokens"] is None
+    finally:
+        eng.shutdown()
 
 
 # -- 2. HLO structural audits over the bench train steps --------------------

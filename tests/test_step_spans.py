@@ -436,8 +436,10 @@ def test_every_phase_reaches_trace_annotation_before_its_work(
     assert steps == [{"step": step0 + i + 1} for i in range(len(steps))]
     decodes = [kw for kind, name, kw in log
                if kind == "enter" and name == "serve.decode"]
-    assert decodes and all(kw == {"batch": 1, "bucket": 1}
-                           for kw in decodes)
+    assert decodes and all(
+        {"batch": 1, "bucket": 1, "kv_tiles": 1}.items() <= kw.items()
+        and set(kw) == {"batch", "bucket", "kv_tiles", "kv_tiles_table"}
+        for kw in decodes)
 
 
 # -- start-up ---------------------------------------------------------------------

@@ -25,6 +25,7 @@ Prints one JSON line per model: {"model", "transposes", "convolutions",
 
 import contextlib
 import json
+import math
 import os
 import re
 import sys
@@ -150,6 +151,30 @@ def cache_layer_slices(text, cache_shape):
             dims = dims[1:]
         if len(dims) == 4 and (dims[0], dims[1], dims[3]) == (nb, bs, dh):
             found.append(line.strip()[:200])
+    return found
+
+
+def pool_sized_results(compiled_text, pool_shape):
+    """``(name, op)`` of every instruction of COMPILED HLO text's entry
+    computation whose result has as many elements as the cache pool
+    ``pool_shape``, views and plumbing aside (parameters, bitcasts,
+    tuples).  What a decode
+    program that updates its pool in place leaves is the in-place writes
+    (two scatter fusions an attention layer, K and V); a ``copy``, or a
+    fusion that is not a write, is a second pool in HBM and a pass over
+    the first (PERF.md, PR 27)."""
+    want = math.prod(int(d) for d in pool_shape)
+    found = []
+    # the entry computation only: a fusion's body repeats its result
+    entry = compiled_text[compiled_text.rindex("\nENTRY "):]
+    for line in entry.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = \w+\[([\d,]+)\]\S* ([\w\-]+)\(",
+                     line)
+        if not m or m.group(3) in ("parameter", "bitcast",
+                                   "get-tuple-element", "tuple"):
+            continue
+        if math.prod(int(d) for d in m.group(2).split(",")) == want:
+            found.append((m.group(1), m.group(3)))
     return found
 
 
