@@ -325,6 +325,31 @@ class FlashAttentionOp(OpDef):
         return [jnp.einsum("bhqk,bhkd->bhqd", p, v)], []
 
 
+def masked_attention(q, k, v, keep, scale):
+    """Dense grouped-query attention of a span of query rows over key
+    rows under an explicit mask: the span attention of the serving
+    programs (a whole prompt; a chunk or verify rows over the table's
+    gathered view), whatever leading axes the operands share.
+
+    ``q (..., T, Hq, Dh)``; ``k``/``v (..., S, Hkv, Dh)``, kv head g
+    serving q heads ``[g*group, (g+1)*group)`` as in
+    :func:`paged_attention`; ``keep (..., T, S)`` bool, every row
+    keeping at least one key; ``scale`` multiplies the scores (an f32
+    scalar).  Softmax in f32.  Returns ``(..., T, Hkv, group, Dh)`` in
+    q's dtype: the q heads in their order, one reshape from ``(..., T,
+    Hq * Dh)``."""
+    from .flash_attention import gqa_group
+
+    Hkv = k.shape[-2]
+    qg = q.reshape(q.shape[:-2] + (Hkv, gqa_group(q.shape[-2], Hkv),
+                                   q.shape[-1]))
+    sc = jnp.einsum("...qkgd,...skd->...kgqs", qg, k) * scale
+    sc = jnp.where(keep[..., None, None, :, :], sc,
+                   jnp.asarray(-jnp.inf, sc.dtype))
+    pr = jax.nn.softmax(sc.astype(jnp.float32), axis=-1).astype(q.dtype)
+    return jnp.einsum("...kgqs,...skd->...qkgd", pr, v)
+
+
 # -- paged attention (serving) -----------------------------------------------
 # Positions of a row's context the Mosaic decode kernels fold in one
 # step of their walk: one score product, one softmax update and one
@@ -433,7 +458,7 @@ def paged_attention(q, k_cache, v_cache, block_tables, context_lens,
         addressed in place — by the kernel's DMA index, or as one more
         index of the jnp gather — and never sliced out first: a slice
         of the stack that feeds a custom call is a copy of that
-        layer's whole pool (serve/engine.py passes its stack as is).
+        layer's whole pool (serve/programs.py passes its stack as is).
       flat_heads: the caches are FLAT in their minor axis, ``(L,
         num_blocks, block_size, flat_heads * Dh)``, every kv head of a
         position side by side (serve/hybrid.py's layout for heads

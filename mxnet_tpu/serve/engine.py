@@ -26,13 +26,12 @@ recompiles, the serving analog of ``BucketingModule``'s bucket trick.
 
 The KV-cache is ONE device-resident array pair per engine,
 (layers, num_blocks, block_size, kv_heads, head_dim), carved into
-blocks by ``kv_block_manager.BlockManager``; decode attends through
-``ops.attention.paged_attention``.  Every program passes the stacked
-array whole and names the layer by a static index (``layer=i``,
-``ck[i, table]``) — never ``ck[i]``, which on the chip is a copy of
-that layer's whole pool.  Cache-pressure policy lives in
-``scheduler.Scheduler`` (preemption + back-pressure), never here —
-the engine only executes the schedule it is handed.
+blocks by ``kv_block_manager.BlockManager``; the compiled programs
+live below this module, in ``serve/programs.py`` (``serve/spec.py`` and
+``serve/hybrid.py`` build verify/draft and the hybrid decoders' on it).
+Cache-pressure policy lives in ``scheduler.Scheduler`` (preemption +
+back-pressure), never here — the engine only executes the schedule it
+is handed.
 
 With ``tp=N`` (env ``MXTPU_SERVE_TP``) the same programs run GSPMD-
 partitioned over a ``{'tp': N}`` mesh: parameters shard per the
@@ -63,14 +62,12 @@ from ..aot import export_store as aot_store
 from ..aot import warmup as aot_warmup
 from ..base import env_flag, env_int
 from ..lint.annotations import hot_path
-from ..models.generate import (_fc, _gelu, _ln, detect_gpt_variant,
-                               normalize_gpt_params,
+from ..models.generate import (detect_gpt_variant, normalize_gpt_params,
                                reconcile_decode_config)
 from ..parallel import partition as partition_mod
 from ..parallel.mesh import NamedSharding, PartitionSpec, make_mesh
 from ..models.hybrid import HybridDecoder
-from ..ops.attention import (PAGED_TILE_TOKENS, paged_attention,
-                             paged_tile_slots, score_scale)
+from ..ops.attention import PAGED_TILE_TOKENS, paged_tile_slots
 from ..telemetry import flight as flight_mod
 from ..telemetry import profiling
 from ..telemetry import statusz as statusz_mod
@@ -79,6 +76,9 @@ from ..telemetry.request_trace import RequestTracer
 from . import adapters as adapters_mod
 from . import hybrid as hybrid_mod
 from .kv_block_manager import BlockManager, HostKVPool
+from .programs import (TOP_LOGPROBS, _ModelCfg, _build_chunk, _build_decode,
+                       _build_prefill, _build_restore, _cfg_fp_fields,
+                       _quantize_gpt_params)
 from .scheduler import (CANCELLED, FINISHED, REJECTED, WAITING, QueueFull,
                         Request, Scheduler)
 from . import spec as spec_mod
@@ -94,40 +94,6 @@ __all__ = ["Engine"]
 # collectable while its programs outlive it.
 _STEP_CACHE = {}
 
-# the static model config the compiled programs close over
-# (numeric_watch is part of it: the watchdog variant returns an extra
-# logits-finite flag, so it is a DIFFERENT compiled program and a
-# different AOT artifact; kv_quant likewise — the int8-KV variant
-# threads two scale arrays through every program.  kv_quant=False is
-# REMOVED from the AOT fingerprint dict so a quant-off engine keeps
-# its pre-quant digests — see _aot_base_fp).
-# ``sampling``/``sample_cap`` replace the old per-engine
-# temperature/top_k TRACE KEYS: sampling params are per-request
-# (B,)-shaped OPERANDS of the sampling-mode programs, so one program
-# per bucket serves any mix of temperature/top-p/top-k with zero
-# retraces.  sampling=False is the historical greedy program,
-# byte-for-byte (and _aot_base_fp re-emits the historical
-# temperature=0.0/top_k=None fingerprint fields for it).
-_ModelCfg = collections.namedtuple("_ModelCfg", [
-    "name", "n_layers", "num_heads", "head_dim", "kv_heads",
-    "pos_table", "swiglu", "tied", "rmsnorm", "window", "block_size",
-    "sampling", "sample_cap", "numeric_watch", "kv_quant",
-    # paged LoRA multiplexing (serve/adapters.py): slot count and the
-    # padded rank ceiling.  adapters=0 (off, the default) follows the
-    # sampling precedent — both fields leave the AOT fingerprint so an
-    # adapters-off engine keeps its historical digests
-    "adapters", "adapter_rank",
-    # hybrid decoders (serve/hybrid.py): the decoder's description and
-    # each layer's place in its kind's cache stack.  None (every gpt()
-    # engine) follows the same only-when-on rule and leaves the AOT
-    # fingerprint, so the gpt programs keep their digests
-    "hybrid"],
-    defaults=(0, 0, None))
-
-# top-logprob candidates every sampling-mode program returns per
-# sampled position (static — the per-request ``logprobs`` count only
-# selects how many of them the host surfaces)
-TOP_LOGPROBS = 5
 
 # per-engine GSPMD placement bundle for tensor-parallel serving (None
 # on the single-device path): the tp mesh, the per-parameter
@@ -179,48 +145,6 @@ def _valid_top_k(k):
     if k < 1:
         raise ValueError(f"top_k must be None/0 or >= 1 (got {k})")
     return k
-
-
-def _cfg_fp_fields(cfg):
-    """``_ModelCfg`` -> AOT-fingerprint fields.  The sampling-mode
-    fields follow the only-when-on rule: a sampling-off cfg re-emits
-    the historical ``temperature=0.0``/``top_k=None`` trace-key fields
-    (dropping sampling/sample_cap), so a greedy engine's digests are
-    byte-identical to pre-operand releases and an upgraded greedy
-    fleet keeps loading its existing artifacts and manifests."""
-    d = dict(cfg._asdict())
-    if not d.get("sampling"):
-        d.pop("sampling", None)
-        d.pop("sample_cap", None)
-        d["temperature"] = 0.0
-        d["top_k"] = None
-    if not d.get("adapters"):
-        # same only-when-on rule: adapters-off keeps pre-LoRA digests
-        d.pop("adapters", None)
-        d.pop("adapter_rank", None)
-    if d.get("hybrid") is None:
-        d.pop("hybrid", None)
-    else:
-        # JSON-stable: the description's fields (the stack indices are
-        # derived from layer_types)
-        dec = d["hybrid"].dec._asdict()
-        dec["layer_types"] = list(dec["layer_types"])
-        d["hybrid"] = dec
-    return d
-
-
-def _rope(u, pos, base=10000.0):
-    """Rotate (N, H, Dh) rows by their own positions (N,) — matches
-    ops/attention.py RoPEOp / generate.py's scalar-position _rot."""
-    half = u.shape[-1] // 2
-    inv = base ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    ang = pos.astype(jnp.float32)[:, None] * inv          # (N, half)
-    cos = jnp.cos(ang)[:, None, :]
-    sin = jnp.sin(ang)[:, None, :]
-    uf = u.astype(jnp.float32)
-    u1, u2 = uf[..., :half], uf[..., half:]
-    return jnp.concatenate([u1 * cos - u2 * sin,
-                            u1 * sin + u2 * cos], axis=-1).astype(u.dtype)
 
 
 class Engine:
@@ -2677,631 +2601,3 @@ class Engine:
         else:
             first = 1
         return tuple(range(first, first + n_caches))
-
-
-# -- quantized serving helpers ------------------------------------------------
-def _quantize_gpt_params(params, name, spec):
-    """Weight-only int8 at load: every matmul projection of the
-    normalized gpt() checkpoint gets per-output-channel symmetric int8
-    weights (``contrib.quantization.quantize_weight``) plus a
-    ``*_wscale`` f32 vector that ``_wfc`` dequantizes on the fly —
-    4x smaller weight reads on the decode hot loop, the
-    ``ops/quantized.py`` weight-only convention.  Embeddings, norms
-    and biases stay fp; a tied LM head IS the embedding matrix, so it
-    stays fp too (quantizing it would also perturb every input
-    embedding lookup)."""
-    from ..contrib.quantization import quantize_weight
-
-    out = dict(params)
-    stems = []
-    for i in range(spec["n_layers"]):
-        p = f"{name}_l{i}"
-        stems += [f"{p}_q", f"{p}_k", f"{p}_v", f"{p}_proj",
-                  f"{p}_ff_up", f"{p}_ff_down"]
-        if spec["swiglu"]:
-            stems.append(f"{p}_ff_gate")
-    if not spec["tied"]:
-        stems.append(f"{name}_head")
-    for stem in stems:
-        w = out.get(f"{stem}_weight")
-        if w is None:
-            continue
-        # mxtpu-lint: disable=host-sync (load path, runs once at
-        # engine construction: the checkpoint must reach the host to
-        # quantize before placement)
-        wq, sc = quantize_weight(np.asarray(w, np.float32))
-        out[f"{stem}_weight"] = wq
-        out[f"{stem}_wscale"] = sc
-    return out
-
-
-def _wfc(params, stem, x):
-    """``_fc`` through a possibly weight-only-int8 checkpoint entry:
-    when ``<stem>_wscale`` exists the int8 weight dequantizes on the
-    fly (``ops/quantized.py``'s weight-only mode — activation-dtype
-    math, 4x smaller weight reads); without it this is exactly
-    ``_fc`` on the fp entry, so quant-off traced programs are
-    byte-for-byte what they were before quantized serving existed."""
-    w = params[f"{stem}_weight"]
-    sc = params.get(f"{stem}_wscale")
-    if sc is not None:
-        w = w.astype(x.dtype) * sc.astype(x.dtype)[:, None]
-    return _fc(x, w, params[f"{stem}_bias"])
-
-
-def _lora_delta(adp, stem, x, slots):
-    """The paged-LoRA low-rank delta for one projection: gather each
-    row's (A, B) slices from the device stacks by its slot operand and
-    compute ``scale * x @ A.T @ B.T`` — never materializing a merged
-    weight.  Slot 0's rows and scale are true zeros, so base rows add
-    exactly ``+0.0`` (token-identical to an adapters-off engine).
-
-    ``slots`` is a scalar for the one-request prefill/chunk programs,
-    ``(B,)`` for decode (2-D ``x``) and verify (3-D ``(B, K+1, D)``
-    ``x`` — the slot broadcasts over the candidate positions)."""
-    a = adp[f"{stem}_A"].astype(x.dtype)          # (S, r, d_in)
-    b = adp[f"{stem}_B"].astype(x.dtype)          # (S, d_out, r)
-    sc = adp["scale"]
-    if slots.ndim == 0:
-        u = x @ a[slots].T                        # (..., r)
-        return (u @ b[slots].T) * sc[slots].astype(x.dtype)
-    ga, gb = a[slots], b[slots]
-    s = sc[slots].astype(x.dtype)
-    if x.ndim == 2:
-        u = jnp.einsum("bi,bri->br", x, ga)
-        return jnp.einsum("br,bor->bo", u, gb) * s[:, None]
-    u = jnp.einsum("bki,bri->bkr", x, ga)
-    return jnp.einsum("bkr,bor->bko", u, gb) * s[:, None, None]
-
-
-def _awfc(cfg, params, adp, stem, x, slots):
-    """:func:`_wfc` plus the request's LoRA delta when the program
-    threads the adapter stacks.  ``adp`` is None on adapters-off
-    engines — a Python-level branch, so their traced programs stay
-    byte-for-byte the historical ones."""
-    base = _wfc(params, stem, x)
-    if adp is None:
-        return base
-    return base + _lora_delta(adp, stem, x, slots)
-
-
-def _kv_quant_vals(vals):
-    """Per-slot-per-head symmetric int8 for K/V rows ``(..., Hkv, Dh)``
-    -> ``(int8 rows, f32 scales (..., Hkv))``.  Each written slot
-    quantizes independently over its own head vector, so the cache
-    contents are a pure function of the fp values written — write
-    ORDER cannot change them, which is what keeps preemption-by-
-    recomputation and chunked re-prefill token-stable under int8 KV
-    (a block-granular scale would re-scale earlier slots on every
-    later write).  Zero vectors keep scale 1.0, ``quantize_weight``'s
-    convention, so untouched cache stays exactly zero."""
-    vf = vals.astype(jnp.float32)
-    amax = jnp.max(jnp.abs(vf), axis=-1)
-    sc = jnp.where(amax > 0, amax / 127.0, 1.0)
-    q = jnp.clip(jnp.round(vf / sc[..., None]), -127, 127).astype(jnp.int8)
-    return q, sc
-
-
-def _kv_dequant(q, sc, dtype):
-    """Invert :func:`_kv_quant_vals`: ``(..., Hkv, Dh)`` int8 plus
-    ``(..., Hkv)`` scales -> fp rows in ``dtype``."""
-    return (q.astype(jnp.float32)
-            * sc.astype(jnp.float32)[..., None]).astype(dtype)
-
-
-# -- compiled-program bodies (close over _ModelCfg ONLY — never an
-# Engine, so the shared _STEP_CACHE cannot retain a retired engine's
-# parameter dict) -------------------------------------------------------------
-def _sample(cfg, logits, key):
-    """Greedy argmax — the sampling-OFF programs' sampler, exactly the
-    historical temperature-0 path (``key`` stays in the signature so
-    the greedy program's operand list never moves).  Stochastic
-    serving threads per-request operands through :func:`_sample_ops`
-    inside the sampling-mode programs instead — temperature/top-k are
-    no longer trace keys anywhere."""
-    del key
-    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-
-
-# -- operand sampling (the sampling-mode programs' warp + sample) ------------
-def _filter_logits(cfg, logits, temp, top_p, top_k):
-    """Temperature/top-k/top-p warping with PER-ROW traced operands.
-
-    ``logits`` (..., V); ``temp``/``top_p`` f32 and ``top_k`` int32
-    broadcastable over the leading dims (0 = filter off for top_k).
-    Returns ``(masked, idx)``: the top-``sample_cap`` candidates'
-    warped logits (filtered positions at -inf) in descending order,
-    and their vocab ids.  ``jax.lax.top_k`` replaces the old
-    full-vocab ``jnp.sort``: the kth-largest threshold only ever
-    needs the leading ``cap`` candidates, and top-p needs the same
-    descending slice — one top_k call serves both (numerical
-    equivalence vs the sort formulation is pinned in
-    tests/test_sampling.py).  Candidates past the cap are never
-    sampled — the cap itself acts as a top-``cap`` filter (exact
-    whenever cap >= vocab, e.g. the tiny-vocab statistical pins).
-    Greedy rows (temp <= 0) come out one-hot on the argmax, so a
-    categorical draw over ``masked`` IS argmax there — every other
-    candidate sits at -inf.
-    """
-    V = logits.shape[-1]
-    cap = min(cfg.sample_cap, V) if cfg.sample_cap else V
-    greedy = temp <= 0.0
-    lg = logits.astype(jnp.float32)
-    scaled = lg / jnp.where(greedy, 1.0, temp)[..., None]
-    vals, idx = jax.lax.top_k(scaled, cap)             # descending
-    # fence the sort's outputs: XLA-CPU's producer-duplicating fusion
-    # otherwise re-runs the whole top-k sort inside every consumer of
-    # ``idx`` (measured 15x on the verify program's acceptance gather)
-    vals, idx = jax.lax.optimization_barrier((vals, idx))
-    j = jnp.arange(cap)
-    k_eff = jnp.where(top_k > 0, jnp.minimum(top_k, cap), cap)
-    keep = j < k_eff[..., None]
-    probs = jax.nn.softmax(jnp.where(keep, vals, -jnp.inf), axis=-1)
-    csum = jnp.cumsum(probs, axis=-1)
-    # nucleus: the smallest candidate set whose mass reaches top_p —
-    # a candidate stays while the mass BEFORE it is under top_p
-    keep = jnp.logical_and(keep, (csum - probs) < top_p[..., None])
-    masked = jnp.where(keep, vals, -jnp.inf)
-    return jnp.where(greedy[..., None],
-                     jnp.where(j == 0, 0.0, -jnp.inf), masked), idx
-
-
-def _sample_ops(cfg, logits, key, temp, top_p, top_k):
-    """Sample one token per row from the warped distribution (greedy
-    rows are exact argmax); int32 ids of the leading shape."""
-    masked, idx = _filter_logits(cfg, logits, temp, top_p, top_k)
-    choice = jax.random.categorical(key, masked, axis=-1)
-    return jnp.take_along_axis(
-        idx, choice[..., None], axis=-1)[..., 0].astype(jnp.int32)
-
-
-def _scatter_probs(probs, idx, V):
-    """Scatter per-candidate probabilities ``(..., cap)`` back onto
-    their vocab ids -> a full ``(..., V)`` probability vector (zeros
-    off the candidate set)."""
-    lead = probs.shape[:-1]
-    flat_p = probs.reshape((-1, probs.shape[-1]))
-    flat_i = idx.reshape((-1, idx.shape[-1]))
-    n = flat_p.shape[0]
-    full = jnp.zeros((n, V), jnp.float32).at[
-        jnp.arange(n)[:, None], flat_i].set(flat_p)
-    return full.reshape(lead + (V,))
-
-
-def _filtered_probs_full(cfg, logits, temp, top_p, top_k):
-    """The warped SAMPLING distribution as a full-vocab probability
-    vector ``(..., V)`` — the REFERENCE view of the warp, used by the
-    test suite's sort-equivalence and distribution pins.  The serving
-    hot path never materializes it: the programs sample straight from
-    the candidate representation (`_filter_logits` + categorical) and
-    the verify program's rejection-sampling acceptance evaluates p and
-    q purely at candidate ids (serve/spec.py)."""
-    masked, idx = _filter_logits(cfg, logits, temp, top_p, top_k)
-    return _scatter_probs(jax.nn.softmax(masked, axis=-1), idx,
-                          logits.shape[-1])
-
-
-def _safe_log(p):
-    """log(p) with exact -inf at p == 0 (a zero-probability token can
-    never win a categorical draw, and a one-hot row samples its hot
-    token deterministically)."""
-    return jnp.where(p > 0, jnp.log(jnp.maximum(p, 1e-38)), -jnp.inf)
-
-
-def _logprob_outs(logits, toks):
-    """The logprob outputs every sampling-mode program returns for its
-    sampled positions: the chosen token's log-softmax plus the
-    ``TOP_LOGPROBS`` best candidates (values + ids).  RAW model
-    logprobs (pre-temperature/filtering, the OpenAI-style convention)
-    — greedy and stochastic rows report the same quantity."""
-    lp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-    chosen = jnp.take_along_axis(
-        lp, toks[..., None].astype(jnp.int32), axis=-1)[..., 0]
-    tv, ti = jax.lax.top_k(lp, min(TOP_LOGPROBS, lp.shape[-1]))
-    return chosen, tv, ti.astype(jnp.int32)
-
-
-def _mlp(cfg, params, p, x, adp=None, slots=None):
-    h2 = _ln(x, params[f"{p}_ln2_gamma"],
-             None if cfg.rmsnorm else params[f"{p}_ln2_beta"])
-    if cfg.swiglu:
-        g = _awfc(cfg, params, adp, f"{p}_ff_gate", h2, slots)
-        gf = g.astype(jnp.float32)               # f32 silu == sym.silu
-        up = ((gf * jax.nn.sigmoid(gf)).astype(g.dtype)
-              * _awfc(cfg, params, adp, f"{p}_ff_up", h2, slots))
-    else:
-        up = _gelu(_awfc(cfg, params, adp, f"{p}_ff_up", h2, slots))
-    return _awfc(cfg, params, adp, f"{p}_ff_down", up, slots)
-
-
-def _logits(cfg, params, x):
-    name = cfg.name
-    final = _ln(x, params[f"{name}_ln_f_gamma"],
-                None if cfg.rmsnorm else params[f"{name}_ln_f_beta"])
-    if cfg.tied:
-        return final @ params[f"{name}_tok_embed_weight"].T.astype(
-            final.dtype)
-    return _wfc(params, f"{name}_head", final)
-
-
-def _forward_token_batch(cfg, params, ck, cv, ksc, vsc, toks, pos, tables,
-                         adp=None, slots=None, shardings=None):
-    """Shared decode math: write each row's K/V at its position,
-    attend through the block tables, return logits (B, V).  With
-    ``cfg.kv_quant`` the caches are int8 and ``ksc``/``vsc`` carry the
-    per-slot-per-head f32 scales (None otherwise): writes quantize,
-    attention dequantizes through the same tables.  ``shardings`` (the
-    program's tp placement bundle) tells ``paged_attention`` the mesh
-    and the axis the cache's head dimension is split over, so the
-    Mosaic kernel runs per head shard."""
-    name = cfg.name
-    Hq, Hkv, Dh = cfg.num_heads, cfg.kv_heads, cfg.head_dim
-    d_model = Hq * Dh
-    B = toks.shape[0]
-    x = params[f"{name}_tok_embed_weight"][toks]           # (B, D)
-    if cfg.pos_table is not None:
-        x = x + params[f"{name}_pos_embed_weight"][0, pos]
-    blk = jnp.take_along_axis(tables, (pos // cfg.block_size)[:, None],
-                              axis=1)[:, 0]
-    off = pos % cfg.block_size
-    ctx = pos + 1
-    paged_kw = {}
-    if shardings is not None:
-        cache_spec = shardings.cache.spec       # (L, nb, bs, Hkv, Dh)
-        paged_kw = {"mesh": shardings.mesh,
-                    "head_axis": (cache_spec[3] if len(cache_spec) > 3
-                                  else None)}
-    for i in range(cfg.n_layers):
-        p = f"{name}_l{i}"
-        h = _ln(x, params[f"{p}_ln1_gamma"],
-                None if cfg.rmsnorm else params[f"{p}_ln1_beta"])
-        q = _awfc(cfg, params, adp, f"{p}_q", h, slots)
-        k = _awfc(cfg, params, adp, f"{p}_k", h, slots)
-        v = _awfc(cfg, params, adp, f"{p}_v", h, slots)
-        qh = q.reshape(B, Hq, Dh)
-        kh = k.reshape(B, Hkv, Dh)
-        vh = v.reshape(B, Hkv, Dh)
-        if cfg.pos_table is None:
-            qh, kh = _rope(qh, pos), _rope(kh, pos)
-        if cfg.kv_quant:
-            kq, ks = _kv_quant_vals(kh)
-            vq, vs = _kv_quant_vals(vh)
-            ck = ck.at[i, blk, off].set(kq)
-            ksc = ksc.at[i, blk, off].set(ks)
-            cv = cv.at[i, blk, off].set(vq)
-            vsc = vsc.at[i, blk, off].set(vs)
-            attn = paged_attention(qh, ck, cv, tables, ctx, layer=i,
-                                   window=cfg.window,
-                                   k_scale=ksc, v_scale=vsc,
-                                   **paged_kw)
-        else:
-            ck = ck.at[i, blk, off].set(kh)
-            cv = cv.at[i, blk, off].set(vh)
-            attn = paged_attention(qh, ck, cv, tables, ctx, layer=i,
-                                   window=cfg.window, **paged_kw)
-        x = x + _awfc(cfg, params, adp, f"{p}_proj",
-                      attn.reshape(B, d_model), slots)
-        x = x + _mlp(cfg, params, p, x, adp=adp, slots=slots)
-    return _logits(cfg, params, x), ck, cv, ksc, vsc
-
-
-def _split_cache_args(cfg, rest):
-    """Unpack a program's post-params positional args: the cache
-    operands (2, or 4 with int8-KV scales) then the host-fed args.
-    Returns ``(ck, cv, ksc, vsc, tail)`` with None scales when not
-    quantized — the builders' one place to agree with _cache_args."""
-    if cfg.kv_quant:
-        return rest[0], rest[1], rest[2], rest[3], rest[4:]
-    return rest[0], rest[1], None, None, rest[2:]
-
-
-def _cache_outs(cfg, ck, cv, ksc, vsc):
-    """The cache tail of a program's output tuple (mirrors
-    :func:`_split_cache_args`)."""
-    if cfg.kv_quant:
-        return (ck, cv, ksc, vsc)
-    return (ck, cv)
-
-
-def _jit_kwargs(cfg, donate, shardings, n_token_args, n_lead=None):
-    """Shared jit options for the bucket programs.  With a tp mesh the
-    in/out shardings are pinned explicitly — params per the partition
-    rules, KV-cache head-sharded (scale arrays too, under int8 KV),
-    everything host-fed replicated — so GSPMD partitions the program
-    (inserting the two all-reduces per layer) instead of inferring a
-    layout per call site.
-
-    ``n_token_args`` counts the host-fed operands between the caches
-    and the rng key AS THE GREEDY PROGRAM takes them; sampling-mode
-    programs append the (temp, top_p, top_k) triple, counted here.
-    ``n_lead`` is the host-bound output count ahead of the watchdog
-    flag/caches (default: 1 sampled-token output, +3 logprob views in
-    sampling mode)."""
-    n_caches = 4 if cfg.kv_quant else 2
-    if cfg.sampling:
-        n_token_args += 3
-    if cfg.adapters:
-        n_token_args += 1            # the per-row adapter-slot operand
-    if n_lead is None:
-        n_lead = 4 if cfg.sampling else 1
-    first = 2 if cfg.adapters else 1  # adp stacks sit after params
-    kw = {"donate_argnums": (tuple(range(first, first + n_caches))
-                             if donate else ())}
-    if shardings is not None:
-        rep = shardings.rep
-        caches = (shardings.cache,) * 2
-        if cfg.kv_quant:
-            caches += (shardings.scale,) * 2
-        lead_in = (shardings.params,)
-        if cfg.adapters:
-            lead_in += (shardings.adapters
-                        if shardings.adapters is not None else rep,)
-        kw["in_shardings"] = (lead_in + caches
-                              + (rep,) * n_token_args + (rep,))
-        out = (rep,) * n_lead
-        if cfg.numeric_watch:
-            out += (rep,)
-        kw["out_shardings"] = out + caches
-    return kw
-
-
-def _build_decode(cfg, donate, shardings=None):
-    def decode(params, *rest):
-        adp = slots = None
-        if cfg.adapters:
-            adp, rest = rest[0], rest[1:]
-        ck, cv, ksc, vsc, tail = _split_cache_args(cfg, rest)
-        toks, pos, tables = tail[:3]
-        tail = tail[3:]
-        if cfg.adapters:
-            slots, tail = tail[0], tail[1:]
-        if cfg.sampling:
-            temp, topp, topk, rng = tail
-        else:
-            rng, = tail
-        logits, ck, cv, ksc, vsc = _forward_token_batch(
-            cfg, params, ck, cv, ksc, vsc, toks, pos, tables,
-            adp=adp, slots=slots, shardings=shardings)
-        if cfg.sampling:
-            tok = _sample_ops(cfg, logits, rng, temp, topp, topk)
-            lead = (tok,) + _logprob_outs(logits, tok)
-        else:
-            tok = _sample(cfg, logits, rng)
-            lead = (tok,)
-        caches = _cache_outs(cfg, ck, cv, ksc, vsc)
-        if cfg.numeric_watch:
-            # one extra all-reduce over the logits: the watchdog flag
-            # rides back with the sampled tokens (the host syncs on
-            # them anyway), so a NaN fires the flight recorder instead
-            # of silently poisoning every later token
-            return lead + (jnp.isfinite(logits).all(),) + caches
-        return lead + caches
-
-    return jax.jit(decode, **_jit_kwargs(cfg, donate, shardings, 3))
-
-
-def _build_prefill(cfg, P, donate, shardings=None):
-    name = cfg.name
-    Hq, Hkv, Dh = cfg.num_heads, cfg.kv_heads, cfg.head_dim
-    group = Hq // Hkv
-    d_model = Hq * Dh
-    window = cfg.window
-
-    def prefill(params, *rest):
-        """Whole-prompt pass at padded length P for ONE request:
-        writes K/V for positions [0, plen) through the block
-        table and samples the token after position plen-1."""
-        adp = slots = None
-        if cfg.adapters:
-            adp, rest = rest[0], rest[1:]
-        ck, cv, ksc, vsc, tail = _split_cache_args(cfg, rest)
-        toks, plen, blk, off = tail[:4]
-        tail = tail[4:]
-        if cfg.adapters:
-            slots, tail = tail[0], tail[1:]
-        if cfg.sampling:
-            temp, topp, topk, rng = tail
-        else:
-            rng, = tail
-        pos = jnp.arange(P)
-        x = params[f"{name}_tok_embed_weight"][toks]       # (P, D)
-        if cfg.pos_table is not None:
-            x = x + params[f"{name}_pos_embed_weight"][0, :P]
-        qp = pos[:, None]
-        kp = pos[None, :]
-        keep = qp >= kp                                    # causal
-        if window:
-            keep = jnp.logical_and(keep, qp - kp < window)
-        for i in range(cfg.n_layers):
-            p = f"{name}_l{i}"
-            h = _ln(x, params[f"{p}_ln1_gamma"],
-                    None if cfg.rmsnorm else params[f"{p}_ln1_beta"])
-            q = _awfc(cfg, params, adp, f"{p}_q", h, slots)
-            k = _awfc(cfg, params, adp, f"{p}_k", h, slots)
-            v = _awfc(cfg, params, adp, f"{p}_v", h, slots)
-            qh = q.reshape(P, Hq, Dh)
-            kh = k.reshape(P, Hkv, Dh)
-            vh = v.reshape(P, Hkv, Dh)
-            if cfg.pos_table is None:
-                qh, kh = _rope(qh, pos), _rope(kh, pos)
-            if cfg.kv_quant:
-                kq, ks = _kv_quant_vals(kh)
-                vq, vs = _kv_quant_vals(vh)
-                ck = ck.at[i, blk, off].set(kq)
-                ksc = ksc.at[i, blk, off].set(ks)
-                cv = cv.at[i, blk, off].set(vq)
-                vsc = vsc.at[i, blk, off].set(vs)
-                # attend to the DEQUANTIZED values: every path must
-                # see the cache's int8 round-trip, or a later chunk /
-                # decode step reading the cache would diverge from the
-                # hidden states this very pass computed
-                kh = _kv_dequant(kq, ks, x.dtype)
-                vh = _kv_dequant(vq, vs, x.dtype)
-            else:
-                ck = ck.at[i, blk, off].set(kh)
-                cv = cv.at[i, blk, off].set(vh)
-            # grouped-query dense causal attention within the
-            # prompt (same head grouping as paged_attention)
-            qg = qh.reshape(P, Hkv, group, Dh)
-            sc = jnp.einsum("qkgd,skd->kgqs", qg, kh)
-            sc = sc * score_scale(Dh)
-            sc = jnp.where(keep[None, None], sc,
-                           jnp.asarray(-jnp.inf, sc.dtype))
-            pr = jax.nn.softmax(sc.astype(jnp.float32),
-                                axis=-1).astype(x.dtype)
-            at = jnp.einsum("kgqs,skd->qkgd", pr, vh)
-            x = x + _awfc(cfg, params, adp, f"{p}_proj",
-                          at.reshape(P, d_model), slots)
-            x = x + _mlp(cfg, params, p, x, adp=adp, slots=slots)
-        logits = _logits(cfg, params, x[plen - 1][None])
-        caches = _cache_outs(cfg, ck, cv, ksc, vsc)
-        if cfg.sampling:
-            tok = _sample_ops(cfg, logits, rng, temp, topp, topk)
-            lp, tv, ti = _logprob_outs(logits, tok)
-            lead = (tok[0], lp[0], tv[0], ti[0])
-        else:
-            tok = _sample(cfg, logits, rng)[0]
-            lead = (tok,)
-        if cfg.numeric_watch:
-            return lead + (jnp.isfinite(logits).all(),) + caches
-        return lead + caches
-
-    return jax.jit(prefill, **_jit_kwargs(cfg, donate, shardings, 4))
-
-
-def _build_restore(cfg, donate, shardings=None):
-    """Host-tier restore program: scatter R parked blocks' host copies
-    back into the device cache through their (freshly allocated) block
-    ids.  Pure data movement — no params, no sampling: the caches are
-    donated through so the copy is in-place, padding rows write zeros
-    into the null block (contents garbage by design), and under tp the
-    replicated host operands scatter onto the head-sharded cache."""
-
-    def restore(*args):
-        if cfg.kv_quant:
-            ck, cv, ksc, vsc = args[:4]
-            blks, hk, hv, hks, hvs = args[4:]
-        else:
-            ck, cv = args[:2]
-            ksc = vsc = None
-            blks, hk, hv = args[2:]
-        ck = ck.at[:, blks].set(hk)
-        cv = cv.at[:, blks].set(hv)
-        if cfg.kv_quant:
-            ksc = ksc.at[:, blks].set(hks)
-            vsc = vsc.at[:, blks].set(hvs)
-        return _cache_outs(cfg, ck, cv, ksc, vsc)
-
-    n_caches = 4 if cfg.kv_quant else 2
-    kw = {"donate_argnums": (tuple(range(n_caches)) if donate else ())}
-    if shardings is not None:
-        rep = shardings.rep
-        caches = (shardings.cache,) * 2
-        if cfg.kv_quant:
-            caches += (shardings.scale,) * 2
-        n_host = 5 if cfg.kv_quant else 3
-        kw["in_shardings"] = caches + (rep,) * n_host
-        kw["out_shardings"] = caches
-    return jax.jit(restore, **kw)
-
-
-def _build_chunk(cfg, C, donate, shardings=None):
-    """Suffix/chunk prefill program: C token rows of ONE request whose
-    earlier positions' K/V already sit in the cache (a prefix-cache hit
-    or previous chunks of the same prompt).  The rows' K/V is written
-    through the block table FIRST and each row then attends to every
-    cache position <= its own through the table — the same
-    write-then-attend trick the decode program uses, which makes
-    in-chunk causality exact without a dense (P, P) score matrix."""
-    name = cfg.name
-    Hq, Hkv, Dh = cfg.num_heads, cfg.kv_heads, cfg.head_dim
-    group = Hq // Hkv
-    d_model = Hq * Dh
-    window = cfg.window
-
-    def chunk(params, *rest):
-        """Rows hold positions [start, start+n_valid) (rows past
-        n_valid are padding: they write into the null block and their
-        outputs are discarded).  Samples the token after position
-        start+n_valid-1 — meaningful on the final chunk only."""
-        adp = slots = None
-        if cfg.adapters:
-            adp, rest = rest[0], rest[1:]
-        ck, cv, ksc, vsc, tail = _split_cache_args(cfg, rest)
-        toks, start, n_valid, table, blk, off = tail[:6]
-        tail = tail[6:]
-        if cfg.adapters:
-            slots, tail = tail[0], tail[1:]
-        if cfg.sampling:
-            temp, topp, topk, rng = tail
-        else:
-            rng, = tail
-        pos = start + jnp.arange(C)
-        x = params[f"{name}_tok_embed_weight"][toks]       # (C, D)
-        if cfg.pos_table is not None:
-            # clamp padded rows: their position may exceed the table
-            pidx = jnp.minimum(pos, cfg.pos_table - 1)
-            x = x + params[f"{name}_pos_embed_weight"][0, pidx]
-        S = table.shape[0] * cfg.block_size
-        spos = jnp.arange(S)[None, :]          # logical cache positions
-        keep = spos <= pos[:, None]            # causal, self included
-        if window:
-            keep = jnp.logical_and(keep, spos > pos[:, None] - window)
-        for i in range(cfg.n_layers):
-            p = f"{name}_l{i}"
-            h = _ln(x, params[f"{p}_ln1_gamma"],
-                    None if cfg.rmsnorm else params[f"{p}_ln1_beta"])
-            q = _awfc(cfg, params, adp, f"{p}_q", h, slots)
-            k = _awfc(cfg, params, adp, f"{p}_k", h, slots)
-            v = _awfc(cfg, params, adp, f"{p}_v", h, slots)
-            qh = q.reshape(C, Hq, Dh)
-            kh = k.reshape(C, Hkv, Dh)
-            vh = v.reshape(C, Hkv, Dh)
-            if cfg.pos_table is None:
-                qh, kh = _rope(qh, pos), _rope(kh, pos)
-            if cfg.kv_quant:
-                kq, ks = _kv_quant_vals(kh)
-                vq, vs = _kv_quant_vals(vh)
-                ck = ck.at[i, blk, off].set(kq)
-                ksc = ksc.at[i, blk, off].set(ks)
-                cv = cv.at[i, blk, off].set(vq)
-                vsc = vsc.at[i, blk, off].set(vs)
-            else:
-                ck = ck.at[i, blk, off].set(kh)
-                cv = cv.at[i, blk, off].set(vh)
-            # all rows share one table: gather the request's logical
-            # cache view ONCE per layer, then mask per-row by position
-            # (ck[i, table] is one gather over the stack; ck[i][table]
-            # would first copy the layer's whole pool)
-            kb = ck[i, table].reshape(S, Hkv, Dh)
-            vb = cv[i, table].reshape(S, Hkv, Dh)
-            if cfg.kv_quant:
-                kb = _kv_dequant(kb, ksc[i, table].reshape(S, Hkv),
-                                 x.dtype)
-                vb = _kv_dequant(vb, vsc[i, table].reshape(S, Hkv),
-                                 x.dtype)
-            qg = qh.reshape(C, Hkv, group, Dh)
-            sc = jnp.einsum("ckgd,skd->kgcs", qg, kb)
-            sc = sc * score_scale(Dh)
-            sc = jnp.where(keep[None, None], sc,
-                           jnp.asarray(-jnp.inf, sc.dtype))
-            pr = jax.nn.softmax(sc.astype(jnp.float32),
-                                axis=-1).astype(x.dtype)
-            at = jnp.einsum("kgcs,skd->ckgd", pr, vb)
-            x = x + _awfc(cfg, params, adp, f"{p}_proj",
-                          at.reshape(C, d_model), slots)
-            x = x + _mlp(cfg, params, p, x, adp=adp, slots=slots)
-        logits = _logits(cfg, params, x[n_valid - 1][None])
-        caches = _cache_outs(cfg, ck, cv, ksc, vsc)
-        if cfg.sampling:
-            tok = _sample_ops(cfg, logits, rng, temp, topp, topk)
-            lp, tv, ti = _logprob_outs(logits, tok)
-            lead = (tok[0], lp[0], tv[0], ti[0])
-        else:
-            tok = _sample(cfg, logits, rng)[0]
-            lead = (tok,)
-        if cfg.numeric_watch:
-            return lead + (jnp.isfinite(logits).all(),) + caches
-        return lead + caches
-
-    return jax.jit(chunk, **_jit_kwargs(cfg, donate, shardings, 6))

@@ -31,8 +31,10 @@ rows, :class:`_SpanMix` for a span of one request):
 Program operands (``Engine._program_specs`` mirrors them): params, the
 four caches ``ck, cv, ssm, conv`` (donated through), the host-fed
 operands of the ``gpt`` program of the same kind, then the state slot
-(``(B,)`` for decode, a scalar for prefill and chunk), the sampling
-triple in sampling mode, the rng key.
+(``(B,)`` for decode, a scalar for prefill and chunk) and the tail
+``serve/programs.py::_finish`` takes, the ``gpt`` programs' epilogue
+(the sampling triple in sampling mode, the rng key).  A span attends
+through ``ops.attention.masked_attention``, as theirs do.
 """
 
 from __future__ import annotations
@@ -45,8 +47,9 @@ import numpy as np
 
 from ..models.generate import _ln
 from ..ops import ssm as ssm_ops
-from ..ops.attention import paged_attention
+from ..ops.attention import masked_attention, paged_attention
 from .kv_block_manager import STATE_POOL_NO_PREFIX
+from .programs import _finish
 
 __all__ = ["HybridCfg", "hybrid_cfg", "check_params", "refuse", "layer",
            "matmul_flops", "build_decode", "build_prefill", "build_chunk",
@@ -264,13 +267,8 @@ class _SpanMix:
             # the layer's whole pool
             k = self.ck[a, self.table].reshape(-1, Hkv, Dh)
             v = self.cv[a, self.table].reshape(-1, Hkv, Dh)
-        qg = q.reshape(self.T, Hkv, dec.num_heads // Hkv, Dh)
-        sc = jnp.einsum("qkgd,skd->kgqs", qg, k) \
-            * np.float32(dec.attention_multiplier)
-        sc = jnp.where(self.keep[None, None], sc,
-                       jnp.asarray(-jnp.inf, sc.dtype))
-        pr = jax.nn.softmax(sc.astype(jnp.float32), axis=-1).astype(h.dtype)
-        at = jnp.einsum("kgqs,skd->qkgd", pr, v)
+        at = masked_attention(q, k, v, self.keep,
+                              np.float32(dec.attention_multiplier))
         return _fc(at.reshape(self.T, -1), self.params[f"{p}_proj_weight"])
 
     def mamba(self, i, h):
@@ -342,26 +340,6 @@ def _stack(hc, params, x, mix):
     for i in range(hc.dec.num_layers):
         x = layer(hc, params, i, x, mix)
     return x, (mix.ck, mix.cv, mix.ssm, mix.conv)
-
-
-def _finish(cfg, logits, caches, tail, scalar):
-    """Sample and assemble a program's outputs exactly as the ``gpt``
-    programs do (lead outputs, the watchdog flag, the caches)."""
-    from . import engine as E
-
-    if cfg.sampling:
-        temp, topp, topk, rng = tail
-        tok = E._sample_ops(cfg, logits, rng, temp, topp, topk)
-        lp, tv, ti = E._logprob_outs(logits, tok)
-        lead = ((tok[0], lp[0], tv[0], ti[0]) if scalar
-                else (tok, lp, tv, ti))
-    else:
-        rng, = tail
-        tok = E._sample(cfg, logits, rng)
-        lead = (tok[0],) if scalar else (tok,)
-    if cfg.numeric_watch:
-        return lead + (jnp.isfinite(logits).all(),) + caches
-    return lead + caches
 
 
 def _jit(fn, donate):

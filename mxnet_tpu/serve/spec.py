@@ -67,8 +67,11 @@ import jax.numpy as jnp
 from ..base import env_float
 from ..models.generate import (detect_gpt_variant, normalize_gpt_params,
                                reconcile_decode_config)
-from ..ops.attention import score_scale
 from ..telemetry import flight as flight_mod
+from .programs import (_ModelCfg, _TableMix, _embed, _filter_logits,
+                       _forward_token_batch, _jit_kwargs, _logits,
+                       _logprob_outs, _operands, _outputs, _safe_log,
+                       _sample, _stack)
 
 __all__ = ["DraftWorker", "ENV_SPEC", "ENV_MIN_ACCEPT"]
 
@@ -124,8 +127,6 @@ class DraftWorker:
                 f"draft positional table ({spec['pos_table']}) is shorter "
                 f"than max_model_len ({engine.max_model_len}) — the draft "
                 "must be able to read every position the target serves")
-        from .engine import _ModelCfg
-
         self.name = name
         self.cfg = _ModelCfg(
             name=name, n_layers=spec["n_layers"],
@@ -308,17 +309,6 @@ def accept_greedy(drafted_row, target_row, k):
 
 
 # -- compiled-program bodies -------------------------------------------------
-def _rope_rows(u, pos):
-    """RoPE over arbitrary leading dims: flatten rows, reuse the
-    engine's rotation, restore the shape."""
-    from .engine import _rope
-
-    lead = u.shape[:-2]
-    flat = u.reshape((-1,) + u.shape[-2:])
-    return _rope(flat, pos.reshape(-1)).reshape(
-        lead + u.shape[-2:])
-
-
 def _build_draft(cfg, k, donate, shardings=None, sample_cfg=None):
     """The k-step draft-proposal program (kind="draft", bucketed over
     the decode batch).  Unrolls ``k+1`` single-token steps of the draft
@@ -344,8 +334,6 @@ def _build_draft(cfg, k, donate, shardings=None, sample_cfg=None):
     HBM traffic on the decode hot path.  Without it (greedy engines)
     the proposal is the historical argmax, byte-for-byte.
     """
-    from .engine import _filter_logits, _forward_token_batch
-
     def draft(params, ck, cv, toks, pos, tables, rng):
         S = tables.shape[1] * cfg.block_size
         cur = toks
@@ -412,14 +400,10 @@ def _build_verify(cfg, k, donate, shardings=None):
     """The target-model verify program (kind="verify", bucketed over
     the decode batch; ``k`` is static config).  Scores ``k+1`` rows per
     request — the last emitted token plus the k drafts — through the
-    paged block table in one dispatch: all rows' K/V is written FIRST,
-    then each row attends to every cache position <= its own (the
-    write-then-attend trick of the decode and chunk programs, which
-    makes in-window causality exact without a dense score matrix).  The
-    attention math mirrors ``ops.attention.paged_attention`` (same
-    gather, same scale-by-multiply, same f32 softmax) so a verify row's
-    logits track what the single-token decode program would compute for
-    the same context.
+    paged block table in one dispatch, the chunk program's
+    write-then-attend with a request axis (``programs._TableMix``), so
+    a verify row's logits track what the single-token decode program
+    would compute for the same context.
 
     On a sampling-mode engine (``cfg.sampling``) the program ALSO owns
     acceptance: rejection sampling (Leviathan et al. 2023; Chen et al.
@@ -436,17 +420,7 @@ def _build_verify(cfg, k, donate, shardings=None):
     the emitted tokens' logprob views, so the host's only sync is the
     result.
     """
-    from .engine import (_awfc, _cache_outs, _filter_logits, _kv_dequant,
-                         _kv_quant_vals, _ln, _logits, _logprob_outs,
-                         _mlp, _safe_log, _sample, _split_cache_args)
-
-    name = cfg.name
-    Hq, Hkv, Dh = cfg.num_heads, cfg.kv_heads, cfg.head_dim
-    group = Hq // Hkv
-    d_model = Hq * Dh
-    window = cfg.window
     K1 = k + 1
-    scale = score_scale(Dh)
 
     def verify(params, *rest):
         """``rows`` (B, K1) int32 token ids; ``pos0`` (B,) the cache
@@ -454,30 +428,16 @@ def _build_verify(cfg, k, donate, shardings=None):
         the target's (B, K1) greedy tokens (row j's token decided after
         consuming rows 0..j) — or, in sampling mode, the
         rejection-sampled emit rows + accepted counts + logprobs."""
-        adp = slots = None
-        if cfg.adapters:
-            adp, rest = rest[0], rest[1:]
-        ck, cv, ksc, vsc, tail = _split_cache_args(cfg, rest)
+        adp, caches, host, slots, sampling = _operands(
+            cfg, rest, 7 if cfg.sampling else 3)
         if cfg.sampling:
-            toks0, drafted, q_at, q_vals, q_idx, pos0, tables = tail[:7]
-            tail = tail[7:]
+            toks0, drafted, q_at, q_vals, q_idx, pos0, tables = host
             rows = jnp.concatenate([toks0[:, None], drafted], axis=1)
         else:
-            rows, pos0, tables = tail[:3]
-            tail = tail[3:]
-        if cfg.adapters:
-            slots, tail = tail[0], tail[1:]
-        if cfg.sampling:
-            temp, topp, topk, rng = tail
-        else:
-            rng, = tail
+            rows, pos0, tables = host
         B = rows.shape[0]
         pos = pos0[:, None] + jnp.arange(K1)[None, :]      # (B, K1)
-        x = params[f"{name}_tok_embed_weight"][rows]       # (B, K1, D)
-        if cfg.pos_table is not None:
-            # clamp padded rows: their position may exceed the table
-            pidx = jnp.minimum(pos, cfg.pos_table - 1)
-            x = x + params[f"{name}_pos_embed_weight"][0, pidx]
+        x = _embed(cfg, params, rows, pos, clamp=True)     # (B, K1, D)
         S = tables.shape[1] * cfg.block_size
         # candidate rows past the request's final position (a quota-
         # capped last iteration) write to the NULL block: a clamped
@@ -488,57 +448,10 @@ def _build_verify(cfg, k, donate, shardings=None):
         bidx = jnp.minimum(pos // cfg.block_size, tables.shape[1] - 1)
         blk = jnp.where(pos < S,
                         jnp.take_along_axis(tables, bidx, axis=1), 0)
-        off = pos % cfg.block_size
-        spos = jnp.arange(S)[None, None, :]
-        keep = spos <= pos[:, :, None]                     # (B, K1, S)
-        if window:
-            keep = jnp.logical_and(keep, spos > pos[:, :, None] - window)
-        for i in range(cfg.n_layers):
-            p = f"{name}_l{i}"
-            h = _ln(x, params[f"{p}_ln1_gamma"],
-                    None if cfg.rmsnorm else params[f"{p}_ln1_beta"])
-            q = _awfc(cfg, params, adp, f"{p}_q", h, slots)
-            kk = _awfc(cfg, params, adp, f"{p}_k", h, slots)
-            v = _awfc(cfg, params, adp, f"{p}_v", h, slots)
-            qh = q.reshape(B, K1, Hq, Dh)
-            kh = kk.reshape(B, K1, Hkv, Dh)
-            vh = v.reshape(B, K1, Hkv, Dh)
-            if cfg.pos_table is None:
-                qh, kh = _rope_rows(qh, pos), _rope_rows(kh, pos)
-            if cfg.kv_quant:
-                kq, ks = _kv_quant_vals(kh)
-                vq, vs = _kv_quant_vals(vh)
-                ck = ck.at[i, blk, off].set(kq)
-                ksc = ksc.at[i, blk, off].set(ks)
-                cv = cv.at[i, blk, off].set(vq)
-                vsc = vsc.at[i, blk, off].set(vs)
-            else:
-                ck = ck.at[i, blk, off].set(kh)
-                cv = cv.at[i, blk, off].set(vh)
-            # every row of a request shares its table: gather the
-            # request's logical cache view once per layer, mask per
-            # row by position (paged_attention's formulation with a
-            # row axis added); ck[i, tables] is one gather over the
-            # stack, ck[i][tables] would first copy the layer's pool
-            kb = ck[i, tables].reshape(B, S, Hkv, Dh)
-            vb = cv[i, tables].reshape(B, S, Hkv, Dh)
-            if cfg.kv_quant:
-                kb = _kv_dequant(kb, ksc[i, tables].reshape(B, S, Hkv),
-                                 x.dtype)
-                vb = _kv_dequant(vb, vsc[i, tables].reshape(B, S, Hkv),
-                                 x.dtype)
-            qg = qh.reshape(B, K1, Hkv, group, Dh)
-            sc = jnp.einsum("bckgd,bskd->bkgcs", qg, kb) * scale
-            sc = jnp.where(keep[:, None, None], sc,
-                           jnp.asarray(-jnp.inf, sc.dtype))
-            pr = jax.nn.softmax(sc.astype(jnp.float32),
-                                axis=-1).astype(x.dtype)
-            at = jnp.einsum("bkgcs,bskd->bckgd", pr, vb)
-            x = x + _awfc(cfg, params, adp, f"{p}_proj",
-                          at.reshape(B, K1, d_model), slots)
-            x = x + _mlp(cfg, params, p, x, adp=adp, slots=slots)
+        mix = _TableMix(cfg, caches, pos, tables, blk,
+                        pos % cfg.block_size)
+        x = _stack(cfg, params, x, mix, adp, slots)
         logits = _logits(cfg, params, x)                   # (B, K1, V)
-        caches = _cache_outs(cfg, ck, cv, ksc, vsc)
         if cfg.sampling:
             # -- rejection-sampling acceptance, on device --------------
             # everything runs in CANDIDATE space (sample_cap wide,
@@ -548,6 +461,7 @@ def _build_verify(cfg, k, donate, shardings=None):
             # full-vocab vector — q arrives as the draft's candidate
             # (probability, id) pairs and is re-evaluated at the
             # target's candidate ids by id matching
+            temp, topp, topk, rng = sampling
             kacc, kres, kbonus = jax.random.split(rng, 3)
             # p: the target's warped sampling distribution per row
             # (operands broadcast over the K1 axis); greedy rows are
@@ -608,12 +522,8 @@ def _build_verify(cfg, k, donate, shardings=None):
             outs = (emit, acc.astype(jnp.int32)) \
                 + _logprob_outs(logits, emit)
         else:
-            outs = (_sample(cfg, logits, rng),)
-        if cfg.numeric_watch:
-            outs = outs + (jnp.isfinite(logits).all(),)
-        return outs + caches
-
-    from .engine import _jit_kwargs
+            outs = (_sample(cfg, logits, *sampling),)
+        return _outputs(cfg, outs, logits, mix.caches)
 
     return jax.jit(verify, **_jit_kwargs(
         cfg, donate, shardings, 7 if cfg.sampling else 3,

@@ -1,4 +1,4 @@
-"""Per-request sampling as traced operands (mxnet_tpu/serve/engine.py)
+"""Per-request sampling as traced operands (mxnet_tpu/serve/programs.py)
 and rejection-sampled speculative decoding (mxnet_tpu/serve/spec.py).
 
 The contracts under test:
@@ -46,6 +46,7 @@ import jax.numpy as jnp
 import mxnet_tpu as mx
 from mxnet_tpu import telemetry
 from mxnet_tpu.serve import engine as engine_mod
+from mxnet_tpu.serve import programs as programs_mod
 
 VOCAB = 53
 
@@ -98,7 +99,7 @@ def _prompts(ns=(7, 12, 5, 9), seed=7):
 
 
 def _cfg(sampling=True, cap=64):
-    return engine_mod._ModelCfg(
+    return programs_mod._ModelCfg(
         name="gpt", n_layers=2, num_heads=4, head_dim=8, kv_heads=4,
         pos_table=96, swiglu=False, tied=False, rmsnorm=False, window=0,
         block_size=4, sampling=sampling, sample_cap=cap,
@@ -242,7 +243,7 @@ def test_lax_topk_matches_sort_reference():
     topp = jnp.ones((16,), jnp.float32)
     for kk in (1, 3, 10, VOCAB):
         topk = jnp.full((16,), kk, jnp.int32)
-        got = np.asarray(engine_mod._filtered_probs_full(
+        got = np.asarray(programs_mod._filtered_probs_full(
             cfg, logits, temp, topp, topk))
         # the historical formulation: full sort, kth-largest threshold
         lg = np.asarray(logits, np.float32) / 0.7
@@ -264,7 +265,7 @@ def test_sampler_distribution_pins():
     logits = jnp.asarray(np.tile(row, (M, 1)))
 
     def draws(temp, top_p, top_k, seed=0):
-        toks = engine_mod._sample_ops(
+        toks = programs_mod._sample_ops(
             cfg, logits, jax.random.PRNGKey(seed),
             jnp.full((M,), temp, jnp.float32),
             jnp.full((M,), top_p, jnp.float32),
@@ -294,7 +295,7 @@ def test_sampler_distribution_pins():
         assert tv < 0.05, (temp, top_p, top_k, tv)
         assert set(got) <= set(want), "sampled outside the filtered set"
     # greedy rows are exact argmax, deterministically
-    toks = engine_mod._sample_ops(
+    toks = programs_mod._sample_ops(
         cfg, logits[:8], jax.random.PRNGKey(3),
         jnp.zeros((8,), jnp.float32), jnp.ones((8,), jnp.float32),
         jnp.zeros((8,), jnp.int32))

@@ -529,7 +529,7 @@ def snap_int8(params, num_heads):
 
     from mxnet_tpu.models.generate import (detect_gpt_variant,
                                            normalize_gpt_params)
-    from mxnet_tpu.serve.engine import _quantize_gpt_params
+    from mxnet_tpu.serve.programs import _quantize_gpt_params
 
     spec = detect_gpt_variant(params, num_heads)
     snapped = normalize_gpt_params(          # dequants *_wscale (f32)
