@@ -132,6 +132,18 @@ def _post_generate(url, prompt, max_new, request_id):
     return payload["tokens"]
 
 
+def _written_blocks(k_layer):
+    """Ids of the blocks of one layer's K pool the traffic wrote."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    written = np.flatnonzero(np.asarray(
+        jnp.any(k_layer != 0, axis=(1, 2, 3))))
+    if written.size < 8:
+        raise AssertionError("traffic left fewer than 8 written KV blocks")
+    return written
+
+
 def _kernel_vs_oracle(eng, cfg, seed):
     """The compiled Pallas paged kernel against the ``impl="jnp"`` oracle
     on the cache the traffic just wrote.  Returns (max abs error,
@@ -153,10 +165,7 @@ def _kernel_vs_oracle(eng, cfg, seed):
 
     layer = cfg["num_layers"] - 1
     k_cache, v_cache = eng._cache_k[layer], eng._cache_v[layer]
-    written = np.flatnonzero(np.asarray(
-        jnp.any(k_cache != 0, axis=(1, 2, 3))))
-    if written.size < 8:
-        raise AssertionError("traffic left fewer than 8 written KV blocks")
+    written = _written_blocks(k_cache)
     rng = np.random.RandomState(seed)
     width = eng.table_width
     batch = cfg["max_batch"]
@@ -181,6 +190,67 @@ def _kernel_vs_oracle(eng, cfg, seed):
     if not np.isfinite(outs["pallas"]).all():
         raise AssertionError("paged kernel produced non-finite values")
     return err, 2.0 ** -6 * vmax
+
+
+def _span_vs_oracle(eng, cfg, seed):
+    """The span attention as the chunk program calls it (the Mosaic
+    kernel on the chip; per head shard under the engine's mesh at tp >
+    1) against plain float32 attention, on the cache the traffic just
+    wrote: the second pass of the prompt longer than ``prefill_chunk``,
+    a bucket of ``prefill_chunk`` rows from position ``prefill_chunk``
+    of which ``chunked - prefill_chunk`` are real, over the whole table.
+    Returns (max abs error over the real rows, tolerance, branch).
+
+    Tolerance: ``_kernel_vs_oracle``'s, 2^-6 of the largest |V| entry
+    (probabilities and output rounded to bf16 on the one side only)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from mxnet_tpu.ops.attention import masked_attention, score_scale
+    from mxnet_tpu.serve.programs import _head_shard_kw
+
+    layer = cfg["num_layers"] - 1
+    ck, cv = eng._cache_k, eng._cache_v
+    written = _written_blocks(ck[layer])
+    rng = np.random.RandomState(seed)
+    table = jnp.asarray(rng.choice(written, size=eng.table_width), jnp.int32)
+    rows, start = cfg["prefill_chunk"], cfg["prefill_chunk"]
+    real = cfg["chunked"] - start
+    Hq, Hkv = cfg["num_heads"], cfg["kv_heads"]
+    Dh = cfg["d_model"] // Hq
+    S = eng.table_width * cfg["block_size"]
+    scale = score_scale(Dh)
+    q = jnp.asarray(rng.standard_normal((rows, Hq, Dh)), ck.dtype)
+    kw = _head_shard_kw(eng._shardings)
+
+    def view(c, table):
+        return c[layer, table].reshape(S, Hkv, Dh)
+
+    @jax.jit
+    def served(q, ck, cv, table):
+        return masked_attention(q, view(ck, table), view(cv, table), start,
+                                scale, n_valid=real, **kw)
+
+    @jax.jit
+    def oracle(q, ck, cv, table):
+        f32 = jnp.float32
+        k, v = view(ck, table).astype(f32), view(cv, table).astype(f32)
+        qg = q.astype(f32).reshape(rows, Hkv, Hq // Hkv, Dh)
+        with jax.default_matmul_precision("highest"):
+            sc = jnp.einsum("qkgd,skd->kgqs", qg, k) * scale
+            keep = (jnp.arange(S)[None, :]
+                    <= start + jnp.arange(rows)[:, None])
+            pr = jax.nn.softmax(jnp.where(keep, sc, -jnp.inf), axis=-1)
+            return jnp.einsum("kgqs,skd->qkgd", pr, v)
+
+    got = np.asarray(served(q, ck, cv, table)).astype(np.float32)
+    want = np.asarray(oracle(q, ck, cv, table))
+    if not np.isfinite(got).all():
+        raise AssertionError("span attention produced non-finite values")
+    vmax = float(jnp.max(jnp.abs(cv[layer].astype(jnp.float32))))
+    return (float(np.max(np.abs(got[:real] - want[:real]))),
+            2.0 ** -6 * vmax, eng._span_impl(rows, S))
 
 
 def serve_phase(cfg, tp=1, reference_tokens=None, seed=0):
@@ -258,6 +328,12 @@ def serve_phase(cfg, tp=1, reference_tokens=None, seed=0):
                 f"decode{b} holds {calls} tpu_custom_call(s) for "
                 f"{cfg['num_layers']} layers: the paged kernel did not "
                 "run compiled")
+        if (on_chip and kind != "decode" and b >= cfg["prefill_chunk"]
+                and calls < cfg["num_layers"]):
+            raise AssertionError(
+                f"{kind}{b} holds {calls} tpu_custom_call(s) for "
+                f"{cfg['num_layers']} layers: the span kernel did not "
+                "run compiled")
 
     server = mx.fleet.ReplicaServer(eng, replica_id="chip-smoke").start()
     try:
@@ -328,6 +404,19 @@ def serve_phase(cfg, tp=1, reference_tokens=None, seed=0):
             report.update(kernel_max_abs_err=err, kernel_tol=tol)
         else:
             report["sharding"] = _check_sharded(eng)
+        # the prompt longer than prefill_chunk went through the chunk
+        # program's span attention: the same call against float32
+        err, tol, branch = _span_vs_oracle(eng, cfg, seed)
+        if on_chip and branch != "kernel":
+            raise AssertionError(
+                f"span attention resolved to {branch!r} on a TPU: the "
+                "smoke's chunk must run the Pallas kernel")
+        if not err <= tol:
+            raise AssertionError(
+                f"span attention ({branch}) vs float32 oracle: max abs "
+                f"error {err} > tolerance {tol}")
+        report.update(span_attention=branch, span_max_abs_err=err,
+                      span_tol=tol)
         if reference_tokens is not None:
             report.update(_token_agreement(tokens, reference_tokens))
     finally:

@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import contextlib
 import contextvars
+import functools
 import logging
 import os
 
@@ -325,25 +326,146 @@ class FlashAttentionOp(OpDef):
         return [jnp.einsum("bhqk,bhkd->bhqd", p, v)], []
 
 
-def masked_attention(q, k, v, keep, scale):
-    """Dense grouped-query attention of a span of query rows over key
-    rows under an explicit mask: the span attention of the serving
-    programs (a whole prompt; a chunk or verify rows over the table's
-    gathered view), whatever leading axes the operands share.
+# -- span attention (serving) ------------------------------------------------
+# The Mosaic span kernel's tiles (ops/pallas_span_attention.py): query rows
+# and key positions a step of its walk folds, and the key positions of one
+# kv head it keeps resident in VMEM.  Constants from the chip, not knobs.
+# One v5e chip, a layer's call at 32 / 8 heads x 128 in bfloat16, ms
+# (PERF.md, PR 32; "dense" is masked_attention's other branch, alone):
+#   rows x keys, first position     dense  (256,1024) (512,512) (512,1024)
+#   2048 x 4096, 0    (2033 real)    7.93    0.526     0.512     0.527
+#   2048 x 4096, 2033 (1800 real)    7.92    0.987     1.030     0.997
+#   2048 x 2048, 0    (2000 real)    1.46    0.524     0.512     0.520
+#   1024 x 1024, 0    ( 900 real)    0.393   0.194     0.181     0.192
+# (512, 2048) and (256, 2048) read 0.61 / 1.08 / 0.61: a wider tile wastes
+# more above the diagonal than its fewer steps save.
+SPAN_BLOCK_Q = 512
+SPAN_BLOCK_K = 512
+SPAN_RESIDENT_TOKENS = 4096
+# A span whose score rectangle (rows x keys, a head) is smaller stays
+# dense: verify's k+1 rows, the low chunk and prefill buckets.  Dense
+# against the kernel, ms: 128 x 4096 0.226 / 0.081, 64 x 4096 0.047 /
+# 0.069; whole prompts 1024 x 1024 0.393 / 0.181, 512 x 512 0.034 / 0.071,
+# 256 x 256 0.009 / 0.037 (the dense form's cost follows the rectangle
+# it writes out, the kernel's has a floor of a kv head's K/V fetch)
+SPAN_KERNEL_MIN_SCORES = 128 * 4096
 
-    ``q (..., T, Hq, Dh)``; ``k``/``v (..., S, Hkv, Dh)``, kv head g
-    serving q heads ``[g*group, (g+1)*group)`` as in
-    :func:`paged_attention`; ``keep (..., T, S)`` bool, every row
-    keeping at least one key; ``scale`` multiplies the scores (an f32
-    scalar).  Softmax in f32.  Returns ``(..., T, Hkv, group, Dh)`` in
-    q's dtype: the q heads in their order, one reshape from ``(..., T,
-    Hq * Dh)``."""
+
+def span_kernel_eligible(T, S, head_dim, block_q=None, block_k=None,
+                         resident=None, min_scores=None):
+    """Whether the span kernel serves ``T`` rows over ``S`` keys: heads
+    that are whole 128-lane tiles (a head is a column block of the
+    operands as they lie in memory) or half of one (64: zero-padded in
+    front of the call), a score rectangle of at least ``min_scores``, and
+    shapes its blocks tile."""
+    bq = min(SPAN_BLOCK_Q, T) if block_q is None else block_q
+    bk = min(SPAN_BLOCK_K, S) if block_k is None else block_k
+    res = min(SPAN_RESIDENT_TOKENS, S) if resident is None else resident
+    if min_scores is None:
+        min_scores = SPAN_KERNEL_MIN_SCORES
+    return ((head_dim % 128 == 0 or head_dim == 64) and T * S >= min_scores
+            and bq % 16 == 0 and T % bq == 0
+            and bk % 128 == 0 and res % bk == 0 and S % res == 0)
+
+
+def resolve_span_impl(T, S, head_dim):
+    """The branch :func:`masked_attention` traces for a span of ``T`` rows
+    over ``S`` keys of ONE request: ``"kernel"`` or ``"dense"``.  Backend
+    and shapes only (``resolve_paged_impl``'s rule: off the chip the
+    dense form runs, the interpreter is for the kernel's own tests)."""
+    if pallas_util.on_tpu() and span_kernel_eligible(T, S, head_dim):
+        return "kernel"
+    return "dense"
+
+
+def span_kv_tiles(T, S, start, n_valid, window=0, impl="kernel"):
+    """``(visited, table)``: the ``(SPAN_BLOCK_Q, SPAN_BLOCK_K)`` tiles
+    one head of a span's attention computes, over the tiles of its ``(T,
+    S)`` rectangle.  Host arithmetic that mirrors the kernel's walk (a
+    test holds it to the mask); the dense branch computes them all."""
+    bq, bk = min(SPAN_BLOCK_Q, T), min(SPAN_BLOCK_K, S)
+    table = -(-T // bq) * -(-S // bk)
+    if impl != "kernel":
+        return table, table
+    visited, end = 0, start + n_valid
+    for p0 in range(start, min(start + T, end), bq):
+        hi = min(p0 + bq, end, S)
+        lo = max(p0 - window + 1, 0) if window else 0
+        visited += max(-(-hi // bk) - lo // bk, 0)
+    return visited, table
+
+
+def _span_keep(start, T, S, window):
+    """``(..., T, S)``: row ``t`` at position ``start + t`` keeps the keys
+    at positions ``<= `` its own (and inside its window)."""
+    pos = jnp.asarray(start)[..., None] + jnp.arange(T)
+    spos = jnp.arange(S)[(None,) * pos.ndim]
+    keep = spos <= pos[..., None]                   # causal, self included
+    if window:
+        keep = jnp.logical_and(keep, spos > pos[..., None] - window)
+    return keep
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "window", "interpret"))
+def _span_kernel_call(q, k, v, start, n_valid, *, scale, window, interpret):
+    # jitted so that the layers of a program share ONE trace of the
+    # kernel (the paged kernel's cost 3-6 s of tracing over five decode
+    # programs at PR 30; the prefill and chunk ladders are 25)
+    from .pallas_span_attention import span_attention_kernel
+
+    return span_attention_kernel(q, k, v, start, n_valid, scale,
+                                 window=window, interpret=interpret)
+
+
+def masked_attention(q, k, v, start, scale, window=0, n_valid=None,
+                     mesh=None, head_axis=None):
+    """Causal grouped-query attention of a span of query rows over key
+    rows: the span attention of the serving programs (a whole prompt; a
+    chunk or verify rows over the table's gathered view), whatever
+    leading axes the operands share.
+
+    ``q (..., T, Hq, Dh)``, row ``t`` at position ``start + t``
+    (``start (...)`` int, traced or not); ``k``/``v (..., S, Hkv, Dh)``,
+    row ``s`` at position ``s``, kv head g serving q heads ``[g*group,
+    (g+1)*group)`` as in :func:`paged_attention`.  A row attends to the
+    keys at positions ``<=`` its own and, with ``window``, ``>`` its own
+    minus ``window``.  ``scale`` multiplies the scores (an f32 scalar).
+    ``n_valid``: the count of real rows, where the caller knows it; the
+    rows past it are padding whose outputs nobody reads (the kernel
+    skips their tiles).  Softmax in f32.  Returns ``(..., T, Hkv, group,
+    Dh)`` in q's dtype: the q heads in their order, one reshape from
+    ``(..., T, Hq * Dh)``.
+
+    Two branches, chosen by :func:`resolve_span_impl` from the backend
+    and the shapes: the Mosaic streaming-softmax walk over the key tiles
+    the span can see (``ops/pallas_span_attention.py``), or the dense
+    form below, which builds the mask and materialises the scores.
+    ``mesh``/``head_axis``: as for :func:`paged_attention`; GSPMD cannot
+    partition a Mosaic call, so under a mesh the kernel runs per head
+    shard inside a ``shard_map``."""
     from .flash_attention import gqa_group
 
-    Hkv = k.shape[-2]
-    qg = q.reshape(q.shape[:-2] + (Hkv, gqa_group(q.shape[-2], Hkv),
-                                   q.shape[-1]))
+    T, Hq, Dh = q.shape[-3:]
+    S, Hkv = k.shape[-3:-1]
+    group = gqa_group(Hq, Hkv)
+    if q.ndim == 3 and resolve_span_impl(T, S, Dh) == "kernel":
+        kernel = functools.partial(
+            _span_kernel_call, scale=np.float32(scale), window=int(window),
+            interpret=not pallas_util.on_tpu())
+        args = (q, k, v, jnp.asarray(start, jnp.int32),
+                jnp.asarray(T if n_valid is None else n_valid, jnp.int32))
+        if mesh is None:
+            return kernel(*args)
+        from jax.sharding import PartitionSpec as P
+
+        heads = P(None, head_axis, None)
+        return jax.shard_map(
+            kernel, mesh=mesh, in_specs=(heads,) * 3 + (P(), P()),
+            out_specs=P(None, head_axis, None, None),
+            check_vma=False)(*args)
+    qg = q.reshape(q.shape[:-2] + (Hkv, group, Dh))
     sc = jnp.einsum("...qkgd,...skd->...kgqs", qg, k) * scale
+    keep = _span_keep(start, T, S, window)
     sc = jnp.where(keep[..., None, None, :, :], sc,
                    jnp.asarray(-jnp.inf, sc.dtype))
     pr = jax.nn.softmax(sc.astype(jnp.float32), axis=-1).astype(q.dtype)

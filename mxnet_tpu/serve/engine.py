@@ -67,7 +67,9 @@ from ..models.generate import (detect_gpt_variant, normalize_gpt_params,
 from ..parallel import partition as partition_mod
 from ..parallel.mesh import NamedSharding, PartitionSpec, make_mesh
 from ..models.hybrid import HybridDecoder
-from ..ops.attention import PAGED_TILE_TOKENS, paged_tile_slots
+from ..ops.attention import (PAGED_TILE_TOKENS, SPAN_BLOCK_K, SPAN_BLOCK_Q,
+                             SPAN_KERNEL_MIN_SCORES, paged_tile_slots,
+                             span_kv_tiles)
 from ..telemetry import flight as flight_mod
 from ..telemetry import profiling
 from ..telemetry import statusz as statusz_mod
@@ -776,6 +778,10 @@ class Engine:
                 # must never be served to an engine whose env pinned
                 # the jnp formulation, and vice versa
                 self._paged_impl(),
+                # likewise the span attention of the prefill and chunk
+                # programs (backend + head size; the buckets that carry
+                # the kernel follow from the constants it names)
+                self._span_impl(),
                 # the parameters' shapes (vocabulary, MLP width): two
                 # engines that differ only there must not share compiled
                 # programs.  Not in the AOT fingerprint, which keeps the
@@ -833,6 +839,11 @@ class Engine:
         # positions a step, gpt and hybrid engines alike
         paged = ({} if self._paged_impl() != "pallas"
                  else dict(paged_attention=f"pallas-tile{PAGED_TILE_TOKENS}"))
+        # the span kernel of the prefill and chunk programs, only-when-on
+        # and versioned by its tiles for the same reason
+        span = ({} if self._span_impl() != "kernel" else dict(
+            span_attention=f"pallas-q{SPAN_BLOCK_Q}k{SPAN_BLOCK_K}"
+                           f"-from{SPAN_KERNEL_MIN_SCORES}"))
         # a hybrid engine's state pool and vocabulary (only-when-on)
         state = ({} if self._state_ssm is None else dict(
             state_slots=int(self._state_ssm.shape[1]),
@@ -842,7 +853,7 @@ class Engine:
             subsystem="serve", cfg=cfg_d,
             num_blocks=self.num_blocks, table_width=self.table_width,
             cache_dtype=str(self._cache_k.dtype), donate=self._donate,
-            **sharded, **spec, **quant, **paged, **state)
+            **sharded, **spec, **quant, **paged, **span, **state)
 
     def _paged_impl(self):
         """The paged-attention implementation this engine's programs
@@ -854,6 +865,23 @@ class Engine:
                                    self.spec["head_dim"])
         return resolve_paged_impl(self.block_size,
                                   self.spec["head_dim"])
+
+    def _span_impl(self, rows=None, keys=None):
+        """The branch ``ops.attention.masked_attention`` traces for a
+        prefill or chunk pass of ``rows`` rows over ``keys`` key
+        positions ("kernel" or "dense"); without them, whether ANY of
+        this engine's programs carries the span kernel: its largest
+        prefill and chunk buckets say (the cap, and the power of two
+        under a cap the kernel's blocks do not tile)."""
+        from ..ops.attention import resolve_span_impl
+        if rows is not None:
+            return resolve_span_impl(rows, keys, self.spec["head_dim"])
+        view = self.table_width * self.block_size
+        top = lambda cap: {cap, 1 << (cap.bit_length() - 1)}
+        passes = ([(p, p) for p in top(self.max_model_len)]
+                  + [(c, view) for c in top(self._chunk_cap())])
+        return ("kernel" if any(self._span_impl(r, k) == "kernel"
+                                for r, k in passes) else "dense")
 
     # -- public API ----------------------------------------------------------
     def submit(self, prompt, max_new_tokens=64, deadline_s=None,
@@ -1334,6 +1362,13 @@ class Engine:
             "paged_tile_tokens": (PAGED_TILE_TOKENS
                                   if self._paged_impl() == "pallas"
                                   else None),
+            # the span attention of the prefill and chunk programs
+            # ("kernel" | "dense") and the score rectangle (rows x keys)
+            # a pass needs for the kernel
+            "span_attention": self._span_impl(),
+            "span_kernel_min_scores": (SPAN_KERNEL_MIN_SCORES
+                                       if self._span_impl() == "kernel"
+                                       else None),
             "aot": aot,
         }
 
@@ -1796,7 +1831,7 @@ class Engine:
             toks = np.zeros(bucket, np.int32)
             toks[:n] = ids
             blk, off = self._slots(self.blocks.table(req.rid), n, bucket)
-            pkind = "prefill"
+            pkind, keys = "prefill", bucket
             fn = self._prefill_fn(bucket)
             args = (self.params,) + self._adapter_args() \
                 + self._cache_args() + (
@@ -1821,7 +1856,7 @@ class Engine:
             blk[:span] = tw[pos // self.block_size]
             off = ((start + np.arange(bucket))
                    % self.block_size).astype(np.int32)
-            pkind = "chunk"
+            pkind, keys = "chunk", self.table_width * self.block_size
             fn = self._chunk_fn(bucket)
             args = (self.params,) + self._adapter_args() \
                 + self._cache_args() + (
@@ -1843,8 +1878,14 @@ class Engine:
                 self._tel_state_resets.labels(
                     reason="preempt" if resume else "admit").inc()
         if sprof.tracing:
+            # which span attention the pass runs, and the key tiles one
+            # head of it computes over those of its (rows, keys) rectangle
+            attn = self._span_impl(bucket, keys)
+            tiles, of = span_kv_tiles(bucket, keys, start, span,
+                                      self.window, attn)
             sprof.note(kind=pkind, tokens=span, bucket=bucket,
-                       cached=req.cached_prefix_len,
+                       cached=req.cached_prefix_len, attn=attn,
+                       kv_tiles=tiles, kv_tiles_table=of,
                        **({} if state is None else {"state": state}))
         t0 = self._perf.t0()
         outs = fn(*args)

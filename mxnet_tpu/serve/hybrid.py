@@ -241,19 +241,12 @@ class _SpanMix:
     carries the slot's state."""
 
     def __init__(self, hc, params, ck, cv, ssm, conv, T, start, n_valid,
-                 slot, blk, off, table, block_size):
+                 slot, blk, off, table):
         self.hc, self.params = hc, params
         self.ck, self.cv, self.ssm, self.conv = ck, cv, ssm, conv
         self.T, self.start, self.slot = T, start, slot
         self.n_valid, self.blk, self.off, self.table = n_valid, blk, off, table
-        rows = jnp.arange(T, dtype=jnp.int32)
-        self.valid = rows < n_valid
-        if table is None:
-            self.keep = rows[:, None] >= rows[None, :]
-        else:
-            S = table.shape[0] * block_size
-            self.keep = (jnp.arange(S, dtype=jnp.int32)[None, :]
-                         <= (start + rows)[:, None])
+        self.valid = jnp.arange(T, dtype=jnp.int32) < n_valid
 
     def attention(self, i, h):
         dec, a = self.hc.dec, self.hc.kv_index[i]
@@ -267,8 +260,9 @@ class _SpanMix:
             # the layer's whole pool
             k = self.ck[a, self.table].reshape(-1, Hkv, Dh)
             v = self.cv[a, self.table].reshape(-1, Hkv, Dh)
-        at = masked_attention(q, k, v, self.keep,
-                              np.float32(dec.attention_multiplier))
+        at = masked_attention(q, k, v, self.start,
+                              np.float32(dec.attention_multiplier),
+                              n_valid=self.n_valid)
         return _fc(at.reshape(self.T, -1), self.params[f"{p}_proj_weight"])
 
     def mamba(self, i, h):
@@ -368,7 +362,7 @@ def build_prefill(cfg, P, donate):
         """Whole prompt at padded length P for ONE request, from
         position 0: the slot's state is written, never read."""
         mix = _SpanMix(hc, params, ck, cv, ssm, conv, P, 0, plen, slot,
-                       blk, off, None, cfg.block_size)
+                       blk, off, None)
         x, caches = _stack(hc, params, _embed(hc.dec, params, toks), mix)
         return _finish(cfg, _logits(hc.dec, params, x[plen - 1][None]),
                        caches, tail, scalar=True)
@@ -385,7 +379,7 @@ def build_chunk(cfg, C, donate):
         attends through the table, starts from the slot's state (from
         zero at start 0) and writes it back."""
         mix = _SpanMix(hc, params, ck, cv, ssm, conv, C, start, n_valid,
-                       slot, blk, off, table, cfg.block_size)
+                       slot, blk, off, table)
         x, caches = _stack(hc, params, _embed(hc.dec, params, toks), mix)
         return _finish(cfg, _logits(hc.dec, params, x[n_valid - 1][None]),
                        caches, tail, scalar=True)

@@ -366,6 +366,17 @@ def _embed(cfg, params, toks, pos, clamp=False):
 
 
 # -- mixer contexts: what a pass hands the layer ------------------------------
+def _head_shard_kw(shardings):
+    """What ``paged_attention`` and ``masked_attention`` need of a tp
+    placement bundle: the mesh and the axis the cache's heads are split
+    over, so that a Mosaic kernel runs per head shard."""
+    if shardings is None:
+        return {}
+    cache_spec = shardings.cache.spec               # (L, nb, bs, Hkv, Dh)
+    return {"mesh": shardings.mesh,
+            "head_axis": cache_spec[3] if len(cache_spec) > 3 else None}
+
+
 class _Mix:
     """A pass's caches (``ck, cv``, then the scales ``ksc, vsc`` of int8
     caches), its rows' positions ``pos`` and the slot ``(blk, off)`` each
@@ -406,13 +417,7 @@ class _DecodeMix(_Mix):
                                   axis=1)[:, 0]
         super().__init__(cfg, caches, pos, blk, pos % cfg.block_size)
         self.tables, self.ctx = tables, pos + 1
-        self.paged_kw = {}
-        if shardings is not None:
-            cache_spec = shardings.cache.spec       # (L, nb, bs, Hkv, Dh)
-            self.paged_kw = {"mesh": shardings.mesh,
-                             "head_axis": (cache_spec[3]
-                                           if len(cache_spec) > 3
-                                           else None)}
+        self.paged_kw = _head_shard_kw(shardings)
 
     def attend(self, i, qh, kh, vh):
         self._kv_write(i, kh, vh)
@@ -424,14 +429,13 @@ class _DecodeMix(_Mix):
 
 class _PromptMix(_Mix):
     """A whole prompt of ONE request from position 0 (``pos`` is
-    ``arange(P)``): dense causal attention within the span."""
+    ``arange(P)``, the first ``n_valid`` rows real): causal attention
+    within the span."""
 
-    def __init__(self, cfg, caches, pos, blk, off):
+    def __init__(self, cfg, caches, pos, blk, off, n_valid=None,
+                 shardings=None):
         super().__init__(cfg, caches, pos, blk, off)
-        qp, kp = pos[:, None], pos[None, :]
-        self.keep = qp >= kp                               # causal
-        if cfg.window:
-            self.keep = jnp.logical_and(self.keep, qp - kp < cfg.window)
+        self.n_valid, self.span_kw = n_valid, _head_shard_kw(shardings)
 
     def attend(self, i, qh, kh, vh):
         # attend to the rows as the cache holds them: every path must
@@ -439,34 +443,34 @@ class _PromptMix(_Mix):
         # step reading the cache would diverge from the hidden states
         # this very pass computed
         kh, vh = self._kv_write(i, kh, vh, read_back=True)
-        return masked_attention(qh, kh, vh, self.keep,
-                                score_scale(self.cfg.head_dim))
+        return masked_attention(qh, kh, vh, 0,
+                                score_scale(self.cfg.head_dim),
+                                window=self.cfg.window,
+                                n_valid=self.n_valid, **self.span_kw)
 
 
 class _TableMix(_Mix):
     """Rows whose earlier positions' K/V already sits in the cache: a
-    chunk of ONE request (``pos (C,)``, ``table (W,)``) or verify's
-    ``k+1`` rows of each of B requests (``pos (B, k+1)``, ``table
-    (B, W)``).  The rows' K/V is written through the table FIRST and
-    each row then attends to every cache position <= its own: decode's
+    chunk of ONE request (``pos (C,)`` from ``start``, the first
+    ``n_valid`` rows real, ``table (W,)``) or verify's ``k+1`` rows of
+    each of B requests (``pos (B, k+1)``, ``start (B,)``, ``table (B,
+    W)``).  The rows' K/V is written through the table FIRST and each
+    row then attends to every cache position <= its own: decode's
     write-then-attend, exact in-span causality without a (C, C) mask."""
 
-    def __init__(self, cfg, caches, pos, table, blk, off):
+    def __init__(self, cfg, caches, pos, table, blk, off, start,
+                 n_valid=None, shardings=None):
         super().__init__(cfg, caches, pos, blk, off)
-        self.table = table
+        self.table, self.start, self.n_valid = table, start, n_valid
         self.S = table.shape[-1] * cfg.block_size
-        spos = jnp.arange(self.S)[(None,) * pos.ndim]   # logical positions
-        self.keep = spos <= pos[..., None]          # causal, self included
-        if cfg.window:
-            self.keep = jnp.logical_and(
-                self.keep, spos > pos[..., None] - cfg.window)
+        self.span_kw = _head_shard_kw(shardings)
 
     def attend(self, i, qh, kh, vh):
         self._kv_write(i, kh, vh)
         ck, cv, *scales = self.caches
         # a request's rows share its table: ONE gather of its logical
-        # view per layer, masked per row (ck[i, table] gathers from the
-        # stack; ck[i][table] would first copy the layer's whole pool)
+        # view per layer (ck[i, table] gathers from the stack;
+        # ck[i][table] would first copy the layer's whole pool)
         view = self.table.shape[:-1] + (self.S, self.cfg.kv_heads)
         kb = ck[i, self.table].reshape(view + (self.cfg.head_dim,))
         vb = cv[i, self.table].reshape(view + (self.cfg.head_dim,))
@@ -474,8 +478,10 @@ class _TableMix(_Mix):
             ksc, vsc = scales
             kb = _kv_dequant(kb, ksc[i, self.table].reshape(view), qh.dtype)
             vb = _kv_dequant(vb, vsc[i, self.table].reshape(view), qh.dtype)
-        return masked_attention(qh, kb, vb, self.keep,
-                                score_scale(self.cfg.head_dim))
+        return masked_attention(qh, kb, vb, self.start,
+                                score_scale(self.cfg.head_dim),
+                                window=self.cfg.window,
+                                n_valid=self.n_valid, **self.span_kw)
 
 
 def layer(cfg, params, i, x, mix, adp=None, slots=None):
@@ -629,7 +635,7 @@ def _build_prefill(cfg, P, donate, shardings=None):
             cfg, rest, 4)
         pos = jnp.arange(P)
         x = _embed(cfg, params, toks, slice(None, P))      # (P, D)
-        mix = _PromptMix(cfg, caches, pos, blk, off)
+        mix = _PromptMix(cfg, caches, pos, blk, off, plen, shardings)
         x = _stack(cfg, params, x, mix, adp, slots)
         return _finish(cfg, _logits(cfg, params, x[plen - 1][None]),
                        mix.caches, sampling, scalar=True)
@@ -676,7 +682,8 @@ def _build_chunk(cfg, C, donate, shardings=None):
          sampling) = _operands(cfg, rest, 6)
         pos = start + jnp.arange(C)
         x = _embed(cfg, params, toks, pos, clamp=True)     # (C, D)
-        mix = _TableMix(cfg, caches, pos, table, blk, off)
+        mix = _TableMix(cfg, caches, pos, table, blk, off, start, n_valid,
+                        shardings)
         x = _stack(cfg, params, x, mix, adp, slots)
         return _finish(cfg, _logits(cfg, params, x[n_valid - 1][None]),
                        mix.caches, sampling, scalar=True)

@@ -449,7 +449,7 @@ def _build_verify(cfg, k, donate, shardings=None):
         blk = jnp.where(pos < S,
                         jnp.take_along_axis(tables, bidx, axis=1), 0)
         mix = _TableMix(cfg, caches, pos, tables, blk,
-                        pos % cfg.block_size)
+                        pos % cfg.block_size, pos0)
         x = _stack(cfg, params, x, mix, adp, slots)
         logits = _logits(cfg, params, x)                   # (B, K1, V)
         if cfg.sampling:

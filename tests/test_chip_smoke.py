@@ -99,6 +99,9 @@ def test_serve_phase_tiny(tel, monkeypatch):
     assert rep["compiles_after_warmup"] == 0
     assert rep["prefix_hits"] >= 3
     assert rep["kernel_max_abs_err"] <= rep["kernel_tol"]
+    # heads of 16 off the chip: the dense branch, against the same oracle
+    assert rep["span_attention"] == "dense"
+    assert rep["span_max_abs_err"] <= rep["span_tol"]
     assert set(rep["programs"]) >= {"serve.decode8", "serve.prefill64",
                                     "serve.chunk64", "serve.chunk8"}
 

@@ -423,6 +423,112 @@ def test_decode_program_holds_no_copy_of_a_cache_pool(kind, one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 2
 
 
+SPAN_KERNEL_CASES = {
+    # name: (rows, keys, Hq, Hkv, window)
+    "doc-batch-chunk": (2048, 4096, 32, 8, 0),
+    "doc-batch-prompt": (2048, 2048, 32, 8, 0),
+    "smallest-bucket": (512, 4096, 32, 8, 0),
+    "windowed": (2048, 4096, 32, 8, 1000),
+    "tp4-shard": (2048, 4096, 8, 2, 0),
+    # a view longer than the resident keys: two grid steps a query tile
+    "long-view": (2048, 8192, 32, 8, 0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(SPAN_KERNEL_CASES))
+def test_span_kernel_compiles_for_the_chip(case, one_chip):
+    """The span kernel through the chip's compiler at the cell's real
+    shapes and at what else the engine asks of it; through the ONE entry
+    point, so the branch the shapes choose is the kernel."""
+    from mxnet_tpu.ops.attention import masked_attention, score_scale
+
+    T, S_, Hq, Hkv, window = SPAN_KERNEL_CASES[case]
+    S = jax.ShapeDtypeStruct
+
+    def fwd(q, k, v, start, n_valid):
+        return masked_attention(q, k, v, start, score_scale(128),
+                                window=window, n_valid=n_valid)
+
+    with hlo_audit.assume_tpu():
+        compiled = _chip_compile(
+            jax.jit(fwd), [S((T, Hq, 128), jnp.bfloat16),
+                           S((S_, Hkv, 128), jnp.bfloat16),
+                           S((S_, Hkv, 128), jnp.bfloat16),
+                           S((), jnp.int32), S((), jnp.int32)], one_chip)
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < T * S_
+
+
+@pytest.mark.parametrize("kind,bucket,keys", [("chunk", 2048, 4096),
+                                              ("prefill", 2048, 2048)])
+def test_span_programs_hold_no_score_tensor(kind, bucket, keys, one_chip):
+    """The chunk program of 2048 rows over the 256-slot table and the
+    2048-row prefill program at doc-batch's width, as the chip's compiler
+    leaves them: one Mosaic call a layer (the span kernel), no f32 result
+    as large as ONE kv head's scores, ``rows x keys`` for its 4 query
+    heads (the dense form wrote all 8 together, 1.07 GB a layer in the
+    chunk program, three times over), and temporaries under that one
+    tensor."""
+    with hlo_audit.assume_tpu():
+        eng, _ = _cell_width_engine("gpt")
+        try:
+            assert eng.statusz()["span_attention"] == "kernel"
+            assert eng._span_impl(bucket, keys) == "kernel"
+            eng._donate = True
+            compiled = _chip_compile(eng._program_builder(kind, bucket),
+                                     eng._program_specs(kind, bucket),
+                                     one_chip)
+            n_layers = eng.spec["n_layers"]
+            heads, kv_heads = eng._cfg.num_heads, eng._cfg.kv_heads
+        finally:
+            eng.shutdown()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == n_layers
+    # (rows x hidden activations in f32 are a fourth of that at most)
+    scores = [r for r in hlo_audit.entry_results(text)
+              if r[1] == "f32" and r[2] >= bucket * keys * heads // kv_heads]
+    assert not scores, scores
+    assert (compiled.memory_analysis().temp_size_in_bytes
+            < heads * bucket * 4096 * 4)
+
+
+def test_small_spans_stay_dense_and_heads_of_64_are_padded(one_chip):
+    """The branch follows the shapes: the 64-row chunk program and the
+    512-row prefill program of the same engine carry no Mosaic call; the
+    hybrid cell's 512-row chunk program (heads of 64 over the 4096-key
+    view) carries one, its attention layer's."""
+    with hlo_audit.assume_tpu():
+        eng, _ = _cell_width_engine("gpt")
+        try:
+            assert eng._span_impl(64, 4096) == "dense"
+            assert eng._span_impl(512, 512) == "dense"
+            texts = [hlo_audit.serve_lower_text(eng, kind, bucket,
+                                                platform="tpu")
+                     for kind, bucket in (("chunk", 64), ("prefill", 512))]
+        finally:
+            eng.shutdown()
+        hyb, _ = _cell_width_engine("hybrid")
+        try:
+            assert hyb.statusz()["span_attention"] == "kernel"
+            assert hyb._span_impl(512, 4096) == "kernel"
+            assert hyb._span_impl(512, 512) == "dense"
+            hyb._donate = True
+            compiled = _chip_compile(hyb._program_builder("chunk", 512),
+                                     hyb._program_specs("chunk", 512),
+                                     one_chip)
+        finally:
+            hyb.shutdown()
+    assert not any("tpu_custom_call" in t for t in texts)
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "span_attention" in text
+    # (the state pool is f32 and larger: whole rectangles only)
+    assert not [r for r in hlo_audit.entry_results(text)
+                if r[1] == "f32" and r[2] >= 512 * 4096 * 4
+                and r[2] % (512 * 4096) == 0]
+
+
 def test_aot_fingerprint_names_the_tiled_kernel(tmp_path):
     """The AOT store has no version but the fingerprint: the paged
     kernel's value moved with the walk, so an artifact exported under

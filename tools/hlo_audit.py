@@ -164,17 +164,26 @@ def pool_sized_results(compiled_text, pool_shape):
     fusion that is not a write, is a second pool in HBM and a pass over
     the first (PERF.md, PR 27)."""
     want = math.prod(int(d) for d in pool_shape)
+    return [(name, op) for name, _, n, op in entry_results(compiled_text)
+            if n == want]
+
+
+def entry_results(compiled_text):
+    """``(name, dtype, elements, op)`` of every instruction of COMPILED
+    HLO text's entry computation, views and plumbing aside (parameters,
+    bitcasts, tuples): what the program writes to HBM."""
     found = []
     # the entry computation only: a fusion's body repeats its result
     entry = compiled_text[compiled_text.rindex("\nENTRY "):]
     for line in entry.splitlines():
-        m = re.match(r"\s*(?:ROOT )?%(\S+) = \w+\[([\d,]+)\]\S* ([\w\-]+)\(",
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = (\w+)\[([\d,]+)\]\S* ([\w\-]+)\(",
                      line)
-        if not m or m.group(3) in ("parameter", "bitcast",
+        if not m or m.group(4) in ("parameter", "bitcast",
                                    "get-tuple-element", "tuple"):
             continue
-        if math.prod(int(d) for d in m.group(2).split(",")) == want:
-            found.append((m.group(1), m.group(3)))
+        found.append((m.group(1), m.group(2),
+                      math.prod(int(d) for d in m.group(3).split(",")),
+                      m.group(4)))
     return found
 
 
