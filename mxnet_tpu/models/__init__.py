@@ -11,8 +11,10 @@ from .vgg import vgg, alexnet
 from .transformer import gpt
 from .generate import gpt_decode_config, gpt_generate
 from .hybrid import hybrid_decoder
+from .moe import moe_decoder
 
 __all__ = ["lenet", "mlp", "resnet", "lstm_unroll", "lstm_cell",
            "LSTMState", "LSTMParam", "ssd",
            "inception_bn", "inception_bn_small", "googlenet", "vgg", "alexnet",
-           "gpt", "gpt_generate", "gpt_decode_config", "hybrid_decoder"]
+           "gpt", "gpt_generate", "gpt_decode_config", "hybrid_decoder",
+           "moe_decoder"]

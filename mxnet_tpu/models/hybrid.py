@@ -67,6 +67,22 @@ class HybridDecoder(collections.namedtuple("HybridDecoder", _FIELDS)):
 
     __slots__ = ()
 
+    # what serve/hybrid.py and the engine read of ANY description: the head
+    # is the embedding, every layer's feed-forward is dense, no layer has a
+    # window, and a position's K/V heads lie side by side in the cache
+    tied = True
+    window_layers = ()
+    kv_flat = True
+
+    @property
+    def ffn_types(self):
+        return ("dense",) * len(self.layer_types)
+
+    @property
+    def global_layers(self):
+        """The layers whose K/V live in the one whole-context cache."""
+        return self.attention_layers
+
     @property
     def num_layers(self):
         return len(self.layer_types)

@@ -67,6 +67,7 @@ from ..models.generate import (detect_gpt_variant, normalize_gpt_params,
 from ..parallel import partition as partition_mod
 from ..parallel.mesh import NamedSharding, PartitionSpec, make_mesh
 from ..models.hybrid import HybridDecoder
+from ..models.moe import MoEDecoder
 from ..ops.attention import (PAGED_TILE_TOKENS, SPAN_BLOCK_K, SPAN_BLOCK_Q,
                              SPAN_KERNEL_MIN_SCORES, paged_tile_slots,
                              span_kv_tiles)
@@ -77,7 +78,8 @@ from ..telemetry.perf_attrib import PerfAttrib
 from ..telemetry.request_trace import RequestTracer
 from . import adapters as adapters_mod
 from . import hybrid as hybrid_mod
-from .kv_block_manager import BlockManager, HostKVPool
+from ..ops import moe as moe_ops
+from .kv_block_manager import BlockManager, HostKVPool, WindowGroup
 from .programs import (TOP_LOGPROBS, _ModelCfg, _build_chunk, _build_decode,
                        _build_prefill, _build_restore, _cfg_fp_fields,
                        _quantize_gpt_params)
@@ -291,17 +293,27 @@ class Engine:
                  quantize=None, kv_dtype=None, host_kv_bytes=None,
                  adapters=None, adapter_rank=None,
                  adapter_host_bytes=None):
-        # a hybrid decoder's description (models/hybrid.py) in place of a
-        # gpt() symbol: layers of two kinds, a state pool beside the K/V
-        self._hybrid = (symbol if isinstance(symbol, HybridDecoder)
-                        else None)
-        if self._hybrid is not None:
-            if num_heads not in (None, self._hybrid.num_heads) or window:
+        # a decoder's DESCRIPTION in place of a gpt() symbol, served
+        # through serve/hybrid.py: a hybrid decoder's (models/hybrid.py:
+        # layers of two kinds, a state pool beside the K/V) or a routed-
+        # expert decoder's (models/moe.py: global and window attention
+        # layers in two cache groups, experts told which they hold).
+        # What it brings is read off the description (its state-space
+        # layers, its window layers, its routed blocks), never its class
+        self._desc = (symbol if isinstance(symbol, (HybridDecoder,
+                                                    MoEDecoder))
+                      else None)
+        # the router's counts ride out of every program, and the probe
+        # through them (serve/hybrid.py)
+        self._routed = (self._desc is not None
+                        and hybrid_mod.routed(self._desc))
+        if self._desc is not None:
+            if num_heads not in (None, self._desc.num_heads) or window:
                 raise ValueError(
-                    "a hybrid decoder carries its own num_heads and has "
-                    "no attention window")
-            num_heads, window, name = (self._hybrid.num_heads, 0,
-                                       self._hybrid.name)
+                    "a described decoder carries its own num_heads and "
+                    "its own attention windows")
+            num_heads, window, name = (self._desc.num_heads, 0,
+                                       self._desc.name)
         elif symbol is not None:
             num_heads, window = reconcile_decode_config(symbol, num_heads,
                                                         window)
@@ -321,19 +333,19 @@ class Engine:
         max_queue = (int(max_queue) if max_queue is not None
                      else env_int("MXTPU_SERVE_MAX_QUEUE", 64))
 
-        if self._hybrid is not None:
-            self.spec = hybrid_mod.check_params(self._hybrid, params)
+        if self._desc is not None:
+            self.spec = hybrid_mod.check_params(self._desc, params)
         else:
             params = normalize_gpt_params(params, name)
             self.spec = detect_gpt_variant(params, num_heads, name)
-        if self._hybrid is not None:
+        if self._desc is not None:
             # what a hybrid engine refuses, by name (docs/how_to/serve.md
             # "Hybrid decoders"): arguments and their env defaults alike
             def _arg(v, env):
                 return int(v) if v is not None else env_int(env, 0)
 
             hybrid_mod.refuse(
-                prefix_cache=bool(prefix_cache),
+                self._desc, prefix_cache=bool(prefix_cache),
                 spec_k=_arg(spec_k, "MXTPU_SERVE_SPEC"),
                 adapters=_arg(adapters, "MXTPU_SERVE_ADAPTERS"),
                 kv_dtype=(kv_dtype
@@ -537,11 +549,27 @@ class Engine:
         self._host_pool = (HostKVPool(self.host_kv_bytes,
                                       block_tokens=self.block_size)
                            if self.host_kv_bytes else None)
+        window_group = None
+        if self._desc is not None and self._desc.window_layers:
+            # the window layers' group, sized by what it has to hold: a
+            # window's blocks for every row of the batch, and the span of
+            # the one chunk pass that runs at a time
+            chunk = (int(prefill_chunk) if prefill_chunk is not None
+                     else env_int("MXTPU_SERVE_PREFILL_CHUNK", 512))
+            scratch = (-(-_next_bucket(chunk, self.max_model_len)
+                         // self.block_size) + 1) if chunk > 0 else 0
+            per_request = -(-self._desc.window // self.block_size) + 1
+            window_group = WindowGroup(
+                self.max_batch * per_request + scratch + 1,
+                self.block_size, self._desc.window, scratch=scratch)
         self.blocks = BlockManager(
             self.num_blocks, self.block_size, prefix_cache=prefix_cache,
             host_pool=self._host_pool,
-            # a hybrid request owns blocks AND one state slot
-            state_slots=self.max_batch if self._hybrid is not None else 0)
+            # a request of a decoder with state-space layers owns blocks
+            # AND one state slot
+            state_slots=(self.max_batch if self._desc is not None
+                         and self._desc.mamba_layers else 0),
+            window_group=window_group)
         # always registered: the eviction path only offloads with a
         # pool attached, but export_blocks (the prefill→decode handoff
         # serializer) gathers device blocks D2H through the same fetch
@@ -610,9 +638,10 @@ class Engine:
                 host_bytes=self.adapter_host_bytes,
                 shardings=(None if self._shardings is None
                            else self._shardings.adapters))
-        # a hybrid decoder's K/V is stacked over its attention layers only
-        L = (self.spec["n_layers"] if self._hybrid is None
-             else len(self._hybrid.attention_layers))
+        # a described decoder's K/V is stacked over the layers that keep
+        # their whole context only (its window layers' group follows below)
+        L = (self.spec["n_layers"] if self._desc is None
+             else len(self._desc.global_layers))
         # int8 KV blocks store quantized slots plus per-slot-per-head
         # f32 scales in a small parallel array pair indexed by the SAME
         # block ids — BlockManager accounting, the radix prefix cache,
@@ -622,7 +651,7 @@ class Engine:
         shape = (L, self.num_blocks, self.block_size,
                  self.spec["kv_heads"], self.spec["head_dim"])
         sshape = shape[:-1]
-        if self._hybrid is not None:
+        if self._desc is not None and self._desc.kv_flat:
             # flat in the minor axis: a (kv_heads, head_dim) = (8, 64)
             # bf16 tile is padded fourfold on the chip (serve/hybrid.py)
             shape = shape[:3] + (shape[3] * shape[4],)
@@ -650,8 +679,30 @@ class Engine:
         # recurrent states float32, convolution rows in the activation
         # dtype, slot 0 the null slot that padded rows write to
         self._state_ssm = self._state_conv = None
-        if self._hybrid is not None:
-            hd = self._hybrid
+        # the window layers' group: its own stack over them, its own
+        # (fewer) blocks
+        self._cache_wk = self._cache_wv = None
+        if window_group is not None:
+            wshape = ((len(self._desc.window_layers),
+                       window_group.num_blocks) + shape[2:])
+            self._cache_wk = jnp.zeros(wshape, cache_dt)
+            self._cache_wv = jnp.zeros(wshape, cache_dt)
+        # the routed blocks' probe (serve/hybrid.py): the newest passes'
+        # first rows in and out of every routed block, and whose rows
+        # they were; see routed_probe
+        self._probe = None
+        self._probe_rows = {"decode": (), "span": None}
+        if self._routed:
+            self._probe = jnp.zeros(
+                hybrid_mod.probe_shape(self._desc, self.max_batch), dt)
+        # the caches a description's programs take behind the K/V pair,
+        # in operand order, as this engine's attributes
+        self._extra_caches = () if self._desc is None else tuple(
+            {"ssm": "_state_ssm", "conv": "_state_conv", "wk": "_cache_wk",
+             "wv": "_cache_wv", "probe": "_probe"}[name]
+            for name in hybrid_mod.extra_caches(self._desc))
+        if self._desc is not None and self._desc.mamba_layers:
+            hd = self._desc
             M, S = len(hd.mamba_layers), self.max_batch + 1
             self._state_ssm = jnp.zeros(
                 (M, S, hd.mamba_heads, hd.mamba_head_dim, hd.mamba_state),
@@ -675,8 +726,8 @@ class Engine:
             kv_quant=self._kv_quant,
             adapters=self._adapters,
             adapter_rank=self.adapter_rank if self._adapters else 0,
-            hybrid=(None if self._hybrid is None
-                    else hybrid_mod.hybrid_cfg(self._hybrid)))
+            hybrid=(None if self._desc is None
+                    else hybrid_mod.hybrid_cfg(self._desc)))
         # every parameter's shape and dtype, once: what _spec_key() needs
         # beyond _ModelCfg to tell two engines' compiled programs apart
         # (the vocabulary, the MLP width: widths no cfg field carries)
@@ -743,7 +794,24 @@ class Engine:
             "mxtpu_serve_evictions", "retained-block evictions (lifetime)")
         self._tel_rejected = telemetry.gauge(
             "mxtpu_serve_rejected", "rejected requests (lifetime)")
-        if self._hybrid is not None:
+        if window_group is not None:
+            self._tel_group_blocks = telemetry.gauge(
+                "mxtpu_serve_kv_blocks_in_use",
+                "KV-cache blocks allocated, by layer group", ("group",))
+            self._tel_window_freed = telemetry.counter(
+                "mxtpu_serve_kv_window_blocks_freed_total",
+                "window-group blocks returned when their last position "
+                "left the window")
+        if self._routed:
+            self._tel_moe_picks = telemetry.counter(
+                "mxtpu_serve_moe_picks_total",
+                "router picks of real rows, by whether this program "
+                "holds the expert", ("held",))
+        # this step's window-group blocks freed and router counts
+        # (ops.moe.STATS), summed over its passes
+        self._window_freed = 0
+        self._moe_step = None
+        if self._state_ssm is not None:
             self._tel_state_slots = telemetry.gauge(
                 "mxtpu_serve_state_slots_in_use",
                 "state-pool slots held by admitted requests")
@@ -790,7 +858,11 @@ class Engine:
                 # a hybrid engine's state pool (slots + 1, dtypes)
                 None if self._state_ssm is None else
                 (self._state_ssm.shape[1], str(self._state_ssm.dtype),
-                 str(self._state_conv.dtype)))
+                 str(self._state_conv.dtype)),
+                # a window group (layers, blocks); a routed block's probe
+                None if self._cache_wk is None
+                else tuple(self._cache_wk.shape[:2]),
+                None if self._probe is None else tuple(self._probe.shape))
 
     def _aot_base_fp(self):
         """The on-disk form of _spec_key(): same fields, JSON-stable,
@@ -849,6 +921,11 @@ class Engine:
             state_slots=int(self._state_ssm.shape[1]),
             state_dtypes=[str(self._state_ssm.dtype),
                           str(self._state_conv.dtype)]))
+        if self._cache_wk is not None:
+            state.update(window_group=[int(n)
+                                       for n in self._cache_wk.shape[:2]])
+        if self._probe is not None:
+            state.update(routed_probe=[int(n) for n in self._probe.shape])
         return aot_store.fingerprint(
             subsystem="serve", cfg=cfg_d,
             num_blocks=self.num_blocks, table_width=self.table_width,
@@ -860,7 +937,7 @@ class Engine:
         trace ("pallas" or "jnp") — resolved from the env/backend/cache
         geometry exactly as ``ops.attention.paged_attention`` will."""
         from ..ops.attention import flat_paged_impl, resolve_paged_impl
-        if self._hybrid is not None:
+        if self._desc is not None and self._desc.kv_flat:
             return flat_paged_impl(self.block_size, self.spec["kv_heads"],
                                    self.spec["head_dim"])
         return resolve_paged_impl(self.block_size,
@@ -1118,9 +1195,10 @@ class Engine:
         if sprof.tracing:
             sprof.note(queue=self.scheduler.queue_depth,
                        running=len(self.scheduler.running))
-            if self._hybrid is not None:
+            if self._state_ssm is not None:
                 # slots held right now, like the blocks sampled above
                 sprof.note(state_slots=self.blocks.state_slots_in_use)
+        self._window_freed, self._moe_step = 0, None
         emitted = 0
         for req in prefills:
             sprof.enter("prefill_dispatch", rid=req.rid)
@@ -1177,14 +1255,31 @@ class Engine:
         self._tel_preempt.set(self.scheduler.preemptions)
         self._tel_evict.set(self.blocks.evictions)
         self._tel_rejected.set(self.scheduler.rejections)
-        if self._hybrid is not None:
+        if self._state_ssm is not None:
             self._tel_state_slots.set(self.blocks.state_slots_in_use)
+        groups = None
+        if self.blocks.window is not None:
+            groups = {"global": self.blocks.blocks_in_use,
+                      "window": self.blocks.window.blocks_in_use}
+            for name, n in groups.items():
+                self._tel_group_blocks.labels(group=name).set(n)
+            self._tel_window_freed.inc(self._window_freed)
+        if self._moe_step is not None:
+            picks, held = int(self._moe_step[0]), int(self._moe_step[1])
+            self._tel_moe_picks.labels(held="yes").inc(held)
+            self._tel_moe_picks.labels(held="no").inc(picks - held)
         if sprof.tracing:
             # preemptions is the lifetime count: a reader takes differences
             sprof.note(blocks_in_use=self.blocks.blocks_in_use,
                        emitted=emitted,
                        preemptions=self.scheduler.preemptions,
                        work_left=int(self.has_work()))
+            if groups is not None:
+                # what each layer group holds as the step ends, and what
+                # the window group gave back during it
+                sprof.note(blocks_global=groups["global"],
+                           blocks_window=groups["window"],
+                           window_blocks_freed=self._window_freed)
         sprof.commit(emitted, prefills=len(prefills), decodes=len(decodes))
         return emitted
 
@@ -1315,6 +1410,9 @@ class Engine:
             # cache-cold replica (also nested in kv_blocks.prefix_cache)
             "prefix_cache": self.blocks.prefix_stats(),
             "kv_cache": self.kv_cache_stats(),
+            # a routed-expert decoder's two layer groups: blocks held,
+            # free, requests, the window and what it freed (None else)
+            "kv_groups": self.blocks.group_stats(),
             # a hybrid decoder's state pool: slots, in use, bytes, dtypes
             # (None for gpt engines)
             "state_cache": self.state_cache_stats(),
@@ -1505,6 +1603,8 @@ class Engine:
                "bytes_in_use_per_device":
                    per_block * self.blocks.blocks_in_use,
                "dtype": str(self._cache_k.dtype)}
+        if self._cache_wk is not None:
+            out["window_group_bytes"] = 2 * int(self._cache_wk.nbytes)
         if self._kv_quant:
             # the dequantization scales are real HBM too: the honest
             # per-chip KV footprint is bytes + scale_bytes — an f32
@@ -1532,6 +1632,34 @@ class Engine:
                 "bytes_per_slot": total // (slots + 1),
                 "ssm_dtype": str(self._state_ssm.dtype),
                 "conv_dtype": str(self._state_conv.dtype)}
+
+    def routed_probe(self):
+        """What every routed block of the newest decode pass and of the
+        newest prefill or chunk pass took and gave, as the serving
+        programs themselves computed it (``serve/hybrid.py``, "probe"):
+        ``{"layers": (i, ...), "decode": (u, y), "span": (u, y),
+        "decode_rids": [...], "span_rows": (rid, start)}``.  ``u`` and
+        ``y`` are host arrays ``(rows, routed layer, d_model)`` in the
+        activation dtype: a block's normed input and its output (shared
+        expert plus the held experts' part) for the pass's first
+        ``max_batch`` rows, both zero for rows that were padding or that
+        no pass has written yet.  ``decode_rids[r]`` is the request of the
+        decode pass's row ``r`` (its newest position); the span's rows are
+        positions ``start ...`` of request ``rid``.  A reference's routed
+        block over ``u`` should give ``y``: a check of what was really
+        served, with nothing served for the check.  None for an engine
+        without a routed block."""
+        if self._probe is None:
+            return None
+        # mxtpu-lint: disable=host-sync (a check's read, outside any step)
+        rows = np.asarray(jax.device_get(self._probe))
+        return {"layers": hybrid_mod.probe_layers(self._desc),
+                "decode": (rows[hybrid_mod.PROBE_DECODE, :, :, 0],
+                           rows[hybrid_mod.PROBE_DECODE, :, :, 1]),
+                "span": (rows[hybrid_mod.PROBE_SPAN, :, :, 0],
+                         rows[hybrid_mod.PROBE_SPAN, :, :, 1]),
+                "decode_rids": list(self._probe_rows["decode"]),
+                "span_rows": self._probe_rows["span"]}
 
     def shutdown(self):
         """Cancel in-flight work and release the device cache.
@@ -1568,8 +1696,7 @@ class Engine:
         for arr in (self._owned + [self._cache_k, self._cache_v]
                     + ([self._scale_k, self._scale_v]
                        if self._scale_k is not None else [])
-                    + ([self._state_ssm, self._state_conv]
-                       if self._state_ssm is not None else [])):
+                    + [getattr(self, a) for a in self._extra_caches]):
             try:
                 arr.delete()
             except (RuntimeError, ValueError):
@@ -1578,6 +1705,7 @@ class Engine:
         self._cache_k = self._cache_v = None
         self._scale_k = self._scale_v = None
         self._state_ssm = self._state_conv = None
+        self._cache_wk = self._cache_wv = self._probe = None
         if self._host_pool is not None:
             # the DRAM tier releases WITH the device buffers: two
             # engines back-to-back must never transiently hold two
@@ -1647,19 +1775,61 @@ class Engine:
     def _req_state_operand(self, req):
         """Scalar state-slot operand of a hybrid engine's prefill and
         chunk programs (empty for gpt engines)."""
-        if self._hybrid is None:
+        if self._state_ssm is None:
             return ()
         return (jnp.asarray(self.blocks.state_slot(req.rid), jnp.int32),)
 
     def _batch_state_operands(self, reqs, bucket):
-        """(B,)-shaped state slots of a hybrid engine's decode program;
-        padded rows name the null slot 0."""
-        if self._hybrid is None:
+        """What a described decoder's decode program takes behind the
+        block tables: the rows' state slots ``(B,)`` where it has a state
+        pool (padded rows name the null slot 0), then the rows' window-
+        group tables where it has a window group."""
+        out = ()
+        if self._state_ssm is not None:
+            slots = np.zeros(bucket, np.int32)
+            for i, req in enumerate(reqs):
+                slots[i] = self.blocks.state_slot(req.rid)
+            out += (jnp.asarray(slots),)
+        if self.blocks.window is not None:
+            tables = np.zeros((bucket, self.table_width), np.int32)
+            for i, req in enumerate(reqs):
+                self.blocks.window_table(req.rid, tables[i])
+            out += (jnp.asarray(tables),)
+        return out
+
+    def _window_operands(self, req, start, end, bucket, table):
+        """The window group's operands of a prefill or
+        chunk pass over positions ``[start, end)``.  ``table``: a chunk
+        pass, which writes its whole span through the group's table and
+        then attends through it; the operands are the table and the write
+        blocks.  Else a whole prompt, which attends to its own rows: the
+        group holds only what a query at ``end`` still sees, the rows
+        behind that (and every bucket's padding) write to the null block,
+        and the operand is the write blocks.  Empty for an engine
+        without the group."""
+        if self.blocks.window is None:
             return ()
-        slots = np.zeros(bucket, np.int32)
-        for i, req in enumerate(reqs):
-            slots[i] = self.blocks.state_slot(req.rid)
-        return (jnp.asarray(slots),)
+        tw = np.zeros(self.table_width, np.int32)
+        lo = start if table else max(0, end - self._desc.window + 1)
+        self.blocks.window_cover(req.rid, lo, end)
+        self.blocks.window_table(req.rid, tw)
+        blk = np.zeros(bucket, np.int32)
+        pos = np.arange(start, end)
+        blk[:end - start] = np.where(pos >= lo, tw[pos // self.block_size],
+                                     0)
+        return ((jnp.asarray(tw),) if table else ()) + (jnp.asarray(blk),)
+
+    def _note_moe(self, stats):
+        """A pass's router counts (``ops.moe.STATS``) onto its span and
+        into the step's sum."""
+        # mxtpu-lint: disable=host-sync (host numpy already: the counts
+        # arrived in _unpack_outs's batched read, with the tokens)
+        stats = np.asarray(stats, np.int64)
+        self._moe_step = (stats if self._moe_step is None
+                          else self._moe_step + stats)
+        if self._sprof.tracing:
+            self._sprof.note(**{k: int(v) for k, v in
+                                zip(moe_ops.STATS, stats)})
 
     def _note_logprobs(self, req, chosen, tv, ti):
         """Record emitted tokens' logprob outputs on the request: the
@@ -1701,6 +1871,12 @@ class Engine:
         # scheduler needs the sampled tokens on the host)
         return jax.device_get(tuple(outs[:n_lead]))
 
+    def _n_lead(self):
+        """Host-bound outputs of a prefill, chunk or decode program: the
+        sampled token (with its logprob views in sampling mode) and,
+        where a feed-forward block is routed, the router's counts."""
+        return (4 if self._sampling else 1) + int(self._routed)
+
     def _cache_args(self):
         """The device cache operands every target-model program takes:
         (k, v) — plus the int8-KV scale pair when quantized (the same
@@ -1708,10 +1884,9 @@ class Engine:
         if self._kv_quant:
             return (self._cache_k, self._cache_v,
                     self._scale_k, self._scale_v)
-        if self._hybrid is not None:
-            return (self._cache_k, self._cache_v,
-                    self._state_ssm, self._state_conv)
-        return (self._cache_k, self._cache_v)
+        # then what a description brings (serve/hybrid.py::extra_caches)
+        return (self._cache_k, self._cache_v) + tuple(
+            getattr(self, a) for a in self._extra_caches)
 
     def _set_caches(self, arrs):
         """Adopt a program's returned (donated-through) cache operands
@@ -1719,11 +1894,10 @@ class Engine:
         if self._kv_quant:
             (self._cache_k, self._cache_v,
              self._scale_k, self._scale_v) = arrs
-        elif self._hybrid is not None:
-            (self._cache_k, self._cache_v,
-             self._state_ssm, self._state_conv) = arrs
         else:
-            self._cache_k, self._cache_v = arrs
+            self._cache_k, self._cache_v, *extra = arrs
+            for a, arr in zip(self._extra_caches, extra, strict=True):
+                setattr(self, a, arr)
 
     def _host_kv_fetch(self, blk):
         """Device→host copy of ONE block's K/V (and int8 scale slots)
@@ -1839,6 +2013,7 @@ class Engine:
                     jnp.asarray(blk), jnp.asarray(off)) \
                 + self._req_adapter_operand(req) \
                 + self._req_state_operand(req) \
+                + self._window_operands(req, 0, n, bucket, table=False) \
                 + self._req_sampling_operands(req) + (sub,)
         else:
             # suffix/chunk pass: positions [start, end) attend through
@@ -1865,10 +2040,12 @@ class Engine:
                     jnp.asarray(blk), jnp.asarray(off)) \
                 + self._req_adapter_operand(req) \
                 + self._req_state_operand(req) \
+                + self._window_operands(req, start, end, bucket,
+                                        table=True) \
                 + self._req_sampling_operands(req) + (sub,)
         sprof = self._sprof
         state = None
-        if self._hybrid is not None:
+        if self._state_ssm is not None:
             # a pass from position 0 starts the slot's state from zero
             # inside the program: at admission, and again when a
             # preempted request is prefilled anew
@@ -1891,10 +2068,15 @@ class Engine:
         outs = fn(*args)
         self._perf.done(t0, pkind, bucket, outs)
         sprof.enter("device_wait")
-        lead = self._unpack_outs(outs, 4 if self._sampling else 1,
+        lead = self._unpack_outs(outs, self._n_lead(),
                                  "prefill_logits", rid=req.rid)
         sprof.enter("host_sync")
         tok = lead[0]
+        if self._routed:
+            self._note_moe(lead[-1])
+            self._probe_rows["span"] = (req.rid, start)
+        if self.blocks.window is not None:
+            self._window_freed += self.blocks.window_trim(req.rid, end)
         req.prefill_passes += 1
         req.cache_len = end
         self._stats.on_prefill(span)
@@ -1962,14 +2144,21 @@ class Engine:
                   *self._batch_sampling_operands(reqs, bucket), sub)
         self._perf.done(t0, "decode", bucket, outs)
         self._sprof.enter("device_wait")
-        lead = self._unpack_outs(outs, 4 if self._sampling else 1,
+        lead = self._unpack_outs(outs, self._n_lead(),
                                  "decode_logits", batch_size=B,
                                  rids=[r.rid for r in reqs])
         self._sprof.enter("host_sync")
         out = lead[0]
         now = self.clock()
+        if self._routed:
+            self._note_moe(lead[-1])
+            self._probe_rows["decode"] = [r.rid for r in reqs]
+        trim = self.blocks.window is not None
         for i, req in enumerate(reqs):
             req.cache_len += 1
+            if trim:
+                self._window_freed += self.blocks.window_trim(
+                    req.rid, req.cache_len)
             req.tokens.append(int(out[i]))
             if self._sampling:
                 self._note_logprobs(req, lead[1][i:i + 1],
@@ -2359,11 +2548,11 @@ class Engine:
         tests/test_perf_contract.py."""
         from .. import flops as flops_mod
 
-        if self._hybrid is not None:
+        if self._desc is not None:
             # 2 operations per matrix parameter per row, the tied head
             # once per sampled row; attention's score terms left out
             rows = 1 if kind != "decode" else bucket
-            return (hybrid_mod.matmul_flops(self._hybrid, bucket, rows),
+            return (hybrid_mod.matmul_flops(self._desc, bucket, rows),
                     None)
         if kind in ("draft", "draft_chunk") and self._spec is not None:
             cfg, params = self._spec.cfg, self._spec.params
@@ -2438,11 +2627,21 @@ class Engine:
 
         def aslot(shape):
             # the per-request adapter-slot index operand (scalar for
-            # prefill/chunk, (B,) for decode/verify); a hybrid engine's
-            # state-slot operand sits in the same place, the same shape
-            if not self._cfg.adapters and not self._cfg.hybrid:
-                return ()
-            return (sds(shape, i32),)
+            # prefill/chunk, (B,) for decode/verify); a state pool's
+            # slot operand sits in the same place, the same shape, and a
+            # window group's operands behind it: its tables for decode,
+            # its table and write blocks for a chunk, its write blocks
+            # for a whole prompt
+            out = ()
+            if self._cfg.adapters or self._state_ssm is not None:
+                out += (sds(shape, i32),)
+            if self.blocks.window is not None:
+                table = sds(shape + (self.table_width,), i32)
+                if kind == "decode":
+                    return out + (table,)
+                out += ((table,) if kind == "chunk" else ()) \
+                    + (sds((bucket,), i32),)
+            return out
 
         if kind in ("draft", "draft_chunk"):
             # draft-side programs: the draft checkpoint's params and
@@ -2471,10 +2670,10 @@ class Engine:
         # caches in every target-model program (same order as
         # _cache_args)
         caches = (cspec, cspec)
-        if self._hybrid is not None:
-            # the state pool follows the K/V (same order as _cache_args)
-            caches += (sds(self._state_ssm.shape, self._state_ssm.dtype),
-                       sds(self._state_conv.shape, self._state_conv.dtype))
+        # what a description brings follows the K/V (same order as
+        # _cache_args): the state pool, the window group's stack, the probe
+        caches += tuple(sds(getattr(self, a).shape, getattr(self, a).dtype)
+                        for a in self._extra_caches)
         if self._kv_quant:
             sspec = sds(self._scale_k.shape, self._scale_k.dtype,
                         sh.scale if sh is not None else None)

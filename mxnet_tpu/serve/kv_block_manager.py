@@ -105,7 +105,7 @@ from .. import telemetry
 from ..base import env_flag, env_float, env_int
 
 __all__ = ["BlockManager", "HostKVPool", "NoFreeBlocks", "RadixSummary",
-           "chain_keys"]
+           "WindowGroup", "chain_keys"]
 
 # chaos-harness fault: simulated seconds per host-tier restore claim (a
 # slow DRAM copy); with a restore budget set, a delay past the budget
@@ -121,6 +121,11 @@ _ROOT = b"mxtpu-radix-root"
 # (prefix_stats() and the engine's refusal both say it)
 STATE_POOL_NO_PREFIX = ("a cached block holds K/V only; the recurrent state "
                         "at its edge was not kept")
+
+
+# why a block manager with a window group keeps it off
+WINDOW_NO_PREFIX = ("a window layer's blocks behind the window went back to "
+                    "their free list; a cached prefix would need them again")
 
 
 class NoFreeBlocks(Exception):
@@ -540,6 +545,129 @@ class HostKVPool:
                     "discarded_tokens": self.discarded_tokens}
 
 
+class WindowGroup:
+    """The blocks of the layers that see only the last ``window``
+    positions: a LAYER GROUP with a cache stack, a free list and a table
+    per request of its own, beside the global group's (the
+    ``BlockManager`` proper, whose layers see the whole context).
+
+    A request holds the blocks that cover the positions a later query can
+    still see, a contiguous run of LOGICAL blocks ``[first, first +
+    len(blocks))`` (logical block ``j`` covers positions ``[j * bs, (j +
+    1) * bs)``): :meth:`cover` extends the run in front of a pass,
+    :meth:`trim` returns the blocks whose last position has left the
+    window of the NEXT query to the free list after it.  Between passes a
+    request holds at most ``per_request = ceil(window / bs) + 1`` blocks;
+    INSIDE a chunk pass it also holds the chunk's own span (the pass
+    writes, then attends through the table), at most ``scratch`` blocks
+    more, and one chunk pass runs at a time.  Admission is by
+    reservation, so that no pass can find the free list short: a request
+    is admitted while ``(admitted + 1) * per_request + scratch`` blocks
+    exist.  Block 0 is the null block, as in the global group: a table
+    reads 0 where the request holds nothing.
+
+    Not locked on its own: ``BlockManager`` calls it under its lock."""
+
+    def __init__(self, num_blocks, block_size, window, scratch=0):
+        if window < 1:
+            raise ValueError("a window group needs window >= 1")
+        self.num_blocks, self.block_size = int(num_blocks), int(block_size)
+        self.window, self.scratch = int(window), int(scratch)
+        self.per_request = -(-self.window // self.block_size) + 1
+        if self.capacity < 1:
+            raise ValueError(
+                f"a window group of {num_blocks} blocks cannot hold one "
+                f"request: {self.per_request} blocks a request, "
+                f"{self.scratch} for a chunk pass, and the null block")
+        self.freed = 0               # blocks returned by trim(), lifetime
+        self.reset()
+
+    def reset(self):
+        self._free = deque(range(1, self.num_blocks))
+        self._first = {}             # rid -> first held logical block
+        self._blocks = {}            # rid -> physical blocks, in order
+
+    @property
+    def total_blocks(self):
+        return self.num_blocks - 1
+
+    @property
+    def capacity(self):
+        """Requests the group can hold at once."""
+        return (self.total_blocks - self.scratch) // self.per_request
+
+    @property
+    def blocks_in_use(self):
+        return self.total_blocks - len(self._free)
+
+    def can_admit(self):
+        return len(self._blocks) < self.capacity
+
+    def admit(self, rid):
+        if not self.can_admit():
+            raise NoFreeBlocks(
+                f"request {rid!r}: the window group holds "
+                f"{len(self._blocks)} requests, its {self.total_blocks} "
+                f"blocks cover {self.capacity}")
+        self._first[rid], self._blocks[rid] = 0, []
+
+    def cover(self, rid, lo, hi):
+        """Hold blocks for positions ``[lo, hi)`` in front of a pass that
+        writes them (what is held already stays)."""
+        bs, blocks = self.block_size, self._blocks[rid]
+        if not blocks:
+            self._first[rid] = lo // bs
+        end = self._first[rid] + len(blocks)
+        if lo // bs > end:
+            raise ValueError(
+                f"request {rid!r}: positions [{lo}, {hi}) leave a gap "
+                f"behind logical block {end} of the window group")
+        need = (hi - 1) // bs + 1 - end
+        if need > len(self._free):
+            raise NoFreeBlocks(
+                f"request {rid!r} needs {need} window-group blocks, "
+                f"{len(self._free)} free")
+        blocks.extend(self._free.popleft() for _ in range(max(need, 0)))
+
+    def trim(self, rid, next_pos):
+        """Return the blocks no query at ``next_pos`` or later can see;
+        the number returned."""
+        blocks = self._blocks.get(rid)
+        if not blocks:
+            return 0
+        # logical block j is dead when its last position, (j + 1) * bs - 1,
+        # lies behind the window's first, next_pos - window + 1
+        alive = max(next_pos - self.window + 1, 0) // self.block_size
+        n = min(max(alive - self._first[rid], 0), len(blocks))
+        self._free.extend(blocks[:n])
+        del blocks[:n]
+        self._first[rid] += n
+        self.freed += n
+        return n
+
+    def release(self, rid):
+        self._free.extend(self._blocks.pop(rid, ()))
+        self._first.pop(rid, None)
+
+    def held(self, rid):
+        """(first logical block, physical blocks) of ``rid``."""
+        return self._first[rid], list(self._blocks[rid])
+
+    def table(self, rid, out):
+        """``rid``'s LOGICAL table into ``out (width,)``, a zeroed int32
+        row: the null block stays wherever it holds nothing."""
+        first, blocks = self._first[rid], self._blocks[rid]
+        out[first:first + len(blocks)] = blocks
+        return out
+
+    def stats(self):
+        return {"in_use": self.blocks_in_use, "free": len(self._free),
+                "total": self.total_blocks, "requests": len(self._blocks),
+                "capacity": self.capacity, "window": self.window,
+                "per_request": self.per_request, "scratch": self.scratch,
+                "freed": self.freed}
+
+
 class BlockManager:
     """Host-side block accounting.  Mutations are serialized by the
     RLock below: the scheduler drives allocation from the engine's step
@@ -550,7 +678,7 @@ class BlockManager:
     ``allocate``/``ensure_capacity`` call ``_take`` under the lock."""
 
     def __init__(self, num_blocks, block_size, prefix_cache=None,
-                 host_pool=None, state_slots=0):
+                 host_pool=None, state_slots=0, window_group=None):
         if num_blocks < 2:
             raise ValueError("need >= 2 blocks (block 0 is the null block)")
         if block_size < 1:
@@ -579,6 +707,17 @@ class BlockManager:
             self.prefix_off_reason = STATE_POOL_NO_PREFIX
         self._slot_free = deque(range(1, self.state_slots + 1))  # guarded-by: _lock
         self._slot_of = {}                        # guarded-by: _lock
+        # the window layers' group (a WindowGroup or None): a request is
+        # admitted into both groups or neither, and free() leaves both
+        self.window = window_group
+        if self.window is not None:
+            if self.prefix_cache:
+                raise ValueError(
+                    "a window group and the radix prefix cache cannot go "
+                    "together: " + WINDOW_NO_PREFIX)
+            if self.window.block_size != block_size:
+                raise ValueError("both groups share one block size")
+            self.prefix_off_reason = WINDOW_NO_PREFIX
         # block 0 reserved as the null/padding block
         self._free = deque(range(1, num_blocks))  # guarded-by: _lock
         self._tables = {}                         # guarded-by: _lock
@@ -696,7 +835,25 @@ class BlockManager:
                     + sum(len(b) for b in self._retained.values()))
 
     def utilization(self):
+        """Blocks in use over blocks that exist, both groups counted."""
+        if self.window is not None:
+            with self._lock:
+                return ((self.blocks_in_use + self.window.blocks_in_use)
+                        / max(1, self.total_blocks
+                              + self.window.total_blocks))
         return self.blocks_in_use / max(1, self.total_blocks)
+
+    def group_stats(self):
+        """Per layer group, what it holds right now (None without a
+        window group): the ``kv_groups`` section of ``/statusz``."""
+        if self.window is None:
+            return None
+        with self._lock:
+            return {"global": {"in_use": self.blocks_in_use,
+                               "free": len(self._free),
+                               "total": self.total_blocks,
+                               "requests": len(self._tables)},
+                    "window": self.window.stats()}
 
     def occupancy(self):
         """One JSON-ready snapshot of the block accounting — the
@@ -792,6 +949,8 @@ class BlockManager:
         with self._lock:
             if self.state_slots and not self._slot_free:
                 return False          # blocks AND a state slot, or neither
+            if self.window is not None and not self.window.can_admit():
+                return False          # both groups, or neither
         return need <= self.free_blocks
 
     def fits_at_all(self, n_tokens):
@@ -874,6 +1033,8 @@ class BlockManager:
             raise ValueError(
                 "block export: a hybrid decoder's request is K/V blocks "
                 "AND a recurrent state, and only the blocks would travel")
+        if self.window is not None:
+            raise ValueError("block export: " + WINDOW_NO_PREFIX)
         with self._lock:
             if not self.prefix_cache or self._offload_fetch is None:
                 return []
@@ -919,6 +1080,8 @@ class BlockManager:
             raise ValueError(
                 "block import: a hybrid decoder cannot resume from K/V "
                 "blocks alone (no recurrent state comes with them)")
+        if self.window is not None:
+            raise ValueError("block import: " + WINDOW_NO_PREFIX)
         imported = deduped = 0
         with self._lock:
             expect_parent = None
@@ -1057,6 +1220,10 @@ class BlockManager:
                 raise NoFreeBlocks(
                     f"request {rid!r} needs a state slot, all "
                     f"{self.state_slots} are held")
+            if self.window is not None and not self.window.can_admit():
+                raise NoFreeBlocks(
+                    f"request {rid!r}: the window group is full "
+                    f"({self.window.capacity} requests)")
             hits, host_keys = [], []
             if self.prefix_cache and token_ids is not None:
                 hits, host_keys = self._walk(token_ids, salt=salt)
@@ -1139,6 +1306,8 @@ class BlockManager:
             self._lens[rid] = n * self.block_size
             if self.state_slots:
                 self._slot_of[rid] = self._slot_free.popleft()
+            if self.window is not None:
+                self.window.admit(rid)
             self._chain[rid] = ([key for key, _ in hits]
                                 + [key for key, _, _ in claimed])
             if token_ids is not None:
@@ -1157,11 +1326,35 @@ class BlockManager:
             if need > 0:
                 table.extend(self._take(need))
                 self._lens[rid] = len(table) * self.block_size
+            if self.window is not None:
+                # the decode step's own position; what the window still
+                # shows of the rest is held since the passes before
+                self.window.cover(rid, n_tokens - 1, n_tokens)
             return list(table)
 
     def table(self, rid):
         with self._lock:
             return list(self._tables[rid])
+
+    def window_cover(self, rid, lo, hi):
+        """The window group holds positions ``[lo, hi)`` of ``rid`` from
+        here on (``WindowGroup.cover``): in front of a prefill or chunk
+        pass that writes them."""
+        with self._lock:
+            self.window.cover(rid, lo, hi)
+
+    def window_trim(self, rid, next_pos):
+        """After a pass: the window group's blocks of ``rid`` that no
+        query at ``next_pos`` or later sees go back to its free list;
+        how many went (0 for a request that has left)."""
+        with self._lock:
+            return self.window.trim(rid, next_pos)
+
+    def window_table(self, rid, out):
+        """``rid``'s logical window-group table into the zeroed row
+        ``out``."""
+        with self._lock:
+            return self.window.table(rid, out)
 
     def state_slot(self, rid):
         """The state-pool slot ``rid`` holds (hybrid engines only)."""
@@ -1321,6 +1514,8 @@ class BlockManager:
             slot = self._slot_of.pop(rid, None)
             if slot is not None:
                 self._slot_free.append(slot)
+            if self.window is not None:
+                self.window.release(rid)
             self._chain.pop(rid, None)
             self._host_tokens.pop(rid, None)
             loose = []
@@ -1339,6 +1534,8 @@ class BlockManager:
             self._free = deque(range(1, self.num_blocks))
             self._slot_free = deque(range(1, self.state_slots + 1))
             self._slot_of.clear()
+            if self.window is not None:
+                self.window.reset()
             self._tables.clear()
             self._lens.clear()
             self._retained.clear()

@@ -94,21 +94,32 @@ def _cfg_fp_fields(cfg):
     return d
 
 
-def _rope(u, pos, base=10000.0):
+def _rope(u, pos, base=10000.0, inv=None, rot=None, scale=None):
     """Rotate rows ``(..., H, Dh)`` by their own positions ``(...,)`` —
-    matches ops/attention.py RoPEOp / generate.py's scalar-position _rot."""
+    matches ops/attention.py RoPEOp / generate.py's scalar-position _rot.
+    ``inv``: a table of ``rot / 2`` inverse frequencies in place of
+    ``base``'s; ``rot``: only the first ``rot`` of the ``Dh`` dimensions
+    turn, the rest pass through; ``scale`` multiplies cosines and sines
+    (models/moe.py's ``Rope``).  Without them: the whole head at
+    ``base``."""
     if pos.ndim != 1:          # verify's (B, k+1) rows: as one flat list
         return _rope(u.reshape((-1,) + u.shape[-2:]), pos.reshape(-1),
-                     base).reshape(u.shape)
-    half = u.shape[-1] // 2
-    inv = base ** (-jnp.arange(half, dtype=jnp.float32) / half)
+                     base, inv, rot, scale).reshape(u.shape)
+    width = u.shape[-1] if rot is None else rot
+    half = width // 2
+    if inv is None:
+        inv = base ** (-jnp.arange(half, dtype=jnp.float32) / half)
     ang = pos.astype(jnp.float32)[:, None] * inv          # (N, half)
     cos = jnp.cos(ang)[:, None, :]
     sin = jnp.sin(ang)[:, None, :]
+    if scale is not None:
+        cos, sin = cos * np.float32(scale), sin * np.float32(scale)
     uf = u.astype(jnp.float32)
-    u1, u2 = uf[..., :half], uf[..., half:]
-    return jnp.concatenate([u1 * cos - u2 * sin,
-                            u1 * sin + u2 * cos], axis=-1).astype(u.dtype)
+    u1, u2 = uf[..., :half], uf[..., half:width]
+    return jnp.concatenate([u1 * cos - u2 * sin, u1 * sin + u2 * cos]
+                           + ([] if width == u.shape[-1]
+                              else [uf[..., width:]]),
+                           axis=-1).astype(u.dtype)
 
 
 # -- quantized serving helpers ------------------------------------------------
