@@ -9,10 +9,20 @@
       ...
   eng.shutdown()
 
-Each ``step()`` is one scheduler iteration: at most
+Each pass is one scheduler iteration: at most
 ``max_prefills_per_step`` prefills (one jit-compiled program per
 prompt-length bucket) followed by ONE batched single-token decode over
-every running request (one program per batch bucket).  A prefill skips
+every running request (one program per batch bucket).  The step loop
+runs one pass AHEAD of what it has read: a ``step()`` with pass t
+enqueued and unread schedules pass t+1 from what the host can foresee
+(positions, who finishes: a request ends by length alone), enqueues it
+behind pass t with the rows' input tokens taken from pass t's output ON
+THE DEVICE, and only then blocks on pass t and does its bookkeeping, so
+the host's turn is no idle time of the device's (``_step_inner``).  What
+a caller sees between two calls is one settled snapshot: tokens,
+``cache_len``, status, stamps and stats move together when a pass is
+read, and every reader from outside the loop reads the unread pass
+first.  A prefill skips
 whatever block-aligned prefix the content-addressed KV cache already
 holds (``MXTPU_SERVE_PREFIX_CACHE``) and runs only the suffix through
 a third program family — the *chunk* program, which attends through
@@ -80,8 +90,9 @@ from . import adapters as adapters_mod
 from . import hybrid as hybrid_mod
 from ..ops import moe as moe_ops
 from .kv_block_manager import BlockManager, HostKVPool, WindowGroup
-from .programs import (TOP_LOGPROBS, _ModelCfg, _build_chunk, _build_decode,
-                       _build_prefill, _build_restore, _cfg_fp_fields,
+from .programs import (TOK_PUT, TOK_PUT1, TOK_TAKE, TOP_LOGPROBS, _ModelCfg,
+                       _build_chunk, _build_decode, _build_prefill,
+                       _build_restore, _build_token_program, _cfg_fp_fields,
                        _quantize_gpt_params)
 from .scheduler import (CANCELLED, FINISHED, REJECTED, WAITING, QueueFull,
                         Request, Scheduler)
@@ -149,6 +160,41 @@ def _valid_top_k(k):
     if k < 1:
         raise ValueError(f"top_k must be None/0 or >= 1 (got {k})")
     return k
+
+
+class _Part:
+    """One program of a pass, enqueued and not read yet: its host-bound
+    outputs still on the device (``lead``, and ``ok`` the watchdog's
+    flag), and what the engine kept from the dispatch for the read."""
+
+    __slots__ = ("reqs", "lead", "ok", "args", "start", "end", "n", "freed")
+
+    def __init__(self, reqs, lead, ok, args, start=0, end=0, n=0):
+        self.reqs, self.lead, self.ok, self.args = reqs, lead, ok, args
+        # a prefill pass: positions [start, end) of the n to prefill
+        self.start, self.end, self.n = start, end, n
+        self.freed = 0           # window-group blocks its trim gave back
+
+
+class _Pass:
+    """One scheduler iteration's programs from enqueue to read.
+    ``reason`` says how it was enqueued: None behind an unread pass
+    (``ahead``), else why nothing was unread (``settled``)."""
+
+    __slots__ = ("reason", "prefills", "decode", "n_prefills", "n_decodes",
+                 "queue", "running", "state_slots", "emitted", "freed",
+                 "moe", "read")
+
+    def __init__(self, reason, n_prefills, n_decodes, queue, running,
+                 state_slots):
+        self.reason = reason
+        self.prefills, self.decode = [], None
+        self.n_prefills, self.n_decodes = n_prefills, n_decodes
+        self.queue, self.running = queue, running
+        self.state_slots = state_slots
+        self.emitted = self.freed = 0
+        self.moe = None
+        self.read = False
 
 
 class Engine:
@@ -591,6 +637,26 @@ class Engine:
         self._stats = StatsRecorder(clock=clock)
         self.clock = clock
         self._step_id = 0
+        # -- the step loop's look-ahead (see _step_inner) ------------------
+        # passes enqueued and not read: one between two step() calls
+        # while work is left, two inside a call between enqueueing the
+        # next and reading the oldest
+        self._flight = collections.deque()
+        self._passes = 0            # passes enqueued, lifetime
+        # why the pass the NEXT call enqueues starts behind nothing,
+        # when that was a decision (None: the engine was idle)
+        self._held = None
+        # passes by how they were enqueued: {"ahead": n, "settled":
+        # {reason: n}} (statusz "step_order")
+        self._order = {"ahead": 0, "settled": {}}
+        # tokens of passes a reader read between two calls: the next
+        # step() returns them with its own
+        self._read_emitted = 0
+        # step() against a reader on another thread (statusz handlers);
+        # _in_step keeps a reader reached from INSIDE a step (an
+        # exception's flight dump) from reading mid-step
+        self._step_lock = threading.RLock()
+        self._in_step = False
         # n>1 sample groups whose siblings wait for the primary's
         # prefill to publish the prompt's blocks (submit() appends from
         # handler threads, the step thread drains)
@@ -710,6 +776,20 @@ class Engine:
             self._state_conv = jnp.zeros(
                 (M, S, (hd.mamba_conv - 1) * hd.conv_dim), dt)
         self._key = jax.random.PRNGKey(seed)
+        # the token pool (serve/programs.py): the sampled tokens of the
+        # unread pass, on the device for the next pass's operands.  A
+        # decode pass's rows head it; behind them one row a prefill of a
+        # pass, two passes' worth, so that the pass being enqueued does
+        # not write over what its own decode has yet to take
+        self._tok_fns = {}
+        self._tok_pool = None
+        self._tok_row = 0
+        if not self.spec_k:          # positions are not foreseeable there
+            pool = np.zeros(self.max_batch + 2 * max(
+                1, self.scheduler.max_prefills_per_step), np.int32)
+            self._tok_pool = (jnp.asarray(pool) if self._shardings is None
+                              else jax.device_put(pool,
+                                                  self._shardings.rep))
         # donating the cache through each step avoids a full cache copy
         # per token; CPU PJRT can't donate (it would warn every call)
         self._donate = (jax.default_backend() != "cpu")
@@ -794,6 +874,11 @@ class Engine:
             "mxtpu_serve_evictions", "retained-block evictions (lifetime)")
         self._tel_rejected = telemetry.gauge(
             "mxtpu_serve_rejected", "rejected requests (lifetime)")
+        self._tel_passes = telemetry.counter(
+            "mxtpu_serve_passes_total",
+            "passes by how they were enqueued: behind an unread pass "
+            "(ahead), or with nothing unread and why (settled)",
+            ("order", "reason"))
         if window_group is not None:
             self._tel_group_blocks = telemetry.gauge(
                 "mxtpu_serve_kv_blocks_in_use",
@@ -807,10 +892,6 @@ class Engine:
                 "mxtpu_serve_moe_picks_total",
                 "router picks of real rows, by whether this program "
                 "holds the expert", ("held",))
-        # this step's window-group blocks freed and router counts
-        # (ops.moe.STATS), summed over its passes
-        self._window_freed = 0
-        self._moe_step = None
         if self._state_ssm is not None:
             self._tel_state_slots = telemetry.gauge(
                 "mxtpu_serve_state_slots_in_use",
@@ -1100,8 +1181,9 @@ class Engine:
         return out
 
     def step(self):
-        """One scheduler iteration: admit + prefill, then one batched
-        decode.  Returns the number of tokens emitted.
+        """Read one pass (admit + prefill, then one batched decode), with
+        the next enqueued behind it first.  Returns the number of tokens
+        that became visible since the last call.
 
         An unhandled exception dumps the flight-recorder ring to
         ``MXTPU_FLIGHT_DIR`` before propagating — the post-mortem
@@ -1112,7 +1194,12 @@ class Engine:
             # write one full post-mortem per call)
             raise RuntimeError("engine is shut down")
         try:
-            return self._step_inner()
+            with self._step_lock:
+                self._in_step = True
+                try:
+                    return self._step_inner()
+                finally:
+                    self._in_step = False
         except Exception:
             rec = flight_mod.recorder()
             rec.record("error", site="engine.step",
@@ -1144,7 +1231,8 @@ class Engine:
             pending, self._pending_fanout = self._pending_fanout, []
         keep = []
         for primary, sibs in pending:
-            if not primary.tokens and not primary.done:
+            if not (primary.tokens or primary.flight_tokens
+                    or primary.done):
                 keep.append((primary, sibs))
                 continue
             rest = []
@@ -1170,76 +1258,76 @@ class Engine:
                 self._pending_fanout = keep + self._pending_fanout
 
     @hot_path
-    def _step_inner(self):
+    def _step_inner(self, read_only=False):
+        """One call of the step loop, which runs one pass ahead of what
+        it has read.  With pass t enqueued and unread, the call
+
+        1. schedules pass t+1 from what the host can foresee (a row one
+           position on, ``Request.next_pos``; a row whose last token pass
+           t produces is not in it, ``Request.finishing``) and enqueues
+           it behind pass t, the rows' input tokens taken from pass t's
+           outputs on the device (the token pool);
+        2. blocks on pass t's outputs and does pass t's host work:
+           tokens, stamps, stats, request trace, finishes, gauges;
+        3. returns pass t's emitted count.  What the caller does next
+           runs while the device works on t+1.
+
+        With nothing unread the call first enqueues pass t itself.  Depth
+        0 is the same loop: a pass whose operands need values only the
+        host will have is enqueued with nothing unread (``_Pass.reason``
+        says why: the engine was idle, a speculative engine, a schedule
+        that must preempt, a step the perf sampler times, host-tier
+        restores, a reader from outside the loop).  ``read_only``: the
+        call of a reader, which enqueues nothing."""
         self._step_id += 1
-        # arm (or not) this step's dispatch timing — with sampling off
-        # (the default) every t0() below returns None and no dispatch
-        # gains a sync
-        self._perf.arm(self._step_id)
         # the one step instrument (telemetry/profiling.py): begin/enter/
-        # commit tile the iteration into phases; with telemetry on the
-        # same intervals are the serve.step > serve.prefill | serve.decode
-        # > serve.<phase> spans, and note() hangs the counts on them
+        # wait/commit tile the call into phases; with telemetry on the
+        # same intervals are the serve.step > serve.<phase> | (serve.
+        # prefill | serve.decode > serve.<phase>) spans, and note() hangs
+        # the counts on them
         sprof = self._sprof
         sprof.begin(self._step_id)
-        self._release_fanout()
-        prefills, decodes = self.scheduler.schedule()
-        if self._host_pool is not None:
-            # host-tier hits allocated by this schedule() queue their
-            # restores; dispatch them NOW, before the first prefill/
-            # decode program that reads the blocks
-            self._restore_pending()
-        # blocks for this iteration are all held right now — the honest
-        # high-water sample (post-drain reads would be ~0)
-        self._stats.on_utilization(self.blocks.utilization())
-        if sprof.tracing:
-            sprof.note(queue=self.scheduler.queue_depth,
-                       running=len(self.scheduler.running))
-            if self._state_ssm is not None:
-                # slots held right now, like the blocks sampled above
-                sprof.note(state_slots=self.blocks.state_slots_in_use)
-        self._window_freed, self._moe_step = 0, None
-        emitted = 0
-        for req in prefills:
-            sprof.enter("prefill_dispatch", rid=req.rid)
-            # the per-iteration prefill token budget is shared with the
-            # decode slots: each decode slot emits up to 1 + spec_k tokens
-            # this step (one, without speculative decoding), so a chunk
-            # shrinks by the batch's worst-case token count
-            emitted += self._run_prefill(
-                req, decode_slots=len(decodes) * (1 + self.spec_k))
-        if decodes:
-            bucket = _next_bucket(len(decodes), self.max_batch)
-            sprof.enter("decode_dispatch", batch=len(decodes), bucket=bucket,
-                        **(self._kv_tiles(decodes, bucket)
-                           if sprof.tracing else {}))
-            if self._spec is not None:
-                emitted += self._run_spec_decode(decodes)
-            else:
-                emitted += self._run_decode(decodes)
+        flight, done, launched = self._flight, None, False
+        if not flight and not read_only:
+            first, launched = self._launch(None), True
+            if first is not None and first.read:
+                done = first         # a speculative engine's: read already
+        if flight:
+            done = flight[0]
+            if len(flight) == 1 and not read_only:
+                hold = self._hold()
+                if hold is None:
+                    if launched:
+                        sprof.enter("schedule")
+                    self._launch(done)
+                else:
+                    self._held = hold
+            self._read(done)
         sprof.enter("callbacks")
-        if prefills or decodes:
+        emitted = done.emitted if done is not None else 0
+        n_pre, n_dec = ((done.n_prefills, done.n_decodes)
+                        if done is not None else (0, 0))
+        if done is not None:
             # scheduler decisions ride the flight ring (bounded, always
             # on) so post-mortems see the recent schedule; with the host
             # tier live its occupancy rides along (off-path records stay
             # byte-identical)
             step_fields = dict(
-                id=self._step_id, prefills=len(prefills),
-                decodes=len(decodes), queue=self.scheduler.queue_depth,
+                id=self._step_id, prefills=n_pre, decodes=n_dec,
+                queue=self.scheduler.queue_depth,
                 blocks_in_use=self.blocks.blocks_in_use)
             if self._host_pool is not None:
                 step_fields["host_kv_entries"] = len(self._host_pool)
                 step_fields["host_kv_bytes"] = self._host_pool.bytes_used
             flight_mod.recorder().record("step", **step_fields)
-        if emitted == 0 and not prefills and not decodes:
+            self._noop_steps = 0
+        elif not flight:
             self._noop_steps += 1
             if self._noop_steps > 1000 and self.scheduler.has_work():
                 raise RuntimeError(
                     "scheduler stalled: work queued but 1000 consecutive "
                     "steps scheduled nothing (cache/queue misconfigured?)")
-        else:
-            self._noop_steps = 0
-        self._stats.on_step(emitted, decode_batch=len(decodes))
+        self._stats.on_step(emitted, decode_batch=n_dec)
         self._perf.on_step(emitted)
         if self._spec is not None:
             # bound the draft ingest ledger by the LIVE running set: a
@@ -1258,30 +1346,169 @@ class Engine:
         if self._state_ssm is not None:
             self._tel_state_slots.set(self.blocks.state_slots_in_use)
         groups = None
+        freed = done.freed if done is not None else 0
         if self.blocks.window is not None:
             groups = {"global": self.blocks.blocks_in_use,
                       "window": self.blocks.window.blocks_in_use}
             for name, n in groups.items():
                 self._tel_group_blocks.labels(group=name).set(n)
-            self._tel_window_freed.inc(self._window_freed)
-        if self._moe_step is not None:
-            picks, held = int(self._moe_step[0]), int(self._moe_step[1])
+            self._tel_window_freed.inc(freed)
+        if done is not None and done.moe is not None:
+            picks, held = int(done.moe[0]), int(done.moe[1])
             self._tel_moe_picks.labels(held="yes").inc(held)
             self._tel_moe_picks.labels(held="no").inc(picks - held)
         if sprof.tracing:
-            # preemptions is the lifetime count: a reader takes differences
-            sprof.note(blocks_in_use=self.blocks.blocks_in_use,
+            # the queue and the batch as the schedule of the pass this
+            # call read left them; preemptions is the lifetime count: a
+            # reader takes differences
+            queue, running = ((done.queue, done.running)
+                              if done is not None else
+                              (self.scheduler.queue_depth,
+                               len(self.scheduler.running)))
+            sprof.note(queue=queue, running=running,
+                       blocks_in_use=self.blocks.blocks_in_use,
                        emitted=emitted,
                        preemptions=self.scheduler.preemptions,
-                       work_left=int(self.has_work()))
+                       work_left=int(self.has_work()),
+                       ahead=int(done is not None
+                                 and done.reason is None))
+            if self._state_ssm is not None:
+                # slots held as that pass was scheduled
+                sprof.note(state_slots=(
+                    done.state_slots if done is not None
+                    else self.blocks.state_slots_in_use))
             if groups is not None:
                 # what each layer group holds as the step ends, and what
-                # the window group gave back during it
+                # the window group gave back to the pass it read
                 sprof.note(blocks_global=groups["global"],
                            blocks_window=groups["window"],
-                           window_blocks_freed=self._window_freed)
-        sprof.commit(emitted, prefills=len(prefills), decodes=len(decodes))
+                           window_blocks_freed=freed)
+        sprof.commit(emitted, prefills=n_pre, decodes=n_dec)
+        emitted += self._read_emitted
+        self._read_emitted = 0
         return emitted
+
+    def _hold(self):
+        """Why the next pass cannot be enqueued behind the unread one,
+        from what the engine can observe (None: it can).  A dispatch the
+        perf sampler times blocks on its outputs and wants the device's
+        queue empty in front of it."""
+        if (self._perf.t0() is not None
+                or self._perf.samples(self._passes + 1)):
+            return "perf_sample"
+        return None
+
+    @hot_path
+    def _launch(self, behind):
+        """Schedule one pass and enqueue its programs: behind the unread
+        pass ``behind`` where the schedule can be made without reading it,
+        else after reading it here (a victim of preemption resumes from
+        every token it has, and a host-tier restore is dispatched into a
+        settled cache).  Returns the pass, or None when the schedule is
+        empty.  A speculative engine's pass is read inside its own launch
+        (a verify yields 1..k+1 tokens: nothing of the next pass is
+        foreseeable) and comes back read."""
+        sprof = self._sprof
+        self._release_fanout()
+        reason = None
+        sched = self.scheduler.schedule(preempt=behind is None)
+        if sched is None:
+            self._read(behind)
+            reason = "preempt"
+            sprof.enter("schedule")
+            sched = self.scheduler.schedule()
+        prefills, decodes = sched
+        if self._host_pool is not None:
+            # host-tier hits allocated by this schedule() queue their
+            # restores; dispatch them NOW, before the first prefill/
+            # decode program that reads the blocks
+            if (behind is not None and not behind.read
+                    and self.blocks.has_pending_restores()):
+                self._read(behind)
+                reason = "restore"
+                sprof.enter("schedule")
+            self._restore_pending()
+        # blocks for this iteration are all held right now — the honest
+        # high-water sample (post-drain reads would be ~0)
+        self._stats.on_utilization(self.blocks.utilization())
+        if not prefills and not decodes:
+            return None
+        if behind is None:
+            reason = ("spec" if self._spec is not None
+                      else self._held or "idle_start")
+        self._held = None
+        self._passes += 1
+        # arm (or not) this pass's dispatch timing — with sampling off
+        # (the default) every t0() below returns None and no dispatch
+        # gains a sync
+        self._perf.arm(self._passes)
+        p = _Pass(reason, len(prefills), len(decodes),
+                  self.scheduler.queue_depth, len(self.scheduler.running),
+                  self.blocks.state_slots_in_use
+                  if self._state_ssm is not None else 0)
+        if reason is None:
+            self._order["ahead"] += 1
+        else:
+            self._order["settled"][reason] = \
+                self._order["settled"].get(reason, 0) + 1
+        self._tel_passes.labels(
+            order="ahead" if reason is None else "settled",
+            reason=reason or "").inc()
+        for req in prefills:
+            sprof.enter("prefill_dispatch", rid=req.rid)
+            # the per-iteration prefill token budget is shared with the
+            # decode slots: each decode slot emits up to 1 + spec_k tokens
+            # this step (one, without speculative decoding), so a chunk
+            # shrinks by the batch's worst-case token count
+            p.prefills.append(self._run_prefill(
+                req, decode_slots=len(decodes) * (1 + self.spec_k)))
+        if self._spec is not None:
+            self._read(p)        # the prefills: their tokens feed the draft
+            if decodes:
+                sprof.enter("decode_dispatch")
+                p.emitted += self._run_spec_decode(decodes)
+            return p
+        if decodes:
+            sprof.enter("decode_dispatch")
+            p.decode = self._run_decode(decodes)
+        self._flight.append(p)
+        return p
+
+    @hot_path
+    def _read(self, p):
+        """Block on pass ``p``'s outputs and do its host work: from here
+        its tokens, positions, finishes and stats are what a caller
+        sees.  Part by part, so that a read that raises half way is taken
+        up where it stopped."""
+        while p.prefills:
+            p.emitted += self._read_prefill(p, p.prefills[0])
+            del p.prefills[0]
+        if p.decode is not None:
+            p.emitted += self._read_decode(p, p.decode)
+            p.decode = None
+        p.read = True
+        if self._flight and self._flight[0] is p:
+            self._flight.popleft()
+
+    def _read_flight(self):
+        """What every reader from outside the step loop does first: read
+        the passes still unread, so that tokens, cache and state pools
+        agree in what it sees.  A reader reached from inside a step, or
+        on another thread while a step runs, sees that step's snapshot
+        as it always has."""
+        if not self._flight or self._in_step or not self._alive:
+            return
+        if not self._step_lock.acquire(blocking=False):
+            return
+        try:
+            self._in_step = True
+            while self._flight:
+                self._read_emitted += self._step_inner(read_only=True)
+            if self.scheduler.has_work():
+                self._held = "reader"
+        finally:
+            self._in_step = False
+            self._step_lock.release()
 
     def _kv_tiles(self, reqs, bucket):
         """How much of the block table one layer's paged-attention call
@@ -1293,19 +1520,20 @@ class Engine:
         span, window = slots * self.block_size, self.window
         walked = 0
         for req in reqs:
-            ctx = req.cache_len + 1
+            ctx = req.next_pos() + 1
             walked += -(-ctx // span) - (max(ctx - window, 0) // span
                                          if window else 0)
         return {"kv_tiles": walked,
                 "kv_tiles_table": bucket * -(-self.table_width // slots)}
 
     def has_work(self):
-        """Whether ``step()`` still has anything to do: scheduler
-        queues/batches, OR n>1 siblings awaiting release — a step-loop
-        driver that only polled ``scheduler.has_work()`` would park
+        """Whether ``step()`` still has anything to do: a pass unread,
+        scheduler queues/batches, OR n>1 siblings awaiting release — a
+        step-loop driver that only polled ``scheduler.has_work()`` would park
         with fanout siblings still pending (the fleet replica's pump
         reads this)."""
-        return self.scheduler.has_work() or self._has_pending_fanout()
+        return (len(self._flight) > 0 or self.scheduler.has_work()
+                or self._has_pending_fanout())
 
     def run(self):
         """Pump ``step()`` until every queued request resolves."""
@@ -1325,7 +1553,9 @@ class Engine:
             self.step()
 
     def stats(self):
-        """Immutable ``ServeStats`` snapshot of the engine right now."""
+        """Immutable ``ServeStats`` snapshot of the engine right now (a
+        pass still unread is read first, as by every reader below)."""
+        self._read_flight()
         return self._stats.snapshot(self.scheduler, self.blocks)
 
     # -- SLO breach detection (flight-recorder triggers) ---------------------
@@ -1361,6 +1591,7 @@ class Engine:
         """Live engine state for the ``/statusz`` endpoint: in-flight
         requests with ages and phases, queue/cache occupancy, program
         and AOT-store state."""
+        self._read_flight()
         now = self.clock()
         reqs = []
         mid_prefill = {id(r) for r in self.scheduler.prefilling}
@@ -1398,6 +1629,10 @@ class Engine:
         return {
             "alive": self._alive,
             "steps": self._step_id,
+            # passes by how they were enqueued: behind an unread pass
+            # (ahead), or with nothing unread and why (settled)
+            "step_order": {"ahead": self._order["ahead"],
+                           "settled": dict(self._order["settled"])},
             "queue_depth": self.scheduler.queue_depth,
             "running": len(self.scheduler.running),
             "in_flight": reqs,
@@ -1567,6 +1802,7 @@ class Engine:
         handoff uses, so a truncated or corrupted pull breaks the
         chain and the suffix recomputes (degradation, never
         corruption).  Returns ``(imported, deduped, rejected)``."""
+        self._read_flight()
         return self.blocks.import_blocks(records, salt=salt)
 
     def sharding_info(self):
@@ -1592,6 +1828,7 @@ class Engine:
         ``kv_heads/tp`` of every block, so per-chip bytes (total and
         in-use) drop by the tp degree and the same per-chip HBM budget
         funds ``tp``x the blocks."""
+        self._read_flight()
         if self._cache_k is None:
             return None
         total = 2 * int(self._cache_k.nbytes)          # K and V
@@ -1621,6 +1858,7 @@ class Engine:
         """The ``/statusz`` ``state_cache`` section of a hybrid engine:
         the pool's slots (the null slot excluded), how many admitted
         requests hold one, its bytes and dtypes (None for gpt engines)."""
+        self._read_flight()
         if self._state_ssm is None:
             return None
         slots = int(self._state_ssm.shape[1]) - 1
@@ -1649,6 +1887,7 @@ class Engine:
         block over ``u`` should give ``y``: a check of what was really
         served, with nothing served for the check.  None for an engine
         without a routed block."""
+        self._read_flight()
         if self._probe is None:
             return None
         # mxtpu-lint: disable=host-sync (a check's read, outside any step)
@@ -1672,6 +1911,15 @@ class Engine:
         as-is are never touched."""
         if not self._alive:
             return
+        try:
+            # the tokens of a pass already enqueued are its requests'
+            self._read_flight()
+        except Exception:
+            # a device that fell over: its requests are cancelled below
+            flight_mod.recorder().record(
+                "error", site="engine.shutdown",
+                error=traceback.format_exc(limit=4))
+        self._flight.clear()
         for req in (list(self.scheduler.running)
                     + list(self.scheduler.prefilling)):
             self.scheduler.finish(req, status=CANCELLED)
@@ -1696,7 +1944,9 @@ class Engine:
         for arr in (self._owned + [self._cache_k, self._cache_v]
                     + ([self._scale_k, self._scale_v]
                        if self._scale_k is not None else [])
-                    + [getattr(self, a) for a in self._extra_caches]):
+                    + [getattr(self, a) for a in self._extra_caches]
+                    + ([] if self._tok_pool is None
+                       else [self._tok_pool])):
             try:
                 arr.delete()
             except (RuntimeError, ValueError):
@@ -1706,6 +1956,8 @@ class Engine:
         self._scale_k = self._scale_v = None
         self._state_ssm = self._state_conv = None
         self._cache_wk = self._cache_wv = self._probe = None
+        self._tok_pool = None
+        self._tok_fns = {}
         if self._host_pool is not None:
             # the DRAM tier releases WITH the device buffers: two
             # engines back-to-back must never transiently hold two
@@ -1819,14 +2071,13 @@ class Engine:
                                      0)
         return ((jnp.asarray(tw),) if table else ()) + (jnp.asarray(blk),)
 
-    def _note_moe(self, stats):
+    def _note_moe(self, p, stats):
         """A pass's router counts (``ops.moe.STATS``) onto its span and
-        into the step's sum."""
+        into pass ``p``'s sum."""
         # mxtpu-lint: disable=host-sync (host numpy already: the counts
         # arrived in _unpack_outs's batched read, with the tokens)
         stats = np.asarray(stats, np.int64)
-        self._moe_step = (stats if self._moe_step is None
-                          else self._moe_step + stats)
+        p.moe = stats if p.moe is None else p.moe + stats
         if self._sprof.tracing:
             self._sprof.note(**{k: int(v) for k, v in
                                 zip(moe_ops.STATS, stats)})
@@ -1847,29 +2098,66 @@ class Engine:
                      for t, v in zip(ti[j][:req.logprobs],
                                      tv[j][:req.logprobs])])
 
-    def _unpack_outs(self, outs, n_lead, anomaly, **fields):
-        """Split a program's output tuple: adopt the donated-through
-        caches, bring the ``n_lead`` host-bound outputs (sampled
-        tokens, and in sampling mode the logprob views) to the host in
-        ONE batched read, and fire the numeric-watchdog anomaly when
-        the logits-finite flag rode along false."""
+    def _adopt(self, outs, n_lead):
+        """Split a program's output tuple as it is enqueued: adopt the
+        donated-through caches (the next program's operands) and return
+        the ``n_lead`` host-bound outputs (sampled tokens, and in
+        sampling mode the logprob views), still on the device, with the
+        numeric watchdog's logits-finite flag (None without it)."""
+        ok = None
         if self._cfg.numeric_watch:
-            lead, ok = outs[:n_lead], outs[n_lead]
-            self._set_caches(outs[n_lead + 1:])
+            ok, n_caches = outs[n_lead], n_lead + 1
+        else:
+            n_caches = n_lead
+        self._set_caches(outs[n_caches:])
+        return tuple(outs[:n_lead]), ok
+
+    def _unpack_outs(self, lead, ok, anomaly, **fields):
+        """Bring a program's host-bound outputs (:meth:`_adopt`) to the
+        host in ONE batched read, and fire the numeric-watchdog anomaly
+        when the logits-finite flag rode along false."""
+        if ok is not None:
             # one batched read: the sampled tokens must reach the host
             # anyway, so the watchdog flag rides the same sync instead
             # of forcing a second one
             # mxtpu-lint: disable=host-sync (designed sync point: the
             # scheduler needs the sampled tokens on the host)
-            got = jax.device_get(tuple(lead) + (ok,))
+            got = jax.device_get(lead + (ok,))
             if not got[-1]:
                 flight_mod.record_anomaly(anomaly, step=self._step_id,
                                           **fields)
             return got[:-1]
-        self._set_caches(outs[n_lead:])
         # mxtpu-lint: disable=host-sync (designed sync point: the
         # scheduler needs the sampled tokens on the host)
-        return jax.device_get(tuple(outs[:n_lead]))
+        return jax.device_get(lead)
+
+    def _token_program(self, kind, n=0):
+        """One of the token pool's small programs (``serve/programs.py``),
+        compiled for a decode bucket of ``n`` rows (``TOK_PUT1``: one
+        prefill's token, whatever its bucket).  Made ready by ``warmup``
+        with the decode, prefill and chunk entries they serve; they are
+        no manifest kind of their own."""
+        fn = self._tok_fns.get((kind, n))
+        if fn is not None:
+            return fn
+        key = (self._spec_key(), kind, n, self._tok_pool.shape)
+        fn = _STEP_CACHE.get(key)
+        if fn is None:
+            i32 = jnp.dtype(jnp.int32)
+            rep = None if self._shardings is None else self._shardings.rep
+            sds = lambda shape: jax.ShapeDtypeStruct(shape, i32,
+                                                     sharding=rep)
+            pool = sds(self._tok_pool.shape)
+            specs = {TOK_TAKE: (pool, sds((n,)), sds((n,))),
+                     TOK_PUT: (pool, sds((n,))),
+                     TOK_PUT1: (pool, sds(()), sds(()))}[kind]
+            with telemetry.span("serve.token_program", kind=kind,
+                                bucket=int(n)):
+                fn = _build_token_program(kind, self._shardings).lower(
+                    *specs).compile()
+            _STEP_CACHE[key] = fn
+        self._tok_fns[kind, n] = fn
+        return fn
 
     def _n_lead(self):
         """Host-bound outputs of a prefill, chunk or decode program: the
@@ -1976,14 +2264,19 @@ class Engine:
 
     @hot_path
     def _run_prefill(self, req, decode_slots=0):
-        """Run one prefill pass for ``req``: the whole uncached suffix
+        """Enqueue one prefill pass for ``req``: the whole uncached suffix
         (cold path, or a prefix-cache hit's remainder), or — when the
         scheduler put it in the chunked-prefill lane — ONE budget-sized
-        chunk.  Returns the tokens emitted (1 on the pass that samples
-        the first token, 0 for an intermediate chunk)."""
+        chunk.  Returns the unread :class:`_Part`; :meth:`_read_prefill`
+        reads it.  What the NEXT schedule needs of the pass is done here,
+        from what the host knows already: the request's positions in
+        flight, its place in the decode batch after the last chunk, the
+        prompt's blocks published (their ids are the host's; the K/V is
+        in the queue ahead of any reader's program), the window group
+        trimmed."""
         ids = req.prefill_ids()
         n = int(ids.size)
-        start = int(req.cache_len)     # cached prefix + finished chunks
+        start = int(req.next_pos())    # cached prefix + earlier chunks
         resume = req.n_preemptions > 0
         chunked = self.scheduler.is_prefilling(req)
         if chunked:
@@ -2043,49 +2336,80 @@ class Engine:
                 + self._window_operands(req, start, end, bucket,
                                         table=True) \
                 + self._req_sampling_operands(req) + (sub,)
-        sprof = self._sprof
-        state = None
+        note = {}
         if self._state_ssm is not None:
             # a pass from position 0 starts the slot's state from zero
             # inside the program: at admission, and again when a
             # preempted request is prefilled anew
-            state = ("carried" if start else
-                     "reset" if resume else "fresh")
+            note["state"] = ("carried" if start else
+                             "reset" if resume else "fresh")
             if not start:
                 self._tel_state_resets.labels(
                     reason="preempt" if resume else "admit").inc()
-        if sprof.tracing:
+        if self._sprof.tracing:
             # which span attention the pass runs, and the key tiles one
             # head of it computes over those of its (rows, keys) rectangle
             attn = self._span_impl(bucket, keys)
             tiles, of = span_kv_tiles(bucket, keys, start, span,
                                       self.window, attn)
-            sprof.note(kind=pkind, tokens=span, bucket=bucket,
-                       cached=req.cached_prefix_len, attn=attn,
-                       kv_tiles=tiles, kv_tiles_table=of,
-                       **({} if state is None else {"state": state}))
+            note.update(kind=pkind, tokens=span, bucket=bucket,
+                        cached=req.cached_prefix_len, attn=attn,
+                        kv_tiles=tiles, kv_tiles_table=of)
         t0 = self._perf.t0()
         outs = fn(*args)
         self._perf.done(t0, pkind, bucket, outs)
-        sprof.enter("device_wait")
-        lead = self._unpack_outs(outs, self._n_lead(),
-                                 "prefill_logits", rid=req.rid)
-        sprof.enter("host_sync")
-        tok = lead[0]
-        if self._routed:
-            self._note_moe(lead[-1])
-            self._probe_rows["span"] = (req.rid, start)
+        lead, ok = self._adopt(outs, self._n_lead())
+        part = _Part(req, lead, ok, note, start, end, n)
+        req.flight_len += span
         if self.blocks.window is not None:
-            self._window_freed += self.blocks.window_trim(req.rid, end)
-        req.prefill_passes += 1
-        req.cache_len = end
-        self._stats.on_prefill(span)
+            part.freed = self.blocks.window_trim(req.rid, end)
         # publish the newly-FULL blocks under their chain keys so later
         # prompts (or this request's own post-preemption resume) can
         # reuse them — host-side dict work only
         # the request's adapter id salts the chain: adapter K/V is
         # content-disjoint from base (and other-adapter) K/V
         self.blocks.note_tokens(req.rid, ids[:end], salt=req.adapter_id)
+        if end >= n:
+            # the sampled token is the request's next: it joins the
+            # decode batch of the next schedule, its token on the device
+            # until this pass is read
+            req.flight_tokens += 1
+            if self._tok_pool is not None:
+                # the rows behind the decode's, in turn: two passes'
+                # worth, so this pass's prefills leave alone what the
+                # pass before put there for this pass's decode to take
+                self._tok_row = (self._tok_row + 1) % (
+                    self._tok_pool.shape[0] - self.max_batch)
+                req.flight_src = self.max_batch + self._tok_row
+                self._tok_pool = self._token_program(TOK_PUT1)(
+                    self._tok_pool, lead[0],
+                    jnp.asarray(req.flight_src, jnp.int32))
+            self.scheduler.prefill_done(req)
+            self.scheduler.admit_running(req)
+        return part
+
+    @hot_path
+    def _read_prefill(self, p, part):
+        """Read one prefill pass of pass ``p``.  Returns the tokens
+        emitted (1 on the pass that samples the first token, 0 for an
+        intermediate chunk)."""
+        req, sprof = part.reqs, self._sprof
+        sprof.wait("serve.prefill", rid=req.rid, **part.args)
+        lead = self._unpack_outs(part.lead, part.ok, "prefill_logits",
+                                 rid=req.rid)
+        sprof.enter("host_sync")
+        start, end, n = part.start, part.end, part.n
+        span = end - start
+        resume = req.n_preemptions > 0
+        tok = lead[0]
+        if self._routed:
+            self._note_moe(p, lead[-1])
+            self._probe_rows["span"] = (req.rid, start)
+        p.freed += part.freed
+        req.flight_len -= span
+        req.prefill_passes += 1
+        req.cache_len = end
+        self._stats.on_prefill(span)
         if end < n:
             # intermediate chunk: the sampled token is bogus (mid-
             # prompt) and dropped; the request stays in the prefilling
@@ -2093,10 +2417,9 @@ class Engine:
             self._rtrace.event(req, "prefill_chunk", done=int(end),
                                target=int(n), tokens=int(span))
             return 0
+        req.flight_tokens -= 1
         self._rtrace.event(req, "prefill_end", tokens=int(n - start),
                            resume=resume)
-        self.scheduler.prefill_done(req)
-        self.scheduler.admit_running(req)
         now = self.clock()
         if req.first_token_t is None:
             req.first_token_t = now
@@ -2122,43 +2445,78 @@ class Engine:
 
     @hot_path
     def _run_decode(self, reqs):
+        """Enqueue the batched decode of ``reqs``; returns the unread
+        :class:`_Part` (:meth:`_read_decode` reads it).  A row whose
+        newest token the host has not read takes it from the token pool,
+        on the device; a batch that needs none of those gets its tokens
+        from the host, through the same program."""
         B = len(reqs)
         bucket = _next_bucket(B, self.max_batch)
+        note = {}
+        if self._sprof.tracing:
+            note = dict(batch=B, bucket=bucket,
+                        **self._kv_tiles(reqs, bucket))
         toks = np.zeros(bucket, np.int32)
         pos = np.zeros(bucket, np.int32)
         tables = np.zeros((bucket, self.table_width), np.int32)
+        src = None
         for i, req in enumerate(reqs):
-            toks[i] = req.tokens[-1]
-            pos[i] = req.cache_len
+            if req.flight_tokens:
+                if src is None:
+                    src = np.full(bucket, -1, np.int32)
+                src[i] = req.flight_src
+            else:
+                toks[i] = req.tokens[-1]
+            pos[i] = req.next_pos()
             t = self.blocks.table(req.rid)
             tables[i, :len(t)] = t
+        toks = jnp.asarray(toks)
+        if src is not None:
+            toks = self._token_program(TOK_TAKE, bucket)(
+                self._tok_pool, toks, jnp.asarray(src))
         fn = self._decode_fn(bucket)
         self._key, sub = jax.random.split(self._key)
         t0 = self._perf.t0()
         outs = fn(self.params, *self._adapter_args(),
                   *self._cache_args(),
-                  jnp.asarray(toks), jnp.asarray(pos),
+                  toks, jnp.asarray(pos),
                   jnp.asarray(tables),
                   *self._batch_adapter_operands(reqs, bucket),
                   *self._batch_state_operands(reqs, bucket),
                   *self._batch_sampling_operands(reqs, bucket), sub)
         self._perf.done(t0, "decode", bucket, outs)
-        self._sprof.enter("device_wait")
-        lead = self._unpack_outs(outs, self._n_lead(),
-                                 "decode_logits", batch_size=B,
-                                 rids=[r.rid for r in reqs])
+        lead, ok = self._adopt(outs, self._n_lead())
+        part = _Part(reqs, lead, ok, note)
+        self._tok_pool = self._token_program(TOK_PUT, bucket)(
+            self._tok_pool, lead[0])
+        trim = self.blocks.window is not None
+        for i, req in enumerate(reqs):
+            req.flight_len += 1
+            req.flight_tokens += 1
+            req.flight_src = i
+            if trim:
+                part.freed += self.blocks.window_trim(req.rid,
+                                                      req.next_pos())
+        return part
+
+    @hot_path
+    def _read_decode(self, p, part):
+        reqs = part.reqs
+        B = len(reqs)
+        self._sprof.wait("serve.decode", **part.args)
+        lead = self._unpack_outs(part.lead, part.ok, "decode_logits",
+                                 batch_size=B, rids=[r.rid for r in reqs])
         self._sprof.enter("host_sync")
         out = lead[0]
         now = self.clock()
         if self._routed:
-            self._note_moe(lead[-1])
+            self._note_moe(p, lead[-1])
             self._probe_rows["decode"] = [r.rid for r in reqs]
-        trim = self.blocks.window is not None
+        p.freed += part.freed
         for i, req in enumerate(reqs):
+            req.flight_len -= 1
+            req.flight_tokens -= 1
             req.cache_len += 1
-            if trim:
-                self._window_freed += self.blocks.window_trim(
-                    req.rid, req.cache_len)
             req.tokens.append(int(out[i]))
             if self._sampling:
                 self._note_logprobs(req, lead[1][i:i + 1],
@@ -2232,6 +2590,10 @@ class Engine:
         for req in reqs:
             self._spec_ingest(req)
         bucket = _next_bucket(B, self.max_batch)
+        note = {}
+        if self._sprof.tracing:
+            note = dict(batch=B, bucket=bucket,
+                        **self._kv_tiles(reqs, bucket))
         toks = np.zeros(bucket, np.int32)
         pos = np.zeros(bucket, np.int32)
         tables = np.zeros((bucket, self.table_width), np.int32)
@@ -2263,9 +2625,9 @@ class Engine:
                       *self._batch_adapter_operands(reqs, bucket),
                       *samp, sub)
             self._perf.done(t0, "verify", bucket, outs)
-            self._sprof.enter("device_wait")
+            self._sprof.wait("serve.decode", **note)
             emit_rows, acc, lp, tv, ti = self._unpack_outs(
-                outs, 5, "verify_logits", batch_size=B,
+                *self._adopt(outs, 5), "verify_logits", batch_size=B,
                 rids=[r.rid for r in reqs])
             self._sprof.enter("host_sync")
             emitted = 0
@@ -2305,7 +2667,7 @@ class Engine:
             jp, jtab, sub)
         self._perf.done(t0, "draft", bucket, douts)
         drafted, sw.cache_k, sw.cache_v = douts
-        self._sprof.enter("device_wait")
+        self._sprof.wait("serve.decode", **note)
         # mxtpu-lint: disable=host-sync (designed sync point: the
         # drafted ids feed the verify dispatch's host-built rows)
         drafted = np.asarray(drafted)
@@ -2385,6 +2747,7 @@ class Engine:
 
     def save_manifest(self, path):
         """Write the manifest as JSONL for a later ``warmup(path)``."""
+        self._read_flight()
         with open(path, "w") as f:
             for e in self._manifest.entries():
                 f.write(json.dumps(e) + "\n")
@@ -2432,8 +2795,18 @@ class Engine:
                 for e in entries:
                     cap, bucket = caps.get(e["kind"]), int(e["bucket"])
                     if cap is not None and 1 <= bucket <= cap:
-                        self._program(e["kind"], _next_bucket(bucket, cap))
+                        bucket = _next_bucket(bucket, cap)
+                        self._program(e["kind"], bucket)
                         ready += 1
+                        # the token pool's small programs come ready with
+                        # the entries they serve (no kind of their own)
+                        if self._tok_pool is None:
+                            continue
+                        if e["kind"] == "decode":
+                            self._token_program(TOK_TAKE, bucket)
+                            self._token_program(TOK_PUT, bucket)
+                        elif e["kind"] in ("prefill", "chunk"):
+                            self._token_program(TOK_PUT1)
         finally:
             self._warming = False
         return ready

@@ -926,6 +926,12 @@ class BlockManager:
         with self._lock:
             return self._host_tokens.get(rid, 0)
 
+    def has_pending_restores(self):
+        """Whether an allocation has queued host-tier restores that the
+        engine has not dispatched yet."""
+        with self._lock:
+            return bool(self._pending_restores)
+
     def take_pending_restores(self):
         """Atomically drain the queued (block, host arrays) restores —
         the engine dispatches the host→device copies before the first

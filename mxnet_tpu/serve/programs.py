@@ -679,6 +679,40 @@ def _build_restore(cfg, donate, shardings=None):
     return jax.jit(restore, **kw)
 
 
+# -- the token pool: a sampled token from one pass to the next, on device ----
+# ``Engine`` enqueues pass t+1 before it reads pass t's tokens, so a row's
+# input token is still on the device.  Three small programs around the
+# bucket programs, which stay as they are: a decode pass's tokens go to
+# the head of a small int32 pool, a prefill pass's token to a row behind
+# it, and the next decode's token operand takes each row from the pool
+# (``src`` its row there) or from the host (``src`` -1: the host has read
+# that token already).  A decode pass whose tokens are all on the host
+# gets them as before, so ONE decode program serves both.
+TOK_TAKE, TOK_PUT, TOK_PUT1 = "tok_take", "tok_put", "tok_put1"
+
+
+def _build_token_program(kind, shardings=None):
+    """``TOK_TAKE`` ``(pool, toks, src) -> toks``, ``TOK_PUT`` ``(pool,
+    out) -> pool`` (a decode bucket's tokens to rows ``[0, bucket)``),
+    ``TOK_PUT1`` ``(pool, tok, at) -> pool`` (one prefill's token)."""
+    def tok_take(pool, toks, src):
+        return jnp.where(src >= 0, pool[jnp.maximum(src, 0)], toks)
+
+    def tok_put(pool, out):
+        return jax.lax.dynamic_update_slice(pool, out, (0,))
+
+    def tok_put1(pool, tok, at):
+        return pool.at[at].set(tok)
+
+    fn = {TOK_TAKE: tok_take, TOK_PUT: tok_put, TOK_PUT1: tok_put1}[kind]
+    kw = {}
+    if shardings is not None:
+        kw = {"in_shardings": (shardings.rep,) * (2 if kind == TOK_PUT
+                                                  else 3),
+              "out_shardings": shardings.rep}
+    return jax.jit(fn, **kw)
+
+
 def _build_chunk(cfg, C, donate, shardings=None):
     """Suffix/chunk prefill program: C token rows of ONE request whose
     earlier positions' K/V already sit in the cache (a prefix-cache hit

@@ -104,6 +104,15 @@ class Request:
         self.token_logprobs = []   # per emitted token (sampling mode)
         self.top_logprobs = []     # [[token, logprob] x logprobs] rows
         self.cache_len = 0         # K/V slots valid for this request
+        # the pass the engine has enqueued and not read yet (the step
+        # loop runs one pass ahead of its reads): the K/V positions it
+        # writes for this request and the tokens it emits (0 or 1).
+        # tokens, cache_len, status and the stamps move TOGETHER when the
+        # pass is read; what has to look ahead (the next position, the
+        # next block, who is about to finish) adds these
+        self.flight_len = 0
+        self.flight_tokens = 0
+        self.flight_src = 0        # the unread token's row in the pool
         self.cached_prefix_len = 0  # slots reused from the prefix cache
         # of cached_prefix_len, the slots restored host->device from
         # the DRAM offload tier (0 means all device-resident hits)
@@ -140,6 +149,16 @@ class Request:
     def target_len(self):
         """Total sequence length when this request completes."""
         return self.prompt.size + self.max_new_tokens
+
+    def next_pos(self):
+        """The position the next pass writes first: behind what is
+        cached and what the unread pass is writing."""
+        return self.cache_len + self.flight_len
+
+    def finishing(self):
+        """Whether the unread pass emits this request's last token: a
+        request ends by length alone, so the host knows before it reads."""
+        return len(self.tokens) + self.flight_tokens >= self.max_new_tokens
 
     def ttft(self):
         if self.first_token_t is None or self.submit_t is None:
@@ -389,11 +408,18 @@ class Scheduler:
         return bool(self.waiting or self.running or self.prefilling)
 
     # -- one iteration's decisions -------------------------------------------
-    def schedule(self):
+    def schedule(self, preempt=True):
         """Decide this iteration's work: ``(prefills, decodes)``.
 
+        With ``preempt=False`` (the engine has a pass enqueued and
+        unread): None as soon as the decode batch cannot be secured
+        without a preemption, and nobody is preempted.  A victim resumes
+        from its prompt plus EVERY token so far, and the newest is still
+        on the device: the engine reads the pass, then asks again.
+
         1. Expire overdue waiting requests (deadline -> REJECTED).
-        2. Secure the next cache slot for every running request,
+        2. Secure the next cache slot for every running request that
+           the unread pass does not finish (``Request.finishing``),
            preempting latest arrivals when blocks run out.
         3. Continue any in-flight chunked prefill: its request leads
            ``prefills`` (the engine runs ONE chunk) and owns this
@@ -427,6 +453,8 @@ class Scheduler:
             for req in list(self.running):
                 if req not in self.running:
                     continue       # preempted as an earlier victim
+                if req.finishing():
+                    continue       # its last token is on its way
                 # with speculative decoding the verify program writes
                 # up to spec_slots positions past the plain-decode one
                 # — reserve them NOW so the dispatch can never be the
@@ -436,11 +464,13 @@ class Scheduler:
                 # they never need (and must never allocate — the block
                 # table has exactly max_model_len/block_size slots)
                 # real blocks
-                need = min(req.cache_len + 1 + self.spec_slots,
+                need = min(req.next_pos() + 1 + self.spec_slots,
                            req.target_len())
                 try:
                     self.blocks.ensure_capacity(req.rid, need)
                 except NoFreeBlocks:
+                    if not preempt:
+                        return None
                     victim = self._pick_victim(req)
                     self.preempt(victim)
                     if victim is not req:

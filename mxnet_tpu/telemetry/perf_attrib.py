@@ -182,11 +182,15 @@ class PerfAttrib:
         """Called once at the top of every engine step: decides whether
         THIS step's dispatches are timed (every ``sample_every``-th
         step).  Never armed when sampling is off (the default)."""
-        if self.sample_every > 0 and step_id % self.sample_every == 0:
-            self._armed = True
+        self._armed = self.samples(step_id)
+        if self._armed:
             self._step_s = 0.0
-        else:
-            self._armed = False
+
+    def samples(self, step_id):
+        """Whether ``arm(step_id)`` would time that step's dispatches: a
+        timed dispatch blocks on its outputs, so the engine enqueues
+        nothing behind an unread pass for it."""
+        return self.sample_every > 0 and step_id % self.sample_every == 0
 
     def t0(self):
         """Dispatch-start stamp: a clock read when this step is armed,

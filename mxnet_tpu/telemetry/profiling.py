@@ -14,33 +14,48 @@ entry.  Every instant between begin and commit lies in exactly one
 phase, so the per-step phase seconds sum to the step wall time by
 construction (pinned in tests/test_profiling.py).  ``enter`` comes
 BEFORE the work it names — a trace annotation cannot be named after
-the fact.  Phases:
+the fact.
+
+**A step is one call of ``Engine.step()``, and the engine runs one pass
+ahead of what it has read** (``serve/engine.py``, "The step loop"): a
+call enqueues the NEXT pass's programs and only then blocks on the pass
+the call before enqueued.  The phases say what the host did in the call,
+whichever pass it did it for:
 
   schedule          admission fanout + scheduler.schedule() +
-                    host-KV restore dispatch + utilization sampling
+                    host-KV restore dispatch + utilization sampling,
+                    for the pass this call enqueues
   prefill_dispatch  host operand build + async prefill/chunk dispatch
+                    of the pass this call enqueues
   decode_dispatch   host operand build + async decode/draft/verify
-                    dispatch (spec ingest rides here too)
-  device_wait       time blocked on device results (the designed
+                    dispatch (spec ingest rides here too), likewise
+  device_wait       time blocked on the results of the pass this call
+                    READS: the one enqueued by the call before, with
+                    the next already queued behind it (the designed
                     ``_unpack_outs`` sync, plus the greedy-spec
                     drafted/verified syncs)
-  host_sync         post-sync host bookkeeping: token append, radix/
-                    scheduler updates, request-trace events
+  host_sync         host bookkeeping of the pass just read: token
+                    append, scheduler updates, request-trace events
   callbacks         step tail: flight record, stats/perf callbacks,
                     spec-window prune, telemetry gauges
 
 **The same intervals as spans.**  With telemetry enabled every step is
 also a ``SpanTracer`` span ``serve.step`` (args ``step``, and through
-``note()`` the engine's counts), every phase interval a span
-``serve.<phase>``, and the passes they belong to the spans between:
-``prefill_dispatch`` opens a ``serve.prefill`` (one per prefill pass),
-``decode_dispatch`` a ``serve.decode`` (one per step), the phases that
-follow are their children, ``schedule`` and ``callbacks`` are children
-of ``serve.step``.  All are stamped from the SAME clock reads as the
-phase seconds (so the phase spans tile their step exactly) and enter a
-``jax.profiler.TraceAnnotation`` as they open, so a device trace names
-an idle gap by the phase the host was in.  There is no second
-instrument: the engine makes one call per interval.
+``note()`` the counts of the pass the step read), every phase interval a
+span ``serve.<phase>``.  ``schedule``, the two dispatch phases and
+``callbacks`` are children of ``serve.step``.  ``wait(name, ...)`` opens
+the span of the pass being read, ``serve.prefill`` (one per prefill
+pass) or ``serve.decode`` (one per step), with the counts the engine
+kept from its dispatch, and the pass's ``device_wait`` and ``host_sync``
+are its children: a pass's span is the host's wait for it and its
+bookkeeping, NOT its dispatch, which happened a call earlier beside
+another pass's wait.  (A speculative engine reads a pass inside the call
+that enqueues it; its verify dispatch, between the draft's wait and its
+own, stays inside the ``serve.decode`` that is open.)  All are stamped
+from the SAME clock reads as the phase seconds (so the phase spans tile
+their step exactly) and enter a ``jax.profiler.TraceAnnotation`` as they
+open, so a device trace names an idle gap by the phase the host was in.
+There is no second instrument: the engine makes one call per interval.
 
 **Cost.**  An ``enter`` is one ``perf_counter`` read and a dict add —
 the recorder is default ON (``MXTPU_STEP_PROFILE=0`` to disable, spans
@@ -83,12 +98,11 @@ PHASE_SECONDS_BUCKETS = (1e-6, 2.5e-6, 5e-6, 1e-5, 2.5e-5, 5e-5,
                          1e-4, 2.5e-4, 5e-4, 1e-3, 2.5e-3, 5e-3,
                          1e-2, 2.5e-2, 5e-2, 0.1, 0.25)
 
-# a dispatch phase opens the span of the pass it belongs to; schedule
-# and callbacks sit directly under serve.step; device_wait and host_sync
-# stay inside the pass that is open
-_OPENS_PASS = {"prefill_dispatch": "serve.prefill",
-               "decode_dispatch": "serve.decode"}
-_STEP_PHASES = ("schedule", "callbacks")
+# device_wait and host_sync stay inside the span of the pass being read
+# (``wait`` opens it); every other phase sits directly under serve.step
+# and closes the pass that is open, but for a speculative engine's
+# second dispatch of the decode it is reading
+_PASS_PHASES = ("device_wait", "host_sync")
 
 _STATUSZ_RECENT = 50     # ring tail carried on statusz / flight dumps
 
@@ -107,6 +121,9 @@ class _NoopStepProfiler:
         pass
 
     def enter(self, phase, **args):
+        pass
+
+    def wait(self, name, **args):
         pass
 
     def note(self, **args):
@@ -178,33 +195,44 @@ class StepProfiler:
             self._spans = [tr.span("serve.step", step=step_id).start(now),
                            tr.span("serve.schedule").start(now)]
 
-    def enter(self, phase, **args):
-        """Close the open phase and open ``phase`` at one clock read.
-        ``args`` go to the pass span when this phase opens one
-        (``serve.prefill`` / ``serve.decode``), else to the phase's."""
+    def _turn(self, phase):
+        """Close the open phase's seconds and open ``phase``'s, at one
+        clock read, which it returns."""
         now = self._clock()
         cur = self._cur
         cur[self._phase] = cur.get(self._phase, 0.0) + (now - self._t_cursor)
         self._t_cursor = now
         self._phase = phase
+        return now
+
+    def enter(self, phase, **args):
+        """Close the open phase and open ``phase`` at one clock read;
+        ``args`` go to the phase's span."""
+        now = self._turn(phase)
+        if not self.tracing:
+            return
+        spans = self._spans
+        inside = len(spans) == 3 and (
+            phase in _PASS_PHASES or (phase == "decode_dispatch"
+                                      and spans[1].name == "serve.decode"))
+        self._close(2 if inside else 1, now)
+        spans.append(tel.tracer().span("serve." + phase, **args).start(now))
+
+    def wait(self, name, **args):
+        """Open the span of the pass the engine is about to read
+        (``serve.prefill`` / ``serve.decode``, ``args`` the counts kept
+        from its dispatch) and its ``device_wait``, at one clock read."""
+        now = self._turn("device_wait")
         if not self.tracing:
             return
         spans, tr = self._spans, tel.tracer()
-        opens = _OPENS_PASS.get(phase)
-        if opens == "serve.decode" and len(spans) == 3 \
-                and spans[1].name == opens:
-            opens = None          # a later dispatch of the same decode
-        # a new pass and a step-level phase close the pass that is open
-        self._close(1 if opens or phase in _STEP_PHASES else len(spans) - 1,
-                    now)
-        if opens:
-            spans.append(tr.span(opens, **args).start(now))
-            args = {}
-        spans.append(tr.span("serve." + phase, **args).start(now))
+        self._close(1, now)
+        spans.append(tr.span(name, **args).start(now))
+        spans.append(tr.span("serve.device_wait").start(now))
 
     def note(self, **args):
-        """Counts for the innermost pass span open (``serve.prefill`` /
-        ``serve.decode``), or for ``serve.step`` between passes.  Call
+        """Counts for the span of the pass being read (``serve.prefill``
+        / ``serve.decode``), or for ``serve.step`` outside one.  Call
         under ``if sprof.tracing`` where building the args costs."""
         if self.tracing:
             self._spans[-2].set(**args)
@@ -219,10 +247,8 @@ class StepProfiler:
     def commit(self, emitted=0, prefills=0, decodes=0):
         """Seal the in-flight step: the open phase ends here, the entry
         enters the ring, totals/histograms update."""
-        now = self._clock()
+        now = self._turn(self._phase)
         cur = self._cur
-        cur[self._phase] = cur.get(self._phase, 0.0) + (now - self._t_cursor)
-        self._t_cursor = now
         if self.tracing:
             self._close(0, now)
         wall = now - self._t_begin

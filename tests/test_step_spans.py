@@ -162,11 +162,14 @@ def test_every_step_span_has_the_right_parent(tel, model):
     assert len(by_id) == len(spans)
     steps = _named(spans, "serve.step")
     assert steps and all(s[PARENT] is None for s in steps)
+    # the loop runs one pass ahead: a call dispatches the next pass
+    # (phases under the step) and then reads the one before, whose span
+    # holds its wait and its bookkeeping
     want = {"serve.schedule": {"serve.step"},
             "serve.callbacks": {"serve.step"},
             "serve.prefill": {"serve.step"}, "serve.decode": {"serve.step"},
-            "serve.prefill_dispatch": {"serve.prefill"},
-            "serve.decode_dispatch": {"serve.decode"},
+            "serve.prefill_dispatch": {"serve.step"},
+            "serve.decode_dispatch": {"serve.step"},
             "serve.device_wait": {"serve.prefill", "serve.decode"},
             "serve.host_sync": {"serve.prefill", "serve.decode"}}
     seen = set()
@@ -175,14 +178,16 @@ def test_every_step_span_has_the_right_parent(tel, model):
             assert by_id[s[PARENT]][NAME] in want[s[NAME]], s
             seen.add(s[NAME])
     assert seen == set(want)
-    # the second step prefilled one request and decoded the other: its
-    # children in the order they ran
+    # the second step dispatched the decode of both requests, then read
+    # the pass that prefilled one and decoded the other: its children in
+    # the order they ran
     assert [c[NAME] for c in _children(spans, steps[1])] \
-        == ["serve.schedule", "serve.prefill", "serve.decode",
-            "serve.callbacks"]
+        == ["serve.schedule", "serve.decode_dispatch", "serve.prefill",
+            "serve.decode", "serve.callbacks"]
+    assert steps[0][ARGS]["ahead"] == 0 and steps[1][ARGS]["ahead"] == 1
     passes = _named(spans, "serve.prefill") + _named(spans, "serve.decode")
     for p in passes:
-        assert [c[NAME] for c in _children(spans, p)][-2:] \
+        assert [c[NAME] for c in _children(spans, p)] \
             == ["serve.device_wait", "serve.host_sync"]
 
 
@@ -228,8 +233,9 @@ def test_phase_spans_tile_the_step_and_equal_the_profile(tel, model):
 
 def test_spec_decode_keeps_both_dispatches_in_one_decode(tel, model):
     """Greedy speculative decoding dispatches twice per step (draft,
-    then verify, each with its wait): one ``serve.decode``, five phase
-    spans that still tile it."""
+    then verify, each with its wait) and reads the pass in the call that
+    enqueues it: one ``serve.decode`` from the draft's wait on, the
+    verify's dispatch inside it, four phase spans that tile it."""
     net, params = model
     draft = {k: v for k, v in params.items() if not k.startswith("gpt_l1_")}
     eng = _engine(model, spec_k=2, draft_params=draft, draft_num_heads=4,
@@ -243,7 +249,7 @@ def test_spec_decode_keeps_both_dispatches_in_one_decode(tel, model):
     for d in decodes:
         kids = _children(spans, d)
         assert [k[NAME] for k in kids if k[NAME] in PHASE_SPANS] \
-            == ["serve.decode_dispatch", "serve.device_wait",
+            == ["serve.device_wait",
                 "serve.decode_dispatch", "serve.device_wait",
                 "serve.host_sync"]
         assert kids[0][START] == pytest.approx(d[START])
@@ -253,6 +259,7 @@ def test_spec_decode_keeps_both_dispatches_in_one_decode(tel, model):
     ingests = _named(spans, "serve.spec_ingest")
     assert ingests and all(
         by_id[i[PARENT]][NAME] == "serve.decode_dispatch" for i in ingests)
+    assert all(s[ARGS]["ahead"] == 0 for s in _named(spans, "serve.step"))
 
 
 # -- the step: counts where the work happens ------------------------------------------
@@ -270,7 +277,8 @@ def test_step_args_count_the_work(tel, model):
         == list(range(first_id, first_id + len(steps)))
     for s in steps:
         assert set(s[ARGS]) == {"step", "queue", "running", "blocks_in_use",
-                                "emitted", "preemptions", "work_left"}
+                                "emitted", "preemptions", "work_left",
+                                "ahead"}
         assert all(type(v) is int for v in s[ARGS].values())
     assert sum(s[ARGS]["emitted"] for s in steps) \
         == sum(len(r.tokens) for r in reqs) == 12

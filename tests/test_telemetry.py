@@ -140,7 +140,8 @@ def test_disabled_serve_step_allocates_no_span_and_bounds_clock_reads(
     """The untraced step's bound: no span object, no annotation, and the
     step instrument reads its clock once per phase interval — begin,
     callbacks and commit (3 a step) plus dispatch, wait and sync (3)
-    for each prefill pass and for the decode; the engine's own clock
+    for each prefill pass and for the decode (the dispatch a call before
+    the wait: the loop runs one pass ahead); the engine's own clock
     (request stamps and ``StatsRecorder``) once per schedule(), per
     ``on_step``, per admission, per first token, per decode and per
     finish — the admission stamp is the one read this record added."""
@@ -185,11 +186,18 @@ def test_disabled_serve_step_allocates_no_span_and_bounds_clock_reads(
         first_tokens += entry["prefills"]
         decodes += bool(entry["decodes"])
         busy = entry["prefills"] + bool(entry["decodes"])
-        assert reads["prof"] - before["prof"] == 3 + 3 * busy
-    assert reads["prof"] == 3 * steps + 3 * (passes + decodes)
+        # a call dispatches the NEXT pass and waits for and syncs the one
+        # it reads (the entry's): the same count once the loop is under
+        # way, so the bound is on the run, not on each call
+        assert reads["prof"] - before["prof"] <= 3 + 3 * (busy + 1) + 1
+    # ... and one more read where the loop starts from idle: that call
+    # schedules (and dispatches) twice, the pass it reads and the next
+    restarts = 1
+    assert reads["prof"] == 3 * steps + 3 * (passes + decodes) + restarts
     finishes = 2
     assert reads["req"] - submits \
-        == 2 * steps + admissions + first_tokens + decodes + finishes
+        == 2 * steps + restarts + admissions + first_tokens + decodes \
+        + finishes
     assert telemetry.tracer().spans() == []
     eng.shutdown()
 
