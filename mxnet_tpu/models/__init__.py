@@ -12,9 +12,10 @@ from .transformer import gpt
 from .generate import gpt_decode_config, gpt_generate
 from .hybrid import hybrid_decoder
 from .moe import moe_decoder
+from .branch import branch_decoder
 
 __all__ = ["lenet", "mlp", "resnet", "lstm_unroll", "lstm_cell",
            "LSTMState", "LSTMParam", "ssd",
            "inception_bn", "inception_bn_small", "googlenet", "vgg", "alexnet",
            "gpt", "gpt_generate", "gpt_decode_config", "hybrid_decoder",
-           "moe_decoder"]
+           "moe_decoder", "branch_decoder"]

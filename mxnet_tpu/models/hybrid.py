@@ -69,10 +69,12 @@ class HybridDecoder(collections.namedtuple("HybridDecoder", _FIELDS)):
 
     # what serve/hybrid.py and the engine read of ANY description: the head
     # is the embedding, every layer's feed-forward is dense, no layer has a
-    # window, and a position's K/V heads lie side by side in the cache
+    # window, a position's K/V heads lie side by side in the cache, and
+    # the state-space heads share one group's B and C
     tied = True
     window_layers = ()
     kv_flat = True
+    mamba_groups = 1
 
     @property
     def ffn_types(self):
@@ -260,9 +262,12 @@ def _ref_attention(dec, P, p, h):
 
 
 def _ref_mamba(dec, P, p, h):
+    """A Mamba-2 mixer of ``dec.mamba_groups`` state groups (head ``h``
+    reads the ``B`` and ``C`` rows of group ``h // (H / G)`` and the gated
+    norm takes its RMS over each group's channels; one group: all)."""
     T = h.shape[0]
-    H, Pd, N, K = (dec.mamba_heads, dec.mamba_head_dim, dec.mamba_state,
-                   dec.mamba_conv)
+    H, Pd, N, K, G = (dec.mamba_heads, dec.mamba_head_dim, dec.mamba_state,
+                      dec.mamba_conv, dec.mamba_groups)
     di, cd = dec.d_inner, dec.conv_dim
     zxd = h @ P[f"{p}_in_proj_weight"].T
     z, xBC, dt = zxd[:, :di], zxd[:, di:di + cd], zxd[:, di + cd:]
@@ -273,7 +278,9 @@ def _ref_mamba(dec, P, p, h):
         conv = conv + pad[j:j + T] * w[None, :, j]
     xBC = _silu(conv)
     x = xBC[:, :di].reshape(T, H, Pd)
-    Bm, Cm = xBC[:, di:di + N], xBC[:, di + N:]
+    # every head its own group's rows, (T, H, N)
+    Bm = jnp.repeat(xBC[:, di:di + G * N].reshape(T, G, N), H // G, axis=1)
+    Cm = jnp.repeat(xBC[:, di + G * N:].reshape(T, G, N), H // G, axis=1)
     dt = jax.nn.softplus(dt + P[f"{p}_dt_bias"][None, :])         # (T, H)
     a = jnp.exp(-jnp.exp(P[f"{p}_A_log"])[None, :] * dt)
     D = P[f"{p}_D"]
@@ -281,16 +288,16 @@ def _ref_mamba(dec, P, p, h):
     def step(S, inp):
         x_t, B_t, C_t, dt_t, a_t = inp
         S = (a_t[:, None, None] * S
-             + (dt_t[:, None] * x_t)[:, :, None] * B_t[None, None, :])
-        y = jnp.sum(S * C_t[None, None, :], axis=-1) + D[:, None] * x_t
+             + (dt_t[:, None] * x_t)[:, :, None] * B_t[:, None, :])
+        y = jnp.sum(S * C_t[:, None, :], axis=-1) + D[:, None] * x_t
         return S, y
 
     # the recurrence as written, one position at a time
     _, y = jax.lax.scan(step, jnp.zeros((H, Pd, N), jnp.float32),
                         (x, Bm, Cm, dt, a))
-    y = y.reshape(T, di) * _silu(z)
-    y = _rms(y, P[f"{p}_norm_gamma"], dec.eps)
-    return y @ P[f"{p}_out_proj_weight"].T
+    y = (y.reshape(T, di) * _silu(z)).reshape(T, G, di // G)
+    y = _rms(y, P[f"{p}_norm_gamma"].reshape(G, di // G), dec.eps)
+    return y.reshape(T, di) @ P[f"{p}_out_proj_weight"].T
 
 
 def reference_logits(dec, params, tokens):

@@ -132,6 +132,10 @@ class MoEDecoder(collections.namedtuple("MoEDecoder", _FIELDS)):
     tied = False
     mamba_layers = ()
     kv_flat = False
+    # its routed blocks: a softmax router, SwiGLU experts in the model width
+    router_score = "softmax"
+    expert_act = "swiglu"
+    latent = 0
 
     @property
     def num_layers(self):

@@ -583,9 +583,10 @@ def paged_attention(q, k_cache, v_cache, block_tables, context_lens,
         layer's whole pool (serve/programs.py passes its stack as is).
       flat_heads: the caches are FLAT in their minor axis, ``(L,
         num_blocks, block_size, flat_heads * Dh)``, every kv head of a
-        position side by side (serve/hybrid.py's layout for heads
-        smaller than the 128 lanes: a ``(8, 64)`` bfloat16 tile is
-        padded fourfold on the chip, side by side nothing is).  Takes
+        position side by side (serve/hybrid.py's layout where ``(Hkv,
+        Dh)`` does not fill a tile: an ``(8, 64)`` bfloat16 tile is
+        padded fourfold on the chip, a ``(2, 128)`` one eightfold, side
+        by side nothing is).  Takes
         ``layer``; no window, int8 scales or mesh.
 
     Returns (B, Hq, Dh) attention output in q's dtype.
@@ -674,9 +675,9 @@ def paged_attention(q, k_cache, v_cache, block_tables, context_lens,
 
 def packed_eligible(kv_heads, head_dim):
     """Whether the packed kernel can serve a flat cache of this geometry:
-    whole kv heads fill the 128 lanes (head size 64: two, 32: four) and
-    the kv heads divide into such groups."""
-    return (head_dim < 128 and 128 % head_dim == 0
+    whole kv heads fill the 128 lanes (head size 128: one, 64: two, 32:
+    four) and the kv heads divide into such groups."""
+    return (head_dim <= 128 and 128 % head_dim == 0
             and kv_heads % (128 // head_dim) == 0)
 
 

@@ -9,8 +9,9 @@ all ``E``, computes its own experts' part and leaves the rest to the
 other shares.  Nothing is ever dropped: there is no capacity factor, a
 busy expert simply gets more rows.
 
-- :func:`route`: float32 softmax over all experts, the ``k`` largest,
-  their weights divided by their sum.
+- :func:`route`: float32 scores over all experts (a softmax, or
+  sigmoids picked with a selection bias), the ``k`` largest, their
+  weights divided by their sum.
 - :func:`dispatch`: the picks of held experts sorted by expert, and the
   rows each held expert got (its *group*); picks of absent experts and of
   a bucket's padding rows sort behind every group and belong to none.
@@ -19,7 +20,8 @@ busy expert simply gets more rows.
   Mosaic grouped-matmul kernel that ships with jax (``megablox.gmm``:
   its grid visits only the row tiles that hold rows and reads only the
   experts that got any), elsewhere ``lax.ragged_dot``.
-- :func:`routed_experts`: gather, up-gate product, SwiGLU, down product,
+- :func:`routed_experts`: gather, up(-gate) product, the expert's
+  activation (SwiGLU, or an ungated squared ReLU), down product,
   weighted sum back onto the rows; all device work under the name scope
   :data:`SCOPE`, whatever implements it.  A program that holds a small
   share of the experts works on the front of the sorted picks where the
@@ -38,7 +40,7 @@ import numpy as np
 from . import pallas_util
 
 __all__ = ["route", "dispatch", "grouped_matmul", "routed_experts",
-           "short_path", "resolve_moe_impl", "SCOPE", "STATS"]
+           "short_path", "resolve_moe_impl", "relu2", "SCOPE", "STATS"]
 
 # named scope of the experts' device work: device-trace operation names
 # carry it
@@ -55,7 +57,8 @@ STATS = ("moe_picks", "moe_picks_held", "moe_load_max", "moe_experts_hit",
 # 80), and a tile that visits a group is computed whole, so a tall tile is
 # mostly padding (at 512 rows a span's products ran at a sixth of their
 # time at 128; my chip run, PR 34).  K, N: an expert's matrix in few, large
-# pieces, both passes being bound by reading it.
+# pieces, both passes being bound by reading it: at most this wide, and
+# whole lane groups that divide the axis where there are such (:func:`_tile`)
 _TILE_M = 128
 _TILE_K = 1024
 _TILE_N = 1024
@@ -80,12 +83,25 @@ def resolve_moe_impl(impl=None):
     return impl
 
 
-def route(logits, top_k):
-    """``logits (T, E)`` -> ``(idx (T, k) int32, w (T, k) float32)``: the
-    ``k`` most probable experts of each row under a float32 softmax over
-    ALL experts, their probabilities divided by their sum."""
-    pr = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    w, idx = jax.lax.top_k(pr, top_k)
+def route(logits, top_k, score="softmax", bias=None):
+    """``logits (T, E)`` -> ``(idx (T, k) int32, w (T, k) float32)``: each
+    row's ``k`` best experts over ALL experts and their scores divided by
+    their sum, everything float32.  ``score="softmax"``: the ``k`` most
+    probable under a softmax.  ``score="sigmoid"``: each expert's score
+    is the sigmoid of its logit, and the ``k`` largest of ``score +
+    bias`` are picked (``bias (E,)``, the selection bias: it moves
+    picks, never a weight)."""
+    if score == "softmax":
+        pr = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+        w, idx = jax.lax.top_k(pr, top_k)
+    elif score == "sigmoid":
+        pr = jax.nn.sigmoid(logits.astype(jnp.float32))
+        _, idx = jax.lax.top_k(
+            pr if bias is None else pr + bias.astype(jnp.float32), top_k)
+        w = jnp.take_along_axis(pr, idx, axis=-1)
+    else:
+        raise ValueError(f"moe: score must be softmax|sigmoid "
+                         f"(got {score!r})")
     return idx.astype(jnp.int32), w / jnp.sum(w, axis=-1, keepdims=True)
 
 
@@ -106,6 +122,16 @@ def dispatch(idx, offset, count, valid=None):
     sizes = jnp.sum(key[:, None] == jnp.arange(count, dtype=jnp.int32),
                     axis=0, dtype=jnp.int32)
     return order, sizes, held
+
+
+def _tile(n, cap):
+    """The widest tile of ``n`` columns: ``n`` itself under ``cap``, else
+    the largest multiple of 128 up to ``cap`` that divides ``n`` (2688 =
+    3 x 896: no ragged last tile), else ``cap``."""
+    if n <= cap:
+        return n
+    return next((t for t in range(cap - cap % 128, 0, -128) if n % t == 0),
+                cap)
 
 
 def grouped_matmul(x, w, sizes, impl=None, interpret=None):
@@ -133,7 +159,7 @@ def grouped_matmul(x, w, sizes, impl=None, interpret=None):
     with jax.enable_x64(False):
         out = gmm(x, w.astype(x.dtype), sizes,
                   preferred_element_type=x.dtype,
-                  tiling=(tm, min(_TILE_K, K), min(_TILE_N, N)),
+                  tiling=(tm, _tile(K, _TILE_K), _tile(N, _TILE_N)),
                   interpret=bool(interpret))
     return out[:M] if pad else out
 
@@ -153,19 +179,31 @@ def _silu_f32(x):
     return xf * jax.nn.sigmoid(xf)
 
 
+def relu2(x):
+    """``relu(x)^2``, squared in float32, in x's dtype."""
+    r = jnp.maximum(x.astype(jnp.float32), np.float32(0.0))
+    return (r * r).astype(x.dtype)
+
+
 # jitted so that the routed layers of a program share ONE trace
-@functools.partial(jax.jit, static_argnames=("offset", "num_experts", "impl"))
+@functools.partial(jax.jit, static_argnames=("offset", "num_experts", "impl",
+                                             "act"))
 def routed_experts(x, w_in, w_out, idx, w, offset, num_experts, valid=None,
-                   impl=None):
+                   impl=None, act="swiglu"):
     """The held experts' part of a routed layer over rows ``x (T, D)``.
 
     ``w_in (count, D, 2 F)`` (gate columns first) and ``w_out (count, F,
-    D)`` are the held experts' SwiGLU matrices, ``idx``/``w (T, k)`` what
+    D)`` are the held experts' SwiGLU matrices (``act="swiglu"``), or
+    ``w_in (count, D, F)`` those of ungated experts ``W_out relu(W_in
+    x)^2`` (``act="relu2"``); ``D`` is whatever width the experts work
+    in (the model's, or a latent).  ``idx``/``w (T, k)`` are what
     :func:`route` returned, ``offset`` the first held expert's id,
     ``num_experts`` how many the router scores.
     Returns ``(y (T, D) float32, stats int32)``: ``y[t] = sum over
     row t's picks of held experts of w * E(x[t])`` (zero for a row with
     none, and for rows ``valid`` marks as padding), and :data:`STATS`."""
+    if act not in ("swiglu", "relu2"):
+        raise ValueError(f"moe: act must be swiglu|relu2 (got {act!r})")
     T, k = idx.shape
     count, D, F2 = w_in.shape
     with jax.named_scope(SCOPE):
@@ -179,9 +217,12 @@ def routed_experts(x, w_in, w_out, idx, w, offset, num_experts, valid=None,
             back onto the rows."""
             xs = x[order[:m] // k]                             # (m, D)
             gu = grouped_matmul(xs, w_in, sizes, impl)
-            act = (_silu_f32(gu[:, :F2 // 2]).astype(gu.dtype)
-                   * gu[:, F2 // 2:])
-            ys = grouped_matmul(act, w_out, sizes, impl)       # (m, D)
+            if act == "swiglu":
+                hid = (_silu_f32(gu[:, :F2 // 2]).astype(gu.dtype)
+                       * gu[:, F2 // 2:])
+            else:
+                hid = relu2(gu)
+            ys = grouped_matmul(hid, w_out, sizes, impl)       # (m, D)
             y = jnp.zeros((T, D), jnp.float32)
             for j in range(k):
                 # a pick of no group reads garbage and multiplies it by

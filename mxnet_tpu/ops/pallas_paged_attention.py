@@ -37,10 +37,11 @@ Hkv, Dh)`` operand and all query heads score it at once, ``(Hq, Dh) x
 (Dh, T * block_size * Hkv)``; an additive mask keeps each query head's
 own kv head.  Reading one head out of the tile instead picks a sublane
 of every position's ``(Hkv, Dh)`` tile and was what the kernel's time
-went to (0.72 us a 16-token block; PERF.md, PR 30).  Heads under 128
-over a flat cache (``paged_attention_packed_kernel``): the lanes hold
-``128 / Dh`` kv heads side by side and the queries are block-diagonal,
-one ``(rows, 128) x (128, T * block_size)`` product per lane group.
+went to (0.72 us a 16-token block; PERF.md, PR 30).  A FLAT cache
+(``paged_attention_packed_kernel``): the lanes hold ``128 / Dh`` kv
+heads side by side and the queries are block-diagonal, one ``(rows,
+128) x (128, T * block_size)`` product per lane group (heads of 128: a
+lane group is one kv head and its rows that head's query heads).
 With int8 KV blocks (``k_scale``/``v_scale`` per-slot-per-head f32
 scales) the scales ride the same tile copies and the dequantize happens
 in VMEM, so the HBM read is the int8 bytes.
@@ -415,7 +416,8 @@ def paged_attention_packed_kernel(q, k_cache, v_cache, block_tables,
                                   context_lens, layer, scale=None,
                                   interpret=None):
     """Paged decode attention over a FLAT stacked cache ``(L, num_blocks,
-    block_size, Hkv * Dh)`` for heads smaller than the 128 lanes.
+    block_size, Hkv * Dh)``: heads smaller than the 128 lanes, or few
+    heads of 128 (two of them pad a ``(16, 128)`` tile eightfold).
 
     A cache whose two minor axes are ``(Hkv, Dh) = (8, 64)`` is tiled
     (16, 128) in bfloat16 on the chip: padded fourfold, in HBM and in

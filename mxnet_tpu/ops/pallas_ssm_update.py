@@ -19,6 +19,11 @@ lanes.  ``dt * x`` arrives transposed, (P, heads), so that a head's
 column broadcasts along the lanes with no relayout, and ``y`` leaves
 the same way; the caller transposes both (a few KB).
 
+``B`` and ``C`` belong to state GROUPS (head ``h`` reads group ``h //
+(H / G)``).  A grid step takes the heads of whole groups, or a part of
+one group's, and its block of the two vectors holds exactly the groups
+its heads read, so inside the kernel a head's group is a static row.
+
 Padded rows of a bucket name the null slot 0, whose contents are
 garbage by design.
 """
@@ -49,17 +54,20 @@ HEADS_PER_STEP = 64
 
 
 def _kernel(slots_ref, pool_ref, xdt_ref, dec_ref, b_ref, c_ref,
-            y_ref, pool_out_ref, *, hb):
+            y_ref, pool_out_ref, *, hb, gb):
     del slots_ref                       # consumed by the index maps
-    Bv = b_ref[0]                       # (1, N)
-    Cv = c_ref[0]
+    # the step's groups, (1, N) each; its heads divide evenly among them
+    Bv = [b_ref[0, j:j + 1] for j in range(gb)]
+    Cv = [c_ref[0, j:j + 1] for j in range(gb)]
     for h in range(hb):
+        j = h * gb // hb
         S = pool_ref[0, 0, h]           # (P, N)
         col = xdt_ref[0, 0, :, h:h + 1]         # (P, 1)
         a = dec_ref[0, 0, :, h:h + 1]           # (1, 1)
-        S = a * S + col * Bv
+        S = a * S + col * Bv[j]
         pool_out_ref[0, 0, h] = S
-        y_ref[0, 0, :, h:h + 1] = jnp.sum(S * Cv, axis=-1, keepdims=True)
+        y_ref[0, 0, :, h:h + 1] = jnp.sum(S * Cv[j], axis=-1,
+                                          keepdims=True)
 
 
 @hot_path
@@ -67,8 +75,8 @@ def ssm_update_kernel(pool, layer, slots, x, dt, dA, Bm, Cm, D,
                       heads_per_step=None, interpret=None):
     """Same contract as ``ops.ssm.ssm_state_update``: pool (L, S, H, P,
     N) float32 with the static ``layer``; slots (B,) int32; x (B, H,
-    P); dt, dA (B, H); Bm, Cm (B, N); D (H,).  Returns ``(y (B, H, P)
-    in x's dtype, the pool)``."""
+    P); dt, dA (B, H); Bm, Cm (B, G, N) or, one group, (B, N); D (H,).
+    Returns ``(y (B, H, P) in x's dtype, the pool)``."""
     L, S, H, P, N = pool.shape
     B = x.shape[0]
     if not 0 <= layer < L:
@@ -81,6 +89,14 @@ def ssm_update_kernel(pool, layer, slots, x, dt, dA, Bm, Cm, D,
         raise ValueError(f"ssm_update: {H} heads do not divide into "
                          f"groups of {hb}")
     G = H // hb
+    # heads of one state group, and how a step's heads meet the groups:
+    # ``gb`` whole groups a step, or a group over ``spb`` steps
+    R = H // (Bm.shape[1] if Bm.ndim == 3 else 1)
+    if hb % R and R % hb:
+        raise ValueError(f"ssm_update: a step's {hb} heads neither hold "
+                         f"whole groups of {R} nor divide one")
+    gb, spb = max(1, hb // R), max(1, R // hb)
+    nblk = H // (gb * R)                # blocks of groups a row
     if interpret is None:
         interpret = not pallas_util.on_tpu()
     f32 = jnp.float32
@@ -89,12 +105,17 @@ def ssm_update_kernel(pool, layer, slots, x, dt, dA, Bm, Cm, D,
     xdt = (xf * dt.astype(f32)[..., None]).reshape(B, G, hb, P)
     xdt = jnp.swapaxes(xdt, 2, 3)
     dec = jnp.exp(dA.astype(f32)).reshape(B, G, 1, hb)
-    b3 = Bm.astype(f32).reshape(B, 1, N)
-    c3 = Cm.astype(f32).reshape(B, 1, N)
+    b3 = Bm.astype(f32).reshape(B * nblk, gb, N)
+    c3 = Cm.astype(f32).reshape(B * nblk, gb, N)
 
     per_state = idx32(lambda b, g, sl: (layer, sl[b], g, 0, 0))
     per_row = idx32(lambda b, g, sl: (b, g, 0, 0))
-    per_vec = idx32(lambda b, g, sl: (b, 0, 0))
+    if nblk == 1:                       # every step reads the row's one
+        per_vec = idx32(lambda b, g, sl: (b, 0, 0))
+    else:
+        # lax.div: jnp's floor division does not lower here under x64
+        per_vec = idx32(lambda b, g, sl: (
+            b * nblk + jax.lax.div(g, jnp.int32(spb)), 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(B, G),
@@ -102,8 +123,8 @@ def ssm_update_kernel(pool, layer, slots, x, dt, dA, Bm, Cm, D,
             pl.BlockSpec((1, 1, hb, P, N), per_state),
             pl.BlockSpec((1, 1, P, hb), per_row),
             pl.BlockSpec((1, 1, 1, hb), per_row),
-            pl.BlockSpec((1, 1, N), per_vec),
-            pl.BlockSpec((1, 1, N), per_vec),
+            pl.BlockSpec((1, gb, N), per_vec),
+            pl.BlockSpec((1, gb, N), per_vec),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, P, hb), per_row),
@@ -115,7 +136,7 @@ def ssm_update_kernel(pool, layer, slots, x, dt, dA, Bm, Cm, D,
         kw["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"))
     yT, pool = pl.pallas_call(
-        functools.partial(_kernel, hb=hb),
+        functools.partial(_kernel, hb=hb, gb=gb),
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((B, G, P, hb), f32),
                    jax.ShapeDtypeStruct(pool.shape, pool.dtype)],

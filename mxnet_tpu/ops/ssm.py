@@ -5,6 +5,11 @@ The recurrence, per head ``h`` with state ``S`` (P x N):
     S_t = a_t S_{t-1} + dt_t * x_t (x) B_t        a_t = exp(dA_t) <= 1
     y_t = S_t C_t + D * x_t
 
+``B_t`` and ``C_t`` belong to a state GROUP: with ``G`` groups over ``H``
+heads, head ``h`` reads the rows of group ``h // (H / G)``.  Both
+functions take them as ``(..., G, N)``, or as ``(..., N)`` where all the
+heads share one group.
+
 Two formulations of the SAME arithmetic:
 
 - :func:`ssd_chunked_scan`: the chunked (state-space-dual) form for a
@@ -85,15 +90,26 @@ def ssd_chunked_scan(x, dt, dA, Bm, Cm, D, state, chunk):
 
     x (T, H, P) in the activation dtype; dt (T, H) float32 step sizes
     (0 at padded positions); dA (T, H) float32 log-decays
-    (``-exp(A_log) * dt``, so 0 where dt is 0); Bm, Cm (T, N) the one
-    group's input and output projections; D (H,); state (H, P, N)
-    float32 in front of the span.  A span that is not a whole number
-    of chunks is padded with positions of ``dt`` 0.  Returns ``(y (T,
-    H, P) in x's dtype, state after the last position (H, P, N)
-    float32)``.
+    (``-exp(A_log) * dt``, so 0 where dt is 0); Bm, Cm (T, G, N) the
+    groups' input and output projections ((T, N): one group); D (H,);
+    state (H, P, N) float32 in front of the span.  A span that is not a
+    whole number of chunks is padded with positions of ``dt`` 0.
+    Returns ``(y (T, H, P) in x's dtype, state after the last position
+    (H, P, N) float32)``.
     """
     T0, H, P = x.shape
     N = Bm.shape[-1]
+    if Bm.ndim == 3:
+        # the groups share nothing: each is the one-group scan over its
+        # own heads
+        G = Bm.shape[1]
+        y, last = jax.vmap(
+            lambda *group: ssd_chunked_scan(*group, chunk),
+            in_axes=(1, 1, 1, 1, 1, 0, 0), out_axes=(1, 0))(
+                x.reshape(T0, G, H // G, P), dt.reshape(T0, G, H // G),
+                dA.reshape(T0, G, H // G), Bm, Cm, D.reshape(G, H // G),
+                state.reshape(G, H // G, P, N))
+        return y.reshape(T0, H, P), last.reshape(H, P, N)
     Q = min(int(chunk), T0)
     if T0 % Q:
         pad = Q - T0 % Q
@@ -143,13 +159,23 @@ def ssd_chunked_scan(x, dt, dA, Bm, Cm, D, state, chunk):
     return y.reshape(T, H, P)[:T0].astype(x.dtype), last
 
 
+def _per_head(v, H):
+    """A group vector ``(B, G, N)`` or ``(B, N)`` as every head of ``H``
+    reads it: float32, broadcastable against ``(B, H, P, N)``."""
+    v = v.astype(_F32)
+    if v.ndim == 2:
+        return v[:, None, None, :]
+    return jnp.repeat(v, H // v.shape[1], axis=1)[:, :, None, :]
+
+
 def ssm_state_update(pool, layer, slots, x, dt, dA, Bm, Cm, D, impl=None):
     """One position for each row, states updated in place in the pool.
 
     pool (L, S, H, P, N) float32, the donated stack of every layer's
     states; ``layer`` a static index; slots (B,) int32 (padded rows
     name the null slot 0); x (B, H, P); dt, dA (B, H) float32; Bm, Cm
-    (B, N); D (H,).  Returns ``(y (B, H, P) in x's dtype, pool)``.
+    (B, G, N), or (B, N) for one group; D (H,).  Returns ``(y (B, H, P)
+    in x's dtype, pool)``.
     ``impl``: None follows the backend (the kernel on a TPU); ``"jnp"``
     forces the XLA form, the kernel's parity oracle.
     """
@@ -163,12 +189,13 @@ def ssm_state_update(pool, layer, slots, x, dt, dA, Bm, Cm, D, impl=None):
                                  Bm, Cm, D)
     with jax.named_scope("ssm_state_update"):
         S = pool[layer, slots]                               # (B,H,P,N)
+        H = S.shape[1]
         xdt = x.astype(_F32) * dt.astype(_F32)[..., None]
         S = (jnp.exp(dA.astype(_F32))[..., None, None] * S
-             + xdt[..., None] * Bm.astype(_F32)[:, None, None, :])
+             + xdt[..., None] * _per_head(Bm, H))
         # an elementwise product and a sum, not an einsum: a float32
         # matmul at default precision would round the state to bf16
-        y = jnp.sum(S * Cm.astype(_F32)[:, None, None, :], axis=-1)
+        y = jnp.sum(S * _per_head(Cm, H), axis=-1)
         y = y + x.astype(_F32) * D.astype(_F32)[None, :, None]
         pool = pool.at[layer, slots].set(S)
     return y.astype(x.dtype), pool

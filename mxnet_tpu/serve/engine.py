@@ -76,6 +76,7 @@ from ..models.generate import (detect_gpt_variant, normalize_gpt_params,
                                reconcile_decode_config)
 from ..parallel import partition as partition_mod
 from ..parallel.mesh import NamedSharding, PartitionSpec, make_mesh
+from ..models.branch import BranchDecoder
 from ..models.hybrid import HybridDecoder
 from ..models.moe import MoEDecoder
 from ..ops.attention import (PAGED_TILE_TOKENS, SPAN_BLOCK_K, SPAN_BLOCK_Q,
@@ -341,13 +342,16 @@ class Engine:
                  adapter_host_bytes=None):
         # a decoder's DESCRIPTION in place of a gpt() symbol, served
         # through serve/hybrid.py: a hybrid decoder's (models/hybrid.py:
-        # layers of two kinds, a state pool beside the K/V) or a routed-
+        # layers of two kinds, a state pool beside the K/V), a routed-
         # expert decoder's (models/moe.py: global and window attention
-        # layers in two cache groups, experts told which they hold).
+        # layers in two cache groups, experts told which they hold) or a
+        # one-branch decoder's (models/branch.py: a layer is a mixer OR a
+        # feed-forward part).
         # What it brings is read off the description (its state-space
         # layers, its window layers, its routed blocks), never its class
         self._desc = (symbol if isinstance(symbol, (HybridDecoder,
-                                                    MoEDecoder))
+                                                    MoEDecoder,
+                                                    BranchDecoder))
                       else None)
         # the router's counts ride out of every program, and the probe
         # through them (serve/hybrid.py)
@@ -1651,6 +1655,11 @@ class Engine:
             # a hybrid decoder's state pool: slots, in use, bytes, dtypes
             # (None for gpt engines)
             "state_cache": self.state_cache_stats(),
+            # a described decoder's layers: kinds and counts, its state
+            # groups, its router, picks, latent width and experts held
+            # (None for gpt engines)
+            "decoder": (None if self._desc is None
+                        else hybrid_mod.describe(self._desc)),
             # host-DRAM offload tier occupancy and hit/restore counters
             # (None when the tier is off — the inert default)
             "host_kv": self.host_kv_stats(),

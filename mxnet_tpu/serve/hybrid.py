@@ -1,12 +1,16 @@
 """Serving programs for DESCRIBED decoders, whose layers are not all of
 one kind: hybrid decoders (``models/hybrid.py``: state-space and
-attention layers, a per-request state pool beside the paged K/V cache)
-and routed-expert decoders (``models/moe.py``: global and window
+attention layers, a per-request state pool beside the paged K/V cache),
+routed-expert decoders (``models/moe.py``: global and window
 attention layers in two cache GROUPS, a dense or a routed feed-forward
-block per layer).
+block per layer) and one-branch decoders (``models/branch.py``: every
+layer a mixer OR a feed-forward part, state-space layers in state
+groups, routed experts in a latent width).
 
-ONE layer function, :func:`layer`, is ``norm -> mixer(kind) ->
-ffn(kind)``; the decode, prefill and chunk builders all call it and
+ONE layer function, :func:`layer`, runs the branches the description
+gives the layer, ``norm -> mixer(kind)`` and ``norm -> ffn(kind)``, each
+added to the residual stream (either may be ``"none"``); the decode,
+prefill and chunk builders all call it and
 differ only in the *mixer context* they hand it (:class:`_DecodeMix` for
 one position of B rows, :class:`_SpanMix` for a span of one request):
 
@@ -22,7 +26,9 @@ one position of B rows, :class:`_SpanMix` for a span of one request):
   pools stacked over the STATE-SPACE layers, ``ssm (M, S+1, H, P, N)``
   float32 and ``conv (M, S+1, (K-1) * C)`` in the activation dtype (a
   slot's K-1 rows side by side, for the same reason), slot 0 the null
-  slot that padded rows write to.  Decode updates the live
+  slot that padded rows write to.  Its ``B`` and ``C`` rows come in
+  ``mamba_groups`` state groups, each read by its own heads and normed
+  on its own behind the gate.  Decode updates the live
   rows' states in place at ``(layer, slot[b])``
   (``ops.ssm.ssm_state_update``); a span runs the chunked scan
   (``ops.ssm.ssd_chunked_scan``) from the slot's state, or from ZERO
@@ -44,8 +50,11 @@ one position of B rows, :class:`_SpanMix` for a span of one request):
   through ``masked_attention`` with the same window.
 
 A **routed** feed-forward block (``ffn_types[i] == "moe"``) routes in
-float32 over all experts and computes the experts the description says
-this program holds (``ops/moe.py``, dropless), plus the shared expert;
+float32 over all experts (``router_score``: a softmax, or sigmoids with
+a selection bias) and computes the experts the description says
+this program holds (``ops/moe.py``, dropless; ``expert_act``: SwiGLU or
+an ungated squared ReLU; with ``latent`` in a narrower width, between a
+projection down and one up), plus the shared expert;
 a bucket's padding rows route nowhere.  The router's counts of a pass
 (``ops.moe.STATS``, summed over the routed layers) ride back with the
 sampled token, in front of the caches.  Every routed block also leaves
@@ -92,7 +101,7 @@ from .programs import _finish, _rope
 __all__ = ["HybridCfg", "hybrid_cfg", "check_params", "refuse", "layer",
            "matmul_flops", "build_decode", "build_prefill", "build_chunk",
            "routed", "extra_caches", "probe_layers", "probe_shape",
-           "SCAN_SCOPE", "PROBE_DECODE", "PROBE_SPAN"]
+           "describe", "SCAN_SCOPE", "PROBE_DECODE", "PROBE_SPAN"]
 
 # named scope of the chunked scan: device-trace operation names carry it
 SCAN_SCOPE = "ssd_chunked_scan"
@@ -171,6 +180,26 @@ def _what(dec):
     return "routed-expert decoder" if routed(dec) else "hybrid decoder"
 
 
+def describe(dec):
+    """What the description's layers are, for ``statusz()``: how many of
+    each mixer and feed-forward kind, and what its state-space layers
+    and routed blocks are made of."""
+    out = {"layers": dec.num_layers,
+           "mixers": dict(collections.Counter(dec.layer_types)),
+           "ffns": dict(collections.Counter(dec.ffn_types))}
+    if dec.mamba_layers:
+        out["state"] = {"heads": dec.mamba_heads, "groups": dec.mamba_groups,
+                        "head_dim": dec.mamba_head_dim,
+                        "state": dec.mamba_state, "chunk": dec.mamba_chunk}
+    if routed(dec):
+        out["experts"] = {"router": dec.router_score, "picks": dec.top_k,
+                          "of": dec.num_experts,
+                          "held": [dec.expert_offset, dec.expert_count],
+                          "act": dec.expert_act, "latent": dec.latent,
+                          "routed_scale": dec.routed_scale}
+    return out
+
+
 def refuse(dec, prefix_cache, spec_k, adapters, kv_dtype, quantize, tp,
            host_kv_bytes):
     """What an engine over a described decoder cannot do yet, each
@@ -198,7 +227,10 @@ def refuse(dec, prefix_cache, spec_k, adapters, kv_dtype, quantize, tp,
         why.update(prefix_cache=(prefix_cache, WINDOW_NO_PREFIX))
     if dec.window_layers or routed(dec):
         why.update(spec_k=(spec_k, "the verify program has no window group "
-                           "and no routed feed-forward block"))
+                           "and no routed feed-forward block"
+                           + (", and would have to roll the recurrent "
+                              "state back over rejected tokens"
+                              if dec.mamba_layers else "")))
     if routed(dec):
         why.update(tp=(tp > 1, "experts under a mesh need the exchange of "
                        "rows between shards, which is not written"))
@@ -258,10 +290,27 @@ def _mamba_steps(dec, params, p, dt, valid=None):
     return dt, -jnp.exp(params[f"{p}_A_log"].astype(jnp.float32)) * dt
 
 
+def _mamba_bc(dec, xBC):
+    """The convolved ``xBC``'s ``B`` and ``C`` columns as ``ops.ssm``
+    takes them: ``(rows, G, N)``, or ``(rows, N)`` for one group."""
+    di, G, N = dec.d_inner, dec.mamba_groups, dec.mamba_state
+    Bm, Cm = xBC[:, di:di + G * N], xBC[:, di + G * N:]
+    if G > 1:
+        Bm, Cm = (v.reshape(v.shape[0], G, N) for v in (Bm, Cm))
+    return Bm, Cm
+
+
 def _mamba_out(dec, params, p, y, z):
-    """Gate, then norm over all of d_inner, then the output projection."""
+    """Gate, then norm over each state group's channels (one group: all
+    of d_inner), then the output projection."""
     g = (y.astype(jnp.float32) * _silu_f32(z)).astype(y.dtype)
-    g = _ln(g, params[f"{p}_norm_gamma"], None, eps=dec.eps)
+    gamma, G = params[f"{p}_norm_gamma"], dec.mamba_groups
+    if G > 1:
+        rows = g.shape[0]
+        g = _ln(g.reshape(rows, G, -1), gamma.reshape(G, -1), None,
+                eps=dec.eps).reshape(rows, -1)
+    else:
+        g = _ln(g, gamma, None, eps=dec.eps)
     return _fc(g, params[f"{p}_out_proj_weight"])
 
 
@@ -271,6 +320,15 @@ def _swiglu(h, w_in, w_out):
     F = w_in.shape[0] // 2
     act = _silu_f32(gu[..., :F]).astype(gu.dtype) * gu[..., F:]
     return _fc(act, w_out)
+
+
+def _relu2(h, w_in, w_out):
+    """``W_out relu(W_in h)^2``: an ungated block."""
+    return _fc(moe_ops.relu2(_fc(h, w_in)), w_out)
+
+
+# a feed-forward block of each ``expert_act``
+_FFN = {"swiglu": _swiglu, "relu2": _relu2}
 
 
 def _qkv(dec, params, p, h, Hq=None):
@@ -439,12 +497,12 @@ class _DecodeMix(_Mix):
         self.conv = self.conv.at[m, self.slots].set(
             rows.astype(self.conv.dtype).reshape(B, -1))
         xBC = xBC[:, 0]
-        di, N = dec.d_inner, dec.mamba_state
+        di = dec.d_inner
         x = xBC[:, :di].reshape(B, dec.mamba_heads, dec.mamba_head_dim)
         dt, dA = _mamba_steps(dec, P, p, dt)
         y, self.ssm = ssm_ops.ssm_state_update(
-            self.ssm, m, self.slots, x, dt, dA, xBC[:, di:di + N],
-            xBC[:, di + N:], P[f"{p}_D"])
+            self.ssm, m, self.slots, x, dt, dA, *_mamba_bc(dec, xBC),
+            P[f"{p}_D"])
         return _mamba_out(dec, P, p, y.reshape(B, di), z)
 
 
@@ -531,48 +589,63 @@ class _SpanMix(_Mix):
         dt, dA = _mamba_steps(dec, P, p, dt, self.valid)
         with jax.named_scope(SCAN_SCOPE):
             y, S = ssm_ops.ssd_chunked_scan(
-                xBC[:, :di].reshape(T, H, Pd), dt, dA, xBC[:, di:di + N],
-                xBC[:, di + N:], P[f"{p}_D"], S0, dec.mamba_chunk)
+                xBC[:, :di].reshape(T, H, Pd), dt, dA,
+                *_mamba_bc(dec, xBC), P[f"{p}_D"], S0, dec.mamba_chunk)
         self.ssm = self.ssm.at[m, self.slot].set(S)
         return _mamba_out(dec, P, p, y.reshape(T, di), z)
 
 
-# which mixer a layer kind is
-_MIXER = {"attention": "attention", "mamba": "mamba",
+# which mixer a layer kind is (None: the layer has no mixer branch)
+_MIXER = {"attention": "attention", "mamba": "mamba", "none": None,
           FULL: "gated_attention", WINDOW: "gated_attention"}
 
 
 def _routed(dec, params, p, h, mix):
     """A routed feed-forward block over rows ``h``: the shared expert
     plus ``routed_scale`` times the held experts' part.  The router is
-    float32 all the way (logits, softmax, the ``top_k`` weights)."""
+    float32 all the way (logits, scores, the ``top_k`` weights).  With a
+    latent the experts work on ``W_down h`` and their weighted sum, this
+    program's PART of it, goes through ``W_up``."""
     logits = jnp.dot(h.astype(jnp.float32),
                      params[f"{p}_router_weight"].T.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
-    idx, w = moe_ops.route(logits, dec.top_k)
+    idx, w = moe_ops.route(logits, dec.top_k, dec.router_score,
+                           params.get(f"{p}_router_bias"))
+    u = _fc(h, params[f"{p}_latent_down_weight"]) if dec.latent else h
     y, stats = moe_ops.routed_experts(
-        h, params[f"{p}_experts_in_weight"],
+        u, params[f"{p}_experts_in_weight"],
         params[f"{p}_experts_out_weight"], idx, w, dec.expert_offset,
-        dec.num_experts, valid=mix.valid)
+        dec.num_experts, valid=mix.valid, act=dec.expert_act)
     mix.moe_stats = stats if mix.moe_stats is None else mix.moe_stats + stats
-    shared = _swiglu(h, params[f"{p}_shared_in_weight"],
-                     params[f"{p}_shared_out_weight"])
+    shared = _FFN[dec.expert_act](h, params[f"{p}_shared_in_weight"],
+                                  params[f"{p}_shared_out_weight"])
+    if dec.latent:
+        y = _fc((np.float32(dec.routed_scale) * y).astype(h.dtype),
+                params[f"{p}_latent_up_weight"])
+        return (shared.astype(jnp.float32)
+                + y.astype(jnp.float32)).astype(h.dtype)
     return (shared.astype(jnp.float32)
             + np.float32(dec.routed_scale) * y).astype(h.dtype)
 
 
 def layer(hc, params, i, x, mix):
-    """THE layer of a described decoder: norm -> mixer(kind) ->
-    ffn(kind), each branch scaled by the residual multiplier.  ``mix``
-    carries the caches and the pass's operands and answers the mixer the
-    layer's kind names (``_MIXER``)."""
+    """THE layer of a described decoder: the branches its description
+    gives it, ``norm -> mixer(kind)`` then ``norm -> ffn(kind)``, each
+    scaled by the residual multiplier and added to the stream; a kind of
+    ``"none"`` is a branch the layer does not have.  ``mix`` carries the
+    caches and the pass's operands and answers the mixer the layer's
+    kind names (``_MIXER``)."""
     dec = hc.dec
     p = f"{dec.name}_l{i}"
-    h = _ln(x, params[f"{p}_ln1_gamma"], None, eps=dec.eps)
-    y = getattr(mix, _MIXER[dec.layer_types[i]])(i, h)
-    x = _residual(x, y, dec.residual_multiplier)
+    mixer, ffn = _MIXER[dec.layer_types[i]], dec.ffn_types[i]
+    if mixer is not None:
+        h = _ln(x, params[f"{p}_ln1_gamma"], None, eps=dec.eps)
+        y = getattr(mix, mixer)(i, h)
+        x = _residual(x, y, dec.residual_multiplier)
+    if ffn == "none":
+        return x
     h = _ln(x, params[f"{p}_ln2_gamma"], None, eps=dec.eps)
-    if dec.ffn_types[i] == "moe":
+    if ffn == "moe":
         y = _routed(dec, params, p, h, mix)
         mix.watch(i, h, y)
     else:

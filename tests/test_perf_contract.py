@@ -330,6 +330,8 @@ PAGED_KERNEL_CASES = {
     "windowed": (16, 32, 8, 128, jnp.bfloat16, 1000, False),
     "tp4-shard": (16, 8, 2, 128, jnp.bfloat16, 0, False),
     "one-row": (1, 32, 8, 128, jnp.bfloat16, 0, False),
+    # two kv heads of 128 side by side under 32 query heads (PR 36)
+    "reason-long": (64, 32, 2, 128, jnp.bfloat16, 0, True),
 }
 
 
@@ -432,6 +434,8 @@ SPAN_KERNEL_CASES = {
     "tp4-shard": (2048, 4096, 8, 2, 0),
     # a view longer than the resident keys: two grid steps a query tile
     "long-view": (2048, 8192, 32, 8, 0),
+    # 16 query heads a kv head (PR 36)
+    "gqa-16": (2048, 2048, 32, 2, 0),
 }
 
 
@@ -458,6 +462,62 @@ def test_span_kernel_compiles_for_the_chip(case, one_chip):
     assert compiled.as_text().count(
         'custom_call_target="tpu_custom_call"') == 1
     assert compiled.memory_analysis().temp_size_in_bytes < T * S_
+
+
+@pytest.mark.parametrize("H,G,rows", [(64, 1, 64), (128, 8, 64),
+                                      (128, 8, 8)])
+def test_state_update_kernel_compiles_for_the_chip(H, G, rows, one_chip):
+    """The single-token state update through the chip's compiler at the
+    two hybrid cells' shapes: 64 heads in one state group, and 128 heads
+    in 8 groups (a grid step takes 64 heads = 4 whole groups with their
+    own B and C rows).  The pool is updated in place: no temporary of a
+    layer's pool."""
+    from mxnet_tpu.ops.ssm import ssm_state_update
+
+    S = jax.ShapeDtypeStruct
+    L, slots, P, N = 5, 65, 64, 128
+    bc = (rows, N) if G == 1 else (rows, G, N)
+
+    def fwd(pool, sl, x, dt, dA, Bm, Cm, D):
+        return ssm_state_update(pool, 3, sl, x, dt, dA, Bm, Cm, D)
+
+    with hlo_audit.assume_tpu():
+        compiled = _chip_compile(
+            jax.jit(fwd, donate_argnums=0),
+            [S((L, slots, H, P, N), jnp.float32), S((rows,), jnp.int32),
+             S((rows, H, P), jnp.bfloat16), S((rows, H), jnp.float32),
+             S((rows, H), jnp.float32), S(bc, jnp.bfloat16),
+             S(bc, jnp.bfloat16), S((H,), jnp.float32)], one_chip)
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') == 1
+    layer_bytes = slots * H * P * N * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes // 4
+
+
+@pytest.mark.parametrize("act,K,F,D", [("swiglu", 3072, 1024, 3072),
+                                       ("relu2", 1024, 2688, 1024)])
+def test_routed_experts_compile_for_the_chip(act, K, F, D, one_chip):
+    """The held experts' two grouped products through the chip's compiler
+    at the two routed cells' widths: SwiGLU experts in the model width,
+    and ungated experts in a 1024-wide latent whose hidden width 2688 no
+    1024-column tile divides (the tiles are 896 there)."""
+    from mxnet_tpu.ops import moe as moe_ops
+
+    S = jax.ShapeDtypeStruct
+    T, k, count, E = 64, 22, 128, 512
+    gated = 2 if act == "swiglu" else 1
+
+    def fwd(x, w_in, w_out, idx, w):
+        return moe_ops.routed_experts(x, w_in, w_out, idx, w, 0, E, act=act)
+
+    with hlo_audit.assume_tpu():
+        compiled = _chip_compile(
+            jax.jit(fwd),
+            [S((T, K), jnp.bfloat16), S((count, K, gated * F), jnp.bfloat16),
+             S((count, F, D), jnp.bfloat16), S((T, k), jnp.int32),
+             S((T, k), jnp.float32)], one_chip)
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') == 2
 
 
 @pytest.mark.parametrize("kind,bucket,keys", [("chunk", 2048, 4096),

@@ -76,6 +76,17 @@ def routed():
     return dec, dec.init_params(3)
 
 
+@pytest.fixture(scope="module")
+def branch():
+    dec = mx.models.branch_decoder(
+        61, 32, ["mamba", "moe", "attention", "moe"] * 2, num_heads=4,
+        kv_heads=2, head_dim=16, mamba_heads=4, mamba_head_dim=8,
+        mamba_state=16, mamba_groups=2, mamba_chunk=8, num_experts=16,
+        top_k=3, expert_ff=24, shared_ff=40, latent=16, routed_scale=2.5,
+        experts_held=(4, 8))
+    return dec, dec.init_params(3)
+
+
 def _prompts(lens, vocab=VOCAB, seed=7):
     rng = np.random.RandomState(seed)
     return [rng.randint(0, vocab, (n,)).astype(np.int32) for n in lens]
@@ -116,6 +127,8 @@ KINDS = {
                None, "state"),
     "routed_window": ("routed", dict(GEO, prefill_chunk=16), (9, 21, 13),
                       (12, 6, 10), None, "window"),
+    "one_branch": ("branch", dict(GEO, prefill_chunk=16), (9, 21, 13),
+                   (8, 6, 10), None, "state"),
     "speculative": ("gpt", dict(GEO, spec_k=2), (9, 14), (7, 5), None,
                     "spec"),
 }
