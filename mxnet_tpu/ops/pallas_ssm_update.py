@@ -24,8 +24,16 @@ the same way; the caller transposes both (a few KB).
 one group's, and its block of the two vectors holds exactly the groups
 its heads read, so inside the kernel a head's group is a static row.
 
-Padded rows of a bucket name the null slot 0, whose contents are
-garbage by design.
+A dead row, one that names the null slot 0 (a bucket's padding), does
+no work: the body runs under ``pl.when(slot != 0)``, and every block
+index of a dead row is its SOURCE row's, the next live row (the last
+one, behind it) at the head step the live row's own steps meet it on.
+Consecutive grid steps with the same block index copy nothing in or
+out, so a dead row moves no state, no per-row vector and nothing of
+``y``: the kernel's cost follows the live rows, not the bucket, and the
+null slot is not written (unless no row at all is live).  The grid
+walks its steps in order for this, so both axes are ``arbitrary``.
+Dead rows' ``y`` is exactly 0.
 """
 
 from __future__ import annotations
@@ -53,21 +61,41 @@ KERNEL_NAME = "ssm_state_update"
 HEADS_PER_STEP = 64
 
 
-def _kernel(slots_ref, pool_ref, xdt_ref, dec_ref, b_ref, c_ref,
-            y_ref, pool_out_ref, *, hb, gb):
-    del slots_ref                       # consumed by the index maps
-    # the step's groups, (1, N) each; its heads divide evenly among them
-    Bv = [b_ref[0, j:j + 1] for j in range(gb)]
-    Cv = [c_ref[0, j:j + 1] for j in range(gb)]
-    for h in range(hb):
-        j = h * gb // hb
-        S = pool_ref[0, 0, h]           # (P, N)
-        col = xdt_ref[0, 0, :, h:h + 1]         # (P, 1)
-        a = dec_ref[0, 0, :, h:h + 1]           # (1, 1)
-        S = a * S + col * Bv[j]
-        pool_out_ref[0, 0, h] = S
-        y_ref[0, 0, :, h:h + 1] = jnp.sum(S * Cv[j], axis=-1,
-                                          keepdims=True)
+def _kernel(slots_ref, src_ref, pin_ref, pool_ref, xdt_ref, dec_ref,
+            b_ref, c_ref, y_ref, pool_out_ref, *, hb, gb):
+    del src_ref, pin_ref                # consumed by the index maps
+
+    @pl.when(slots_ref[pl.program_id(0)] != 0)
+    def _():
+        # the step's groups, (1, N) each; its heads divide evenly among them
+        Bv = [b_ref[0, j:j + 1] for j in range(gb)]
+        Cv = [c_ref[0, j:j + 1] for j in range(gb)]
+        for h in range(hb):
+            j = h * gb // hb
+            S = pool_ref[0, 0, h]           # (P, N)
+            col = xdt_ref[0, 0, :, h:h + 1]         # (P, 1)
+            a = dec_ref[0, 0, :, h:h + 1]           # (1, 1)
+            S = a * S + col * Bv[j]
+            pool_out_ref[0, 0, h] = S
+            y_ref[0, 0, :, h:h + 1] = jnp.sum(S * Cv[j], axis=-1,
+                                              keepdims=True)
+
+
+def _sources(slots, G):
+    """Each row's source row, and the head step a dead row is pinned to
+    (-1 for a live row): a dead row takes the next live row at its first
+    step, so that row's blocks arrive while the live row in front still
+    computes, or, behind the last live row, that row at its last step.
+    Either way a dead step's block indices equal those of a live step
+    beside it."""
+    B = slots.shape[0]
+    live = slots != 0
+    rows = jnp.arange(B, dtype=jnp.int32)
+    nxt = jax.lax.cummin(jnp.where(live, rows, B), reverse=True)
+    prv = jax.lax.cummax(jnp.where(live, rows, -1))
+    src = jnp.where(nxt < B, nxt, jnp.maximum(prv, 0))
+    pin = jnp.where(live, -1, jnp.where(nxt < B, 0, G - 1))
+    return src, pin.astype(jnp.int32)
 
 
 @hot_path
@@ -108,16 +136,24 @@ def ssm_update_kernel(pool, layer, slots, x, dt, dA, Bm, Cm, D,
     b3 = Bm.astype(f32).reshape(B * nblk, gb, N)
     c3 = Cm.astype(f32).reshape(B * nblk, gb, N)
 
-    per_state = idx32(lambda b, g, sl: (layer, sl[b], g, 0, 0))
-    per_row = idx32(lambda b, g, sl: (b, g, 0, 0))
+    slots = jnp.asarray(slots, jnp.int32)
+    src, pin = _sources(slots, G)
+
+    def at(fn):
+        # a dead row's step reads its source row at its pinned head step
+        return idx32(lambda b, g, sl, sr, pn: fn(
+            sl, sr[b], jnp.where(pn[b] < 0, g, pn[b])))
+
+    per_state = at(lambda sl, r, g: (layer, sl[r], g, 0, 0))
+    per_row = at(lambda sl, r, g: (r, g, 0, 0))
     if nblk == 1:                       # every step reads the row's one
-        per_vec = idx32(lambda b, g, sl: (b, 0, 0))
+        per_vec = at(lambda sl, r, g: (r, 0, 0))
     else:
         # lax.div: jnp's floor division does not lower here under x64
-        per_vec = idx32(lambda b, g, sl: (
-            b * nblk + jax.lax.div(g, jnp.int32(spb)), 0, 0))
+        per_vec = at(lambda sl, r, g: (
+            r * nblk + jax.lax.div(g, jnp.int32(spb)), 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=3,
         grid=(B, G),
         in_specs=[
             pl.BlockSpec((1, 1, hb, P, N), per_state),
@@ -134,20 +170,22 @@ def ssm_update_kernel(pool, layer, slots, x, dt, dA, Bm, Cm, D,
     kw = {}
     if not interpret:
         kw["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel"))
+            dimension_semantics=("arbitrary", "arbitrary"))
     yT, pool = pl.pallas_call(
         functools.partial(_kernel, hb=hb, gb=gb),
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((B, G, P, hb), f32),
                    jax.ShapeDtypeStruct(pool.shape, pool.dtype)],
-        # operand 0 is the scalar-prefetched slots; the pool is operand 1
-        input_output_aliases={1: 1},
+        # operands 0-2 are scalar-prefetched; the pool is operand 3
+        input_output_aliases={3: 1},
         name=KERNEL_NAME,
         # mxtpu-lint: disable=host-sync (static host flag chosen at
         # trace time, never a device value)
         interpret=bool(interpret),
         **kw,
-    )(jnp.asarray(slots, jnp.int32), pool, xdt, dec, b3, c3)
+    )(slots, src, pin, pool, xdt, dec, b3, c3)
     y = jnp.swapaxes(yT, 2, 3).reshape(B, H, P)
     y = y + xf * D.astype(f32)[None, :, None]
+    # a dead row's y block was never written
+    y = jnp.where((slots != 0)[:, None, None], y, 0)
     return y.astype(x.dtype), pool

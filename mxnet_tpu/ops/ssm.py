@@ -28,7 +28,9 @@ Two formulations of the SAME arithmetic:
   stack is a copy of that layer's whole pool (PERF.md, PR 27).  On a
   TPU the update runs as the Mosaic kernel in
   ``ops/pallas_ssm_update.py`` (slots scalar-prefetched, the pool
-  aliased in place).
+  aliased in place; a row that names the null slot 0 does no work
+  there and its ``y`` is 0, where this XLA form computes it and writes
+  slot 0).
 
 The causal depthwise convolution in front of the scan keeps the last
 ``K - 1`` rows of its input between programs (:func:`causal_conv`).

@@ -747,7 +747,7 @@ class Engine:
                 self._scale_v = jnp.zeros(sshape, jnp.float32)
         # the per-request state pool of a hybrid decoder, beside the K/V:
         # recurrent states float32, convolution rows in the activation
-        # dtype, slot 0 the null slot that padded rows write to
+        # dtype, slot 0 the null slot that padded rows name
         self._state_ssm = self._state_conv = None
         # the window layers' group: its own stack over them, its own
         # (fewer) blocks
@@ -904,6 +904,11 @@ class Engine:
                 "mxtpu_serve_state_resets_total",
                 "prefill passes that started a slot's state from zero",
                 ("reason",))
+            self._tel_state_skipped = telemetry.counter(
+                "mxtpu_serve_state_updates_skipped_total",
+                "state updates the state kernel skips: decode rows that "
+                "name the null slot, times the state layers")
+            self._state_skipped = 0
         telemetry.gauge("mxtpu_serve_blocks_total",
                         "allocatable KV-cache blocks").set(
             self.blocks.total_blocks)
@@ -1877,6 +1882,7 @@ class Engine:
                 "layers": int(self._state_ssm.shape[0]),
                 "bytes_total": total,
                 "bytes_per_slot": total // (slots + 1),
+                "updates_skipped": self._state_skipped,
                 "ssm_dtype": str(self._state_ssm.dtype),
                 "conv_dtype": str(self._state_conv.dtype)}
 
@@ -2465,6 +2471,14 @@ class Engine:
         if self._sprof.tracing:
             note = dict(batch=B, bucket=bucket,
                         **self._kv_tiles(reqs, bucket))
+        if self._state_ssm is not None:
+            # the padded rows name the null slot: the state kernel does
+            # no work for them (ops/pallas_ssm_update.py)
+            skipped = (bucket - B) * int(self._state_ssm.shape[0])
+            self._state_skipped += skipped
+            self._tel_state_skipped.inc(skipped)
+            if self._sprof.tracing:
+                note["state_rows_skipped"] = bucket - B
         toks = np.zeros(bucket, np.int32)
         pos = np.zeros(bucket, np.int32)
         tables = np.zeros((bucket, self.table_width), np.int32)

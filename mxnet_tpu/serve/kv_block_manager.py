@@ -695,7 +695,7 @@ class BlockManager:
         # a hybrid decoder's per-request state pool (serve/hybrid.py):
         # a request takes ONE slot with its first blocks and gives it
         # back with them (finish, cancel, preempt), under the same lock.
-        # Slot 0 is the null slot padded rows write to; 0 slots = a
+        # Slot 0 is the null slot padded rows name; 0 slots = a
         # gpt() engine, and every path below is the pre-state one
         self.state_slots = int(state_slots)
         self.prefix_off_reason = None

@@ -8,6 +8,8 @@ recurrence, every held expert by a one-hot product, no cache, no kernel)
 is the yardstick throughout.
 """
 
+import re
+
 import numpy as np
 import pytest
 
@@ -20,6 +22,7 @@ from mxnet_tpu.ops import moe as moe_ops
 from mxnet_tpu.ops import ssm
 from mxnet_tpu.ops.attention import paged_attention
 from mxnet_tpu.ops.pallas_ssm_update import ssm_update_kernel
+from mxnet_tpu.serve import engine as engine_mod
 from mxnet_tpu.serve import hybrid as hybrid_mod
 from mxnet_tpu.serve.scheduler import FINISHED
 
@@ -125,6 +128,48 @@ def test_requests_side_by_side_equal_serving_alone(model):
     eng.run()
     assert [list(r.tokens) for r in reqs] == alone
     eng.shutdown()
+
+
+def _skipped_total():
+    text = mx.telemetry.to_prometheus_text(mx.telemetry.registry())
+    m = re.search(r"^mxtpu_serve_state_updates_skipped_total (\S+)$", text,
+                  re.M)
+    return float(m.group(1)) if m else 0.0
+
+
+def test_a_padded_decode_row_is_skipped_and_counted(model, monkeypatch):
+    """Three requests at once in the 4-row bucket, so a decode pass of
+    all three has one dead row: the grouped state kernel (interpreted, a
+    group a head step) runs inside the engine's programs, every generated
+    position is within 1e-4 of the reference's best, ``serve.decode``
+    says the pass skipped one row, and the counter rises by the state
+    layers for each such pass."""
+    monkeypatch.setattr(engine_mod, "_STEP_CACHE", {})
+    monkeypatch.setattr(ssm, "ssm_state_update",
+                        lambda pool, layer, slots, *a: ssm_update_kernel(
+                            pool, layer, slots, *a, heads_per_step=2,
+                            interpret=True))
+    mx.telemetry.enable()
+    try:
+        mx.telemetry.tracer().clear()
+        before = _skipped_total()
+        eng = _engine(model)
+        prompts = [_prompt(70 + i, n) for i, n in enumerate((7, 12, 5))]
+        reqs = [eng.submit(p, max_new_tokens=8) for p in prompts]
+        eng.run()
+        for prompt, req in zip(prompts, reqs):
+            assert _regret(model, prompt, np.asarray(req.tokens)).max() <= 1e-4
+        decodes = [s[5] for s in mx.telemetry.tracer().spans(
+            prefix="serve.") if s[0] == "serve.decode"]
+        three = [a for a in decodes if a["batch"] == 3]
+        assert three and all(a["state_rows_skipped"] == 1 for a in three)
+        assert sum(a["state_rows_skipped"] for a in decodes) == len(three)
+        skipped = eng._state_ssm.shape[0] * len(three)
+        assert _skipped_total() - before == skipped
+        assert eng.statusz()["state_cache"]["updates_skipped"] == skipped
+        eng.shutdown()
+    finally:
+        mx.telemetry.disable()
 
 
 def test_what_the_engine_holds_and_says_of_a_one_branch_decoder(model):
@@ -359,36 +404,58 @@ def _update_inputs(rng, Bn, H, P, N, G, slots=6, layers=2):
     return pool, x, dt, dA, Bm, Cm, D
 
 
-@pytest.mark.parametrize("H,G,hb", [
-    (4, 2, None),       # the XLA form
-    (4, 2, 4),          # a step takes both groups
-    (4, 2, 2),          # a step takes one whole group
-    (8, 2, 2),          # a group spans two steps
-    (8, 4, 4),          # two groups a step, two steps a row
-    (4, 1, 2),          # one group, two steps
+@pytest.mark.parametrize("H,G,hb,slots", [
+    (4, 2, None, [4, 1, 2]),        # the XLA form
+    (4, 2, 4, [4, 1, 2]),           # a step takes both groups
+    (4, 2, 2, [4, 1, 2]),           # a step takes one whole group
+    (8, 2, 2, [4, 1, 2]),           # a group spans two steps
+    (8, 4, 4, [4, 1, 2]),           # two groups a step, two steps a row
+    (4, 1, 2, [4, 1, 2]),           # one group, two steps
+    # dead rows (slot 0): trailing, in front of and between live rows,
+    # and one live row, at several groups a step and a group over steps
+    (4, 2, 4, [4, 1, 0, 0]),
+    (8, 2, 2, [0, 4, 0, 1, 0]),
+    (8, 4, 4, [0, 2, 0, 5]),
+    (8, 2, 2, [0, 0, 3, 0]),
 ], ids=["jnp", "groups-in-a-step", "a-group-a-step", "group-over-steps",
-        "two-groups-two-steps", "one-group"])
-def test_grouped_state_update_equals_one_step_of_the_recurrence(H, G, hb):
+        "two-groups-two-steps", "one-group", "groups-in-a-step-dead-behind",
+        "group-over-steps-dead-between", "two-groups-two-steps-dead-between",
+        "group-over-steps-one-live"])
+def test_grouped_state_update_equals_one_step_of_the_recurrence(H, G, hb,
+                                                                slots):
     """``hb`` None: ``ssm_state_update``'s XLA form; else the kernel in
-    interpret mode with ``hb`` heads a grid step."""
+    interpret mode with ``hb`` heads a grid step, with dead rows in TPU
+    interpret mode (a block is copied in or out only when its index
+    changes, as on the chip; a never-written buffer holds NaN).  A dead
+    row's ``y`` is exactly 0 and no slot a live row does not name moves,
+    slot 0 included."""
+    from jax.experimental.pallas import tpu as pltpu
+
     rng = np.random.default_rng(H * 10 + G)
-    pool, x, dt, dA, Bm, Cm, D = _update_inputs(rng, 3, H, 8, 16, G)
-    slots = np.array([4, 1, 2], np.int32)
+    pool, x, dt, dA, Bm, Cm, D = _update_inputs(rng, len(slots), H, 8, 16, G)
+    slots = np.array(slots, np.int32)
     args = [jnp.asarray(a) for a in (x, dt, dA, Bm, Cm, D)]
     if hb is None:
         y, new = ssm.ssm_state_update(jnp.asarray(pool), 1, jnp.asarray(slots),
                                       *args, impl="jnp")
     else:
-        y, new = ssm_update_kernel(jnp.asarray(pool), 1, jnp.asarray(slots),
-                                   *args, heads_per_step=hb, interpret=True)
-    new = np.asarray(new)
+        mode = (pltpu.InterpretParams(uninitialized_memory="nan")
+                if (slots == 0).any() else None)
+        with pltpu.force_tpu_interpret_mode(mode):
+            y, new = ssm_update_kernel(jnp.asarray(pool), 1,
+                                       jnp.asarray(slots), *args,
+                                       heads_per_step=hb, interpret=True)
+    y, new = np.asarray(y), np.asarray(new)
     for b, slot in enumerate(slots):
+        if not slot:
+            assert (y[b] == 0).all()
+            continue
         want_y, want_S = _recurrence(x[b:b + 1], dt[b:b + 1], dA[b:b + 1],
                                      Bm[b:b + 1], Cm[b:b + 1], D,
                                      pool[1, slot])
-        np.testing.assert_allclose(np.asarray(y)[b], want_y[0], atol=2e-5)
+        np.testing.assert_allclose(y[b], want_y[0], atol=2e-5)
         np.testing.assert_allclose(new[1, slot], want_S, atol=2e-5)
-    untouched = [s for s in range(pool.shape[1]) if s not in slots]
+    untouched = [s for s in range(pool.shape[1]) if s not in slots[slots > 0]]
     assert (new[1, untouched] == pool[1, untouched]).all()
     assert (new[0] == pool[0]).all()
 

@@ -4,6 +4,8 @@ vocabulary under 100.  ``models/hybrid.py::reference_logits`` (float32,
 token-by-token recurrence, no cache) is the yardstick throughout.
 """
 
+import re
+
 import numpy as np
 import pytest
 
@@ -91,28 +93,47 @@ def test_chunked_scan_equals_the_recurrence(T):
     np.testing.assert_allclose(np.asarray(S), S_ref, atol=2e-5, rtol=1e-5)
 
 
-def test_state_update_kernel_equals_the_jnp_update():
-    """The Mosaic kernel (interpreted here) against the gather/scatter
-    form: live slots agree to rounding, other slots are untouched."""
+@pytest.mark.parametrize("slots", [
+    [2, 4, 1],                      # every row live
+    [2, 4, 1, 0, 0],                # dead rows behind the live ones
+    [0, 2, 0, 4, 0, 1],             # dead rows in front of and between them
+    [0, 0, 3, 0],                   # a single live row
+], ids=["all-live", "trailing-dead", "dead-between", "one-live"])
+def test_state_update_kernel_equals_the_jnp_update(slots):
+    """The Mosaic kernel against the gather/scatter form: live slots
+    agree to rounding, a dead row's (null slot 0's) ``y`` is exactly 0,
+    and every slot no live row names is untouched, slot 0 included.
+    With dead rows the kernel runs under TPU interpret mode, which copies
+    a block in or out only when its index changes, as the chip does, and
+    fills never-written buffers with NaN."""
+    from jax.experimental.pallas import tpu as pltpu
     from mxnet_tpu.ops.pallas_ssm_update import ssm_update_kernel
 
     rng = np.random.default_rng(0)
-    L, S, Hh, P, N, B = 3, 5, 8, 16, 128, 3
+    L, S, Hh, P, N, B = 3, 5, 8, 16, 128, len(slots)
     pool = jnp.asarray(rng.normal(size=(L, S, Hh, P, N)), jnp.float32)
-    slots = jnp.asarray([2, 4, 1], jnp.int32)
     x = jnp.asarray(rng.normal(size=(B, Hh, P)), jnp.float32)
     dt = jnp.asarray(rng.uniform(0.01, 0.1, size=(B, Hh)), jnp.float32)
     Bm = jnp.asarray(rng.normal(size=(B, N)), jnp.float32)
     Cm = jnp.asarray(rng.normal(size=(B, N)), jnp.float32)
     D = jnp.ones((Hh,), jnp.float32)
-    y0, p0 = ssm.ssm_state_update(pool, 1, slots, x, dt, -2 * dt, Bm, Cm, D,
+    sl = jnp.asarray(slots, jnp.int32)
+    y0, p0 = ssm.ssm_state_update(pool, 1, sl, x, dt, -2 * dt, Bm, Cm, D,
                                   impl="jnp")
-    y1, p1 = ssm_update_kernel(pool, 1, slots, x, dt, -2 * dt, Bm, Cm, D,
-                               heads_per_step=4, interpret=True)
-    np.testing.assert_allclose(np.asarray(y1), np.asarray(y0), atol=1e-5)
-    np.testing.assert_allclose(np.asarray(p1), np.asarray(p0), atol=1e-6)
-    assert (np.asarray(p1[0]) == np.asarray(pool[0])).all()
-    assert (np.asarray(p1[1, 3]) == np.asarray(pool[1, 3])).all()
+    mode = (pltpu.InterpretParams(uninitialized_memory="nan") if 0 in slots
+            else None)
+    with pltpu.force_tpu_interpret_mode(mode):
+        y1, p1 = ssm_update_kernel(pool, 1, sl, x, dt, -2 * dt, Bm, Cm, D,
+                                   heads_per_step=4, interpret=True)
+    live = np.asarray(slots) != 0
+    named = np.asarray(slots)[live]
+    y0, p0, y1, p1 = map(np.asarray, (y0, p0, y1, p1))
+    np.testing.assert_allclose(y1[live], y0[live], atol=1e-5)
+    np.testing.assert_allclose(p1[1, named], p0[1, named], atol=1e-6)
+    assert (y1[~live] == 0).all()
+    others = [s for s in range(S) if s not in named]
+    assert (p1[1, others] == np.asarray(pool)[1, others]).all()
+    assert (p1[[0, 2]] == np.asarray(pool)[[0, 2]]).all()
 
 
 # -- the engine against the reference ------------------------------------------
@@ -300,6 +321,55 @@ def test_state_spans_counters_and_statusz(model):
         assert 'mxtpu_serve_state_resets_total{reason="preempt"}' in text
         assert "mxtpu_serve_state_slots_in_use 0" in text
         assert all(r.status == FINISHED for r in reqs)
+        eng.shutdown()
+    finally:
+        mx.telemetry.disable()
+
+
+def _skipped_total():
+    text = mx.telemetry.to_prometheus_text(mx.telemetry.registry())
+    m = re.search(r"^mxtpu_serve_state_updates_skipped_total (\S+)$", text,
+                  re.M)
+    return float(m.group(1)) if m else 0.0
+
+
+def test_a_padded_decode_row_is_skipped_and_counted(model, monkeypatch):
+    """Three requests at once in the 4-row bucket, so a decode pass of
+    all three has one dead row: the state kernel (interpreted, a group
+    over two head steps) runs inside the engine's programs, every
+    generated position equals the reference, ``serve.decode`` says the
+    pass skipped one row, and the counter rises by the state layers for
+    each such pass."""
+    from mxnet_tpu.ops.pallas_ssm_update import ssm_update_kernel
+
+    monkeypatch.setattr(engine_mod, "_STEP_CACHE", {})
+    monkeypatch.setattr(ssm, "ssm_state_update",
+                        lambda pool, layer, slots, *a: ssm_update_kernel(
+                            pool, layer, slots, *a, heads_per_step=2,
+                            interpret=True))
+    dec, params = model
+    mx.telemetry.enable()
+    try:
+        mx.telemetry.tracer().clear()
+        before = _skipped_total()
+        eng = _engine(model)
+        prompts = [_prompt(60 + i, n) for i, n in enumerate((6, 9, 4))]
+        reqs = [eng.submit(p, max_new_tokens=6) for p in prompts]
+        eng.run()
+        for prompt, req in zip(prompts, reqs):
+            toks = np.concatenate([prompt, req.tokens])
+            ref = np.asarray(dec.reference_logits(params, toks[:-1]))
+            ref = ref[len(prompt) - 1:]
+            regret = ref.max(-1) - ref[np.arange(6), req.tokens]
+            assert regret.max() <= 1e-4, regret
+        decodes = [s[5] for s in mx.telemetry.tracer().spans(
+            prefix="serve.") if s[0] == "serve.decode"]
+        three = [a for a in decodes if a["batch"] == 3]
+        assert three and all(a["state_rows_skipped"] == 1 for a in three)
+        assert sum(a["state_rows_skipped"] for a in decodes) == len(three)
+        skipped = eng._state_ssm.shape[0] * len(three)
+        assert _skipped_total() - before == skipped
+        assert eng.statusz()["state_cache"]["updates_skipped"] == skipped
         eng.shutdown()
     finally:
         mx.telemetry.disable()
