@@ -560,7 +560,7 @@ class FleetCollector:
             row["tok_per_sec"] = round(rate, 3)
         for f in ("ttft_ms_p99", "tpot_ms_p99", "perf_mfu",
                   "perf_achieved_tflops", "perf_tok_flops",
-                  "perf_cost_per_1k_tokens_s", "perf_sampled",
+                  "perf_cost_per_1k_tokens_s", "perf_timed",
                   "prefix_hits", "prefix_resurrections",
                   "prefix_tokens_saved", "summary_keys",
                   "pull_attempts", "pull_blocks_imported"):
@@ -647,7 +647,7 @@ class FleetCollector:
                 agg["_tpot"].append(row["tpot_ms_p99"])
             # role-keyed goodput: MFU averages over the role's fresh
             # replicas, achieved TFLOP/s sums to the role's delivered
-            # compute rate (both absent until a replica has sampled)
+            # compute rate (both absent until a replica has timed a run)
             if row.get("perf_mfu") is not None:
                 agg["_mfu"].append(row["perf_mfu"])
             agg["achieved_tflops"] = round(
@@ -738,7 +738,7 @@ class FleetCollector:
                     ("stale", agg["stale"]),
                     ("achieved_tflops", agg["achieved_tflops"]),
                     ("mfu_mean", agg["mfu_mean"])):
-                if value is None:     # no replica has sampled yet
+                if value is None:     # no replica has timed a run yet
                     continue
                 telemetry.gauge(
                     f"mxtpu_fleet_agg_{field}",

@@ -136,15 +136,15 @@ class ServeMonitor:
             spec = (f" spec={s.spec_accept_rate:.2f}"
                     f"/{s.accepted_per_verify:.2f}")
         # performance-attribution tail (the same only-once-measured
-        # rule as the spec tail): appears only after a sampled timing
-        # exists (MXTPU_PERF_ATTRIB_SAMPLE>0 and a sampled step ran),
-        # so plain lines stay byte-identical to the pre-attribution
+        # rule as the spec tail): appears only after a program run was
+        # timed (telemetry on: the step record dates every pass), so
+        # plain lines stay byte-identical to the pre-attribution
         # format — and engines without perf_summary (fakes, older
         # duck-typed drivers) log exactly as before
         perf = ""
         summary = getattr(self.engine, "perf_summary", None)
         p = summary() if callable(summary) else None
-        if p and p.get("sampled"):
+        if p and p.get("timed"):
             mfu = p.get("mfu")
             mfu_s = "-" if mfu is None else f"{100.0 * mfu:.1f}%"
             tf = p.get("tok_flops")

@@ -168,13 +168,15 @@ class _Part:
     outputs still on the device (``lead``, and ``ok`` the watchdog's
     flag), and what the engine kept from the dispatch for the read."""
 
-    __slots__ = ("reqs", "lead", "ok", "args", "start", "end", "n", "freed")
+    __slots__ = ("reqs", "lead", "ok", "args", "start", "end", "n", "freed",
+                 "rec")
 
     def __init__(self, reqs, lead, ok, args, start=0, end=0, n=0):
         self.reqs, self.lead, self.ok, self.args = reqs, lead, ok, args
         # a prefill pass: positions [start, end) of the n to prefill
         self.start, self.end, self.n = start, end, n
         self.freed = 0           # window-group blocks its trim gave back
+        self.rec = None          # the step record's pass timer (tracing)
 
 
 class _Pass:
@@ -847,21 +849,22 @@ class Engine:
         self._alive = True
         self._noop_steps = 0
         # per-program performance attribution (telemetry/perf_attrib):
-        # cost table fills at program-resolve cadence (default on),
-        # sampled device timing rides the step cadence behind
-        # MXTPU_PERF_ATTRIB_SAMPLE.  Constructed here — after
-        # telemetry.enable() in the usual ordering — because it caches
-        # its metric handles at construction (the handle-caching
-        # asymmetry), and NEVER enters _spec_key/_aot_base_fp: both
-        # knobs in any combination leave tokens, program cache keys
-        # and AOT fingerprints byte-identical
+        # cost table fills at program-resolve cadence (default on); its
+        # device times come from the step record's pass timer below
+        # (telemetry on).  Constructed here — after telemetry.enable()
+        # in the usual ordering — because it caches its metric handles
+        # at construction (the handle-caching asymmetry), and NEVER
+        # enters _spec_key/_aot_base_fp: on or off it leaves tokens,
+        # program cache keys and AOT fingerprints byte-identical
         self._perf = PerfAttrib()
         # per-step host-overhead decomposition (telemetry/profiling):
         # same construction ordering + inertness rule as PerfAttrib —
         # caches its histogram handle here, never enters
         # _spec_key/_aot_base_fp.  Default on (MXTPU_STEP_PROFILE=0
-        # swaps in the NOOP recorder)
-        self._sprof = profiling.make_step_profiler()
+        # swaps in the NOOP recorder); with telemetry on it times every
+        # part of every pass and hands the times to the cost table
+        self._sprof = profiling.make_step_profiler(
+            on_pass=self._perf.timed if self._perf.enabled else None)
         # live-state gauges stamped once per step (no-op when telemetry
         # is disabled); cumulative serve counters live in StatsRecorder
         self._tel_queue = telemetry.gauge(
@@ -1285,9 +1288,9 @@ class Engine:
         0 is the same loop: a pass whose operands need values only the
         host will have is enqueued with nothing unread (``_Pass.reason``
         says why: the engine was idle, a speculative engine, a schedule
-        that must preempt, a step the perf sampler times, host-tier
-        restores, a reader from outside the loop).  ``read_only``: the
-        call of a reader, which enqueues nothing."""
+        that must preempt, host-tier restores, a reader from outside the
+        loop).  ``read_only``: the call of a reader, which enqueues
+        nothing."""
         self._step_id += 1
         # the one step instrument (telemetry/profiling.py): begin/enter/
         # wait/commit tile the call into phases; with telemetry on the
@@ -1304,13 +1307,9 @@ class Engine:
         if flight:
             done = flight[0]
             if len(flight) == 1 and not read_only:
-                hold = self._hold()
-                if hold is None:
-                    if launched:
-                        sprof.enter("schedule")
-                    self._launch(done)
-                else:
-                    self._held = hold
+                if launched:
+                    sprof.enter("schedule")
+                self._launch(done)
             self._read(done)
         sprof.enter("callbacks")
         emitted = done.emitted if done is not None else 0
@@ -1337,7 +1336,7 @@ class Engine:
                     "scheduler stalled: work queued but 1000 consecutive "
                     "steps scheduled nothing (cache/queue misconfigured?)")
         self._stats.on_step(emitted, decode_batch=n_dec)
-        self._perf.on_step(emitted)
+        self._perf.on_step(emitted, timed=sprof.tracing)
         if self._spec is not None:
             # bound the draft ingest ledger by the LIVE running set: a
             # request that leaves the engine between decodes (preempted,
@@ -1366,6 +1365,7 @@ class Engine:
             picks, held = int(done.moe[0]), int(done.moe[1])
             self._tel_moe_picks.labels(held="yes").inc(held)
             self._tel_moe_picks.labels(held="no").inc(picks - held)
+        left = None
         if sprof.tracing:
             # the queue and the batch as the schedule of the pass this
             # call read left them; preemptions is the lifetime count: a
@@ -1374,11 +1374,12 @@ class Engine:
                               if done is not None else
                               (self.scheduler.queue_depth,
                                len(self.scheduler.running)))
+            left = int(self.has_work())
             sprof.note(queue=queue, running=running,
                        blocks_in_use=self.blocks.blocks_in_use,
                        emitted=emitted,
                        preemptions=self.scheduler.preemptions,
-                       work_left=int(self.has_work()),
+                       work_left=left,
                        ahead=int(done is not None
                                  and done.reason is None))
             if self._state_ssm is not None:
@@ -1392,20 +1393,10 @@ class Engine:
                 sprof.note(blocks_global=groups["global"],
                            blocks_window=groups["window"],
                            window_blocks_freed=freed)
-        sprof.commit(emitted, prefills=n_pre, decodes=n_dec)
+        sprof.commit(emitted, prefills=n_pre, decodes=n_dec, work_left=left)
         emitted += self._read_emitted
         self._read_emitted = 0
         return emitted
-
-    def _hold(self):
-        """Why the next pass cannot be enqueued behind the unread one,
-        from what the engine can observe (None: it can).  A dispatch the
-        perf sampler times blocks on its outputs and wants the device's
-        queue empty in front of it."""
-        if (self._perf.t0() is not None
-                or self._perf.samples(self._passes + 1)):
-            return "perf_sample"
-        return None
 
     @hot_path
     def _launch(self, behind):
@@ -1447,10 +1438,6 @@ class Engine:
                       else self._held or "idle_start")
         self._held = None
         self._passes += 1
-        # arm (or not) this pass's dispatch timing — with sampling off
-        # (the default) every t0() below returns None and no dispatch
-        # gains a sync
-        self._perf.arm(self._passes)
         p = _Pass(reason, len(prefills), len(decodes),
                   self.scheduler.queue_depth, len(self.scheduler.running),
                   self.blocks.state_slots_in_use
@@ -1463,6 +1450,8 @@ class Engine:
         self._tel_passes.labels(
             order="ahead" if reason is None else "settled",
             reason=reason or "").inc()
+        if sprof.tracing:
+            sprof.reason = reason        # carried by the pass's parts
         for req in prefills:
             sprof.enter("prefill_dispatch", rid=req.rid)
             # the per-iteration prefill token budget is shared with the
@@ -2261,9 +2250,8 @@ class Engine:
                 args += (jnp.asarray(hks), jnp.asarray(hvs))
             with telemetry.span("serve.host_kv_restore",
                                 blocks=len(batch)):
-                t0 = self._perf.t0()
                 outs = self._program("restore", bucket)(*args)
-                self._perf.done(t0, "restore", bucket, outs)
+                self._perf.done("restore", bucket)
                 self._set_caches(outs)
 
     def _slots(self, table, n, pad_to):
@@ -2370,9 +2358,8 @@ class Engine:
             note.update(kind=pkind, tokens=span, bucket=bucket,
                         cached=req.cached_prefix_len, attn=attn,
                         kv_tiles=tiles, kv_tiles_table=of)
-        t0 = self._perf.t0()
         outs = fn(*args)
-        self._perf.done(t0, pkind, bucket, outs)
+        first = self._perf.done(pkind, bucket)
         lead, ok = self._adopt(outs, self._n_lead())
         part = _Part(req, lead, ok, note, start, end, n)
         req.flight_len += span
@@ -2401,6 +2388,9 @@ class Engine:
                     jnp.asarray(req.flight_src, jnp.int32))
             self.scheduler.prefill_done(req)
             self.scheduler.admit_running(req)
+        if self._sprof.tracing:
+            part.rec = self._sprof.enqueued(pkind, bucket, span, lead[0],
+                                            first)
         return part
 
     @hot_path
@@ -2409,7 +2399,7 @@ class Engine:
         emitted (1 on the pass that samples the first token, 0 for an
         intermediate chunk)."""
         req, sprof = part.reqs, self._sprof
-        sprof.wait("serve.prefill", rid=req.rid, **part.args)
+        sprof.wait("serve.prefill", part.rec, rid=req.rid, **part.args)
         lead = self._unpack_outs(part.lead, part.ok, "prefill_logits",
                                  rid=req.rid)
         sprof.enter("host_sync")
@@ -2499,7 +2489,6 @@ class Engine:
                 self._tok_pool, toks, jnp.asarray(src))
         fn = self._decode_fn(bucket)
         self._key, sub = jax.random.split(self._key)
-        t0 = self._perf.t0()
         outs = fn(self.params, *self._adapter_args(),
                   *self._cache_args(),
                   toks, jnp.asarray(pos),
@@ -2507,11 +2496,14 @@ class Engine:
                   *self._batch_adapter_operands(reqs, bucket),
                   *self._batch_state_operands(reqs, bucket),
                   *self._batch_sampling_operands(reqs, bucket), sub)
-        self._perf.done(t0, "decode", bucket, outs)
+        first = self._perf.done("decode", bucket)
         lead, ok = self._adopt(outs, self._n_lead())
         part = _Part(reqs, lead, ok, note)
         self._tok_pool = self._token_program(TOK_PUT, bucket)(
             self._tok_pool, lead[0])
+        if self._sprof.tracing:
+            part.rec = self._sprof.enqueued("decode", bucket, B, lead[0],
+                                            first)
         trim = self.blocks.window is not None
         for i, req in enumerate(reqs):
             req.flight_len += 1
@@ -2526,7 +2518,7 @@ class Engine:
     def _read_decode(self, p, part):
         reqs = part.reqs
         B = len(reqs)
-        self._sprof.wait("serve.decode", **part.args)
+        self._sprof.wait("serve.decode", part.rec, **part.args)
         lead = self._unpack_outs(part.lead, part.ok, "decode_logits",
                                  batch_size=B, rids=[r.rid for r in reqs])
         self._sprof.enter("host_sync")
@@ -2579,13 +2571,12 @@ class Engine:
                             tokens=span):
             # the chunk program built over the DRAFT config: same
             # write-then-attend body, draft params and draft caches
-            t0 = self._perf.t0()
             outs = self._program("draft_chunk", bucket)(
                 sw.params, sw.cache_k, sw.cache_v,
                 jnp.asarray(toks), jnp.asarray(0, jnp.int32),
                 jnp.asarray(span, jnp.int32), jnp.asarray(tw),
                 jnp.asarray(blk), jnp.asarray(off), sub)
-            self._perf.done(t0, "draft_chunk", bucket, outs)
+            self._perf.done("draft_chunk", bucket)
             _, sw.cache_k, sw.cache_v = outs
         sw.note_ingested(req, span)
 
@@ -2629,26 +2620,30 @@ class Engine:
         self._key, sub = jax.random.split(self._key)
         if self._sampling:
             samp = self._batch_sampling_operands(reqs, bucket)
-            t0 = self._perf.t0()
             douts = self._draft_fn(bucket)(
                 sw.params, sw.cache_k, sw.cache_v,
                 jnp.asarray(toks), jp, jtab, *samp, sub)
-            self._perf.done(t0, "draft", bucket, douts)
+            first = self._perf.done("draft", bucket)
             drafted, q_at, q_vals, q_idx, sw.cache_k, sw.cache_v = douts
             # drafted ids and their candidate-space q views stay ON
             # DEVICE: acceptance runs inside the verify program, so
             # the only host sync this iteration is the emitted rows
             fn = self._verify_fn(bucket)
             self._key, sub = jax.random.split(self._key)
-            t0 = self._perf.t0()
             outs = fn(self.params, *self._adapter_args(),
                       *self._cache_args(),
                       jnp.asarray(toks), drafted, q_at, q_vals,
                       q_idx, jp, jtab,
                       *self._batch_adapter_operands(reqs, bucket),
                       *samp, sub)
-            self._perf.done(t0, "verify", bucket, outs)
-            self._sprof.wait("serve.decode", **note)
+            first = self._perf.done("verify", bucket) or first
+            rec = None
+            if self._sprof.tracing:
+                # the draft rides in the verify's part: its outputs stay
+                # on the device
+                rec = self._sprof.enqueued("verify", bucket, B, outs[0],
+                                           first)
+            self._sprof.wait("serve.decode", rec, **note)
             emit_rows, acc, lp, tv, ti = self._unpack_outs(
                 *self._adopt(outs, 5), "verify_logits", batch_size=B,
                 rids=[r.rid for r in reqs])
@@ -2684,13 +2679,15 @@ class Engine:
                 else:
                     self.blocks.truncate(req.rid, req.cache_len)
             return emitted
-        t0 = self._perf.t0()
         douts = self._draft_fn(bucket)(
             sw.params, sw.cache_k, sw.cache_v, jnp.asarray(toks),
             jp, jtab, sub)
-        self._perf.done(t0, "draft", bucket, douts)
+        first = self._perf.done("draft", bucket)
         drafted, sw.cache_k, sw.cache_v = douts
-        self._sprof.wait("serve.decode", **note)
+        rec = None
+        if self._sprof.tracing:
+            rec = self._sprof.enqueued("draft", bucket, B, drafted, first)
+        self._sprof.wait("serve.decode", rec, **note)
         # mxtpu-lint: disable=host-sync (designed sync point: the
         # drafted ids feed the verify dispatch's host-built rows)
         drafted = np.asarray(drafted)
@@ -2700,13 +2697,15 @@ class Engine:
         rows[:, 1:] = drafted
         fn = self._verify_fn(bucket)
         self._key, sub = jax.random.split(self._key)
-        t0 = self._perf.t0()
         outs = fn(self.params, *self._adapter_args(),
                   *self._cache_args(),
                   jnp.asarray(rows), jp, jtab,
                   *self._batch_adapter_operands(reqs, bucket), sub)
-        self._perf.done(t0, "verify", bucket, outs)
-        self._sprof.enter("device_wait")
+        first = self._perf.done("verify", bucket)
+        rec = None
+        if self._sprof.tracing:
+            rec = self._sprof.enqueued("verify", bucket, B, outs[0], first)
+        self._sprof.wait(None, rec)
         if self._cfg.numeric_watch:
             out, ok = outs[0], outs[1]
             self._set_caches(outs[2:])

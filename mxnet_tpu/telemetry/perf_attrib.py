@@ -14,38 +14,35 @@ dispatch-path cost, no extra syncs.  When a backend reports no usable
 cost analysis the engine's analytic fallback (``flops.gpt_token_flops``
 / ``gpt_prefill_flops``) fills the flops column instead.
 
-**Sampled device timing (default OFF, ``MXTPU_PERF_ATTRIB_SAMPLE=N``
-samples every Nth step).**  On sampled steps only, each dispatch is
-bracketed ``t0()`` .. ``done()``: ``done`` calls ``block_until_ready``
-on the program's outputs and records the elapsed wall-time into a
-``mxtpu_serve_program_seconds{kind,bucket}`` histogram plus derived
-achieved-TFLOP/s, MFU (vs ``flops.peak_flops_per_chip``), MBU (vs
-``flops.peak_hbm_bytes_per_chip``) and cost-per-1k-tokens gauges.  The
-sync is rate-gated and rides the engine's existing step cadence, so
-with sampling off (the default) the hot path gains ZERO host syncs —
-``done(None, ...)`` is a dict lookup and an integer add.  The engine's
-step loop immediately consumes the outputs anyway (the designed
-``_unpack_outs`` sync point), so sampled timing re-orders the wait, it
-does not add device work.
+**Device time of every pass (telemetry on).**  ``done(kind, bucket)``
+counts each dispatch (a dict lookup and an integer add) and says
+whether it was the program's first, which the step record's pass timer
+carries as ``first_run``.  The timer (``telemetry/profiling.py``, "The
+pass record") dates each part of a pass on the device from the clock
+reads the step's phases take anyway — no sync is added, and the loop
+keeps running ahead — and hands every part to :meth:`timed`: its device
+seconds count toward the cost per 1k tokens; an EXACT part's also
+enter a ``mxtpu_serve_program_seconds{kind,bucket}`` histogram and the
+per-program table with derived achieved-TFLOP/s, MFU (vs
+``flops.peak_flops_per_chip``), MBU (vs ``flops.peak_hbm_bytes_per_
+chip``) and cost-per-1k-tokens gauges.  With telemetry off nothing is
+timed and the timing columns stay empty.
 
 Inertness contract (the PR 10/11 rule): attribution never touches
-tokens, program cache keys, or AOT fingerprints — both knobs in any
-combination leave greedy output byte-identical and ``_spec_digest``
-unchanged (pinned in tests/test_perf_attrib.py).
+tokens, program cache keys, or AOT fingerprints — on or off, with
+telemetry on or off, greedy output is byte-identical and
+``_spec_digest`` unchanged (pinned in tests/test_perf_attrib.py).
 """
 
 from __future__ import annotations
 
 import math
-import time
 
-from ..base import env_flag, env_int
+from ..base import env_flag
 
-__all__ = ["PerfAttrib", "ENV_ENABLE", "ENV_SAMPLE",
-           "PROGRAM_SECONDS_BUCKETS"]
+__all__ = ["PerfAttrib", "ENV_ENABLE", "PROGRAM_SECONDS_BUCKETS"]
 
 ENV_ENABLE = "MXTPU_PERF_ATTRIB"          # cost table (default on)
-ENV_SAMPLE = "MXTPU_PERF_ATTRIB_SAMPLE"   # sample every Nth step (0=off)
 
 # finer-grained than metrics.DEFAULT_BUCKETS: bucketed serve programs
 # live in the 10us .. 1s band on real chips
@@ -53,26 +50,27 @@ PROGRAM_SECONDS_BUCKETS = (1e-5, 2.5e-5, 5e-5, 1e-4, 2.5e-4, 5e-4,
                            1e-3, 2.5e-3, 5e-3, 1e-2, 2.5e-2, 5e-2,
                            0.1, 0.25, 0.5, 1.0, 2.5)
 
-_RECENT = 512    # per-program recent-sample window for p99
+_RECENT = 512    # per-program recent-time window for p99
+_GAUGE_EVERY = 64  # timed steps between gauge refreshes (and every read)
 
 
 class _Prog:
     """Per-(kind,bucket) dispatch/timing accumulator."""
 
-    __slots__ = ("dispatches", "sampled", "total_s", "recent")
+    __slots__ = ("dispatches", "timed", "total_s", "recent")
 
     def __init__(self):
         self.dispatches = 0
-        self.sampled = 0
+        self.timed = 0
         self.total_s = 0.0
-        self.recent = []          # bounded ring of sampled seconds
+        self.recent = []          # bounded ring of exact device seconds
 
     def record(self, dt):
-        if self.sampled < _RECENT:
+        if self.timed < _RECENT:
             self.recent.append(dt)
         else:
-            self.recent[self.sampled % _RECENT] = dt
-        self.sampled += 1
+            self.recent[self.timed % _RECENT] = dt
+        self.timed += 1
         self.total_s += dt
 
     def p99(self):
@@ -82,7 +80,7 @@ class _Prog:
         return s[min(len(s) - 1, int(0.99 * len(s)))]
 
     def mean(self):
-        return self.total_s / self.sampled if self.sampled else None
+        return self.total_s / self.timed if self.timed else None
 
 
 class PerfAttrib:
@@ -91,18 +89,14 @@ class PerfAttrib:
     construction).  The engine is never referenced — like the program
     builders, this object must not retain a retired engine."""
 
-    def __init__(self, clock=time.perf_counter):
+    def __init__(self):
         self.enabled = env_flag(ENV_ENABLE, True)
-        self.sample_every = max(0, env_int(ENV_SAMPLE, 0))
-        self._clock = clock
         self._cost = {}           # (kind, bucket) -> cost-table entry
         self._prog = {}           # (kind, bucket) -> _Prog
-        self._armed = False
-        self._step_s = 0.0        # timed seconds within the armed step
-        self._sampled_steps = 0
+        self._timed_steps = 0     # steps the pass timer ran in
         self._tokens = 0          # all emitted tokens (cheap int add)
-        self._sampled_tokens = 0  # emitted during sampled steps
-        self._device_s = 0.0      # timed seconds across sampled steps
+        self._timed_tokens = 0    # emitted during timed steps
+        self._device_s = 0.0      # device seconds of every timed part
         self.cost_errors = 0      # cost_analysis() refusals (statusz)
         try:
             from .. import flops as _flops
@@ -119,11 +113,12 @@ class PerfAttrib:
 
         self._hist = tel.histogram(
             "mxtpu_serve_program_seconds",
-            "sampled device wall-time per serve program dispatch",
+            "device seconds per serve program run, from the step "
+            "record's exact pass stamps",
             ("kind", "bucket"), buckets=PROGRAM_SECONDS_BUCKETS)
         self._g_tflops = tel.gauge(
             "mxtpu_serve_achieved_tflops",
-            "achieved TFLOP/s over sampled dispatches", ("kind",))
+            "achieved TFLOP/s over timed program runs", ("kind",))
         self._g_mfu = tel.gauge(
             "mxtpu_serve_mfu",
             "achieved FLOP/s over peak_flops_per_chip", ("kind",))
@@ -132,7 +127,7 @@ class PerfAttrib:
             "achieved bytes/s over peak HBM bandwidth", ("kind",))
         self._g_cost = tel.gauge(
             "mxtpu_serve_cost_per_1k_tokens_seconds",
-            "sampled device-seconds per 1000 emitted tokens")
+            "timed device-seconds per 1000 emitted tokens")
 
     # -- cost table (compile cadence) -----------------------------------
     def note_cost(self, kind, bucket, fn, fallback_flops=None,
@@ -177,79 +172,63 @@ class PerfAttrib:
         """The cost-table entry for (kind, bucket), or None."""
         return self._cost.get((kind, int(bucket)))
 
-    # -- sampled timing (step cadence) ----------------------------------
-    def arm(self, step_id):
-        """Called once at the top of every engine step: decides whether
-        THIS step's dispatches are timed (every ``sample_every``-th
-        step).  Never armed when sampling is off (the default)."""
-        self._armed = self.samples(step_id)
-        if self._armed:
-            self._step_s = 0.0
-
-    def samples(self, step_id):
-        """Whether ``arm(step_id)`` would time that step's dispatches: a
-        timed dispatch blocks on its outputs, so the engine enqueues
-        nothing behind an unread pass for it."""
-        return self.sample_every > 0 and step_id % self.sample_every == 0
-
-    def t0(self):
-        """Dispatch-start stamp: a clock read when this step is armed,
-        None otherwise (the default — no syscalls, no syncs)."""
-        return self._clock() if self._armed else None
-
-    def done(self, t0, kind, bucket, outs=None):
-        """Dispatch-end bracket.  Always counts the dispatch (dict
-        lookup + int add); on armed steps additionally blocks on
-        ``outs`` and records the elapsed device wall-time."""
+    # -- device time (step cadence) -------------------------------------
+    def done(self, kind, bucket):
+        """Count one dispatch of (kind, bucket) — a dict lookup and an
+        integer add — and say whether it was that program's first in
+        this engine."""
         key = (kind, int(bucket))
         p = self._prog.get(key)
         if p is None:
             p = self._prog[key] = _Prog()
         p.dispatches += 1
-        if t0 is None:
+        return p.dispatches == 1
+
+    def timed(self, kind, bucket, seconds, exact):
+        """One part of a pass as the step record's pass timer dated it:
+        every part's device seconds count toward the cost per token, an
+        exact part's also toward its program's row and histogram."""
+        self._device_s += seconds
+        if not exact:
             return
-        if outs is not None:
-            import jax
+        key = (kind, int(bucket))
+        p = self._prog.get(key)
+        if p is None:
+            p = self._prog[key] = _Prog()
+        p.record(seconds)
+        self._hist.labels(kind=kind, bucket=str(int(bucket))).observe(
+            seconds)
 
-            # rate-gated sampled sync (armed steps only; the default
-            # path passes t0=None and never reaches here)
-            jax.block_until_ready(outs)
-        dt = self._clock() - t0
-        p.record(dt)
-        self._step_s += dt
-        self._hist.labels(kind=kind, bucket=str(int(bucket))).observe(dt)
-
-    def on_step(self, emitted):
-        """Called once per engine step with the tokens emitted; closes
-        out an armed step (token accounting + gauge refresh)."""
+    def on_step(self, emitted, timed=False):
+        """Called once per engine step with the tokens emitted; ``timed``:
+        the pass timer ran in it (token accounting + gauge refresh)."""
         self._tokens += int(emitted)
-        if not self._armed:
+        if not (timed and self.enabled):
             return
-        self._armed = False
-        self._sampled_steps += 1
-        self._sampled_tokens += int(emitted)
-        self._device_s += self._step_s
-        self._update_gauges()
+        self._timed_steps += 1
+        self._timed_tokens += int(emitted)
+        if self._timed_steps % _GAUGE_EVERY == 0:
+            self._update_gauges()
 
     # -- derived utilization --------------------------------------------
     def _kind_rates(self):
         """{kind: (seconds, achieved_flops, achieved_bytes)} over the
-        sampled dispatches (flops/bytes from the cost table, so a
+        exactly timed runs (flops/bytes from the cost table, so a
         missing entry contributes time but no utilization)."""
         agg = {}
         for (kind, bucket), p in self._prog.items():
-            if not p.sampled:
+            if not p.timed:
                 continue
             ent = self._cost.get((kind, bucket)) or {}
             s, f, b = agg.get(kind, (0.0, 0.0, 0.0))
             s += p.total_s
-            f += (ent.get("flops") or 0.0) * p.sampled
-            b += (ent.get("bytes_accessed") or 0.0) * p.sampled
+            f += (ent.get("flops") or 0.0) * p.timed
+            b += (ent.get("bytes_accessed") or 0.0) * p.timed
             agg[kind] = (s, f, b)
         return agg
 
     def _totals(self):
-        """(seconds, flops, bytes) across all sampled dispatches."""
+        """(seconds, flops, bytes) across all exactly timed runs."""
         s = f = b = 0.0
         for ks, kf, kb in self._kind_rates().values():
             s += ks
@@ -266,23 +245,23 @@ class PerfAttrib:
                 self._g_mfu.labels(kind=kind).set(f / s / self.peak_flops)
             if self.peak_bytes:
                 self._g_mbu.labels(kind=kind).set(b / s / self.peak_bytes)
-        if self._sampled_tokens:
-            self._g_cost.set(
-                1000.0 * self._device_s / self._sampled_tokens)
+        if self._timed_tokens:
+            self._g_cost.set(1000.0 * self._device_s / self._timed_tokens)
 
     def mfu(self):
-        """Overall sampled MFU, or None (no samples / unknown peak)."""
+        """Overall MFU of the timed runs, or None (none / unknown
+        peak)."""
         s, f, _ = self._totals()
         if s <= 0.0 or not self.peak_flops:
             return None
         return f / s / self.peak_flops
 
     def tok_flops(self):
-        """Achieved FLOPs per emitted token over sampled steps."""
+        """Achieved FLOPs per emitted token over timed steps."""
         _, f, _ = self._totals()
-        if not self._sampled_tokens or f <= 0.0:
+        if not self._timed_tokens or f <= 0.0:
             return None
-        return f / self._sampled_tokens
+        return f / self._timed_tokens
 
     # -- surfaces --------------------------------------------------------
     def summary(self):
@@ -290,26 +269,30 @@ class PerfAttrib:
         None when attribution is disabled."""
         if not self.enabled:
             return None
+        self._update_gauges()
         s, f, b = self._totals()
-        sampled = sum(p.sampled for p in self._prog.values())
         out = {
-            "sampled": sampled,
+            "timed": sum(p.timed for p in self._prog.values()),
             "achieved_tflops": (f / s / 1e12) if s > 0.0 else None,
             "mfu": self.mfu(),
             "mbu": (b / s / self.peak_bytes
                     if s > 0.0 and self.peak_bytes else None),
             "tok_flops": self.tok_flops(),
-            "cost_per_1k_tokens_s": (
-                1000.0 * self._device_s / self._sampled_tokens
-                if self._sampled_tokens else None),
+            "cost_per_1k_tokens_s": self._cost_per_1k(),
         }
         return out
 
+    def _cost_per_1k(self):
+        if not self._timed_tokens:
+            return None
+        return 1000.0 * self._device_s / self._timed_tokens
+
     def statusz(self):
-        """The engine statusz ``perf`` section: knob state, overall
-        goodput, and the per-program table; None when disabled."""
+        """The engine statusz ``perf`` section: overall goodput and the
+        per-program table; None when disabled."""
         if not self.enabled:
             return None
+        self._update_gauges()
         total_s, total_f, _ = self._totals()
         progs = []
         for key in sorted(set(self._cost) | set(self._prog)):
@@ -322,7 +305,7 @@ class PerfAttrib:
                 "kind": kind,
                 "bucket": bucket,
                 "dispatches": p.dispatches if p else 0,
-                "sampled": p.sampled if p else 0,
+                "timed": p.timed if p else 0,
                 "mean_s": mean,
                 "p99_s": p.p99() if p else None,
                 "flops": flops,
@@ -339,9 +322,8 @@ class PerfAttrib:
             progs.append(row)
         out = {
             "enabled": True,
-            "sample_every": self.sample_every,
-            "sampled_steps": self._sampled_steps,
-            "sampled_tokens": self._sampled_tokens,
+            "timed_steps": self._timed_steps,
+            "timed_tokens": self._timed_tokens,
             "tokens": self._tokens,
             "device_seconds": self._device_s,
             "cost_errors": self.cost_errors,
@@ -351,9 +333,7 @@ class PerfAttrib:
                                 if total_s > 0.0 else None),
             "mfu": self.mfu(),
             "tok_flops": self.tok_flops(),
-            "cost_per_1k_tokens_s": (
-                1000.0 * self._device_s / self._sampled_tokens
-                if self._sampled_tokens else None),
+            "cost_per_1k_tokens_s": self._cost_per_1k(),
             "programs": progs,
         }
         return out

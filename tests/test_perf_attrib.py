@@ -6,17 +6,21 @@ wiring) — the PR-17 acceptance surface on CPU:
     section with nonzero flops after warmup — on the fresh-trace path,
     the warm-AOT restart path AND the process-local step-cache-hit
     path (a warm engine must not report an empty perf section)
-  * inertness: MXTPU_PERF_ATTRIB / MXTPU_PERF_ATTRIB_SAMPLE in any
-    combination leave greedy tokens byte-identical and the AOT
-    fingerprint (_spec_digest) unchanged; sampling off records zero
-    timings
-  * three-view agreement: statusz per-program sampled counts == the
+  * inertness: MXTPU_PERF_ATTRIB and the step record's pass timer
+    (telemetry on) in any combination leave greedy tokens byte-identical
+    and the AOT fingerprint (_spec_digest) unchanged; telemetry off
+    records zero timings
+  * three-view agreement: statusz per-program timed counts == the
     mxtpu_serve_program_seconds{kind,bucket} histogram counts in the
     registry == the rows tools/metrics_report.py renders
-  * satellites: ServeMonitor perf tail appears only once a sample
-    exists (plain lines byte-identical), metrics_report numeric-aware
+  * satellites: ServeMonitor perf tail appears only once a run was
+    timed (plain lines byte-identical), metrics_report numeric-aware
     label ordering, fleet replica/collector/fleet_report MFU-goodput
     plumbing, tools/perf_report.py breakdown rendering
+
+Device times come from the pass timer (``telemetry/profiling.py``, "The
+pass record"); a CPU pass is done before the host looks, so the timed
+tests take every read as blocked (``exact``).
 """
 
 import json
@@ -34,7 +38,7 @@ sys.path.insert(0, os.path.join(REPO, "tools"))
 import mxnet_tpu as mx
 from mxnet_tpu import telemetry
 from mxnet_tpu.serve import engine as engine_mod
-from mxnet_tpu.telemetry import perf_attrib
+from mxnet_tpu.telemetry import perf_attrib, profiling
 
 VOCAB = 53
 SEQ = 64
@@ -62,6 +66,30 @@ def _params(net, seed=3):
                         + (1.0 if name.endswith("gamma") else 0.0)
                         ).astype(np.float32)
     return params
+
+
+class _NeverSeenDone:
+    """A part's output whose readiness the pass timer never sees."""
+
+    def is_ready(self):
+        return False
+
+
+@pytest.fixture
+def exact(monkeypatch):
+    """Every read counts as blocked: a CPU pass is done before the host
+    looks, and here no poll and no look as its wait opens sees it done."""
+    enqueued = profiling.StepProfiler.enqueued
+    monkeypatch.setattr(
+        profiling.StepProfiler, "enqueued",
+        lambda self, kind, bucket, rows, lead, first_run: enqueued(
+            self, kind, bucket, rows, _NeverSeenDone(), first_run))
+
+
+@pytest.fixture
+def timed(tel, exact):
+    """The pass timer on: telemetry enabled, every wait exact."""
+    return tel
 
 
 @pytest.fixture(scope="module")
@@ -123,10 +151,10 @@ def test_cost_table_all_families_nonzero_flops(model, draft_model):
             assert r["flops"] and r["flops"] > 0, r
             assert r["source"] in ("cost_analysis", "analytic"), r
             # warmup resolves programs without dispatching or timing
-            assert r["sampled"] == 0 and r["mean_s"] is None, r
-        # no sample yet -> goodput columns empty, summary sampled == 0
-        assert perf["sampled_steps"] == 0
-        assert eng.perf_summary()["sampled"] == 0
+            assert r["timed"] == 0 and r["mean_s"] is None, r
+        # nothing timed yet -> goodput columns empty, summary timed == 0
+        assert perf["timed_steps"] == 0
+        assert eng.perf_summary()["timed"] == 0
     finally:
         eng.shutdown()
 
@@ -166,33 +194,39 @@ def test_cost_table_warm_aot_and_cache_hit_paths(model, tmp_path):
 
 
 # -- inertness: knobs never touch tokens or fingerprints ---------------------
-def test_sampling_and_kill_switch_inert(model, monkeypatch):
+def test_pass_timer_and_kill_switch_inert(model, exact, monkeypatch):
     """Greedy tokens and the AOT fingerprint are byte-identical across
-    MXTPU_PERF_ATTRIB / MXTPU_PERF_ATTRIB_SAMPLE in any combination
-    (the PR 10/11 inertness rule)."""
+    MXTPU_PERF_ATTRIB and the pass timer (telemetry) in any combination
+    (the PR 10/11 inertness rule); telemetry off times nothing."""
     prompts = _prompts()
 
     base = _engine(model)
     toks = _serve(base, prompts)
     digest = base._spec_digest
     perf = base.statusz()["perf"]
-    assert perf["sampled_steps"] == 0 and perf["tokens"] > 0
-    assert all(r["sampled"] == 0 for r in perf["programs"])
+    assert perf["timed_steps"] == 0 and perf["tokens"] > 0
+    assert all(r["timed"] == 0 for r in perf["programs"])
     base.shutdown()
 
-    monkeypatch.setenv(perf_attrib.ENV_SAMPLE, "1")
-    sampled = _engine(model)
-    assert _serve(sampled, prompts) == toks
-    assert sampled._spec_digest == digest
-    perf = sampled.statusz()["perf"]
-    assert perf["sampled_steps"] > 0 and perf["sampled_tokens"] > 0
+    telemetry.reset()
+    telemetry.enable()
+    try:
+        on = _engine(model)
+        assert _serve(on, prompts) == toks
+    finally:
+        telemetry.disable()
+    assert on._spec_digest == digest
+    perf = on.statusz()["perf"]
+    assert perf["timed_steps"] > 0 and perf["timed_tokens"] > 0
     assert perf["device_seconds"] > 0
-    timed = [r for r in perf["programs"] if r["sampled"]]
-    assert timed and all(r["mean_s"] > 0 for r in timed)
-    # shares partition the sampled step budget
-    assert sum(r["share"] for r in timed) == pytest.approx(1.0)
-    assert sampled.perf_summary()["sampled"] > 0
-    sampled.shutdown()
+    rows = [r for r in perf["programs"] if r["timed"]]
+    assert rows and all(r["mean_s"] > 0 for r in rows)
+    # every run of every program was timed, and the shares partition it
+    assert all(r["timed"] == r["dispatches"] for r in rows)
+    assert sum(r["share"] for r in rows) == pytest.approx(1.0)
+    assert on.perf_summary()["timed"] == sum(r["timed"] for r in rows)
+    on.shutdown()
+    telemetry.reset()
 
     monkeypatch.setenv(perf_attrib.ENV_ENABLE, "0")
     off = _engine(model)
@@ -204,19 +238,18 @@ def test_sampling_and_kill_switch_inert(model, monkeypatch):
 
 
 # -- three-view agreement ----------------------------------------------------
-def test_three_view_agreement(model, tel, monkeypatch):
-    """statusz per-program sampled counts == the registry's
+def test_three_view_agreement(model, timed):
+    """statusz per-program timed counts == the registry's
     mxtpu_serve_program_seconds{kind,bucket} histogram counts == the
     per-label rows metrics_report renders."""
     import metrics_report
 
-    monkeypatch.setenv(perf_attrib.ENV_SAMPLE, "1")
     eng = _engine(model)
     try:
         _serve(eng, _prompts())
         perf = eng.statusz()["perf"]
-        by_label = {(r["kind"], str(r["bucket"])): r["sampled"]
-                    for r in perf["programs"] if r["sampled"]}
+        by_label = {(r["kind"], str(r["bucket"])): r["timed"]
+                    for r in perf["programs"] if r["timed"]}
         assert by_label
 
         snap = telemetry.snapshot()["metrics"]
@@ -255,7 +288,8 @@ def test_metrics_report_numeric_label_order():
 
 
 # -- satellite: ServeMonitor perf tail ---------------------------------------
-def test_monitor_perf_tail_only_after_sample(model, monkeypatch, caplog):
+def test_monitor_perf_tail_only_after_a_timed_run(model, exact,
+                                                 monkeypatch, caplog):
     logger = logging.getLogger("mxtpu.test.perfmon")
     prompts = _prompts()
 
@@ -275,8 +309,8 @@ def test_monitor_perf_tail_only_after_sample(model, monkeypatch, caplog):
 
     plain = _engine(model)
     _serve(plain, prompts)
-    unsampled_line = line_for(plain)
-    assert "mfu=" not in unsampled_line
+    untimed_line = line_for(plain)
+    assert "mfu=" not in untimed_line
     plain.shutdown()
 
     # the kill switch produces the SAME line (byte-identical plain
@@ -284,29 +318,33 @@ def test_monitor_perf_tail_only_after_sample(model, monkeypatch, caplog):
     monkeypatch.setenv(perf_attrib.ENV_ENABLE, "0")
     killed = _engine(model)
     _serve(killed, prompts)
-    assert normalize(line_for(killed)) == normalize(unsampled_line)
+    assert normalize(line_for(killed)) == normalize(untimed_line)
     killed.shutdown()
     monkeypatch.delenv(perf_attrib.ENV_ENABLE)
 
-    monkeypatch.setenv(perf_attrib.ENV_SAMPLE, "1")
-    sampled = _engine(model)
-    _serve(sampled, prompts)
-    tail = line_for(sampled)
+    telemetry.reset()
+    telemetry.enable()
+    try:
+        timed = _engine(model)
+        _serve(timed, prompts)
+    finally:
+        telemetry.disable()
+        telemetry.reset()
+    tail = line_for(timed)
     assert "mfu=" in tail and "tok_flops=" in tail
-    sampled.shutdown()
+    timed.shutdown()
 
 
 # -- satellite: fleet plumbing ----------------------------------------------
-def test_replica_state_carries_perf(model, monkeypatch):
+def test_replica_state_carries_perf(model, timed):
     from mxnet_tpu.fleet.replica import ReplicaServer
 
-    monkeypatch.setenv(perf_attrib.ENV_SAMPLE, "1")
     eng = _engine(model)
     try:
         _serve(eng, _prompts())
         srv = ReplicaServer(eng)
         state = srv._replica_state()
-        assert state["perf"]["sampled"] > 0
+        assert state["perf"]["timed"] > 0
         assert state["perf"]["achieved_tflops"] > 0
     finally:
         eng.shutdown()
@@ -322,9 +360,9 @@ def test_collector_role_mfu_goodput_aggregates():
     col = FleetCollector(urls=["http://a:1", "http://b:1",
                                "http://c:1"], interval_s=0)
     try:
-        perfs = [{"sampled": 5, "mfu": 0.2, "achieved_tflops": 1.0,
+        perfs = [{"timed": 5, "mfu": 0.2, "achieved_tflops": 1.0,
                   "tok_flops": 2e6, "cost_per_1k_tokens_s": 0.1},
-                 {"sampled": 9, "mfu": 0.4, "achieved_tflops": 3.0,
+                 {"timed": 9, "mfu": 0.4, "achieved_tflops": 3.0,
                   "tok_flops": 2e6, "cost_per_1k_tokens_s": 0.3},
                  None]          # a replica predating the perf plane
         for view, perf in zip(col.views(), perfs):
@@ -344,7 +382,7 @@ def test_collector_role_mfu_goodput_aggregates():
         assert agg["achieved_tflops"] == pytest.approx(4.0)
         rows = {r["url"]: r for r in view["replicas"]}
         assert rows["http://a:1"]["perf_mfu"] == pytest.approx(0.2)
-        assert rows["http://b:1"]["perf_sampled"] == 9
+        assert rows["http://b:1"]["perf_timed"] == 9
         assert "perf_mfu" not in rows["http://c:1"]
 
         import fleet_report
@@ -359,11 +397,9 @@ def test_collector_role_mfu_goodput_aggregates():
 
 
 # -- satellite: tools/perf_report.py ----------------------------------------
-def test_perf_report_renders_breakdown(model, monkeypatch, tmp_path,
-                                       capsys):
+def test_perf_report_renders_breakdown(model, timed, tmp_path, capsys):
     import perf_report
 
-    monkeypatch.setenv(perf_attrib.ENV_SAMPLE, "1")
     eng = _engine(model)
     try:
         _serve(eng, _prompts())

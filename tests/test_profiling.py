@@ -199,6 +199,327 @@ def test_step_profiler_env_knobs(monkeypatch):
     assert live.enabled and live._ring.maxlen == 7
 
 
+# -- the pass record (pure unit: fake clock, fake readiness) -------------------
+class _Lead:
+    """A part's output as the pass timer sees it: ``is_ready()`` says what
+    the test set, and counts the polls."""
+
+    def __init__(self):
+        self.ready = False
+        self.polls = 0
+
+    def is_ready(self):
+        self.polls += 1
+        return self.ready
+
+
+class _Driver:
+    """Drives a ``StepProfiler`` by hand on a fake clock in milliseconds."""
+
+    def __init__(self):
+        self.now = 0.0
+        self.heard = []
+        self.sp = profiling.StepProfiler(
+            clock=lambda: self.now / 1e3, ring=16,
+            on_pass=lambda *a: self.heard.append(a))
+
+    def at(self, ms, call, *args, **kw):
+        self.now = ms
+        return getattr(self.sp, call)(*args, **kw)
+
+    def part(self, kind, bucket=4, first=False, reason=None):
+        lead = _Lead()
+        self.sp.reason = reason
+        return lead, self.sp.enqueued(kind, bucket, 3, lead, first)
+
+
+def _passes():
+    return {round(s[3] * 1e3, 6): s for s in
+            telemetry.tracer().spans(prefix="serve.pass")}
+
+
+def test_pass_stamps_tile_device_time(tel):
+    """Five parts over five calls: running ahead, a host late past the
+    device (the caller's turn), a pass read with nothing behind it, the
+    engine emptied, a start from idle.  Every part runs from max(its
+    enqueue, the end of the part before) to its end, and the parts and
+    the idle between them tile the device's timeline."""
+    d = _Driver()
+    d.at(0, "begin", 1)
+    d.at(1, "enter", "prefill_dispatch")
+    la, a = d.part("prefill", 16, first=True, reason="idle_start")
+    d.at(3, "enter", "schedule")                  # a enqueued at 3
+    d.at(4, "enter", "decode_dispatch")
+    lb, b = d.part("decode", first=True)
+    d.at(6, "wait", "serve.prefill", a)           # b enqueued at 6
+    d.at(20, "enter", "host_sync")                # a read: blocked 14 ms
+    d.at(21, "enter", "callbacks")
+    d.at(22, "commit", 1, work_left=1)
+    d.at(23, "begin", 2)
+    d.at(24, "enter", "decode_dispatch")
+    lc, c = d.part("decode")
+    d.at(27, "wait", "serve.decode", b)           # c enqueued at 27
+    lb.ready = True
+    d.at(30, "enter", "host_sync")
+    d.at(31, "enter", "callbacks")
+    d.at(32, "commit", 1, work_left=1)
+    lc.ready = True                               # done in the caller's turn
+    d.at(50, "begin", 3)
+    assert c.dry == (0.032, 0.050, "caller")
+    d.at(51, "enter", "decode_dispatch")
+    ld, dd = d.part("decode")
+    d.at(53, "wait", "serve.decode", c)
+    d.at(53.05, "enter", "host_sync")             # c read: did not block
+    d.at(54, "enter", "callbacks")
+    d.at(55, "commit", 1, work_left=1)
+    d.at(56, "begin", 4)
+    d.at(56.5, "wait", "serve.decode", dd)        # nothing enqueued behind
+    d.at(66, "enter", "host_sync")
+    d.at(67, "enter", "callbacks")
+    d.at(68, "commit", 1, work_left=0)            # the engine is empty
+    d.at(200, "begin", 5)
+    d.at(201, "enter", "prefill_dispatch")
+    le, e = d.part("prefill", 16, reason="idle_start")
+    d.at(203, "wait", "serve.prefill", e)
+    d.at(240, "enter", "host_sync")
+    d.at(241, "commit", 1, work_left=0)
+    got = _passes()
+    assert sorted(got) == [3, 20, 30, 53, 203]
+    want = {  # start: (end, exact, first_run, idle_before, idle_max, cause)
+        3: (20, True, True, 0, 0, None),
+        20: (30, True, True, 0, 0, None),
+        30: (50, False, False, 0, 0, None),
+        53: (66, True, False, 3, 21, "caller"),
+        203: (240, True, False, 137, 137, "no_work")}
+    for start, (end, exact_, first, lo, hi, cause) in want.items():
+        s = got[start]
+        args = s[5]
+        assert s[4] * 1e3 == pytest.approx(end)
+        assert (args["exact"], args["first_run"], args["idle_cause"]) == \
+            (exact_, first, cause), start
+        assert args["idle_before_s"] * 1e3 == pytest.approx(lo)
+        assert args["idle_max_s"] * 1e3 == pytest.approx(hi)
+    device = sum(s[4] - s[3] for s in got.values())
+    idle = sum(s[5]["idle_before_s"] for s in got.values())
+    assert (device + idle) * 1e3 == pytest.approx(240 - 3)
+    assert [s[5]["dispatch_s"] * 1e3 for s in got.values()] == \
+        pytest.approx([2, 2, 3, 2, 2])
+    assert [s[5]["step"] for s in got.values()] == [1, 1, 2, 3, 5]
+    assert [s[5]["reason"] for s in got.values()] == \
+        ["idle_start", None, None, None, "idle_start"]
+    # the same parts as the cost table hears them, and as counters
+    assert [(k, b, round(t * 1e3, 6), x) for k, b, t, x in d.heard] == [
+        ("prefill", 16, 17, True), ("decode", 4, 10, True),
+        ("decode", 4, 20, False), ("decode", 4, 13, True),
+        ("prefill", 16, 37, True)]
+    snap = telemetry.snapshot()["metrics"]
+
+    def counter(name):
+        return {tuple(s["labels"].values())[0]: s["value"]
+                for s in snap[name]["samples"]}
+
+    assert counter("mxtpu_serve_device_seconds_total") == pytest.approx(
+        {"prefill": 0.054, "decode": 0.043})
+    assert counter("mxtpu_serve_device_idle_seconds_total") == \
+        pytest.approx({"caller": 0.003, "no_work": 0.137})
+    assert counter("mxtpu_serve_first_runs_total") == {"prefill": 1,
+                                                       "decode": 1}
+    # the newest part is polled while unread, at each turn (a: at 4 and 6,
+    # then b is the newest), and a part not seen done as its wait opens
+    # (a at 6, e at 203: enqueued in the turn that opens its wait)
+    assert (la.polls, le.polls) == (3, 1) and lc.polls > 0
+
+
+def test_exact_follows_the_wait(tel):
+    """A part seen done as its wait opens did not block, however long the
+    wait: that part is not exact, and neither is the one behind it, whose
+    start is then unknown.  A part not seen done blocked the host, however
+    short the read: a blocked read after an idle makes the next part exact
+    again.  The device running dry inside a phase names that phase."""
+    d = _Driver()
+    d.at(0, "begin", 1)
+    d.at(1, "enter", "decode_dispatch")
+    la, a = d.part("decode")
+    d.at(2, "enter", "callbacks")
+    d.at(3, "commit", 1, work_left=1)
+    d.at(4, "begin", 2)
+    d.at(5, "enter", "decode_dispatch")
+    lb, b = d.part("decode")
+    d.at(5.5, "enter", "schedule")                # b enqueued at 5.5
+    la.ready = True                               # a: done behind b's enqueue
+    d.at(6, "wait", "serve.decode", a)
+    d.at(6.05, "enter", "host_sync")              # a: no block
+    d.at(7, "enter", "callbacks")
+    d.at(8, "commit", 1, work_left=1)
+    d.at(9, "begin", 3)
+    d.at(10, "enter", "decode_dispatch")
+    lc, c = d.part("decode")
+    d.at(11, "wait", "serve.decode", b)
+    d.at(19, "enter", "host_sync")                # b: blocked, start unknown
+    lc.ready = True
+    d.at(20, "enter", "callbacks")                # c done inside host_sync
+    d.at(21, "commit", 1, work_left=1)
+    d.at(22, "begin", 4)
+    d.at(23, "enter", "decode_dispatch")
+    ld, dd = d.part("decode")
+    d.at(25, "wait", "serve.decode", c)           # c: seen done at 20
+    d.at(25.05, "enter", "host_sync")
+    d.at(26, "enter", "callbacks")
+    d.at(27, "commit", 1, work_left=1)
+    d.at(28, "begin", 5)
+    d.at(29, "wait", "serve.decode", dd)
+    d.at(40, "enter", "host_sync")                # d: blocked, after idle
+    d.at(41, "commit", 1, work_left=0)
+    got = [s[5] for s in telemetry.tracer().spans(prefix="serve.pass")]
+    assert [g["exact"] for g in got] == [False, False, False, True]
+    assert got[3]["idle_cause"] == "host_sync"
+    assert got[3]["idle_before_s"] * 1e3 == pytest.approx(25 - 20)
+    assert got[3]["idle_max_s"] * 1e3 == pytest.approx(25 - 19)
+
+
+def test_a_part_done_as_its_wait_opens_is_not_exact(tel):
+    """A part the device finished while the host was busy elsewhere, with
+    the next part already behind it (no idle to see): its read does not
+    block however long the wait lasts (a stall of the host's own), so it
+    is not exact, it ends by the wait's opening, and the part behind it,
+    whose start is then unknown, is not exact either."""
+    d = _Driver()
+    d.at(0, "begin", 1)
+    d.at(1, "enter", "decode_dispatch")
+    la, a = d.part("decode")
+    d.at(2, "enter", "callbacks")
+    d.at(3, "commit", 1, work_left=1)
+    d.at(4, "begin", 2)
+    d.at(5, "enter", "decode_dispatch")
+    lb, b = d.part("decode")
+    d.at(6, "enter", "callbacks")                 # b queued behind a
+    la.ready = True                               # a done, b running
+    d.at(16, "wait", "serve.decode", a)
+    d.at(19, "enter", "host_sync")                # 3 ms: not a block
+    d.at(20, "commit", 1, work_left=1)
+    d.at(21, "begin", 3)
+    d.at(22, "wait", "serve.decode", b)
+    d.at(30, "enter", "host_sync")                # b: blocked
+    d.at(31, "commit", 1, work_left=0)
+    got = {round(s[3] * 1e3, 6): s for s in
+           telemetry.tracer().spans(prefix="serve.pass")}
+    assert sorted(got) == [2, 16]
+    assert got[2][4] * 1e3 == pytest.approx(16)
+    assert [(g[5]["exact"], g[5]["idle_before_s"]) for g in got.values()] \
+        == [(False, 0.0), (False, 0.0)]
+    assert [(h[0], h[3]) for h in d.heard] == [("decode", False)] * 2
+
+
+def test_the_timer_follows_telemetry(tel):
+    """Parts stamped while telemetry was on are dropped when it goes off
+    mid-run (no poll, no record); nothing is timed until it is on again."""
+    d = _Driver()
+    d.at(0, "begin", 1)
+    d.at(1, "enter", "decode_dispatch")
+    la, a = d.part("decode")
+    d.at(2, "commit", 1, work_left=1)
+    telemetry.disable()
+    d.at(3, "begin", 2)
+    assert not d.sp.tracing and not d.sp._unread and la.polls == 0
+    d.at(4, "wait", "serve.decode", None)
+    d.at(9, "enter", "host_sync")
+    d.at(10, "commit", 1, work_left=1)
+    assert d.heard == [] and la.polls == 0
+
+
+# -- the pass record in the engine ---------------------------------------------
+def test_first_run_is_set_once_per_program(tel, model):
+    """``first_run`` marks one part per (kind, bucket) an engine runs, the
+    first (``PerfAttrib.done``'s dispatch count); the counter agrees."""
+    eng = _engine(model)
+    try:
+        for p in _prompts(5, seed=13):
+            assert _run(eng, p, max_new=5).status == "finished"
+        spans = telemetry.tracer().spans(prefix="serve.pass")
+        keys = {(s[5]["kind"], s[5]["bucket"]) for s in spans}
+        firsts = [(s[5]["kind"], s[5]["bucket"]) for s in spans
+                  if s[5]["first_run"]]
+        assert sorted(firsts) == sorted(keys) and len(spans) > len(keys)
+        # the first of each key in time is the one marked
+        seen = set()
+        for s in spans:
+            key = (s[5]["kind"], s[5]["bucket"])
+            assert s[5]["first_run"] == (key not in seen)
+            seen.add(key)
+        fam = telemetry.snapshot()["metrics"]["mxtpu_serve_first_runs_total"]
+        assert sum(s["value"] for s in fam["samples"]) == len(keys)
+    finally:
+        eng.shutdown()
+
+
+def test_idle_after_an_emptied_engine_is_no_work(tel, model):
+    """The engine runs out of work, nothing arrives for 50 ms, then a
+    request does: the device's idle before its first part is ``no_work``
+    and at least the wait; the idle inside a busy run is named by a host
+    phase or the caller's turn, never ``no_work``."""
+    eng = _engine(model)
+    try:
+        _run(eng, _prompts(1, seed=3)[0], max_new=4)
+        eng.run()
+        assert not eng.has_work()
+        time.sleep(0.05)
+        t = time.perf_counter()
+        _run(eng, _prompts(1, seed=4)[0], max_new=4)
+        eng.run()
+        spans = telemetry.tracer().spans(prefix="serve.pass")
+        after = [s for s in spans if s[3] >= t]
+        assert after[0][5]["idle_cause"] == "no_work"
+        assert after[0][5]["idle_before_s"] >= 0.05
+        assert after[0][5]["reason"] == "idle_start"
+        causes = {s[5]["idle_cause"] for s in after[1:]}
+        assert "no_work" not in causes
+        assert causes <= {None, "caller", "device_wait"} | set(
+            profiling.PHASES)
+    finally:
+        eng.shutdown()
+
+
+def test_telemetry_off_reads_no_more_clock_and_polls_nothing(model,
+                                                             monkeypatch):
+    """Off, the timer does nothing: no record, no poll.  On, it reads the
+    clock no more than off does: the stamps are the phases' own reads."""
+    reads = {"n": 0}
+
+    def clock():
+        reads["n"] += 1
+        return reads["n"] * 1e-3
+
+    def serve():
+        eng = _engine(model)
+        eng._sprof._clock = clock
+        reads["n"] = 0
+        for p in _prompts(3, seed=21):
+            eng.submit(p, max_new_tokens=6)
+        eng.run()
+        n, steps = reads["n"], eng._sprof.summary()["steps"]
+        eng.shutdown()
+        return n, steps
+
+    def never(*a, **kw):
+        raise AssertionError("the pass timer ran with telemetry off")
+
+    assert not telemetry.enabled()
+    with monkeypatch.context() as m:
+        m.setattr(profiling._PassRecord, "__init__", never)
+        m.setattr(profiling.StepProfiler, "_poll", never)
+        m.setattr(profiling.StepProfiler, "_stamp", never)
+        off = serve()
+    telemetry.reset()
+    telemetry.enable()
+    try:
+        on = serve()
+        assert telemetry.tracer().spans(prefix="serve.pass")
+    finally:
+        telemetry.disable()
+        telemetry.reset()
+    assert on == off
+
+
 # -- engine integration: three-view agreement ---------------------------------
 def test_statusz_metrics_flight_three_views_agree(model, tel,
                                                   monkeypatch, tmp_path):

@@ -134,6 +134,14 @@ KINDS = {
 }
 
 
+def _depth_0(eng):
+    """The same loop at depth 0: no pass is enqueued behind an unread one
+    (the launch behind it enqueues nothing, so the next call starts with
+    nothing unread and enqueues the pass it reads)."""
+    launch = eng._launch
+    eng._launch = lambda behind: None if behind is not None else launch(None)
+
+
 def _build(kind, request, hold):
     fixture, kw, lens, news, subs, _ = KINDS[kind]
     sym, params = request.getfixturevalue(fixture)
@@ -148,8 +156,7 @@ def _build(kind, request, hold):
             eng.adapter_store.register(aid, _lora(params, seed=seed),
                                        alpha=8.0)
     if hold:
-        # the same loop at depth 0: no pass is enqueued behind an unread one
-        eng._hold = lambda: "held"
+        _depth_0(eng)
     same = kind == "prefix_hits"
     vocab = 61 if fixture in ("hybrid", "routed") else VOCAB
     prompts = _prompts(lens, vocab)
@@ -352,23 +359,39 @@ def test_warm_run_compiles_nothing_and_counts_its_order(gpt, monkeypatch):
     eng.shutdown()
 
 
-def test_a_sampled_step_is_enqueued_with_nothing_unread(gpt, monkeypatch):
-    """A dispatch the perf sampler times blocks on its outputs: that pass,
-    and the one behind it, start with nothing unread; the tokens are those
-    of an engine that samples nothing."""
+def test_timing_every_pass_settles_no_pass(gpt):
+    """The step record times every pass from the clock reads its phases
+    take anyway: with telemetry on, chat-like traffic (a request arriving
+    beside a running decode) runs ahead exactly as with it off, the same
+    tokens and the same passes settled (the idle start alone, no pass
+    for timing), and every part read has its ``serve.pass`` span."""
+    from mxnet_tpu import telemetry
+
     net, params = gpt
 
     def serve():
         eng = mx.serve.Engine(params, symbol=net, **GEO)
         reqs = [eng.submit(p, max_new_tokens=9) for p in _prompts((9, 12))]
+        for _ in range(3):
+            eng.step()
+        reqs.append(eng.submit(_prompts((7,), seed=9)[0], max_new_tokens=6))
         eng.run()
         order = eng.statusz()["step_order"]
+        parts = sum(e["prefills"] + bool(e["decodes"])
+                    for e in eng._sprof.recent(500))
         eng.shutdown()
-        return [list(r.tokens) for r in reqs], order
+        return [list(r.tokens) for r in reqs], order, parts
 
-    plain, order = serve()
-    assert "perf_sample" not in order["settled"]
-    monkeypatch.setenv("MXTPU_PERF_ATTRIB_SAMPLE", "4")
-    sampled, order = serve()
-    assert sampled == plain
-    assert order["settled"]["perf_sample"] >= 2 and order["ahead"] > 0
+    plain, order, _ = serve()
+    telemetry.reset()
+    telemetry.enable()
+    try:
+        timed, timed_order, parts = serve()
+        passes = telemetry.tracer().spans(prefix="serve.pass")
+    finally:
+        telemetry.disable()
+        telemetry.reset()
+    assert timed == plain and timed_order == order
+    assert set(order["settled"]) == {"idle_start"} and order["ahead"] > 0
+    assert len(passes) == parts
+    assert {p[5]["reason"] for p in passes} == {None, "idle_start"}

@@ -5,15 +5,16 @@ performance-attribution plane (``telemetry/perf_attrib.py``).
 Reads a live ``/statusz.json`` endpoint or a saved snapshot (an engine
 ``statusz()`` dict, a full statusz page, a replica scrape, or a
 serve_bench record that embedded one — any JSON containing a ``perf``
-section) and renders, per engine: the sampling state, the overall
-goodput line (sampled device seconds, MFU, achieved TFLOP/s, device
-cost per 1k tokens), and the per-program table sorted by share of the
-sampled step budget — the enumerable answer to "which program family
-do I optimize next".
+section) and renders, per engine: how many steps were timed, the
+overall goodput line (timed device seconds, MFU, achieved TFLOP/s,
+device cost per 1k tokens), and the per-program table sorted by share
+of the exactly timed device time — the enumerable answer to "which
+program family do I optimize next".  The step record times every pass
+while telemetry is on (``telemetry/profiling.py``, "The pass record").
 
-With sampling off (the default) the cost table still prints: flops and
-bytes per (kind, bucket) from ``cost_analysis()``, dispatch counts,
-but no device-time columns.  Pure stdlib.
+With telemetry off the cost table still prints: flops and bytes per
+(kind, bucket) from ``cost_analysis()``, dispatch counts, but no
+device-time columns.  Pure stdlib.
 
 Usage:
   python tools/perf_report.py --url http://host:port
@@ -35,10 +36,10 @@ def fetch(url, timeout=5.0):
 def find_perf_sections(obj, path="$"):
     """Every perf-attribution section in a JSON tree, as
     ``[(path, section)]`` — a section is a dict carrying both
-    ``programs`` and ``sample_every`` (the PerfAttrib.statusz shape)."""
+    ``programs`` and ``timed_steps`` (the PerfAttrib.statusz shape)."""
     out = []
     if isinstance(obj, dict):
-        if "programs" in obj and "sample_every" in obj:
+        if "programs" in obj and "timed_steps" in obj:
             out.append((path, obj))
         else:
             for k, v in obj.items():
@@ -70,10 +71,9 @@ def _fmt_count(v, unit=1e9, nd=2):
 def render(path, perf):
     lines = [f"perf section at {path}:"]
     lines.append(
-        f"  sampling: every {perf.get('sample_every')} step(s)"
-        f" | sampled_steps={perf.get('sampled_steps')}"
+        f"  timed_steps={perf.get('timed_steps')}"
         f" tokens={perf.get('tokens')}"
-        f" sampled_tokens={perf.get('sampled_tokens')}"
+        f" timed_tokens={perf.get('timed_tokens')}"
         f" cost_errors={perf.get('cost_errors')}")
     mfu = perf.get("mfu")
     lines.append(
@@ -89,7 +89,7 @@ def render(path, perf):
         f" hbm={_fmt_count(perf.get('peak_hbm_bytes_per_chip'), 1e9)}GB/s")
     lines.append("")
     lines.append(
-        f"  {'KIND':<12} {'BUCKET':>6} {'DISP':>7} {'SAMPLED':>7} "
+        f"  {'KIND':<12} {'BUCKET':>6} {'DISP':>7} {'TIMED':>7} "
         f"{'MEAN_US':>8} {'P99_US':>8} {'SHARE%':>6} {'GFLOP':>8} "
         f"{'GB':>7} {'TFLOP/S':>8} {'MFU%':>6} {'SRC':<13}")
     rows = sorted(perf.get("programs") or [],
@@ -99,7 +99,7 @@ def render(path, perf):
         rmfu = r.get("mfu")
         lines.append(
             f"  {str(r.get('kind')):<12} {r.get('bucket'):>6} "
-            f"{r.get('dispatches', 0):>7} {r.get('sampled', 0):>7} "
+            f"{r.get('dispatches', 0):>7} {r.get('timed', 0):>7} "
             f"{_fmt_us(r.get('mean_s')):>8} "
             f"{_fmt_us(r.get('p99_s')):>8} "
             f"{_fmt(100 * share if share is not None else None, 1):>6} "

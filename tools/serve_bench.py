@@ -754,20 +754,22 @@ def run_lora(mx, args, make_engine, workload, params):
 
 
 def run_perf_attrib(mx, args, make_engine, workload):
-    """Performance-attribution A/B over the SAME workload: sampled
-    device timing on (every step) vs off.  The acceptance bar: tokens
-    byte-identical, the AOT fingerprint unchanged, the sampling
+    """Performance-attribution A/B over the SAME workload: the step
+    record's pass timer on (telemetry enabled: every part of every pass
+    timed from the phases' clock reads) vs off.  The acceptance bar:
+    tokens byte-identical, the AOT fingerprint unchanged, the timer's
     overhead within measurement noise, and the on-arm's cost table
     populated with nonzero flops for every dispatched family."""
-    import os as _os
-
-    from mxnet_tpu.telemetry import perf_attrib as pa
+    from mxnet_tpu import telemetry
 
     conc = args.concurrency
 
-    def once(sample_every):
-        prev = _os.environ.get(pa.ENV_SAMPLE)
-        _os.environ[pa.ENV_SAMPLE] = str(sample_every)
+    def once(timed):
+        was = telemetry.enabled()
+        if timed:
+            telemetry.enable()
+        else:
+            telemetry.disable()
         try:
             eng = make_engine(conc, max_queue=len(workload) + 1)
             reqs, wall = run_closed(mx, eng, workload, conc)
@@ -775,22 +777,22 @@ def run_perf_attrib(mx, args, make_engine, workload):
             fp = eng._spec_digest
             eng.shutdown()
         finally:
-            if prev is None:
-                _os.environ.pop(pa.ENV_SAMPLE, None)
+            if was:
+                telemetry.enable()
             else:
-                _os.environ[pa.ENV_SAMPLE] = prev
+                telemetry.disable()
         return reqs, wall, perf, fp
 
     # warm the shared program cache AND replay the workload once so
     # neither arm pays compiles or first-touch allocator costs — the
-    # overhead_ratio must compare sampling, not run order
+    # overhead_ratio must compare the timer, not run order
     weng = make_engine(conc, max_queue=len(workload) + 1)
     weng.warmup()
     run_closed(mx, weng, workload, conc)
     weng.shutdown()
 
-    off_reqs, off_wall, off_perf, off_fp = once(0)
-    on_reqs, on_wall, on_perf, on_fp = once(1)
+    off_reqs, off_wall, off_perf, off_fp = once(False)
+    on_reqs, on_wall, on_perf, on_fp = once(True)
     identical = all(
         a.status == b.status == "finished" and a.tokens == b.tokens
         for a, b in zip(off_reqs, on_reqs))
@@ -810,15 +812,15 @@ def run_perf_attrib(mx, args, make_engine, workload):
         "wall_s_off": round(off_wall, 3),
         "tokens_per_sec_on": round(tps_on, 1) if tps_on else None,
         "tokens_per_sec_off": round(tps_off, 1) if tps_off else None,
-        # >1 means the sampled sync cost wall time; CI gates this
-        # loosely (CPU walls are noisy) — the honest number to track
+        # >1 means the timer (and telemetry's spans) cost wall time; CI
+        # gates this loosely (CPU walls are noisy)
         "overhead_ratio": (round(on_wall / off_wall, 3)
                            if off_wall else None),
         # the off arm must record ZERO timings (inert default)...
-        "off_sampled_steps": off_perf["sampled_steps"],
-        # ...while the on arm attributes every step
-        "sampled_steps": on_perf["sampled_steps"],
-        "sampled_dispatches": sum(r["sampled"] for r in rows),
+        "off_timed_steps": off_perf["timed_steps"],
+        # ...while the on arm times every step
+        "timed_steps": on_perf["timed_steps"],
+        "timed_dispatches": sum(r["timed"] for r in rows),
         "cost_table_kinds": sorted({r["kind"] for r in rows}),
         "cost_flops_nonzero": bool(rows) and all(
             r["flops"] and r["flops"] > 0 for r in rows),
@@ -1190,8 +1192,9 @@ def main():
                         "acceptance) and a chi-square/TV distribution-"
                         "agreement pin -> the SAMPLING_BENCH.json "
                         "stage. "
-                        "perf-attrib: device-timing sampling on vs "
-                        "off over the same workload — overhead within "
+                        "perf-attrib: the step record's pass timer "
+                        "(telemetry) on vs off over the same workload "
+                        "— overhead within "
                         "noise, tokens byte-identical, fingerprints "
                         "unchanged, cost table populated -> the "
                         "PERF_ATTRIB_BENCH.json stage. "
@@ -1471,7 +1474,7 @@ def main():
             # the serve_perf contract fields
             out["fingerprint_identical"] = rec["fingerprint_identical"]
             out["overhead_ratio"] = rec["overhead_ratio"]
-            out["sampled_dispatches"] = rec["sampled_dispatches"]
+            out["timed_dispatches"] = rec["timed_dispatches"]
             out["cost_table_kinds"] = rec["cost_table_kinds"]
             out["cost_flops_nonzero"] = rec["cost_flops_nonzero"]
             out["achieved_tflops"] = rec["achieved_tflops"]
