@@ -33,7 +33,11 @@ Two formulations of the SAME arithmetic:
   slot 0).
 
 The causal depthwise convolution in front of the scan keeps the last
-``K - 1`` rows of its input between programs (:func:`causal_conv`).
+``K - 1`` rows of its input between programs (:func:`causal_conv`), in
+a pool stacked like the states' (:func:`conv_rows_shape`); a decode
+step reads and writes a row's at ``(layer, slot[b])``
+(:func:`conv_state_step`: on a TPU the Mosaic kernel in
+``ops/pallas_conv_step.py`` where the pool holds lane rows).
 
 Everything that enters a state is float32; the large products take
 bfloat16 operands with float32 accumulation, like every other matmul of
@@ -49,9 +53,14 @@ import numpy as np
 from . import pallas_util
 
 __all__ = ["causal_conv", "ssd_chunked_scan", "ssm_state_update",
-           "softplus"]
+           "softplus", "conv_rows_shape", "conv_slot_rows",
+           "conv_pool_rows", "conv_state_step", "CONV_LANES"]
 
 _F32 = jnp.float32
+
+# the convolution rows' lane rows, and the sublane tile a slot's lane
+# rows are padded to (the pool's tiling in HBM, which a DMA addresses)
+CONV_LANES, CONV_SUBLANES = 128, 8
 
 
 def softplus(x):
@@ -85,6 +94,72 @@ def causal_conv(u, prev, weight, bias, n_valid=None):
         rows = jax.lax.dynamic_slice_in_dim(
             full, jnp.asarray(n_valid, jnp.int32), K - 1, axis=0)
     return out, rows
+
+
+def conv_rows_shape(K, channels):
+    """One slot's K-1 convolution rows as the pool holds them.  Where
+    the ``channels`` C are a multiple of 128: ``(R, 128)`` lane rows,
+    row ``j`` of the K-1 at lane rows ``[j C / 128, (j + 1) C / 128)``,
+    ``R`` = (K-1) C / 128 rounded up to a multiple of 8 (the rows behind
+    them stay 0), so that a slot is whole tiles of the pool a DMA can
+    address.  Elsewhere the K-1 rows flat, ``((K-1) C,)``."""
+    width = (K - 1) * channels
+    if channels % CONV_LANES:
+        return (width,)
+    rows = width // CONV_LANES
+    return (-(-rows // CONV_SUBLANES) * CONV_SUBLANES, CONV_LANES)
+
+
+def conv_slot_rows(x, K, C):
+    """Slots' rows as the pool holds them, ``(..., *conv_rows_shape(K,
+    C))``, as ``(..., K-1, C)``."""
+    lead = x.shape[:x.ndim - len(conv_rows_shape(K, C))]
+    flat = x.reshape(lead + (-1,))[..., :(K - 1) * C]
+    return flat.reshape(lead + (K - 1, C))
+
+
+def conv_pool_rows(rows, K, C):
+    """``(..., K-1, C)`` as the pool holds a slot's rows."""
+    shape = conv_rows_shape(K, C)
+    lead = rows.shape[:-2]
+    flat = rows.reshape(lead + (-1,))
+    pad = int(np.prod(shape)) - flat.shape[-1]
+    if pad:
+        flat = jnp.pad(flat, ((0, 0),) * len(lead) + ((0, pad),))
+    return flat.reshape(lead + shape)
+
+
+def conv_state_step(pool, layer, slots, u, weight, bias, impl=None):
+    """One position of the causal convolution for each of B rows, each
+    row's K-1 rows in front of it read from and written back to its slot
+    of the pool.
+
+    pool (L, S, *conv_rows_shape(K, C)) in the activation dtype, the
+    donated stack of every state layer's rows; ``layer`` an int; slots
+    (B,) int32 (padded rows name the null slot 0); u (B, C); weight (C,
+    K); bias (C,).  Returns ``(silu(conv) (B, C) in u's dtype, pool)``.
+    ``impl``: None follows the backend and the pool's layout (the kernel
+    in ``ops/pallas_conv_step.py`` on a TPU where the pool holds lane
+    rows: a dead row moves nothing there and its output is 0, where this
+    XLA form computes it and writes slot 0); ``"jnp"`` forces the XLA
+    form, a gather, :func:`causal_conv` and a scatter: the kernel's
+    parity oracle.
+    """
+    if impl not in (None, "jnp"):
+        raise ValueError(f"conv_state_step: impl must be None or 'jnp' "
+                         f"(got {impl!r})")
+    if impl is None and pool.ndim == 4 and pallas_util.on_tpu():
+        from .pallas_conv_step import conv_step_kernel
+
+        return conv_step_kernel(pool, layer, slots, u, weight, bias)
+    C, K = weight.shape
+    # each row is a span of one position in front of its slot's rows
+    prev = conv_slot_rows(pool[layer, slots], K, C)
+    out, rows = jax.vmap(causal_conv, in_axes=(0, 0, None, None))(
+        u[:, None, :], prev.astype(u.dtype), weight, bias)
+    pool = pool.at[layer, slots].set(
+        conv_pool_rows(rows.astype(pool.dtype), K, C))
+    return out[:, 0], pool
 
 
 def ssd_chunked_scan(x, dt, dA, Bm, Cm, D, state, chunk):

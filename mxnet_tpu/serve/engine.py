@@ -90,6 +90,7 @@ from ..telemetry.request_trace import RequestTracer
 from . import adapters as adapters_mod
 from . import hybrid as hybrid_mod
 from ..ops import moe as moe_ops
+from ..ops import ssm as ssm_ops
 from .kv_block_manager import BlockManager, HostKVPool, WindowGroup
 from .programs import (TOK_PUT, TOK_PUT1, TOK_TAKE, TOP_LOGPROBS, _ModelCfg,
                        _build_chunk, _build_decode, _build_prefill,
@@ -780,7 +781,8 @@ class Engine:
                 (M, S, hd.mamba_heads, hd.mamba_head_dim, hd.mamba_state),
                 jnp.float32)
             self._state_conv = jnp.zeros(
-                (M, S, (hd.mamba_conv - 1) * hd.conv_dim), dt)
+                (M, S) + ssm_ops.conv_rows_shape(hd.mamba_conv,
+                                                 hd.conv_dim), dt)
         self._key = jax.random.PRNGKey(seed)
         # the token pool (serve/programs.py): the sampled tokens of the
         # unread pass, on the device for the next pass's operands.  A
@@ -909,8 +911,9 @@ class Engine:
                 ("reason",))
             self._tel_state_skipped = telemetry.counter(
                 "mxtpu_serve_state_updates_skipped_total",
-                "state updates the state kernel skips: decode rows that "
-                "name the null slot, times the state layers")
+                "state updates the state and convolution kernels skip: "
+                "decode rows that name the null slot, times the state "
+                "layers")
             self._state_skipped = 0
         telemetry.gauge("mxtpu_serve_blocks_total",
                         "allocatable KV-cache blocks").set(
@@ -1014,6 +1017,12 @@ class Engine:
             state_slots=int(self._state_ssm.shape[1]),
             state_dtypes=[str(self._state_ssm.dtype),
                           str(self._state_conv.dtype)]))
+        # the convolution rows in lane rows (ops.ssm.conv_rows_shape),
+        # only-when-on: an artifact traced on the flat pool takes other
+        # operands and, on a TPU, bakes the XLA gather and scatter
+        if self._state_conv is not None and self._state_conv.ndim == 4:
+            state.update(conv_rows=[int(n)
+                                    for n in self._state_conv.shape[2:]])
         if self._cache_wk is not None:
             state.update(window_group=[int(n)
                                        for n in self._cache_wk.shape[:2]])
@@ -2462,8 +2471,9 @@ class Engine:
             note = dict(batch=B, bucket=bucket,
                         **self._kv_tiles(reqs, bucket))
         if self._state_ssm is not None:
-            # the padded rows name the null slot: the state kernel does
-            # no work for them (ops/pallas_ssm_update.py)
+            # the padded rows name the null slot: the state and the
+            # convolution kernels do no work for them
+            # (ops/pallas_ssm_update.py, ops/pallas_conv_step.py)
             skipped = (bucket - B) * int(self._state_ssm.shape[0])
             self._state_skipped += skipped
             self._tel_state_skipped.inc(skipped)

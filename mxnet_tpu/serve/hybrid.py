@@ -24,13 +24,15 @@ one position of B rows, :class:`_SpanMix` for a span of one request):
   over a chunk;
 - a **state-space** layer reads and writes the request's slot of two
   pools stacked over the STATE-SPACE layers, ``ssm (M, S+1, H, P, N)``
-  float32 and ``conv (M, S+1, (K-1) * C)`` in the activation dtype (a
-  slot's K-1 rows side by side, for the same reason), slot 0 the null
-  slot that padded rows write to.  Its ``B`` and ``C`` rows come in
-  ``mamba_groups`` state groups, each read by its own heads and normed
-  on its own behind the gate.  Decode updates the live
-  rows' states in place at ``(layer, slot[b])``
-  (``ops.ssm.ssm_state_update``); a span runs the chunked scan
+  float32 and ``conv`` in the activation dtype, a slot's K-1 rows side
+  by side (for the same reason) and, where C is a multiple of 128, cut
+  into lane rows a DMA can address, ``(M, S+1, R, 128)``
+  (``ops.ssm.conv_rows_shape``), slot 0 the null slot that padded rows
+  name.  Its ``B`` and ``C`` rows come in ``mamba_groups`` state
+  groups, each read by its own heads and normed on its own behind the
+  gate.  Decode updates the live rows' convolution rows and states in
+  place at ``(layer, slot[b])`` (``ops.ssm.conv_state_step``,
+  ``ops.ssm.ssm_state_update``); a span runs the chunked scan
   (``ops.ssm.ssd_chunked_scan``) from the slot's state, or from ZERO
   when the span starts at position 0: the first pass of a request (and
   of a preempted request prefilled anew) never reads its slot, so a
@@ -489,14 +491,9 @@ class _DecodeMix(_Mix):
         B = h.shape[0]
         z, xBC, dt = _mamba_in(dec, P, p, h)
         # the convolution over the slot's K-1 rows and this position
-        # each row is a span of one position in front of its slot's rows
-        prev = self.conv[m, self.slots].reshape(B, dec.mamba_conv - 1, -1)
-        xBC, rows = jax.vmap(ssm_ops.causal_conv, in_axes=(0, 0, None, None))(
-            xBC[:, None, :], prev.astype(h.dtype), P[f"{p}_conv_weight"],
+        xBC, self.conv = ssm_ops.conv_state_step(
+            self.conv, m, self.slots, xBC, P[f"{p}_conv_weight"],
             P[f"{p}_conv_bias"])
-        self.conv = self.conv.at[m, self.slots].set(
-            rows.astype(self.conv.dtype).reshape(B, -1))
-        xBC = xBC[:, 0]
         di = dec.d_inner
         x = xBC[:, :di].reshape(B, dec.mamba_heads, dec.mamba_head_dim)
         dt, dA = _mamba_steps(dec, P, p, dt)
@@ -569,23 +566,25 @@ class _SpanMix(_Mix):
         P, p = self.params, f"{dec.name}_l{i}"
         T, di, N = self.T, dec.d_inner, dec.mamba_state
         H, Pd = dec.mamba_heads, dec.mamba_head_dim
+        K, cd = dec.mamba_conv, dec.conv_dim
         z, xBC, dt = _mamba_in(dec, P, p, h)
         if self.table is None:
-            prev = jnp.zeros((dec.mamba_conv - 1, dec.conv_dim), h.dtype)
+            prev = jnp.zeros((K - 1, cd), h.dtype)
             S0 = jnp.zeros((H, Pd, N), jnp.float32)
         else:
             # a pass that starts at position 0 starts from zero whatever
             # the slot holds: admission and re-prefill reset it here
             fresh = self.start == 0
             prev = jnp.where(fresh, jnp.zeros((), h.dtype),
-                             self.conv[m, self.slot].astype(h.dtype)
-                             ).reshape(dec.mamba_conv - 1, dec.conv_dim)
+                             ssm_ops.conv_slot_rows(
+                                 self.conv[m, self.slot], K, cd
+                             ).astype(h.dtype))
             S0 = jnp.where(fresh, np.float32(0.0), self.ssm[m, self.slot])
         xBC, rows = ssm_ops.causal_conv(
             xBC, prev, P[f"{p}_conv_weight"], P[f"{p}_conv_bias"],
             n_valid=self.n_valid)
         self.conv = self.conv.at[m, self.slot].set(
-            rows.astype(self.conv.dtype).reshape(-1))
+            ssm_ops.conv_pool_rows(rows.astype(self.conv.dtype), K, cd))
         dt, dA = _mamba_steps(dec, P, p, dt, self.valid)
         with jax.named_scope(SCAN_SCOPE):
             y, S = ssm_ops.ssd_chunked_scan(

@@ -397,11 +397,12 @@ def _cell_width_engine(kind):
 @pytest.mark.parametrize("kind", ["gpt", "hybrid"])
 def test_decode_program_holds_no_copy_of_a_cache_pool(kind, one_chip):
     """The decode program as the chip's compiler leaves it: one Mosaic
-    call a layer (the paged kernel; in a hybrid the state update for a
-    state-space layer), and the only results of the K/V pool's size are
-    the in-place writes, two scatter fusions an attention layer.  A
-    ``copy`` of the pool, or a temporary of its size, is what PR 27
-    took out; the kernels' views of the stack must stay bitcasts."""
+    call an attention layer (the paged kernel) and two a state-space
+    layer (the convolution rows' step and the state update), and the
+    only results of the K/V pool's size are the in-place writes, two
+    scatter fusions an attention layer.  A ``copy`` of the pool, or a
+    temporary of its size, would be a second pool in HBM and a pass over
+    the first; the kernels' views of the stack must stay bitcasts."""
     with hlo_audit.assume_tpu():
         eng, bucket = _cell_width_engine(kind)
         try:
@@ -413,16 +414,46 @@ def test_decode_program_holds_no_copy_of_a_cache_pool(kind, one_chip):
                                      eng._program_specs("decode", bucket),
                                      one_chip)
             pool_shape, dtype = eng._cache_k.shape, eng._cache_k.dtype
-            n_layers = eng.spec["n_layers"]
+            n_kernels = eng.spec["n_layers"] + (
+                0 if eng._state_ssm is None else eng._state_ssm.shape[0])
         finally:
             eng.shutdown()
     text = compiled.as_text()
-    assert text.count('custom_call_target="tpu_custom_call"') == n_layers
+    assert text.count('custom_call_target="tpu_custom_call"') == n_kernels
     writes = hlo_audit.pool_sized_results(text, pool_shape)
     assert len(writes) == 2 * pool_shape[0], writes
     assert {op for _, op in writes} == {"fusion"}, writes
     pool_bytes = int(np.prod(pool_shape)) * jnp.dtype(dtype).itemsize
     assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 2
+
+
+def test_hybrid_decode_program_holds_no_copy_of_the_conv_rows(one_chip):
+    """The convolution rows' pool of granite's widths (one state layer,
+    65 slots of 3 x 4352 in 104 lane rows) through the chip's compiler:
+    the decode program reads and writes it only through the kernel,
+    which aliases it in place.  No instruction of the program yields an
+    array of the pool's size: not the XLA scatter of the flat pool the
+    kernel replaced, not a copy of the pool into the chip's VMEM and
+    back, which the compiler made around every call while the kernel
+    left the pool's memory to it."""
+    with hlo_audit.assume_tpu():
+        eng, bucket = _cell_width_engine("hybrid")
+        try:
+            eng._donate = True
+            compiled = _chip_compile(eng._program_builder("decode", bucket),
+                                     eng._program_specs("decode", bucket),
+                                     one_chip)
+            conv_shape = eng._state_conv.shape
+        finally:
+            eng.shutdown()
+    assert conv_shape == (1, 65, 104, 128)
+    text = compiled.as_text()
+    assert hlo_audit.pool_sized_results(text, conv_shape) == []
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    # the one call that yields the pool (aliased) is the kernel
+    yields = [line for line in text.splitlines() if " custom-call(" in line
+              and "bf16[1,65,104,128]" in line.split(" custom-call(")[0]]
+    assert len(yields) == 1 and "conv_state_step" in yields[0]
 
 
 SPAN_KERNEL_CASES = {
@@ -492,6 +523,35 @@ def test_state_update_kernel_compiles_for_the_chip(H, G, rows, one_chip):
         'custom_call_target="tpu_custom_call"') == 1
     layer_bytes = slots * H * P * N * 4
     assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes // 4
+
+
+@pytest.mark.parametrize("L,C,rows", [(36, 4352, 32), (36, 4352, 16),
+                                      (5, 10240, 64)])
+def test_conv_step_kernel_compiles_for_the_chip(L, C, rows, one_chip):
+    """The convolution rows' decode step through the chip's compiler at
+    the two hybrid cells' shapes (granite's 3 x 4352 in 104 lane rows,
+    nemotron's 3 x 10240 in 240), the layer a scalar operand: one Mosaic
+    call, and the pool stays where it is, no temporary of its size."""
+    from mxnet_tpu.ops.ssm import conv_rows_shape, conv_state_step
+
+    S = jax.ShapeDtypeStruct
+    shape = (L, 65) + conv_rows_shape(4, C)
+
+    def fwd(pool, sl, u, w, b):
+        return conv_state_step(pool, L - 1, sl, u, w, b)
+
+    with hlo_audit.assume_tpu():
+        compiled = _chip_compile(
+            jax.jit(fwd, donate_argnums=0),
+            [S(shape, jnp.bfloat16), S((rows,), jnp.int32),
+             S((rows, C), jnp.bfloat16), S((C, 4), jnp.bfloat16),
+             S((C,), jnp.bfloat16)], one_chip)
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "conv_state_step" in text and "ssm_state_update" not in text
+    pool_bytes = int(np.prod(shape)) * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 8
+    assert hlo_audit.pool_sized_results(text, shape) == []
 
 
 @pytest.mark.parametrize("act,K,F,D", [("swiglu", 3072, 1024, 3072),

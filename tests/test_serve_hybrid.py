@@ -22,11 +22,11 @@ VOCAB = 61
 CHUNK = 8                          # the scan's chunk at this size
 
 
-def _dec(vocab=VOCAB):
+def _dec(vocab=VOCAB, state=16):
     return H.hybrid_decoder(
         vocab, 32, ["mamba", "mamba", "attention", "mamba"] * 2,
         num_heads=4, kv_heads=2, d_ff=48, mamba_heads=4, mamba_head_dim=16,
-        mamba_state=16, mamba_chunk=CHUNK, embedding_multiplier=3.0,
+        mamba_state=state, mamba_chunk=CHUNK, embedding_multiplier=3.0,
         residual_multiplier=0.5, attention_multiplier=0.2,
         logits_scaling=2.0)
 
@@ -136,19 +136,83 @@ def test_state_update_kernel_equals_the_jnp_update(slots):
     assert (p1[[0, 2]] == np.asarray(pool)[[0, 2]]).all()
 
 
+@pytest.mark.parametrize("C", [128, 256], ids=["3x128", "3x256"])
+@pytest.mark.parametrize("slots", [
+    [2, 4, 1],
+    [2, 4, 1, 0, 0],
+    [0, 2, 0, 4, 0, 1],
+    [0, 0, 3, 0],
+], ids=["all-live", "trailing-dead", "dead-between", "one-live"])
+def test_conv_step_kernel_equals_the_jnp_step(slots, C):
+    """The convolution rows' Mosaic kernel against the gather / conv /
+    scatter form, under TPU interpret mode (a buffer never written is
+    NaN): live rows' outputs and pool rows are bit-identical, a dead
+    row's output is exactly 0, every slot no live row names is untouched
+    (slot 0 included), and so are the other layers."""
+    from jax.experimental.pallas import tpu as pltpu
+    from mxnet_tpu.ops.pallas_conv_step import conv_step_kernel
+
+    rng = np.random.default_rng(C)
+    L, S, K, B = 3, 5, 4, len(slots)
+    rows = jnp.asarray(rng.normal(size=(L, S, K - 1, C)), jnp.bfloat16)
+    pool = ssm.conv_pool_rows(rows, K, C)
+    assert pool.shape == (L, S, 8, 128)          # 3 or 6 lane rows
+    u = jnp.asarray(rng.normal(size=(B, C)), jnp.bfloat16)
+    w = jnp.asarray(rng.normal(size=(C, K)) * 0.5, jnp.bfloat16)
+    b = jnp.asarray(rng.normal(size=(C,)) * 0.1, jnp.bfloat16)
+    sl = jnp.asarray(slots, jnp.int32)
+    y0, p0 = ssm.conv_state_step(pool, 1, sl, u, w, b, impl="jnp")
+    with pltpu.force_tpu_interpret_mode(
+            pltpu.InterpretParams(uninitialized_memory="nan")):
+        y1, p1 = conv_step_kernel(pool, 1, sl, u, w, b, interpret=True)
+    live = np.asarray(slots) != 0
+    named = np.asarray(slots)[live]
+    f32 = lambda a: np.asarray(a.astype(jnp.float32))      # noqa: E731
+    y0, p0, y1, p1, p = map(f32, (y0, p0, y1, p1, pool))
+    assert (y1[live] == y0[live]).all()
+    assert (p1[1, named] == p0[1, named]).all()
+    assert (y1[~live] == 0).all()
+    others = [s for s in range(S) if s not in named]
+    assert (p1[1, others] == p[1, others]).all()
+    assert (p1[[0, 2]] == p[[0, 2]]).all()
+
+
+def test_conv_rows_take_lane_rows_by_their_width():
+    """The pool's layout follows the rows' width alone: lane rows where
+    the channels are a multiple of 128, padded to whole sublane tiles
+    (granite's 3 x 4352 = 102 lane rows -> 104, nemotron's 3 x 10240 =
+    240), flat elsewhere; and a slot's rows go in and come out as they
+    were."""
+    assert ssm.conv_rows_shape(4, 4352) == (104, 128)
+    assert ssm.conv_rows_shape(4, 10240) == (240, 128)
+    assert ssm.conv_rows_shape(4, 96) == (288,)
+    rows = jnp.arange(2 * 3 * 4352, dtype=jnp.float32).reshape(2, 3, 4352)
+    slot = ssm.conv_pool_rows(rows, 4, 4352)
+    assert slot.shape == (2, 104, 128)
+    assert (np.asarray(slot)[:, 102:] == 0).all()
+    assert (ssm.conv_slot_rows(slot, 4, 4352) == rows).all()
+
+
 # -- the engine against the reference ------------------------------------------
 
-@pytest.mark.parametrize("plen,new", [(5, 6), (16, 4), (37, 5)])
+@pytest.mark.parametrize("plen,new,state", [(5, 6, 16), (16, 4, 16),
+                                            (37, 5, 16), (37, 5, 32)])
 def test_prefill_and_decode_through_the_caches_equal_the_reference(
-        model, plen, new):
+        model, plen, new, state):
     """Logits, not tokens: the engine's greedy token at every generated
     position must be the reference's argmax up to rounding.  Everything
     is float32 on the CPU, so the reference logit of the engine's token
     is within 1e-4 of the reference's best (logits of order 1; the
     chunked scan and the cache reorder float32 sums, nothing more).
-    37 tokens take three chunk passes (state carried between programs)."""
+    37 tokens take three chunk passes (state carried between programs);
+    a state of 32 gives rows of 128 channels, which the pool holds in
+    lane rows."""
+    if state != 16:
+        dec = _dec(state=state)
+        model = (dec, dec.init_params(3, "float32"))
     dec, params = model
     eng = _engine(model)
+    assert eng._state_conv.ndim == (3 if state == 16 else 4)
     prompt = _prompt(plen, plen)
     req = _serve(eng, prompt, new)
     toks = np.concatenate([prompt, req.tokens])
@@ -333,13 +397,20 @@ def _skipped_total():
     return float(m.group(1)) if m else 0.0
 
 
-def test_a_padded_decode_row_is_skipped_and_counted(model, monkeypatch):
+@pytest.mark.parametrize("state", [32, 16], ids=["lane-rows", "flat"])
+def test_a_padded_decode_row_is_skipped_and_counted(model, monkeypatch,
+                                                    state):
     """Three requests at once in the 4-row bucket, so a decode pass of
     all three has one dead row: the state kernel (interpreted, a group
-    over two head steps) runs inside the engine's programs, every
+    over two head steps) and, where a row's 4 x 32 + 64 = 128 channels
+    take lane rows, the convolution rows' kernel run inside the engine's
+    programs; 96 channels keep the flat pool and the XLA form.  Every
     generated position equals the reference, ``serve.decode`` says the
-    pass skipped one row, and the counter rises by the state layers for
-    each such pass."""
+    pass skipped one row, the counter rises by the state layers for each
+    such pass, and what the kernels skip is never written: the null
+    slot of the state pool, and of the convolution rows' pool where the
+    kernel holds it, is still all zeros."""
+    from mxnet_tpu.ops.pallas_conv_step import conv_step_kernel
     from mxnet_tpu.ops.pallas_ssm_update import ssm_update_kernel
 
     monkeypatch.setattr(engine_mod, "_STEP_CACHE", {})
@@ -347,12 +418,27 @@ def test_a_padded_decode_row_is_skipped_and_counted(model, monkeypatch):
                         lambda pool, layer, slots, *a: ssm_update_kernel(
                             pool, layer, slots, *a, heads_per_step=2,
                             interpret=True))
+    conv_jnp, kernel_calls = ssm.conv_state_step, []
+
+    def conv_step(pool, layer, slots, *a):
+        # the dispatch a TPU makes, with the kernel interpreted
+        if pool.ndim == 4:
+            kernel_calls.append(layer)
+            return conv_step_kernel(pool, layer, slots, *a, interpret=True)
+        return conv_jnp(pool, layer, slots, *a, impl="jnp")
+
+    monkeypatch.setattr(ssm, "conv_state_step", conv_step)
+    if state != 16:
+        dec = _dec(state=state)
+        model = (dec, dec.init_params(3, "float32"))
     dec, params = model
     mx.telemetry.enable()
     try:
         mx.telemetry.tracer().clear()
         before = _skipped_total()
         eng = _engine(model)
+        lanes = dec.conv_dim % 128 == 0
+        assert eng._state_conv.ndim == (4 if lanes else 3)
         prompts = [_prompt(60 + i, n) for i, n in enumerate((6, 9, 4))]
         reqs = [eng.submit(p, max_new_tokens=6) for p in prompts]
         eng.run()
@@ -370,6 +456,9 @@ def test_a_padded_decode_row_is_skipped_and_counted(model, monkeypatch):
         skipped = eng._state_ssm.shape[0] * len(three)
         assert _skipped_total() - before == skipped
         assert eng.statusz()["state_cache"]["updates_skipped"] == skipped
+        assert bool(kernel_calls) == lanes
+        assert not np.asarray(eng._state_ssm[:, 0]).any()
+        assert np.asarray(eng._state_conv[:, 0]).any() != lanes
         eng.shutdown()
     finally:
         mx.telemetry.disable()
