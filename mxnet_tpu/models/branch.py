@@ -110,6 +110,7 @@ class BranchDecoder(collections.namedtuple("BranchDecoder", _FIELDS)):
     tied = False
     window_layers = ()
     kv_flat = True
+    diffusion = False           # one token a pass
     # its routed blocks: sigmoids with a selection bias, ungated
     # squared-ReLU experts (in the ``latent`` width)
     router_score = "sigmoid"

@@ -74,6 +74,7 @@ class HybridDecoder(collections.namedtuple("HybridDecoder", _FIELDS)):
     tied = True
     window_layers = ()
     kv_flat = True
+    diffusion = False           # one token a pass
     mamba_groups = 1
 
     @property

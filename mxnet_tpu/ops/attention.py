@@ -378,47 +378,55 @@ def resolve_span_impl(T, S, head_dim):
     return "dense"
 
 
-def span_kv_tiles(T, S, start, n_valid, window=0, impl="kernel"):
+def span_kv_tiles(T, S, start, n_valid, window=0, impl="kernel", block=1):
     """``(visited, table)``: the ``(SPAN_BLOCK_Q, SPAN_BLOCK_K)`` tiles
     one head of a span's attention computes, over the tiles of its ``(T,
     S)`` rectangle.  Host arithmetic that mirrors the kernel's walk (a
-    test holds it to the mask); the dense branch computes them all."""
+    test holds it to the mask); the dense branch computes them all.
+    ``block``: the mask's block length (a row sees to its block's end)."""
     bq, bk = min(SPAN_BLOCK_Q, T), min(SPAN_BLOCK_K, S)
     table = -(-T // bq) * -(-S // bk)
     if impl != "kernel":
         return table, table
     visited, end = 0, start + n_valid
     for p0 in range(start, min(start + T, end), bq):
-        hi = min(p0 + bq, end, S)
+        hi = min(-(-min(p0 + bq, end) // block) * block, S)
         lo = max(p0 - window + 1, 0) if window else 0
         visited += max(-(-hi // bk) - lo // bk, 0)
     return visited, table
 
 
-def _span_keep(start, T, S, window):
+def _span_keep(start, T, S, window, block=1):
     """``(..., T, S)``: row ``t`` at position ``start + t`` keeps the keys
-    at positions ``<= `` its own (and inside its window)."""
+    at positions ``<= `` its own (and inside its window); with ``block``
+    > 1 those before its block's end, ``< (pos // block + 1) * block``."""
     pos = jnp.asarray(start)[..., None] + jnp.arange(T)
     spos = jnp.arange(S)[(None,) * pos.ndim]
-    keep = spos <= pos[..., None]                   # causal, self included
+    if block > 1:
+        keep = spos < (pos[..., None] // block + 1) * block
+    else:
+        keep = spos <= pos[..., None]               # causal, self included
     if window:
         keep = jnp.logical_and(keep, spos > pos[..., None] - window)
     return keep
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "window", "interpret"))
-def _span_kernel_call(q, k, v, start, n_valid, *, scale, window, interpret):
+@functools.partial(jax.jit, static_argnames=("scale", "window", "interpret",
+                                             "block"))
+def _span_kernel_call(q, k, v, start, n_valid, *, scale, window, interpret,
+                      block=1):
     # jitted so that the layers of a program share ONE trace of the
     # kernel (the paged kernel's cost 3-6 s of tracing over five decode
     # programs at PR 30; the prefill and chunk ladders are 25)
     from .pallas_span_attention import span_attention_kernel
 
     return span_attention_kernel(q, k, v, start, n_valid, scale,
-                                 window=window, interpret=interpret)
+                                 window=window, interpret=interpret,
+                                 **({"block": block} if block > 1 else {}))
 
 
 def masked_attention(q, k, v, start, scale, window=0, n_valid=None,
-                     mesh=None, head_axis=None):
+                     mesh=None, head_axis=None, block=1):
     """Causal grouped-query attention of a span of query rows over key
     rows: the span attention of the serving programs (a whole prompt; a
     chunk or verify rows over the table's gathered view), whatever
@@ -442,7 +450,9 @@ def masked_attention(q, k, v, start, scale, window=0, n_valid=None,
     form below, which builds the mask and materialises the scores.
     ``mesh``/``head_axis``: as for :func:`paged_attention`; GSPMD cannot
     partition a Mosaic call, so under a mesh the kernel runs per head
-    shard inside a ``shard_map``."""
+    shard inside a ``shard_map``.  ``block`` > 1: the mask is
+    block-causal, a row sees every key before its block's end (``start``
+    and the real rows whole blocks of it)."""
     from .flash_attention import gqa_group
 
     T, Hq, Dh = q.shape[-3:]
@@ -451,7 +461,8 @@ def masked_attention(q, k, v, start, scale, window=0, n_valid=None,
     if q.ndim == 3 and resolve_span_impl(T, S, Dh) == "kernel":
         kernel = functools.partial(
             _span_kernel_call, scale=np.float32(scale), window=int(window),
-            interpret=not pallas_util.on_tpu())
+            interpret=not pallas_util.on_tpu(),
+            **({"block": int(block)} if block > 1 else {}))
         args = (q, k, v, jnp.asarray(start, jnp.int32),
                 jnp.asarray(T if n_valid is None else n_valid, jnp.int32))
         if mesh is None:
@@ -465,7 +476,7 @@ def masked_attention(q, k, v, start, scale, window=0, n_valid=None,
             check_vma=False)(*args)
     qg = q.reshape(q.shape[:-2] + (Hkv, group, Dh))
     sc = jnp.einsum("...qkgd,...skd->...kgqs", qg, k) * scale
-    keep = _span_keep(start, T, S, window)
+    keep = _span_keep(start, T, S, window, block)
     sc = jnp.where(keep[..., None, None, :, :], sc,
                    jnp.asarray(-jnp.inf, sc.dtype))
     pr = jax.nn.softmax(sc.astype(jnp.float32), axis=-1).astype(q.dtype)

@@ -11,7 +11,9 @@ it above the diagonal or past the prompt's end.  Here the scores of a
 running max / sum-exp / f32 accumulators, and a query tile walks ONLY
 the key tiles it can see:
 
-* nothing above its diagonal (``key <= start + row``),
+* nothing above its diagonal (``key <= start + row``; block-causal,
+  ``block`` > 1: nothing past its row's block, ``key < (pos // block +
+  1) * block``),
 * nothing below its window's band (``key > start + row - window``),
 * nothing past the pass's last real row (rows ``>= n_valid`` are padding
   whose outputs are discarded: a tile of them walks nothing and writes
@@ -74,12 +76,23 @@ def _div(x, n):
     return jax.lax.div(x, _i32(n))
 
 
-def _live_keys(span_ref, qi, *, T, S, bq, window):
+def _block_end(pos, block):
+    """One past the last key a row at ``pos`` sees: ``pos + 1``, or its
+    block's end."""
+    if block == 1:
+        return pos + _i32(1)
+    return (_div(pos, block) + _i32(1)) * _i32(block)
+
+
+def _live_keys(span_ref, qi, *, T, S, bq, window, block=1):
     """Query tile ``qi``'s first position and the key range ``[lo, hi)``
     its real rows can see (empty when the tile is all padding)."""
     start, end = span_ref[0], span_ref[0] + span_ref[1]
     p0 = start + jax.lax.rem(qi, _i32(T // bq)) * _i32(bq)
-    hi = jnp.minimum(jnp.minimum(p0 + _i32(bq), end), _i32(S))
+    last = jnp.minimum(p0 + _i32(bq), end)
+    if block > 1:
+        last = _block_end(last - _i32(1), block)
+    hi = jnp.minimum(last, _i32(S))
     lo = jnp.maximum(p0 - _i32(window - 1), 0) if window else _i32(0)
     return p0, lo, jnp.where(p0 < end, hi, lo)
 
@@ -104,12 +117,13 @@ def _fold(s, v, m_prev, l_prev, acc):
 
 
 def _kernel(span_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
-            scale, window, T, S, bq, bk, resident):
+            scale, window, T, S, bq, bk, resident, block=1):
     """Query tile ``program_id(1)`` of head ``program_id(0)`` over the
     ``resident`` key positions of grid step ``program_id(2)``."""
     qi, kb = pl.program_id(1), pl.program_id(2)
     nkb = S // resident
-    p0, lo, hi = _live_keys(span_ref, qi, T=T, S=S, bq=bq, window=window)
+    p0, lo, hi = _live_keys(span_ref, qi, T=T, S=S, bq=bq, window=window,
+                            block=block)
     # key tiles of this step's resident positions, as global tile indices
     base = kb * _i32(resident // bk)
     t_lo = jnp.maximum(_div(lo, bk), base)
@@ -118,7 +132,7 @@ def _kernel(span_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
     # tiles every row of the query tile sees whole need no mask: those
     # that end at or before the first row's position (none under a
     # window, whose band's lower edge cuts the early ones)
-    t_full = t_lo if window else jnp.clip(_div(p0 + _i32(1), bk),
+    t_full = t_lo if window else jnp.clip(_div(_block_end(p0, block), bk),
                                           t_lo, t_hi)
 
     q = q_ref[...]                                      # (bq, Dh)
@@ -133,7 +147,8 @@ def _kernel(span_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
                 preferred_element_type=jnp.float32) * scale
             if masked:
                 key = t * _i32(bk) + col
-                keep = key <= row
+                keep = (key <= row if block == 1
+                        else key < _block_end(row, block))
                 if window:
                     keep = jnp.logical_and(keep, key > row - _i32(window))
                 s = jnp.where(keep, s, _NEG_INF)
@@ -172,8 +187,10 @@ def _kernel(span_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 def span_attention_kernel(q, k, v, start, n_valid, scale, window=0,
                           interpret=None, block_q=SPAN_BLOCK_Q,
                           block_k=SPAN_BLOCK_K,
-                          resident=SPAN_RESIDENT_TOKENS):
-    """Causal (optionally windowed) grouped-query attention of a span.
+                          resident=SPAN_RESIDENT_TOKENS, block=1):
+    """Causal (optionally windowed) grouped-query attention of a span;
+    block-causal with ``block`` > 1 (``start`` and ``n_valid`` whole
+    blocks, and ``block`` dividing the query tile).
 
     ``q (T, Hq, Dh)``: row ``t`` holds position ``start + t``; ``k``/``v
     (S, Hkv, Dh)``: row ``s`` holds position ``s``; kv head ``g`` serves
@@ -193,7 +210,8 @@ def span_attention_kernel(q, k, v, start, n_valid, scale, window=0,
         return span_attention_kernel(
             jnp.pad(q, pad), jnp.pad(k, pad), jnp.pad(v, pad), start,
             n_valid, scale, window=window, interpret=interpret,
-            block_q=block_q, block_k=block_k, resident=resident)[..., :64]
+            block_q=block_q, block_k=block_k, resident=resident,
+            block=block)[..., :64]
     group = gqa_group(Hq, Hkv)
     bq, bk = min(int(block_q), T), min(int(block_k), S)
     resident = min(int(resident), S)
@@ -204,7 +222,10 @@ def span_attention_kernel(q, k, v, start, n_valid, scale, window=0,
     if window < 0:
         raise ValueError(f"span_attention: window must be >= 0 "
                          f"(got {window})")
-    window = int(window)
+    window, block = int(window), int(block)
+    if block < 1 or bq % block:
+        raise ValueError(f"span_attention: a block of {block} does not "
+                         f"divide the query tile of {bq}")
     if interpret is None:
         interpret = not pallas_util.on_tpu()
     nkb = S // resident
@@ -214,7 +235,8 @@ def span_attention_kernel(q, k, v, start, n_valid, scale, window=0,
             return 0, _div(h, group)
         # a step outside the query tile's live range re-reads the nearest
         # live step's block: an unchanged block index is no DMA
-        _, lo, hi = _live_keys(span_ref, qi, T=T, S=S, bq=bq, window=window)
+        _, lo, hi = _live_keys(span_ref, qi, T=T, S=S, bq=bq, window=window,
+                               block=block)
         last = jnp.maximum(_div(hi + _i32(resident - 1), resident) - 1, 0)
         return jnp.clip(kb, _div(lo, resident), last), _div(h, group)
 
@@ -236,7 +258,7 @@ def span_attention_kernel(q, k, v, start, n_valid, scale, window=0,
             vmem_limit_bytes=_VMEM_LIMIT)
     kernel = functools.partial(_kernel, scale=np.float32(scale),
                                window=window, T=T, S=S, bq=bq, bk=bk,
-                               resident=resident)
+                               resident=resident, block=block)
     span = jnp.stack([jnp.asarray(start, jnp.int32),
                       jnp.asarray(n_valid, jnp.int32)])
     out = pl.pallas_call(

@@ -78,7 +78,7 @@ from ..parallel import partition as partition_mod
 from ..parallel.mesh import NamedSharding, PartitionSpec, make_mesh
 from ..models.branch import BranchDecoder
 from ..models.hybrid import HybridDecoder
-from ..models.moe import MoEDecoder
+from ..models.moe import MoEDecoder, transfer_counts
 from ..ops.attention import (PAGED_TILE_TOKENS, SPAN_BLOCK_K, SPAN_BLOCK_Q,
                              SPAN_KERNEL_MIN_SCORES, paged_tile_slots,
                              span_kv_tiles)
@@ -170,7 +170,7 @@ class _Part:
     flag), and what the engine kept from the dispatch for the read."""
 
     __slots__ = ("reqs", "lead", "ok", "args", "start", "end", "n", "freed",
-                 "rec")
+                 "rec", "blocks")
 
     def __init__(self, reqs, lead, ok, args, start=0, end=0, n=0):
         self.reqs, self.lead, self.ok, self.args = reqs, lead, ok, args
@@ -178,6 +178,7 @@ class _Part:
         self.start, self.end, self.n = start, end, n
         self.freed = 0           # window-group blocks its trim gave back
         self.rec = None          # the step record's pass timer (tracing)
+        self.blocks = None       # a denoise pass: (_Block, step, fixed) a row
 
 
 class _Pass:
@@ -199,6 +200,51 @@ class _Pass:
         self.emitted = self.freed = 0
         self.moe = None
         self.read = False
+
+
+class _Block:
+    """A diffusion request's current block as the host foresees it (the
+    schedule is static, so no pass's kind or position waits for a read):
+    ``host`` the tokens its next pass takes (None: the unread pass is
+    denoising it and they are on the device, in the token pool),
+    ``fixed`` how many of its first positions came with the prompt,
+    ``counts`` the positions each denoise step unmasks while masks are
+    left (``models.moe.transfer_counts``), ``step`` the next step
+    (``steps``, past them: the store pass), and ``opens`` whether it is
+    the first block since the request's admission (its store pass brings
+    the first tokens)."""
+
+    __slots__ = ("host", "fixed", "counts", "steps", "step", "opens")
+
+    def __init__(self, dec, fixed, opens=False):
+        self.opens = opens
+        self.fixed = len(fixed)
+        self.host = np.full(dec.block, dec.mask_id, np.int32)
+        self.host[:self.fixed] = fixed
+        self.counts = [c for c in transfer_counts(dec.block - self.fixed,
+                                                  dec.steps) if c]
+        self.steps = dec.steps
+        self.step = 0
+
+    def next_count(self):
+        """Positions the next pass unmasks (0: the store pass)."""
+        return self.counts[self.step] if self.step < len(self.counts) else 0
+
+    def launch(self):
+        """One pass of this block enqueued: ``(self, its step, fixed)``,
+        the step ``steps`` for the store pass."""
+        if self.step >= len(self.counts):
+            return self, self.steps, self.fixed
+        self.step += 1
+        self.host = None
+        return self, self.step - 1, self.fixed
+
+    def read(self, block, step):
+        """Denoise step ``step`` of this block was read: the host holds
+        its output, unless a later step was enqueued behind it (that
+        one took it on the device)."""
+        if step == self.step - 1:
+            self.host = np.asarray(block, np.int32)
 
 
 class Engine:
@@ -360,6 +406,12 @@ class Engine:
         # through them (serve/hybrid.py)
         self._routed = (self._desc is not None
                         and hybrid_mod.routed(self._desc))
+        # block diffusion (models/moe.py): a decode pass denoises each
+        # row's block of ``_block`` positions, tokens are committed a
+        # block at a time (_run_denoise / _read_denoise)
+        self._diffusion = bool(self._desc is not None
+                               and self._desc.diffusion)
+        self._block = self._desc.block if self._diffusion else 1
         if self._desc is not None:
             if num_heads not in (None, self._desc.num_heads) or window:
                 raise ValueError(
@@ -436,6 +488,11 @@ class Engine:
                 "sampling=False forces the greedy-only programs, which "
                 "cannot serve temperature/top_p/top_k defaults — drop "
                 "sampling=False or the stochastic defaults")
+        if self._diffusion and self._sampling:
+            raise ValueError(
+                "a diffusion decoder cannot be served with sampling: the "
+                "denoise pass unmasks by greedy confidence, and no sampled "
+                "schedule is written")
         self.sample_cap = (int(sample_cap) if sample_cap is not None
                            else env_int("MXTPU_SERVE_SAMPLE_CAP", 64))
         if self.sample_cap < 1:
@@ -641,6 +698,14 @@ class Engine:
                                    tenant_share=tenant_share,
                                    prefill_chunk=prefill_chunk,
                                    spec_slots=self.spec_k)
+        if self._diffusion and (self.block_size % self._block
+                                or self.scheduler.prefill_chunk
+                                % self._block):
+            raise ValueError(
+                f"a diffusion decoder's block of {self._block} must divide "
+                f"block_size ({self.block_size}) and prefill_chunk "
+                f"({self.scheduler.prefill_chunk}): passes cover whole "
+                "blocks")
         self._stats = StatsRecorder(clock=clock)
         self.clock = clock
         self._step_id = 0
@@ -766,8 +831,10 @@ class Engine:
         self._probe = None
         self._probe_rows = {"decode": (), "span": None}
         if self._routed:
+            # a denoise pass's rows are its rows' block positions
             self._probe = jnp.zeros(
-                hybrid_mod.probe_shape(self._desc, self.max_batch), dt)
+                hybrid_mod.probe_shape(self._desc,
+                                       self.max_batch * self._block), dt)
         # the caches a description's programs take behind the K/V pair,
         # in operand order, as this engine's attributes
         self._extra_caches = () if self._desc is None else tuple(
@@ -792,7 +859,12 @@ class Engine:
         self._tok_fns = {}
         self._tok_pool = None
         self._tok_row = 0
-        if not self.spec_k:          # positions are not foreseeable there
+        if self._diffusion:
+            # a denoise pass's blocks, a row each: a prefill puts nothing
+            # there (the host knows a request's first block)
+            pool = np.zeros((self.max_batch, self._block), np.int32)
+            self._tok_pool = jnp.asarray(pool)
+        elif not self.spec_k:        # positions are not foreseeable there
             pool = np.zeros(self.max_batch + 2 * max(
                 1, self.scheduler.max_prefills_per_step), np.int32)
             self._tok_pool = (jnp.asarray(pool) if self._shardings is None
@@ -896,6 +968,18 @@ class Engine:
                 "mxtpu_serve_kv_window_blocks_freed_total",
                 "window-group blocks returned when their last position "
                 "left the window")
+        if self._diffusion:
+            self._tel_block_passes = telemetry.counter(
+                "mxtpu_serve_block_passes_total",
+                "decode-pass rows of a diffusion decoder, by kind: a "
+                "denoise step, or the store pass that commits a block",
+                ("kind",))
+            self._tel_unmasked = telemetry.counter(
+                "mxtpu_serve_tokens_unmasked_total",
+                "block positions the denoise passes unmasked")
+            self._tel_committed = telemetry.counter(
+                "mxtpu_serve_blocks_committed_total",
+                "blocks the store passes committed")
         if self._routed:
             self._tel_moe_picks = telemetry.counter(
                 "mxtpu_serve_moe_picks_total",
@@ -1145,6 +1229,18 @@ class Engine:
                   temperature=temperature, top_p=top_p, top_k=top_k,
                   logprobs=logprobs, adapter_id=adapter_id)
         req = Request(prompt, max_new_tokens, **kw)
+        if self._diffusion:
+            # a diffusion decoder prefills whole blocks and opens the
+            # first with the prompt's tail; the mask id is its own
+            if req.prompt.size < self._block:
+                raise ValueError(
+                    f"a diffusion decoder's prompt needs at least one "
+                    f"block ({self._block} tokens; got {req.prompt.size})")
+            if (req.prompt == self._desc.mask_id).any():
+                raise ValueError(
+                    f"the prompt holds the mask id {self._desc.mask_id}, "
+                    "which the denoise passes read as a masked position")
+            req.block = self._block
         if trace_id:
             req.trace_id = str(trace_id)
         if n > 1:
@@ -1464,11 +1560,12 @@ class Engine:
         for req in prefills:
             sprof.enter("prefill_dispatch", rid=req.rid)
             # the per-iteration prefill token budget is shared with the
-            # decode slots: each decode slot emits up to 1 + spec_k tokens
-            # this step (one, without speculative decoding), so a chunk
-            # shrinks by the batch's worst-case token count
+            # decode slots: each decode slot writes up to 1 + spec_k
+            # positions this step (one, without speculative decoding; a
+            # diffusion decoder's block), so a chunk shrinks by the
+            # batch's worst-case count
             p.prefills.append(self._run_prefill(
-                req, decode_slots=len(decodes) * (1 + self.spec_k)))
+                req, decode_slots=sum(map(self.scheduler.ahead, decodes))))
         if self._spec is not None:
             self._read(p)        # the prefills: their tokens feed the draft
             if decodes:
@@ -1527,7 +1624,7 @@ class Engine:
         span, window = slots * self.block_size, self.window
         walked = 0
         for req in reqs:
-            ctx = req.next_pos() + 1
+            ctx = req.next_pos() + self._block
             walked += -(-ctx // span) - (max(ctx - window, 0) // span
                                          if window else 0)
         return {"kv_tiles": walked,
@@ -1905,13 +2002,20 @@ class Engine:
             return None
         # mxtpu-lint: disable=host-sync (a check's read, outside any step)
         rows = np.asarray(jax.device_get(self._probe))
-        return {"layers": hybrid_mod.probe_layers(self._desc),
-                "decode": (rows[hybrid_mod.PROBE_DECODE, :, :, 0],
-                           rows[hybrid_mod.PROBE_DECODE, :, :, 1]),
-                "span": (rows[hybrid_mod.PROBE_SPAN, :, :, 0],
-                         rows[hybrid_mod.PROBE_SPAN, :, :, 1]),
-                "decode_rids": list(self._probe_rows["decode"]),
-                "span_rows": self._probe_rows["span"]}
+        out = {"layers": hybrid_mod.probe_layers(self._desc),
+               "decode": (rows[hybrid_mod.PROBE_DECODE, :, :, 0],
+                          rows[hybrid_mod.PROBE_DECODE, :, :, 1]),
+               "span": (rows[hybrid_mod.PROBE_SPAN, :, :, 0],
+                        rows[hybrid_mod.PROBE_SPAN, :, :, 1]),
+               "decode_rids": list(self._probe_rows["decode"]),
+               "span_rows": self._probe_rows["span"]}
+        if self._diffusion:
+            # a denoise pass's probe rows are its rows' block positions
+            blocks = out["decode_rids"]
+            out["decode_blocks"] = blocks
+            out["decode_rids"] = [rid for rid, _, _ in blocks
+                                  for _ in range(self._block)]
+        return out
 
     def shutdown(self):
         """Cancel in-flight work and release the device cache.
@@ -2161,8 +2265,10 @@ class Engine:
             sds = lambda shape: jax.ShapeDtypeStruct(shape, i32,
                                                      sharding=rep)
             pool = sds(self._tok_pool.shape)
-            specs = {TOK_TAKE: (pool, sds((n,)), sds((n,))),
-                     TOK_PUT: (pool, sds((n,))),
+            # a row's tokens: one, or a diffusion decoder's block
+            row = (n,) + self._tok_pool.shape[1:]
+            specs = {TOK_TAKE: (pool, sds(row), sds((n,))),
+                     TOK_PUT: (pool, sds(row)),
                      TOK_PUT1: (pool, sds(()), sds(()))}[kind]
             with telemetry.span("serve.token_program", kind=kind,
                                 bucket=int(n)):
@@ -2172,10 +2278,13 @@ class Engine:
         self._tok_fns[kind, n] = fn
         return fn
 
-    def _n_lead(self):
+    def _n_lead(self, decode=False):
         """Host-bound outputs of a prefill, chunk or decode program: the
-        sampled token (with its logprob views in sampling mode) and,
-        where a feed-forward block is routed, the router's counts."""
+        sampled token (with its logprob views in sampling mode), or a
+        denoise pass's blocks and unmasked positions, and, where a
+        feed-forward block is routed, the router's counts."""
+        if decode and self._diffusion:
+            return 2 + int(self._routed)
         return (4 if self._sampling else 1) + int(self._routed)
 
     def _cache_args(self):
@@ -2287,13 +2396,17 @@ class Engine:
         in the queue ahead of any reader's program), the window group
         trimmed."""
         ids = req.prefill_ids()
-        n = int(ids.size)
+        # the positions this prefill writes: all of ids, or under block
+        # diffusion their whole blocks (the rest opens the first block)
+        n = int(req.prefill_len())
         start = int(req.next_pos())    # cached prefix + earlier chunks
         resume = req.n_preemptions > 0
         chunked = self.scheduler.is_prefilling(req)
         if chunked:
-            budget = max(1, self.scheduler.prefill_chunk - decode_slots)
-            end = min(n, start + budget)
+            # whole blocks under block diffusion, at least one
+            budget = max(self._block,
+                         self.scheduler.prefill_chunk - decode_slots)
+            end = min(n, start + budget - budget % self._block)
         else:
             end = n
         if not req._prefill_started:
@@ -2308,7 +2421,7 @@ class Engine:
             # program (exactly the pre-prefix-cache path)
             bucket = _next_bucket(n, self.max_model_len)
             toks = np.zeros(bucket, np.int32)
-            toks[:n] = ids
+            toks[:n] = ids[:n]
             blk, off = self._slots(self.blocks.table(req.rid), n, bucket)
             pkind, keys = "prefill", bucket
             fn = self._prefill_fn(bucket)
@@ -2363,8 +2476,8 @@ class Engine:
             # head of it computes over those of its (rows, keys) rectangle
             attn = self._span_impl(bucket, keys)
             tiles, of = span_kv_tiles(bucket, keys, start, span,
-                                      self.window, attn)
-            note.update(kind=pkind, tokens=span, bucket=bucket,
+                                      self.window, attn, block=self._block)
+            note.update(kind=pkind, tokens=span, bucket=bucket, start=start,
                         cached=req.cached_prefix_len, attn=attn,
                         kv_tiles=tiles, kv_tiles_table=of)
         outs = fn(*args)
@@ -2380,7 +2493,16 @@ class Engine:
         # the request's adapter id salts the chain: adapter K/V is
         # content-disjoint from base (and other-adapter) K/V
         self.blocks.note_tokens(req.rid, ids[:end], salt=req.adapter_id)
-        if end >= n:
+        if end >= n and self._diffusion:
+            # the prompt's last n mod B tokens and mask ids open the
+            # first block, which the host knows: no token in flight
+            req.diffusion = _Block(self._desc, ids[n:], opens=True)
+            # a preempted request's block starts over: its record keeps
+            # the committed blocks only
+            req.unmasked = [u for u in req.unmasked if u[0] < n]
+            self.scheduler.prefill_done(req)
+            self.scheduler.admit_running(req)
+        elif end >= n:
             # the sampled token is the request's next: it joins the
             # decode batch of the next schedule, its token on the device
             # until this pass is read
@@ -2431,6 +2553,12 @@ class Engine:
             self._rtrace.event(req, "prefill_chunk", done=int(end),
                                target=int(n), tokens=int(span))
             return 0
+        if self._diffusion:
+            # the prompt's whole blocks are in; its first tokens come
+            # with the first block's store pass (_read_denoise)
+            self._rtrace.event(req, "prefill_end", tokens=int(n - start),
+                               resume=resume)
+            return 0
         req.flight_tokens -= 1
         self._rtrace.event(req, "prefill_end", tokens=int(n - start),
                            resume=resume)
@@ -2464,6 +2592,8 @@ class Engine:
         newest token the host has not read takes it from the token pool,
         on the device; a batch that needs none of those gets its tokens
         from the host, through the same program."""
+        if self._diffusion:
+            return self._run_denoise(reqs)
         B = len(reqs)
         bucket = _next_bucket(B, self.max_batch)
         note = {}
@@ -2525,7 +2655,143 @@ class Engine:
         return part
 
     @hot_path
+    def _run_denoise(self, reqs):
+        """Enqueue a diffusion decoder's decode pass over ``reqs``: each
+        row's block at its committed length, a denoise step of its
+        static schedule or, with no mask left, the store pass (count 0).
+        A row whose block the unread pass is denoising takes it from the
+        token pool, on the device; the host foresees every row's kind
+        and position (``_Block``).  Returns the unread :class:`_Part`."""
+        R, B = len(reqs), self._block
+        bucket = _next_bucket(R, self.max_batch)
+        toks = np.zeros((bucket, B), np.int32)
+        pos = np.zeros(bucket, np.int32)
+        counts = np.zeros(bucket, np.int32)
+        tables = np.zeros((bucket, self.table_width), np.int32)
+        src, rows = None, []
+        for i, req in enumerate(reqs):
+            blk = req.diffusion
+            if blk.host is None:
+                if src is None:
+                    src = np.full(bucket, -1, np.int32)
+                src[i] = req.flight_src
+            else:
+                toks[i] = blk.host
+            pos[i] = req.next_pos()
+            counts[i] = blk.next_count()
+            t = self.blocks.table(req.rid)
+            tables[i, :len(t)] = t
+            rows.append(blk.launch())
+        steps = self._desc.steps
+        at_step = np.bincount([row[1] for row in rows],
+                              minlength=steps + 1)
+        n_store = int(at_step[steps])
+        note = {}
+        if self._sprof.tracing:
+            # block_step: the rows at each step of their blocks, 0 ..
+            # steps - 1 denoising, ``steps`` storing; contexts: the
+            # positions every row's paged query attends over
+            note = dict(batch=R, bucket=bucket,
+                        block_step=",".join(str(int(n)) for n in at_step),
+                        unmasked=int(counts.sum()), committed=n_store,
+                        contexts=int(pos[:R].sum()) + R * B,
+                        **self._kv_tiles(reqs, bucket))
+        self._tel_block_passes.labels(kind="denoise").inc(R - n_store)
+        self._tel_block_passes.labels(kind="store").inc(n_store)
+        toks = jnp.asarray(toks)
+        if src is not None:
+            toks = self._token_program(TOK_TAKE, bucket)(
+                self._tok_pool, toks, jnp.asarray(src))
+        fn = self._decode_fn(bucket)
+        self._key, sub = jax.random.split(self._key)
+        outs = fn(self.params, *self._cache_args(), toks, jnp.asarray(pos),
+                  jnp.asarray(tables), jnp.asarray(counts), sub)
+        first = self._perf.done("decode", bucket)
+        lead, ok = self._adopt(outs, self._n_lead(decode=True))
+        part = _Part(reqs, lead, ok, note)
+        part.blocks = rows
+        self._tok_pool = self._token_program(TOK_PUT, bucket)(
+            self._tok_pool, lead[0])
+        if self._sprof.tracing:
+            part.rec = self._sprof.enqueued("decode", bucket, R, lead[0],
+                                            first)
+        for i, (req, (blk, step, fixed)) in enumerate(zip(reqs, rows)):
+            if step == blk.steps:
+                # the store pass commits the block: its positions and
+                # its new tokens, the last block's tail dropped
+                req.flight_len += B
+                req.flight_tokens += min(
+                    B - fixed,
+                    req.max_new_tokens - len(req.tokens) - req.flight_tokens)
+                req.diffusion = _Block(self._desc, ())
+            else:
+                req.flight_src = i
+        return part
+
+    @hot_path
+    def _read_denoise(self, p, part):
+        """Read a diffusion decoder's decode pass: each row's new block
+        and which positions it unmasked; a store pass's row commits its
+        block (``cache_len`` grows by B in the step its tokens are
+        appended).  Returns the tokens emitted."""
+        reqs, B = part.reqs, self._block
+        self._sprof.wait("serve.decode", part.rec, **part.args)
+        lead = self._unpack_outs(part.lead, part.ok, "decode_logits",
+                                 batch_size=len(reqs),
+                                 rids=[r.rid for r in reqs])
+        self._sprof.enter("host_sync")
+        out, fresh = lead[0], lead[1]
+        now = self.clock()
+        if self._routed:
+            self._note_moe(p, lead[-1])
+            self._probe_rows["decode"] = [
+                (r.rid, int(r.cache_len),
+                 [int(t) for t in np.where(fresh[i], self._desc.mask_id,
+                                           out[i])])
+                for i, r in enumerate(reqs)]
+        emitted = unmasked = committed = 0
+        for i, (req, (blk, step, fixed)) in enumerate(zip(reqs,
+                                                          part.blocks)):
+            base = req.cache_len
+            if step < blk.steps:
+                blk.read(out[i], step)
+                for b in np.flatnonzero(fresh[i]):
+                    req.unmasked.append((base + int(b), int(out[i][b]),
+                                         step))
+                    unmasked += 1
+                continue
+            k = min(B - fixed, req.max_new_tokens - len(req.tokens))
+            if blk.opens and self._sprof.tracing:
+                # admission to the first tokens since it, as a gpt
+                # engine's prefill pass records it
+                telemetry.tracer().add_complete(
+                    "serve.request.prefill", req.admit_t, now,
+                    {"rid": req.rid, "resume": int(req.n_preemptions > 0),
+                     "passes": req.prefill_passes,
+                     "tokens": req.cache_len - req.cached_prefix_len})
+            req.flight_len -= B
+            req.flight_tokens -= k
+            req.cache_len += B
+            req.tokens.extend(int(t) for t in out[i][fixed:fixed + k])
+            committed += 1
+            emitted += k
+            if req.first_token_t is None:
+                req.first_token_t = now
+                self._stats.on_first_token(req.ttft() or 0.0)
+            else:
+                self._stats.on_tokens(req, k, now=now)
+            self._rtrace.event(req, "decode", batch=self._step_id,
+                               batch_size=len(reqs), tokens=len(req.tokens),
+                               emitted=k)
+            self._maybe_finish(req)
+        self._tel_unmasked.inc(unmasked)
+        self._tel_committed.inc(committed)
+        return emitted
+
+    @hot_path
     def _read_decode(self, p, part):
+        if self._diffusion:
+            return self._read_denoise(p, part)
         reqs = part.reqs
         B = len(reqs)
         self._sprof.wait("serve.decode", part.rec, **part.args)
@@ -2837,7 +3103,9 @@ class Engine:
                         if e["kind"] == "decode":
                             self._token_program(TOK_TAKE, bucket)
                             self._token_program(TOK_PUT, bucket)
-                        elif e["kind"] in ("prefill", "chunk"):
+                        elif (e["kind"] in ("prefill", "chunk")
+                              and not self._diffusion):
+                            # (a diffusion prefill puts no token there)
                             self._token_program(TOK_PUT1)
         finally:
             self._warming = False
@@ -2957,6 +3225,9 @@ class Engine:
             # 2 operations per matrix parameter per row, the tied head
             # once per sampled row; attention's score terms left out
             rows = 1 if kind != "decode" else bucket
+            if kind == "decode" and self._diffusion:
+                # a denoise pass runs, and samples, every block position
+                bucket = rows = bucket * self._block
             return (hybrid_mod.matmul_flops(self._desc, bucket, rows),
                     None)
         if kind in ("draft", "draft_chunk") and self._spec is not None:
@@ -3095,6 +3366,12 @@ class Engine:
                 s = sds((L, bucket, bs, Hkv), jnp.dtype(jnp.float32))
                 specs += (s, s)
             return specs
+        if kind == "decode" and self._diffusion:
+            # blocks (R, B), their first positions, tables, the counts
+            return (pspec,) + caches + (
+                sds((bucket, self._block), i32), sds((bucket,), i32),
+                sds((bucket, self.table_width), i32),
+                sds((bucket,), i32), kspec)
         if kind == "decode":
             return (pspec,) + adp() + caches + (sds((bucket,), i32),
                     sds((bucket,), i32),

@@ -49,7 +49,30 @@ one position of B rows, :class:`_SpanMix` for a span of one request):
   went back to their free list, ``kv_block_manager.WindowGroup``).
   Decode attends through ``paged_attention(..., layer=a, window=...)``,
   whose band walk never reads a table slot behind the window; a span
-  through ``masked_attention`` with the same window.
+  through ``masked_attention`` with the same window.  With ``qk_norm``
+  each query and key head is RMS-normed before the rotary turn; without
+  ``gate`` the heads go to the output projection ungated.
+
+A **block-diffusion** description (``dec.diffusion``: ``block`` B,
+``steps``, ``mask_id``; ``models/moe.py``) has no decode of one position:
+its decode program is the DENOISE pass (:func:`build_decode`,
+:class:`_BlockMix`), one program a bucket of R rows.  Each row brings its
+block's B tokens (mask ids included) at positions ``pos .. pos + B - 1``
+(``pos`` its committed length, a multiple of B), writes their K/V into its
+blocks of the cache in every layer (the next pass over the same block
+writes them again), and attends through the paged kernel as ONE query of
+``B * heads`` heads at context ``pos + B``: a row's B positions sit inside
+each kv head's group, so every position sees the whole committed context
+and its own block, the block-causal mask.  The experts and the head run
+over the R * B positions; unmasking happens on the device
+(:func:`unmask`): each masked position's argmax without ``mask_id``, its
+confidence, and the ``counts[r]`` most confident of row r's masked
+positions take their tokens.  The program returns the new block ``(R,
+B)`` and which positions it unmasked; a block with no mask left goes
+through the same program with a count of 0 (the STORE pass: its K/V are
+the clean block's, its logits unused).  A span (prefill, chunk) over whole
+blocks runs under the block-causal mask (``masked_attention(...,
+block=B)``).
 
 A **routed** feed-forward block (``ffn_types[i] == "moe"``) routes in
 float32 over all experts (``router_score``: a softmax, or sigmoids with
@@ -98,12 +121,13 @@ from ..ops import moe as moe_ops
 from ..ops import ssm as ssm_ops
 from ..ops.attention import masked_attention, paged_attention, score_scale
 from .kv_block_manager import STATE_POOL_NO_PREFIX, WINDOW_NO_PREFIX
-from .programs import _finish, _rope
+from .programs import _finish, _outputs, _rope
 
 __all__ = ["HybridCfg", "hybrid_cfg", "check_params", "refuse", "layer",
            "matmul_flops", "build_decode", "build_prefill", "build_chunk",
            "routed", "extra_caches", "probe_layers", "probe_shape",
-           "describe", "SCAN_SCOPE", "PROBE_DECODE", "PROBE_SPAN"]
+           "describe", "unmask", "SCAN_SCOPE", "PROBE_DECODE",
+           "PROBE_SPAN"]
 
 # named scope of the chunked scan: device-trace operation names carry it
 SCAN_SCOPE = "ssd_chunked_scan"
@@ -152,7 +176,8 @@ def probe_layers(dec):
 
 def probe_shape(dec, rows):
     """``(pass kind, row, routed layer, [input | output], d_model)``: the
-    first ``rows`` rows of the newest decode pass and of the newest span,
+    first ``rows`` rows (positions, for a denoise pass: row ``r``'s block
+    at rows ``r * B ..``) of the newest decode pass and of the newest span,
     as each of :func:`probe_layers`' routed blocks saw and left them (its
     normed input, its output; both zero for a bucket's padding rows)."""
     return (2, int(rows), len(probe_layers(dec)), 2, dec.d_model)
@@ -199,6 +224,10 @@ def describe(dec):
                           "held": [dec.expert_offset, dec.expert_count],
                           "act": dec.expert_act, "latent": dec.latent,
                           "routed_scale": dec.routed_scale}
+    if dec.diffusion:
+        out["diffusion"] = {"block": dec.block, "steps": dec.steps,
+                            "mask_id": dec.mask_id,
+                            "remasking": "low_confidence_static"}
     return out
 
 
@@ -236,6 +265,22 @@ def refuse(dec, prefix_cache, spec_k, adapters, kv_dtype, quantize, tp,
     if routed(dec):
         why.update(tp=(tp > 1, "experts under a mesh need the exchange of "
                        "rows between shards, which is not written"))
+    if dec.diffusion:
+        # what a block-diffusion decoder cannot do yet, by name
+        why.update(
+            prefix_cache=(prefix_cache, "a block's K/V are written by "
+                          "every denoise pass and committed by the store "
+                          "pass, and the radix cache does not know them"),
+            host_kv_bytes=(host_kv_bytes, "host K/V offload parks prefix-"
+                           "cache blocks, and a diffusion decoder has none"),
+            spec_k=(spec_k, "a denoise pass already proposes a block; no "
+                    "verify program denoises one"),
+            kv_dtype=(kv_dtype, "int8 K/V is wired into the gpt() programs "
+                      "only"),
+            adapters=(adapters, "LoRA adapters are wired into the gpt() "
+                      "projections only"),
+            tp=(tp > 1, "the denoise pass has no sharding rules, and "
+                "experts under a mesh need the exchange of rows"))
     for arg, (on, reason) in why.items():
         if on:
             raise ValueError(f"a {_what(dec)} cannot be served with "
@@ -414,8 +459,11 @@ class _Mix:
         """Layer ``i``'s query, key and value heads, the first two turned
         by its kind's rotary scheme at the rows' positions."""
         dec = self.hc.dec
-        q, k, v = _qkv(dec, self.params, f"{dec.name}_l{i}", h,
-                       dec.heads[i])
+        p = f"{dec.name}_l{i}"
+        q, k, v = _qkv(dec, self.params, p, h, dec.heads[i])
+        if dec.qk_norm:
+            q = _ln(q, self.params[f"{p}_q_norm_gamma"], None, eps=dec.eps)
+            k = _ln(k, self.params[f"{p}_k_norm_gamma"], None, eps=dec.eps)
         rope = dec.rope_of(dec.layer_types[i])
         kw = dict(inv=rope.inv_freq(), rot=rope.dim,
                   scale=None if rope.yarn is None else rope.scale)
@@ -423,10 +471,13 @@ class _Mix:
 
     def _gated_out(self, i, h, at):
         """Each head's output times its gate (a sigmoid of the layer's
-        normed input, float32), then the output projection."""
+        normed input, float32), then the output projection (ungated
+        without ``gate``)."""
         dec = self.hc.dec
         p = f"{dec.name}_l{i}"
         T, H = h.shape[0], dec.heads[i]
+        if not dec.gate:
+            return _fc(at.reshape(T, -1), self.params[f"{p}_proj_weight"])
         gate = jax.nn.sigmoid(
             _fc(h, self.params[f"{p}_gate_weight"]).astype(jnp.float32))
         at = (at.reshape(T, H, dec.head_dim).astype(jnp.float32)
@@ -503,6 +554,44 @@ class _DecodeMix(_Mix):
         return _mamba_out(dec, P, p, y.reshape(B, di), z)
 
 
+class _BlockMix(_Mix):
+    """A denoise pass: the B positions of each of R rows' blocks, flat as
+    R * B rows.  ``pos (R,)``: each block's first position; K/V through
+    the block tables at every position, one paged query a row of ``B *
+    heads`` heads at context ``pos + B``.  ``rest``: the tail."""
+
+    probe_at = PROBE_DECODE
+
+    def __init__(self, hc, params, caches, pos, tables, rest, block_size):
+        super().__init__(hc, params, caches)
+        B = hc.dec.block
+        self.tail = tuple(rest)
+        self.R, self.tables = pos.shape[0], tables
+        at = pos[:, None] + jnp.arange(B, dtype=pos.dtype)       # (R, B)
+        self.pos = at.reshape(-1)
+        self.blk = jnp.take_along_axis(tables, at // block_size,
+                                       axis=1).reshape(-1)
+        self.off = self.pos % block_size
+        self.ctx = pos + B
+        # a bucket's padding rows write to the null block: they route
+        # nowhere
+        self.valid = self.blk != 0
+
+    def gated_attention(self, i, h):
+        dec = self.hc.dec
+        R, B, Hkv = self.R, dec.block, dec.kv_heads
+        H, Dh = dec.heads[i], dec.head_dim
+        q, k, v = self._gated_qkv(i, h, self.pos)
+        ck, cv = self._write_kv(i, self.blk, k, v)
+        # a row's B positions inside each kv head's group: head
+        # (g, b, j) of the paged query is position b's head g * G + j
+        qg = q.reshape(R, B, Hkv, H // Hkv, Dh).transpose(0, 2, 1, 3, 4)
+        at = paged_attention(qg.reshape(R, -1, Dh), ck, cv, self.tables,
+                             self.ctx, layer=self.hc.kv_index[i])
+        at = at.reshape(R, Hkv, B, H // Hkv, Dh).transpose(0, 2, 1, 3, 4)
+        return self._gated_out(i, h, at.reshape(R * B, H, Dh))
+
+
 class _SpanMix(_Mix):
     """A span of ONE request: rows hold positions [start, start +
     n_valid), rows past n_valid are padding.  ``table`` None: a whole
@@ -558,7 +647,8 @@ class _SpanMix(_Mix):
             k = ck[a, table].reshape(-1, Hkv, Dh)
             v = cv[a, table].reshape(-1, Hkv, Dh)
         at = masked_attention(q, k, v, self.start, score_scale(Dh),
-                              window=window, n_valid=self.n_valid)
+                              window=window, n_valid=self.n_valid,
+                              block=dec.block)
         return self._gated_out(i, h, at)
 
     def mamba(self, i, h):
@@ -616,6 +706,9 @@ def _routed(dec, params, p, h, mix):
         params[f"{p}_experts_out_weight"], idx, w, dec.expert_offset,
         dec.num_experts, valid=mix.valid, act=dec.expert_act)
     mix.moe_stats = stats if mix.moe_stats is None else mix.moe_stats + stats
+    if not dec.shared_ff:
+        # no shared expert (a latent always has one: models/branch.py)
+        return (np.float32(dec.routed_scale) * y).astype(h.dtype)
     shared = _FFN[dec.expert_act](h, params[f"{p}_shared_in_weight"],
                                   params[f"{p}_shared_out_weight"])
     if dec.latent:
@@ -678,9 +771,14 @@ def _outs(cfg, mix, logits, scalar):
     """``_finish``'s outputs, with a routed block's router counts between
     the sampled token's outputs and the watchdog flag / caches."""
     outs = _finish(cfg, logits, mix.caches, mix.tail, scalar=scalar)
+    return _with_stats(mix, outs, 4 if cfg.sampling else 1)
+
+
+def _with_stats(mix, outs, n):
+    """A routed block's router counts between the ``n`` host-bound
+    outputs and the watchdog flag / caches."""
     if not routed(mix.hc.dec):
         return outs
-    n = 4 if cfg.sampling else 1
     return outs[:n] + (mix.moe_stats,) + outs[n:]
 
 
@@ -696,8 +794,56 @@ def _jit(fn, hc, donate):
                    donate_argnums=tuple(range(1, 1 + n)) if donate else ())
 
 
+def unmask(dec, toks, logits, counts):
+    """The greedy unmasking step of a denoise pass.  ``toks (R, B)``: the
+    blocks as they went in; ``logits (R * B, V)``; ``counts (R,)``: how
+    many masked positions each row unmasks.  A position's candidate is
+    its argmax without ``mask_id``, its confidence that token's softmax
+    probability over the vocabulary without ``mask_id`` (float32); the
+    ``counts[r]`` most confident masked positions of row r (ties to the
+    lower position) take their candidates.  Returns ``(block (R, B),
+    unmasked (R, B) bool)``."""
+    R, B = toks.shape
+    lg = logits.astype(jnp.float32).reshape(R, B, -1)
+    lg = jnp.where(jnp.arange(lg.shape[-1]) == dec.mask_id,
+                   np.float32(-np.inf), lg)
+    cand = jnp.argmax(lg, axis=-1).astype(toks.dtype)
+    top = jnp.max(lg, axis=-1, keepdims=True)
+    conf = 1.0 / jnp.sum(jnp.exp(lg - top), axis=-1)           # (R, B)
+    masked = toks == dec.mask_id
+    score = jnp.where(masked, conf, np.float32(-1.0))
+    at = jnp.arange(B)
+    # how many positions of the row come before this one in the order
+    ahead = jnp.logical_or(
+        score[:, None, :] > score[:, :, None],
+        jnp.logical_and(score[:, None, :] == score[:, :, None],
+                        (at[None, :] < at[:, None])[None]))
+    rank = jnp.sum(ahead, axis=-1)
+    fresh = jnp.logical_and(masked, rank < counts[:, None])
+    return jnp.where(fresh, cand, toks), fresh
+
+
+def _build_denoise(cfg, donate):
+    hc = cfg.hybrid
+    dec = hc.dec
+
+    def decode(params, caches, toks, pos, tables, counts, *rest):
+        """R rows' blocks ``toks (R, B)`` at ``pos .. pos + B - 1``: K/V
+        written, attention, experts, head, unmasking (module docstring)."""
+        mix = _BlockMix(hc, params, caches, pos, tables, rest,
+                        cfg.block_size)
+        x = _stack(hc, params, _embed(dec, params, toks.reshape(-1)), mix)
+        logits = _logits(dec, params, x)
+        lead = unmask(dec, toks, logits, counts)
+        return _with_stats(mix, _outputs(cfg, lead, logits, mix.caches), 2)
+
+    return _jit(decode, hc, donate)
+
+
 def build_decode(cfg, donate):
     hc = cfg.hybrid
+    if hc.dec.diffusion:
+        return _build_denoise(cfg, donate)
 
     def decode(params, caches, toks, pos, tables, *rest):
         mix = _DecodeMix(hc, params, caches, pos, tables, rest,

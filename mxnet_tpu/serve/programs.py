@@ -694,12 +694,16 @@ TOK_TAKE, TOK_PUT, TOK_PUT1 = "tok_take", "tok_put", "tok_put1"
 def _build_token_program(kind, shardings=None):
     """``TOK_TAKE`` ``(pool, toks, src) -> toks``, ``TOK_PUT`` ``(pool,
     out) -> pool`` (a decode bucket's tokens to rows ``[0, bucket)``),
-    ``TOK_PUT1`` ``(pool, tok, at) -> pool`` (one prefill's token)."""
+    ``TOK_PUT1`` ``(pool, tok, at) -> pool`` (one prefill's token).  A
+    pool row is one token, or a diffusion decoder's block of them."""
     def tok_take(pool, toks, src):
-        return jnp.where(src >= 0, pool[jnp.maximum(src, 0)], toks)
+        keep = src >= 0
+        if toks.ndim > 1:            # a block a row
+            keep = keep[:, None]
+        return jnp.where(keep, pool[jnp.maximum(src, 0)], toks)
 
     def tok_put(pool, out):
-        return jax.lax.dynamic_update_slice(pool, out, (0,))
+        return jax.lax.dynamic_update_slice(pool, out, (0,) * pool.ndim)
 
     def tok_put1(pool, tok, at):
         return pool.at[at].set(tok)

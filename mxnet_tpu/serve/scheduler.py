@@ -113,6 +113,15 @@ class Request:
         self.flight_len = 0
         self.flight_tokens = 0
         self.flight_src = 0        # the unread token's row in the pool
+        # block diffusion (the engine sets ``block`` > 1): positions are
+        # committed a block at a time, a prefill covers whole blocks, and
+        # ``diffusion`` holds the current block's progress (the engine's)
+        self.block = 1
+        self.diffusion = None
+        # every (position, token, step) a denoise pass unmasked, committed
+        # blocks and the current one, in order, as the passes were read:
+        # what a check replays
+        self.unmasked = []
         self.cached_prefix_len = 0  # slots reused from the prefix cache
         # of cached_prefix_len, the slots restored host->device from
         # the DRAM offload tier (0 means all device-resident hits)
@@ -147,8 +156,18 @@ class Request:
         return self.prompt
 
     def target_len(self):
-        """Total sequence length when this request completes."""
-        return self.prompt.size + self.max_new_tokens
+        """Total sequence length when this request completes: positions
+        its K/V occupy, whole blocks under block diffusion (the last
+        block's tail is computed and dropped)."""
+        n = self.prompt.size + self.max_new_tokens
+        return -(-n // self.block) * self.block
+
+    def prefill_len(self):
+        """Positions the next prefill writes: :meth:`prefill_ids`, its
+        whole blocks only under block diffusion (the rest opens the
+        first block the denoise passes fill)."""
+        n = self.prefill_ids().size
+        return n - n % self.block
 
     def next_pos(self):
         """The position the next pass writes first: behind what is
@@ -157,7 +176,8 @@ class Request:
 
     def finishing(self):
         """Whether the unread pass emits this request's last token: a
-        request ends by length alone, so the host knows before it reads."""
+        request ends by length alone, so the host knows before it reads
+        (a diffusion request's tokens come with a store pass)."""
         return len(self.tokens) + self.flight_tokens >= self.max_new_tokens
 
     def ttft(self):
@@ -464,7 +484,7 @@ class Scheduler:
                 # they never need (and must never allocate — the block
                 # table has exactly max_model_len/block_size slots)
                 # real blocks
-                need = min(req.next_pos() + 1 + self.spec_slots,
+                need = min(req.next_pos() + self.ahead(req),
                            req.target_len())
                 try:
                     self.blocks.ensure_capacity(req.rid, need)
@@ -502,7 +522,7 @@ class Scheduler:
                 # same target_len() cap as the decode loop above (and
                 # ids.size + 1 <= target_len() always, so the cap can
                 # never starve the plain prompt+1 reservation)
-                need = min(ids.size + 1 + self.spec_slots,
+                need = min(req.prefill_len() + self.ahead(req),
                            req.target_len())
                 try:
                     # one call, one prefix walk: allocate prechecks the
@@ -543,7 +563,8 @@ class Scheduler:
                     self._rr_idx += 1    # rotation advances on ADMIT
                 req.status = RUNNING
                 chunked = (self.prefill_chunk > 0
-                           and ids.size - cached > self.prefill_chunk)
+                           and req.prefill_len() - cached
+                           > self.prefill_chunk)
                 self.trace.event(
                     req, "resumed" if req.n_preemptions else "admitted",
                     queue_depth=len(self.waiting),
@@ -628,6 +649,11 @@ class Scheduler:
                              generated=len(req.tokens))
             self.waiting.append(req)
             self.waiting.sort(key=lambda r: r.rid)   # arrival order
+
+    def ahead(self, req):
+        """Positions a decode pass of ``req`` writes, and so reserves:
+        its whole block under block diffusion, else ``1 + spec_slots``."""
+        return req.block if req.block > 1 else 1 + self.spec_slots
 
     def is_prefilling(self, req):
         """Whether ``req`` is mid-chunked-prefill (holds blocks and a
