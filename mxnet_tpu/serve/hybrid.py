@@ -67,12 +67,15 @@ and its own block, the block-causal mask.  The experts and the head run
 over the R * B positions; unmasking happens on the device
 (:func:`unmask`): each masked position's argmax without ``mask_id``, its
 confidence, and the ``counts[r]`` most confident of row r's masked
-positions take their tokens.  The program returns the new block ``(R,
-B)`` and which positions it unmasked; a block with no mask left goes
-through the same program with a count of 0 (the STORE pass: its K/V are
-the clean block's, its logits unused).  A span (prefill, chunk) over whole
-blocks runs under the block-causal mask (``masked_attention(...,
-block=B)``).
+positions take their tokens.  It reduces the head's ``(R * B, V)``
+logits where the head wrote them, in their own dtype: the confidence's
+float32 lives inside its sum over the vocabulary, never in a stored
+copy, and no V-wide array is relaid out to ``(R, B, V)``.  The program
+returns the new block ``(R, B)`` and which positions it unmasked; a
+block with no mask left goes through the same program with a count of 0
+(the STORE pass: its K/V are the clean block's, its logits unused).  A
+span (prefill, chunk) over whole blocks runs under the block-causal mask
+(``masked_attention(..., block=B)``).
 
 A **routed** feed-forward block (``ffn_types[i] == "moe"``) routes in
 float32 over all experts (``router_score``: a softmax, or sigmoids with
@@ -794,22 +797,36 @@ def _jit(fn, hc, donate):
                    donate_argnums=tuple(range(1, 1 + n)) if donate else ())
 
 
+def _confident(dec, logits):
+    """Each of the ``(R * B, V)`` logits' rows: its argmax without
+    ``mask_id`` and that token's softmax probability over the vocabulary
+    without ``mask_id`` (float32), both ``(R * B,)``.  The reductions
+    read the rows as the head wrote them: the max and argmax in their
+    own dtype (an upcast is exact and monotone), the sum with each term
+    upcast inside it, so the float32 lives in the reduction and no
+    float32 copy or ``(R, B, V)`` relayout is ever stored."""
+    lg = jnp.where(jnp.arange(logits.shape[-1]) == dec.mask_id,
+                   jnp.array(-np.inf, logits.dtype), logits)
+    top = jnp.max(lg, axis=-1, keepdims=True).astype(jnp.float32)
+    conf = 1.0 / jnp.sum(jnp.exp(lg.astype(jnp.float32) - top), axis=-1)
+    return jnp.argmax(lg, axis=-1), conf
+
+
 def unmask(dec, toks, logits, counts):
     """The greedy unmasking step of a denoise pass.  ``toks (R, B)``: the
     blocks as they went in; ``logits (R * B, V)``; ``counts (R,)``: how
     many masked positions each row unmasks.  A position's candidate is
     its argmax without ``mask_id``, its confidence that token's softmax
-    probability over the vocabulary without ``mask_id`` (float32); the
+    probability over the vocabulary without ``mask_id`` (float32, inside
+    the reduction over the logits as they are: :func:`_confident`); the
     ``counts[r]`` most confident masked positions of row r (ties to the
-    lower position) take their candidates.  Returns ``(block (R, B),
+    lower position) take their candidates.  Only the ``(R * B,)``
+    results are reshaped to ``(R, B)``.  Returns ``(block (R, B),
     unmasked (R, B) bool)``."""
     R, B = toks.shape
-    lg = logits.astype(jnp.float32).reshape(R, B, -1)
-    lg = jnp.where(jnp.arange(lg.shape[-1]) == dec.mask_id,
-                   np.float32(-np.inf), lg)
-    cand = jnp.argmax(lg, axis=-1).astype(toks.dtype)
-    top = jnp.max(lg, axis=-1, keepdims=True)
-    conf = 1.0 / jnp.sum(jnp.exp(lg - top), axis=-1)           # (R, B)
+    cand, conf = _confident(dec, logits)
+    cand = cand.astype(toks.dtype).reshape(R, B)
+    conf = conf.reshape(R, B)
     masked = toks == dec.mask_id
     score = jnp.where(masked, conf, np.float32(-1.0))
     at = jnp.arange(B)

@@ -12,8 +12,10 @@ within a stated tolerance, tokens and unmask order equal in float32);
 committed K/V against the clean block's; the step loop at depth 1
 against depth 0; what the engine refuses.  Counts and values only."""
 
+import collections
 import os
 import sys
+import types
 
 import numpy as np
 import pytest
@@ -203,6 +205,126 @@ def test_paged_kernel_takes_a_block_a_row():
                     ctx[r] - B + b, B, scale)[0].reshape(Hkv, G, Dh)
                 np.testing.assert_allclose(got[r, :, b], want, rtol=2e-4,
                                            atol=2e-5)
+
+
+# -- the unmasking step --------------------------------------------------------
+
+UR, UB, UV, UMASK = 8, 4, 1021, 700
+
+
+def _unmask_as_before(mask_id, toks, logits, counts):
+    """The reference unmasking step: the logits upcast whole to float32
+    and relaid out to ``(R, B, V)`` before every reduction.  Returns
+    ``(block, unmasked, conf (R, B))``."""
+    R, B = toks.shape
+    lg = logits.astype(jnp.float32).reshape(R, B, -1)
+    lg = jnp.where(jnp.arange(lg.shape[-1]) == mask_id,
+                   np.float32(-np.inf), lg)
+    cand = jnp.argmax(lg, axis=-1).astype(toks.dtype)
+    top = jnp.max(lg, axis=-1, keepdims=True)
+    conf = 1.0 / jnp.sum(jnp.exp(lg - top), axis=-1)
+    masked = toks == mask_id
+    score = jnp.where(masked, conf, np.float32(-1.0))
+    at = jnp.arange(B)
+    ahead = jnp.logical_or(
+        score[:, None, :] > score[:, :, None],
+        jnp.logical_and(score[:, None, :] == score[:, :, None],
+                        (at[None, :] < at[:, None])[None]))
+    rank = jnp.sum(ahead, axis=-1)
+    fresh = jnp.logical_and(masked, rank < counts[:, None])
+    return jnp.where(fresh, cand, toks), fresh, conf
+
+
+def _unmask_inputs(case, dtype, seed=11):
+    """Blocks, logits ``(R * B, V)`` of spread 4 and counts 0 .. B over
+    the rows; row 7 has no masked position left, rows 0-3 are all mask.
+    ``case`` plants: the mask id on top of every position of a row, an
+    exact tie between two tokens of one position, or two masked
+    positions of one row with the same logits (so the same
+    confidence)."""
+    rng = np.random.RandomState(seed)
+    lg = (rng.randn(UR * UB, UV) * 4.0).astype(np.float32)
+    toks = rng.randint(0, UMASK, size=(UR, UB)).astype(np.int32)
+    toks[:4] = UMASK
+    toks[4:7, 1:] = UMASK
+    counts = (np.arange(UR) % (UB + 1)).astype(np.int32)
+    if case == "mask_on_top":
+        lg[UB:2 * UB, UMASK] = 40.0
+    elif case == "token_tie":
+        for p in range(UR * UB):
+            lg[p, [17, 900]] = 24.0             # exact in bfloat16 too
+    elif case == "conf_tie":
+        for r in range(UR):
+            lg[r * UB + 3] = lg[r * UB + 1]
+    return (jnp.asarray(toks), jnp.asarray(lg).astype(dtype),
+            jnp.asarray(counts))
+
+
+@pytest.mark.parametrize("case", ["plain", "mask_on_top", "token_tie",
+                                  "conf_tie"])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_unmask_equals_the_relaid_out_float32_form(case, dtype):
+    """Reducing the head's rows where they are gives the tokens and
+    unmasked positions of the upcast ``(R, B, V)`` form, and its
+    confidences to the last ulps of the sum's order."""
+    dec = types.SimpleNamespace(mask_id=UMASK)
+    toks, lg, counts = _unmask_inputs(case, dtype)
+    want_block, want_fresh, want_conf = _unmask_as_before(
+        UMASK, toks, lg, counts)
+    block, fresh = jax.jit(lambda t, l, c: hybrid_mod.unmask(dec, t, l, c))(
+        toks, lg, counts)
+    cand, conf = hybrid_mod._confident(dec, lg)
+    np.testing.assert_array_equal(np.asarray(block), np.asarray(want_block))
+    np.testing.assert_array_equal(np.asarray(fresh), np.asarray(want_fresh))
+    np.testing.assert_allclose(np.asarray(conf).reshape(UR, UB),
+                               np.asarray(want_conf), rtol=1e-6, atol=0)
+    assert np.asarray(cand).max() < UV and not (np.asarray(cand)
+                                                == UMASK).any()
+    # what the planted cases must show, so none of them passes vacuously
+    fresh = np.asarray(fresh)
+    assert fresh.sum(-1).tolist() == [0, 1, 2, 3, 3, 0, 1, 0]
+    if case == "mask_on_top":
+        assert (np.asarray(lg[UB:2 * UB]).argmax(-1) == UMASK).all()
+    if case == "token_tie":
+        assert (np.asarray(cand) == 17).all()
+    if case == "conf_tie":
+        conf = np.asarray(conf).reshape(UR, UB)
+        assert (conf[:, 3] == conf[:, 1]).all()
+        # positions 1 and 3 tie: 3 never goes before 1
+        assert not (fresh[:, 3] & ~fresh[:, 1]).any()
+
+
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs nested in it."""
+    for e in jaxpr.eqns:
+        yield e
+        for sub in jax.core.jaxprs_in_params(e.params):
+            yield from _eqns(sub)
+
+
+def test_unmask_reduces_the_logits_where_the_head_wrote_them():
+    """No V-wide reshape; every reduction over the vocabulary takes the
+    2-D ``(R * B, V)`` logits, the max and argmax in their own dtype.
+    (XLA fuses the float32 conversion that feeds the sum into the sum:
+    whether a copy is stored is read off the chip's trace.)"""
+    dec = types.SimpleNamespace(mask_id=UMASK)
+    toks, lg, counts = _unmask_inputs("plain", jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(lambda t, l, c: hybrid_mod.unmask(dec, t, l, c))(
+        toks, lg, counts).jaxpr
+    seen = collections.Counter()
+    for e in _eqns(jaxpr):
+        ins = [v.aval for v in e.invars if hasattr(v.aval, "shape")]
+        wide = [a for a in ins if UV in a.shape]
+        name = e.primitive.name
+        assert not (name == "reshape" and wide), e
+        if name in ("argmax", "reduce_max", "reduce_sum") and wide:
+            (a,) = wide
+            assert a.shape == (UR * UB, UV), e
+            if name != "reduce_sum":
+                assert a.dtype == jnp.bfloat16, e
+            seen[name] += 1
+    assert seen == {"argmax": 1, "reduce_max": 1, "reduce_sum": 1}
 
 
 # -- the engine against the reference ------------------------------------------------
